@@ -22,8 +22,8 @@ Two phases, guarded by ``ray_tpu.bench_check``:
 
       - ``pp_decode_tok_s_dynamic`` / ``pp_decode_tok_s_compiled``
 
-    On hosts whose jax cannot run the pp shard_map programs (< 2
-    devices, or no ``jax.shard_map``) the phase records
+    On hosts that cannot run the pp shard_map programs (< 2
+    devices) the phase records
     ``pp_decode_*_skipped`` markers instead — ``bench_check`` treats the
     absence as intentional, never as a silent regression.
 
@@ -231,11 +231,6 @@ def _recorder_cost_s(cfg) -> float:
 def _bench_pp_decode(out: dict, bursts: int) -> None:
     """Debug-model pp=2 decode through the sharded engine, dynamic vs
     compiled loop. Records skip markers when the host can't run pp."""
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        raise RuntimeError("jax.shard_map unavailable (needs jax >= 0.6)")
-
     from ray_tpu.llm import InferenceEngine, create_sharded_executor
     from ray_tpu.llm.engine import Request
 

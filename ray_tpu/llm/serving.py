@@ -189,11 +189,10 @@ class LLMDeployment:
             # the 4/8 chips of a TPU host): tp runs the same programs
             # SPMD with XLA collectives over ICI; pp stages layers with
             # ppermute activation rotation (llm/pp_model.py).
-            import jax
-
             from ..parallel import MeshConfig, create_mesh
+            from ..tpu import leased_devices
 
-            n = len(jax.devices())
+            n = len(leased_devices())
             mesh = create_mesh(MeshConfig(
                 tp=tensor_parallel, pp=pipeline_parallel,
                 dp=max(1, n // (tensor_parallel * pipeline_parallel))))
@@ -835,7 +834,11 @@ class LLMDeployment:
         }]}
 
     def engine_metrics(self) -> dict:
+        from ..tpu import device_report
+
         return {**self.engine.metrics,
+                "attention_impl": self.engine.attention_impl,
+                "device": device_report(),
                 "prefix_cache_hit_rate": self.engine.prefix_cache_hit_rate,
                 "prefill_suffix_frac": self.engine.prefill_suffix_frac,
                 "mixed_dispatch_enabled": self.engine.mixed_dispatch_enabled,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -172,6 +173,24 @@ def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
     if config.attn_impl == "none":  # ablation: identity attention
         g = q.shape[1] // k.shape[1]
         return (q.reshape(q.shape[0], k.shape[1], g, *q.shape[2:]) * v[:, :, None]).reshape(q.shape)
+    batch_axes = ("dcn", "dp", "fsdp")
+    if mesh is not None and mesh.size > 1:
+        n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+        tp = mesh.shape["tp"]
+        if not (q.shape[0] % n_batch or q.shape[1] % tp or k.shape[1] % tp):
+            # The compiler cannot partition a Mosaic kernel by itself
+            # ("wrap the call in a shard_map"): run it per shard, batch
+            # rows over the data axes and kv-head groups over tp — each
+            # is independent in attention, so nothing is exchanged.
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            spec = P(batch_axes, "tp", None, None)
+            return shard_map(
+                functools.partial(flash_attention, causal=True),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False,
+            )(q, k, v)
     return flash_attention(q, k, v, causal=True)
 
 

@@ -12,7 +12,7 @@
 // Concurrency: the embedding process serializes calls (Python side holds a
 // lock); no internal locking needed beyond what the single writer provides.
 //
-// Build: g++ -O2 -shared -fPIC -o libshm_store.so shm_store.cc
+// Build: native/store.py ensure_built() (g++ -O2 -std=c++17 -shared -fPIC)
 
 #include <sys/mman.h>
 #include <sys/stat.h>

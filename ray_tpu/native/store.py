@@ -10,35 +10,59 @@ exposes a thread-safe :class:`ShmStore` owner handle plus a lightweight
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import mmap
 import os
 import subprocess
+import tempfile
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "shm_store.cc")
-_LIB = os.path.join(_DIR, "libshm_store.so")
 
 _lib_handle = None
 _lib_lock = threading.Lock()
 
 
-def _build_if_needed() -> str:
-    if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+def ensure_built() -> tuple[str, bool]:
+    """``(path, built_now)`` of the library for THIS source. The file
+    name carries a digest of ``shm_store.cc``, so a stale or copied
+    binary of other source is never loaded (an mtime comparison is
+    fooled by a copy), and the build goes to a temporary name and is
+    renamed into place, so concurrent first imports — every worker of a
+    fresh checkout — each see either no file or a whole one."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    lib = os.path.join(_DIR, f"libshm_store.{digest}.so")
+    if os.path.exists(lib):
+        return lib, False
+    fd, tmp = tempfile.mkstemp(prefix="libshm_store.", suffix=".so.tmp", dir=_DIR)
+    os.close(fd)
+    try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _LIB, _SRC],
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True,
             capture_output=True,
         )
-    return _LIB
+        os.replace(tmp, lib)
+    finally:
+        with contextlib.suppress(FileNotFoundError):
+            os.unlink(tmp)  # still there only if the build failed
+    for old in glob.glob(os.path.join(_DIR, "libshm_store*.so")):
+        if old != lib:
+            with contextlib.suppress(OSError):
+                os.unlink(old)  # binaries of earlier source
+    return lib, True
 
 
 def _load():
     global _lib_handle
     with _lib_lock:
         if _lib_handle is None:
-            lib = ctypes.CDLL(_build_if_needed())
+            lib = ctypes.CDLL(ensure_built()[0])
             u64, u32, u8p = ctypes.c_uint64, ctypes.c_uint32, ctypes.POINTER(ctypes.c_uint8)
             vp, i32 = ctypes.c_void_p, ctypes.c_int
             lib.store_create.restype = vp

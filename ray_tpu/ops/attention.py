@@ -21,6 +21,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..tpu import on_tpu
+from .trace_log import note_kernel_trace
+
 NEG_INF = -1e30
 
 
@@ -135,8 +138,10 @@ def _flash_forward(
     # fallback for shapes the TPU tiling can't take: ragged blocks or blocks
     # not multiple of the bf16 sublane tile (16)
     if sq % block_q or sk % block_k or block_q % 16 or block_k % 16:
+        note_kernel_trace("flash_attention", "mha_reference")
         o = mha_reference(q, k, v, causal=causal, sm_scale=scale)
         return (o, None) if save_residuals else o
+    note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
     n_q, n_k = sq // block_q, sk // block_k
 
     grid = (b, hq, n_q, n_k)
@@ -469,5 +474,5 @@ def flash_attention(
     blocks exceed the 16 MiB scoped-VMEM stack limit.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     return _make_flash(causal, sm_scale, block_q, block_k, interpret)(q, k, v)

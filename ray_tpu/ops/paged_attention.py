@@ -88,6 +88,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..tpu import on_tpu
+from .trace_log import note_kernel_trace
+
 NEG_INF = -1e30
 
 # Staging rows are the kernel block's sublane dim: keep them a multiple
@@ -265,8 +268,9 @@ def paged_decode_attention(
                   ``llm/executor.py`` already gives them), each shard
                   runs the kernel on its local heads, and nothing is
                   gathered — attention is embarrassingly parallel over
-                  KV heads. Manual over {"tp"} only, so other mesh axes
-                  stay auto-partitioned. Requires ``KH %% tp == 0``
+                  KV heads. Manual over every mesh axis (Mosaic demands
+                  it); operands are replicated over the axes the specs
+                  do not name. Requires ``KH %% tp == 0``
                   (enforced by the executor). Used by PURE-tp meshes
                   only: pp meshes — composed pp×tp included — call the
                   kernel with ``mesh=None`` from inside
@@ -277,7 +281,9 @@ def paged_decode_attention(
     Returns [slots, KH, G, D] in q.dtype.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
+    note_kernel_trace("paged_decode_attention",
+                      "interpret" if interpret else "pallas")
     squeeze_layer = k_pages.ndim == 4
     if squeeze_layer:
         k_pages = k_pages[None]
@@ -388,14 +394,13 @@ def paged_decode_attention(
         return out[:, :, :g] if gp != g else out
 
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
-        # Manual over tp ONLY (other axes stay auto): every shard runs
-        # the identical kernel on its KV-head slice of q/pool/staging —
-        # no collectives, attention is independent per KV head. This is
-        # what lifts the old "paged is single-device only" refusal.
-        if not hasattr(jax, "shard_map"):  # pragma: no cover - old jax
-            raise NotImplementedError(
-                "attention_impl='paged' over a tp mesh needs jax.shard_map "
-                "(jax >= 0.6); use attention_impl='dense'")
+        # Every shard runs the identical kernel on its KV-head slice of
+        # q/pool/staging — no collectives, attention is independent per
+        # KV head. Manual over EVERY mesh axis: Mosaic refuses a kernel
+        # under a region that leaves any axis to the partitioner ("cannot
+        # be automatically partitioned"), even one of size 1; the specs
+        # name tp only, so the other axes see replicated operands, which
+        # is what the engine gives them.
         if kh % mesh.shape["tp"]:
             raise ValueError(
                 f"n_kv_heads={kh} not divisible by tp={mesh.shape['tp']}")
@@ -407,7 +412,6 @@ def paged_decode_attention(
             in_specs=(heads, P(), P(), P(), P(), stacked, stacked,
                       stacked, stacked),
             out_specs=heads,
-            axis_names=frozenset({"tp"}),
             check_vma=False,
         )
         return fn(q, block_tables, base, sl, layer, k_stage, v_stage,

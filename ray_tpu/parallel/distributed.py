@@ -40,14 +40,11 @@ def initialize_process(coordinator: str, num_processes: int, process_id: int) ->
     import jax
 
     if num_processes > 1:
-        try:
-            import os
+        import os
 
-            if os.environ.get("JAX_PLATFORMS", "").startswith("cpu") or (
-                    jax.config.jax_platforms or "").startswith("cpu"):
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jaxlib without gloo: TPU/real backends don't need it
+        if os.environ.get("JAX_PLATFORMS", "").startswith("cpu") or (
+                jax.config.jax_platforms or "").startswith("cpu"):
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator,
             num_processes=num_processes,

@@ -32,6 +32,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from ..tpu import leased_devices
+
 AXIS_ORDER = ("dcn", "pp", "dp", "fsdp", "sp", "ep", "tp")
 # tp innermost: tensor-parallel collectives are per-layer and latency-bound,
 # so they must ride the fastest ICI links (adjacent devices); dcn/pp/dp
@@ -94,7 +96,7 @@ def create_mesh(
     the dcn axis is aligned to slice boundaries (hybrid mesh) so only its
     per-step gradient sync crosses the data-center network.
     """
-    devices = list(devices if devices is not None else jax.devices())
+    devices = list(devices if devices is not None else leased_devices())
     config = mesh_shape_for(len(devices), config)
     sizes = config.sizes()
     shape = tuple(sizes[a] for a in AXIS_ORDER)

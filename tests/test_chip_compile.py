@@ -1,0 +1,96 @@
+"""The main path's Pallas kernels, compiled by the chip's own compiler.
+
+The TPU compiler is installed here and compiles for a chip that is
+described and not attached (``v5e:2x2``), so what Mosaic refuses — a slice
+off the tiling, too much VMEM — fails here at no chip time, which
+interpret-mode tests cannot show. Nothing runs: shapes only. ``interpret``
+is passed explicitly because this process's backend is the CPU, so the
+kernels' own default would pick the interpreter.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.paged_attention import paged_decode_attention
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described v5e:2x2, persistent cache off: a program
+    compiled for a described chip is written to it but cannot be read
+    back without one, and the next compile warns."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash(chip, b, hq, hkv, s, d, backward):
+    q = jax.ShapeDtypeStruct((b, hq, s, d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, hkv, s, d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    return jax.jit(fn).lower(q, kv, kv)
+
+
+def _paged_staging(chip, layers, kh, g, d, page, slots=8, max_len=2560, k_steps=32,
+                   live_pages=8):
+    """The engine's decode call: layer-stacked pool, staging rows of the
+    fused dispatch, at the smoke's serve geometry."""
+    pages_per_seq = max_len // page
+    num_pages = slots + slots * pages_per_seq
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    q = sd((slots, kh, g, d))
+    pool = sd((layers, num_pages, kh, page, d))
+    stage = sd((layers, slots, kh, k_steps, d))
+    tables = sd((slots, pages_per_seq), jnp.int32)
+    pos = sd((slots,), jnp.int32)
+    scalar = sd((), jnp.int32)
+
+    def fn(q, kp, vp, bt, pos, ks, vs, idx, layer):
+        return paged_decode_attention(
+            q, kp, vp, bt, pos, page_size=page, live_pages=live_pages,
+            layer=layer, k_stage=ks, v_stage=vs, stage_idx=idx,
+            interpret=False)
+
+    return jax.jit(fn).lower(q, pool, pool, tables, pos, stage, stage, scalar, scalar)
+
+
+CASES = {
+    # llama3-1b widths: 32 q / 8 kv heads of 64, the train batch
+    "flash-fwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=False),
+    "flash-bwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=True),
+    "paged-staging-1b": lambda c: _paged_staging(c, 16, 8, 4, 64, 64),
+    # head_dim 128 (llama3-8b widths)
+    "flash-fwd-d128": lambda c: _flash(c, 4, 32, 8, 2048, 128, backward=False),
+    "flash-bwd-d128": lambda c: _flash(c, 4, 32, 8, 2048, 128, backward=True),
+    "paged-staging-d128": lambda c: _paged_staging(c, 32, 8, 4, 128, 64),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_the_chip(chip, case):
+    program = CASES[case](chip).compile().as_text()
+    assert "tpu_custom_call" in program

@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from conftest import requires_shard_map
 from ray_tpu.models import LlamaConfig, PRESETS, forward, init_params, loss_fn, param_axes
 from ray_tpu.parallel import MeshConfig, create_mesh
 from ray_tpu.parallel.sharding import shard_params
@@ -59,7 +58,6 @@ def test_sharded_train_step_on_mesh():
     assert all(bool(jnp.isfinite(g).all()) for g in flat)
 
 
-@requires_shard_map
 def test_ring_attention_model_matches_flash():
     mesh = create_mesh(MeshConfig(dp=2, sp=4))
     base = PRESETS["debug-128"]

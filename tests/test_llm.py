@@ -20,7 +20,6 @@ import pytest
 
 from ray_tpu.llm.engine import InferenceEngine, Request
 from ray_tpu.models.llama import PRESETS, forward, init_params
-from conftest import requires_shard_map
 
 
 @pytest.fixture(scope="module")
@@ -349,7 +348,6 @@ def test_tensor_parallel_engine_parity(small_model):
                         max_slots=2, max_len=64, page_size=8)
 
 
-@requires_shard_map
 def test_pipeline_parallel_engine_parity(small_model):
     """The engine staged over a pp mesh (layers AND the page pool sharded
     by stage, activations rotating via ppermute, decode pipelined over
@@ -476,7 +474,6 @@ def test_lora_mixed_batch_matches_merged_weights(small_model, tmp_path):
     assert ad1_toks != ad2_toks  # the adapters actually do something
 
 
-@requires_shard_map
 def test_lora_pp_decode_parity(small_model, tmp_path):
     """LoRA over a PIPELINE mesh (round 8): the adapter stacks shard over
     pp on their layer axis like the params, prefill carries the adapter
@@ -571,7 +568,6 @@ def test_lora_openai_route(small_model, tmp_path):
         dep.close()
 
 
-@requires_shard_map
 def test_tp_pp_composed_engine_parity(small_model):
     """TP x PP inference: layers staged over pp with tp auto-partitioned
     INSIDE each stage (partial-manual shard_map, axis_names={"pp"}) must
@@ -593,7 +589,6 @@ def test_tp_pp_composed_engine_parity(small_model):
     assert got == expected
 
 
-@requires_shard_map
 def test_pp_chunk_pipelined_prefill_parity(small_model):
     """Long prompts prefill as a chunk WAVEFRONT through the pp stages
     (pp_model.pp_prefill_chunks): up to pp consecutive full-size chunks
@@ -858,7 +853,6 @@ def test_engine_cached_vs_cold_greedy_parity(small_model):
     assert cold.metrics["prefix_cached_tokens"] == 0
 
 
-@requires_shard_map
 def test_pp_partial_block_cow_parity(small_model):
     """Round 15 (PR 10 residue a): pp engines admit PARTIAL-block prefix
     hits. The pp prefill scatters rows at (page, offset) granularity, so

@@ -20,7 +20,6 @@ import ray_tpu
 from ray_tpu.llm import InferenceEngine, create_sharded_executor
 from ray_tpu.llm.serving import LLMDeployment
 from ray_tpu.models.llama import PRESETS
-from conftest import requires_shard_map
 
 # Each shard process sees exactly one local CPU device; two shards form
 # the 2-device global mesh.
@@ -93,7 +92,6 @@ def test_multihost_compiled_loop_token_parity(ray_cluster, small_cfg):
         executor.shutdown()
 
 
-@requires_shard_map
 def test_multihost_pp_token_parity(ray_cluster, small_cfg):
     """Pipeline parallelism across hosts: 2 shard processes × 1 device
     each form a pp=2 mesh — each host holds HALF the layers and half the

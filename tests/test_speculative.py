@@ -20,7 +20,6 @@ import pytest
 from ray_tpu.llm.engine import InferenceEngine, Request
 from ray_tpu.llm.speculative import Drafter, NgramDrafter, SpeculationConfig
 from ray_tpu.models.llama import PRESETS, init_params
-from conftest import requires_shard_map
 
 
 @pytest.fixture(scope="module")
@@ -369,7 +368,6 @@ def test_concurrent_adds_during_speculation(small_model):
 
 
 # ----------------------------------------------- multihost / compiled loop
-@requires_shard_map
 def test_multihost_compiled_loop_speculative_parity(ray_cluster):
     """The verify fan-out through BOTH sharded dispatch modes — dynamic
     actor calls and the compiled-loop channel (one resident tick
