@@ -254,7 +254,16 @@ def main(argv: list[str] | None = None) -> int:
         "profile", help="capture an on-demand jax.profiler trace on a worker")
     prof_p.add_argument("--node", default=None,
                         help="node id prefix (default: the driver's node)")
-    prof_p.add_argument("--worker", default=None, help="specific worker id")
+    prof_p.add_argument("--worker", default=None,
+                        help="worker id prefix (default: the worker whose "
+                             "lease holds TPU chips, else one of the node)")
+    prof_p.add_argument("--actor", default=None,
+                        help="trace the worker of this named actor "
+                             "(name, part of it, or actor id prefix)")
+    prof_p.add_argument("--summary", action="store_true",
+                        help="print the capture's reduction: busy/idle per "
+                             "device, top ops and kernels, program spans, "
+                             "idle gaps by span")
     prof_p.add_argument("--duration", type=float, default=5.0,
                         help="capture length in seconds")
     prof_p.add_argument("--list", action="store_true", dest="list_profiles",
@@ -750,10 +759,21 @@ def main(argv: list[str] | None = None) -> int:
                 _print_table(rows, ["path", "node_id", "worker_id", "duration"])
             return 0
         reply = st.capture_profile(node_id=args.node, duration=args.duration,
-                                   worker_id=args.worker)
+                                   worker_id=args.worker, actor=args.actor,
+                                   summary=args.summary)
         if reply.get("error"):
             print(f"error: {reply['error']}", file=sys.stderr)
             return 1
+        if args.summary and not args.as_json:
+            from .observability import profile
+
+            if "summary" in reply:
+                print(profile.render(reply["summary"]))
+            else:  # the capture is there; read it on its node
+                print(f"no summary ({reply.get('summary_error')}); on node "
+                      f"{reply.get('node_id', '')[:12]}: python -m "
+                      f"ray_tpu.observability.profile {reply['path']} --text",
+                      file=sys.stderr)
         print(json.dumps(reply, indent=2, default=str) if args.as_json
               else f"wrote {reply['path']} (worker {reply.get('worker_id', '')[:12]}, "
                    f"{reply.get('duration')}s) — open with XProf/TensorBoard")

@@ -2619,6 +2619,8 @@ class Raylet:
             return {"error": "no reachable worker on node "
                              f"{self.node_id.hex()[:8]}"
                              + (f" matching worker_id {target_id}" if target_id else "")}
+        from ..observability.profile import limit_s as profile_limit_s
+
         target = candidates[0]
         duration = float(p.get("duration", 2.0))
         outdir = os.path.join(self._session_dir, "profiles")
@@ -2627,7 +2629,7 @@ class Raylet:
             reply = await client.call(
                 "CaptureProfile",
                 {"duration": duration, "output_dir": outdir},
-                timeout=duration + 120.0)
+                timeout=duration + profile_limit_s(duration))
         except Exception as e:
             return {"error": f"worker {target.worker_id[:12]} capture failed: {e}"}
         finally:
@@ -2647,6 +2649,23 @@ class Raylet:
                 pass
             reply.setdefault("node_id", self.node_id.hex())
         return reply
+
+    async def handle_SummarizeProfile(self, p: dict) -> dict:
+        """Reduce a capture of this node (``observability/profile.py``) in a
+        child process, for at most ``timeout`` seconds. A step of its own,
+        after ``CaptureProfile`` has replied and registered the artifact, so
+        that a reading that fails or outlasts its limit loses nothing else."""
+        from ..observability import profile
+
+        root = os.path.realpath(os.path.join(self._session_dir, "profiles"))
+        path = os.path.realpath(p.get("path") or "")
+        if os.path.commonpath([root, path]) != root or not os.path.isdir(path):
+            return {"error": f"{p.get('path')!r} is not a capture of this node"}
+        try:
+            return {"summary": await asyncio.get_running_loop().run_in_executor(
+                None, profile.summarize_apart, path, float(p["timeout"]))}
+        except Exception as e:
+            return {"error": f"{type(e).__name__}: {e}"}
 
     async def handle_DebugState(self, p: dict) -> dict:
         return {

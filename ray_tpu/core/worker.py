@@ -2259,12 +2259,18 @@ class CoreWorker:
         return {"summary": self.memory_summary(p.get("limit"))}
 
     async def handle_CaptureProfile(self, p: dict) -> dict:
-        """On-demand ``jax.profiler`` trace capture (reference: `ray timeline`
-        + the dashboard profiler button): runs start_trace/stop_trace around
-        a sleep in an executor thread and returns the artifact directory
-        (xplane.pb + trace.json.gz, loadable in XProf/Perfetto)."""
+        """On-demand ``jax.profiler`` trace capture of THIS worker
+        (reference: `ray timeline` + the dashboard profiler button), in an
+        executor thread. The window is the capture's own ``capture_window``
+        event, which carries ``time.time()`` and ``time.monotonic()`` at its
+        start (``observability/profile.py``), so wall-clock spans map onto
+        the trace. Returns the artifact directory (xplane.pb, loadable in
+        XProf/Perfetto) as soon as the capture ends; reading it is the
+        raylet's ``SummarizeProfile``, a second step in another process."""
         import asyncio
         import tempfile
+
+        from ..observability import profile
 
         cfg = get_config()
         duration = min(float(p.get("duration", 2.0)), cfg.profile_max_duration_s)
@@ -2276,18 +2282,9 @@ class CoreWorker:
                 return {"error": "a profile capture is already in progress"}
             self._profiling = True
 
-        def _capture() -> None:
-            import jax
-
-            os.makedirs(path, exist_ok=True)
-            jax.profiler.start_trace(path)
-            try:
-                time.sleep(duration)
-            finally:
-                jax.profiler.stop_trace()
-
         try:
-            await asyncio.get_running_loop().run_in_executor(None, _capture)
+            await asyncio.get_running_loop().run_in_executor(
+                None, profile.capture, path, duration)
         except Exception as e:
             return {"error": f"{type(e).__name__}: {e}"}
         finally:

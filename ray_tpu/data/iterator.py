@@ -12,7 +12,19 @@ from typing import Iterator
 import numpy as np
 
 from ..core import api as ray
+from ..observability.tracing import annotate
 from .block import BlockAccessor, concat_blocks
+
+_END = object()
+
+
+def _batch_size_of(batch) -> tuple[int, int]:
+    """(rows, bytes) of a batch, for a span's attributes; a numpy batch is
+    a dict of arrays, other formats count rows only."""
+    if isinstance(batch, dict):
+        arrays = [np.asarray(v) for v in batch.values()]
+        return (len(arrays[0]) if arrays else 0), sum(a.nbytes for a in arrays)
+    return getattr(batch, "num_rows", len(batch)), 0
 
 
 def batches_from_blocks(
@@ -131,12 +143,30 @@ class DataIterator:
                 return
             yield ray.get(ref, timeout=120)
 
-    def iter_batches(self, *, batch_size: int | None = 256,
-                     batch_format: str = "numpy", drop_last: bool = False):
-        return batches_from_blocks(
+    def _sized_batches(self, *, batch_size, batch_format, drop_last):
+        """(batch, rows, bytes): sized once, for this span and the next."""
+        batches = batches_from_blocks(
             self._blocks(), batch_size=batch_size,
             batch_format=batch_format, drop_last=drop_last,
         )
+        while True:
+            # what the consumer waits for: its blocks (from the
+            # coordinator and the object store) and their conversion
+            with annotate("data.next_batch") as span:
+                batch = next(batches, _END)
+                if batch is not _END:
+                    rows, nbytes = _batch_size_of(batch)
+                    span.set_metadata(rows=rows, bytes=nbytes)
+            if batch is _END:
+                return
+            yield batch, rows, nbytes
+
+    def iter_batches(self, *, batch_size: int | None = 256,
+                     batch_format: str = "numpy", drop_last: bool = False):
+        for batch, _, _ in self._sized_batches(
+                batch_size=batch_size, batch_format=batch_format,
+                drop_last=drop_last):
+            yield batch
 
     def iter_rows(self):
         for block in self._blocks():
@@ -149,11 +179,12 @@ class DataIterator:
         import jax
 
         prev = None
-        for batch in self.iter_batches(batch_size=batch_size,
-                                       batch_format=batch_format,
-                                       drop_last=drop_last):
+        for batch, rows, nbytes in self._sized_batches(
+                batch_size=batch_size, batch_format=batch_format,
+                drop_last=drop_last):
             arrs = {k: np.asarray(v) for k, v in batch.items()}
-            cur = jax.device_put(arrs, sharding) if sharding else jax.device_put(arrs)
+            with annotate("data.device_put", rows=rows, bytes=nbytes):
+                cur = jax.device_put(arrs, sharding) if sharding else jax.device_put(arrs)
             if prev is not None:
                 yield prev
             prev = cur

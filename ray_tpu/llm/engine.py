@@ -32,6 +32,7 @@ import numpy as np
 
 from ..models.llama import LlamaConfig, PRESETS
 from ..observability import loop_recorder
+from ..observability.tracing import annotate
 from .executor import LocalEngineExecutor
 
 logger = logging.getLogger(__name__)
@@ -60,6 +61,8 @@ class Request:
     lora_slot: int = 0
     arrived_at: float = field(default_factory=time.monotonic)
     arrived_wall: float = field(default_factory=time.time)
+    admitted_at: float | None = None   # monotonic, when a slot was taken
+    prefill_chunks: int = 0            # chunks its prompt was prefilled in
     first_token_at: float | None = None
     first_token_wall: float | None = None
     cached_prefix_tokens: int = 0
@@ -496,6 +499,14 @@ class InferenceEngine:
                         "cow_forks": 0,
                         "prefill_chunks": 0,
                         "decode_steps": 0, "decode_dispatches": 0,
+                        # Where a step's time goes, always on: engine steps
+                        # that had work, their wall time split into the
+                        # wait for device results (the executor's
+                        # ``engine.sync``) and everything else on the host;
+                        # and arrival-to-admission wait per admitted request.
+                        "steps": 0, "step_host_ms_sum": 0.0,
+                        "step_sync_ms_sum": 0.0,
+                        "queue_wait_ms_sum": 0.0, "queue_wait_count": 0,
                         # Per-step schedule mix: how many engine steps ran
                         # fused prefill+decode vs either alone (plus
                         # first-token flush-only steps).
@@ -812,6 +823,22 @@ class InferenceEngine:
 
         Returns emission events ``{"request_id", "token", "done",
         "finish_reason"}``."""
+        with self._lock:
+            counts = (len(self._waiting), len(self._prefilling), len(self._active))
+            busy = bool(any(counts) or self._pending_first)
+        if not busy:
+            return self._step()
+        t0, sync0 = time.monotonic(), self.executor.sync_s
+        with annotate("engine.step", waiting=counts[0], prefilling=counts[1],
+                      active=counts[2]):
+            events = self._step()
+        sync_ms = (self.executor.sync_s - sync0) * 1e3
+        self.metrics["steps"] += 1
+        self.metrics["step_sync_ms_sum"] += sync_ms
+        self.metrics["step_host_ms_sum"] += (time.monotonic() - t0) * 1e3 - sync_ms
+        return events
+
+    def _step(self) -> list[dict]:
         if self.has_work:
             # Belt-and-braces for a demote racing admission: no dispatch
             # ever runs against executor.params=None.
@@ -926,6 +953,16 @@ class InferenceEngine:
         return []
 
     def _admit(self) -> None:
+        with annotate("engine.admit") as span:
+            admitted = self._admit_waiting()
+            span.set_metadata(admitted=len(admitted))
+        now = time.monotonic()
+        for r in admitted:
+            r.admitted_at = now
+            self.metrics["queue_wait_ms_sum"] += (now - r.arrived_at) * 1e3
+            self.metrics["queue_wait_count"] += 1
+
+    def _admit_waiting(self) -> list[Request]:
         admitted: list[Request] = []
         with self._lock:
             while self._waiting and self._free_slots:
@@ -1028,6 +1065,7 @@ class InferenceEngine:
                 r.timeline.add(loop_recorder.EV_PREFIX_HIT,
                                r.cached_prefix_tokens)
             self._record_prefix_match_span(r)
+        return admitted
 
     def _release_admission_locked(self, r: Request) -> None:
         """Undo a half-admitted request's page state (shared refs, fresh
@@ -1229,6 +1267,7 @@ class InferenceEngine:
             self.executor.prefill_many(bt, tokens_m, r.prefill_pos, handle, full)
             self.metrics["prefill_chunks"] += m
             r.prefill_pos += take
+            r.prefill_chunks += m
             r.timeline.add(loop_recorder.EV_PREFILL_CHUNK, take)
         else:
             # Bucket, clamped so the chunk's pages never run past the
@@ -1245,6 +1284,7 @@ class InferenceEngine:
                                   lora_slot=r.lora_slot)
             self.metrics["prefill_chunks"] += 1
             r.prefill_pos += take
+            r.prefill_chunks += 1
             r.timeline.add(loop_recorder.EV_PREFILL_CHUNK, take)
         if not final:
             return []  # more chunks to go
@@ -1287,18 +1327,19 @@ class InferenceEngine:
         events = []
         now = time.monotonic()
         now_wall = time.time()
-        for i, (r, _) in enumerate(live):
-            with self._lock:
-                if r.done:  # cancelled while sampling
-                    continue
-                self._active[r.slot] = r
-            r.pos = len(r.prompt)
-            r.first_token_at = now
-            r.first_token_wall = now_wall
-            r.timeline.add(loop_recorder.EV_FIRST_TOKEN, r.prefill_pos,
-                           now=now_wall)
-            self._record_prefill_span(r)
-            events.append(self._emit(r, int(tokens[i])))
+        with annotate("engine.emit", first_tokens=m):
+            for i, (r, _) in enumerate(live):
+                with self._lock:
+                    if r.done:  # cancelled while sampling
+                        continue
+                    self._active[r.slot] = r
+                r.pos = len(r.prompt)
+                r.first_token_at = now
+                r.first_token_wall = now_wall
+                r.timeline.add(loop_recorder.EV_FIRST_TOKEN, r.prefill_pos,
+                               now=now_wall)
+                self._record_prefill_span(r)
+                events.append(self._emit(r, int(tokens[i])))
         return events
 
     def _record_prefill_span(self, r: Request) -> None:
@@ -1313,7 +1354,10 @@ class InferenceEngine:
             r.trace.get("trace_id", ""), r.trace.get("span_id", ""),
             attrs={"request_id": r.request_id,
                    "prompt_tokens": len(r.prompt),
-                   "cached_prefix_tokens": r.cached_prefix_tokens}))
+                   "cached_prefix_tokens": r.cached_prefix_tokens,
+                   "queue_wait_ms": round(
+                       ((r.admitted_at or r.arrived_at) - r.arrived_at) * 1e3, 3),
+                   "prefill_chunks": r.prefill_chunks}))
 
     # -------------------------------------------------------- flight recorder
     def dump_timeline(self, r: Request, reason: str) -> bool:
@@ -1395,15 +1439,17 @@ class InferenceEngine:
 
     def _emit_decode_events(self, active: dict, tokens, K: int) -> list[dict]:
         events = []
-        for k in range(K):
-            for slot, r in active.items():
-                if r.done:
-                    continue
-                r.pos += 1
-                if r.first_token_at is None:
-                    r.first_token_at = time.monotonic()
-                    r.first_token_wall = time.time()
-                events.append(self._emit(r, int(tokens[k, slot])))
+        with annotate("engine.emit", K=K, slots=len(active)) as span:
+            for k in range(K):
+                for slot, r in active.items():
+                    if r.done:
+                        continue
+                    r.pos += 1
+                    if r.first_token_at is None:
+                        r.first_token_at = time.monotonic()
+                        r.first_token_wall = time.time()
+                    events.append(self._emit(r, int(tokens[k, slot])))
+            span.set_metadata(tokens=len(events))
         return events
 
     def _decode_all(self) -> list[dict]:
@@ -1586,6 +1632,7 @@ class InferenceEngine:
             r = p["request"]
             self.metrics["prefill_chunks"] += 1
             r.prefill_pos = p["start_pos"] + p["take"]
+            r.prefill_chunks += 1
             r.timeline.add(loop_recorder.EV_PREFILL_CHUNK, p["take"])
             if not p["final"]:
                 continue

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import Any
 
 import jax
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import LlamaConfig, PRESETS, init_params
+from ..observability.tracing import annotate
 from ..tpu import leased_devices, on_tpu
 from .model import (copy_pages, decode_loop, init_pages, mixed_dispatch,
                     prefill_chunk, sample_first_batch, verify_block,
@@ -198,6 +200,7 @@ class LocalEngineExecutor:
         self._key = jax.random.PRNGKey(seed ^ 0x5EED)
         # handle -> device hidden state [E] awaiting first-token sampling
         self._hidden: dict[int, Any] = {}
+        self.sync_s = 0.0  # seconds the host blocked on device results (_sync)
         # Serializes every read/replace of self.pages: migration imports
         # and exports run on REQUEST threads while the engine loop keeps
         # dispatching (donating the pool buffer each step) — without the
@@ -323,6 +326,17 @@ class LocalEngineExecutor:
             self.lora_stack, self._put(np.int32(slot)),
             {k: self._put(np.asarray(v)) for k, v in arrays.items()})
 
+    def _sync(self, *arrays) -> tuple:
+        """Device results to host arrays: THE place the engine's host
+        thread blocks on the device. ``engine.sync`` on the profiler's
+        trace; its seconds add up in ``sync_s`` (always on: the engine
+        splits a step into host time and this wait from it)."""
+        t0 = time.monotonic()
+        with annotate("engine.sync"):
+            out = tuple(np.asarray(a) for a in arrays)
+        self.sync_s += time.monotonic() - t0
+        return out
+
     # ------------------------------------------------------------- operations
     def prefill(self, block_table: np.ndarray, tokens: np.ndarray,
                 start_pos: int, handle: int | None, take: int,
@@ -339,7 +353,8 @@ class LocalEngineExecutor:
             if self.lora_stack is not None:
                 kwargs["lora"] = self.lora_stack
                 kwargs["lora_slot"] = self._put(np.int32(lora_slot))
-        with self._pages_lock:
+        with annotate("engine.dispatch", kind="prefill", prefill_tokens=take), \
+                self._pages_lock:
             self.pages, hidden = self._prefill(
                 self.params, self.pages,
                 self._put(block_table.astype(np.int32)),
@@ -364,7 +379,8 @@ class LocalEngineExecutor:
         chunk-pipelined dispatch (``pp_model.pp_prefill_chunks``); when
         ``handle`` is set, the LAST chunk's position ``take - 1`` hidden
         is stashed for first-token sampling."""
-        with self._pages_lock:
+        with annotate("engine.dispatch", kind="prefill",
+                      prefill_tokens=int(tokens_m.size)), self._pages_lock:
             self.pages, hiddens = self._prefill_many(
                 self.params, self.pages,
                 self._put(block_table.astype(np.int32)),
@@ -386,9 +402,10 @@ class LocalEngineExecutor:
         hiddens = jnp.stack(stack + [stack[0]] * (self.max_slots - m))
         padded = np.zeros(self.max_slots, np.float32)
         padded[:m] = temps[:m]
-        toks, self._key = self._sample_first(
-            hiddens, self.params["lm_head"], self._put(padded), self._key)
-        return np.asarray(toks)[:m]
+        with annotate("engine.dispatch", kind="flush", first_tokens=m):
+            toks, self._key = self._sample_first(
+                hiddens, self.params["lm_head"], self._put(padded), self._key)
+        return self._sync(toks)[0][:m]
 
     def _decode_kwargs(self, pos: np.ndarray, n_steps: int,
                        block_tables: np.ndarray, lora_idx) -> dict:
@@ -439,7 +456,8 @@ class LocalEngineExecutor:
                      ).astype(np.int32))
         else:
             kwargs = self._decode_kwargs(pos, n_steps, block_tables, lora_idx)
-        with self._pages_lock:
+        with annotate("engine.dispatch", kind="decode", K=n_steps), \
+                self._pages_lock:
             toks, self._key, self.pages = self._decode_loop(
                 self.params, self.pages,
                 self._put(block_tables.astype(np.int32)),
@@ -451,7 +469,7 @@ class LocalEngineExecutor:
                 self._key, config=self.config, page_size=self.page_size,
                 n_steps=n_steps, **kwargs,
             )
-        return np.asarray(toks)  # [n_steps, slots] — the one sync
+        return self._sync(toks)[0]  # [n_steps, slots] — the one sync
 
     @property
     def supports_speculation(self) -> bool:
@@ -477,7 +495,8 @@ class LocalEngineExecutor:
         # draft depth, like the paged decode bound.
         needed = max(1, (int(pos.max()) + self.page_size - 1)
                      // self.page_size)
-        with self._pages_lock:
+        with annotate("engine.dispatch", kind="verify", K=n_draft), \
+                self._pages_lock:
             toks, live, self._key, self.pages = self._verify(
                 self.params, self.pages,
                 self._put(block_tables.astype(np.int32)),
@@ -491,7 +510,7 @@ class LocalEngineExecutor:
                 live_pages=self._bucket_pages(needed, block_tables.shape[1]),
                 attn_mesh=self._attn_mesh,
             )
-        return np.asarray(toks), np.asarray(live)
+        return self._sync(toks, live)
 
     @property
     def supports_prefix_cow(self) -> bool:
@@ -583,7 +602,9 @@ class LocalEngineExecutor:
             op_live.append(self._bucket_pages(
                 -(-int(p["start_pos"]) // self.page_size), bt.shape[0]))
         kwargs = self._decode_kwargs(pos, n_steps, block_tables, lora_idx)
-        with self._pages_lock:
+        with annotate("engine.dispatch", kind="mixed", K=n_steps,
+                      prefill_tokens=sum(int(p["take"]) for p in prefill_plans)), \
+                self._pages_lock:
             toks, self._key, self.pages, hiddens = self._mixed(
                 self.params, self.pages, tuple(ops),
                 self._put(block_tables.astype(np.int32)),
@@ -598,7 +619,7 @@ class LocalEngineExecutor:
         for p, hidden in zip(prefill_plans, hiddens):
             if p.get("handle") is not None:
                 self._hidden[p["handle"]] = hidden[p["take"] - 1]
-        return np.asarray(toks)  # [n_steps, slots] — still the one sync
+        return self._sync(toks)[0]  # [n_steps, slots] — still the one sync
 
     @property
     def lm_head(self):

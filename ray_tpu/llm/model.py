@@ -532,17 +532,22 @@ def mixed_dispatch(params, pages: dict, prefill_ops, block_tables, tokens,
     hiddens tuple)`` — one ``[C_i, E]`` hidden per prefill op, for
     first-token sampling of ops that finished their prompt.
     """
+    # the scopes name the device ops of the fused program after its two
+    # halves (op_name on the profiler's op line), so that its device time
+    # can be split into prefill and decode; they are metadata only
     hiddens = []
     for (p_bt, p_tokens, p_start), lp in zip(prefill_ops, prefill_live_pages):
-        pages, hidden = prefill_chunk.__wrapped__(
-            params, pages, p_bt, p_tokens, p_start,
-            config=config, page_size=page_size, live_pages=lp)
+        with jax.named_scope("prefill_chunk"):
+            pages, hidden = prefill_chunk.__wrapped__(
+                params, pages, p_bt, p_tokens, p_start,
+                config=config, page_size=page_size, live_pages=lp)
         hiddens.append(hidden)
-    toks, key, pages = decode_loop.__wrapped__(
-        params, pages, block_tables, tokens, pos, temps, eos_ids, remaining,
-        key, config=config, page_size=page_size, n_steps=n_steps, paged=paged,
-        live_pages=live_pages, lora=lora, lora_idx=lora_idx,
-        attn_mesh=attn_mesh)
+    with jax.named_scope("decode_step"):
+        toks, key, pages = decode_loop.__wrapped__(
+            params, pages, block_tables, tokens, pos, temps, eos_ids, remaining,
+            key, config=config, page_size=page_size, n_steps=n_steps, paged=paged,
+            live_pages=live_pages, lora=lora, lora_idx=lora_idx,
+            attn_mesh=attn_mesh)
     return toks, key, pages, tuple(hiddens)
 
 

@@ -17,12 +17,15 @@ never leave the shards.
 
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 from typing import Any
 
 import numpy as np
 
 from ..core import api as ray
+from ..observability.tracing import annotate
 
 
 class EngineShardWorker:
@@ -186,6 +189,7 @@ class ShardedEngineExecutor:
         self._pending: list = []  # in-flight async dispatches (prefill/drop)
         self._loop = None
         self._loop_pending = 0    # loop results put but not yet consumed
+        self.sync_s = 0.0  # seconds blocked on the shards' results (_blocked)
         self.use_compiled_loop = use_compiled_loop
         # Set after build() by create_sharded_executor: whether every
         # shard's local executor takes the fused mixed entry point /
@@ -247,8 +251,22 @@ class ShardedEngineExecutor:
             self._pending.extend(
                 getattr(s, method).remote(*args) for s in self.shards)
 
+    @contextlib.contextmanager
+    def _blocked(self):
+        """The engine's thread waiting for its shards' results: what
+        ``LocalEngineExecutor._sync`` is on one host. ``engine.sync`` on the
+        profiler's trace, its seconds in ``sync_s`` (the engine's
+        ``step_sync_ms_sum``); each shard's own process has the device's
+        ``engine.dispatch`` / ``engine.sync``."""
+        t0 = time.monotonic()
+        try:
+            with annotate("engine.sync", shards=len(self.shards)):
+                yield
+        finally:
+            self.sync_s += time.monotonic() - t0
+
     def _sync(self, timeout: float = 300.0) -> None:
-        with self._dispatch_lock:
+        with self._dispatch_lock, self._blocked():
             if self.use_compiled_loop:
                 self._loop_drain(keep_last=False, timeout=timeout)
                 return
@@ -258,13 +276,13 @@ class ShardedEngineExecutor:
 
     def _all(self, method: str, *args, timeout: float = 300.0):
         with self._dispatch_lock:
-            if self.use_compiled_loop:
-                self._loop_drain(keep_last=False, timeout=timeout)
-                self._loop_put(method, *args)
-                return list(self._loop_drain(keep_last=True, timeout=timeout))
             self._sync(timeout)
-            refs = [getattr(s, method).remote(*args) for s in self.shards]
-            return ray.get(refs, timeout=timeout)
+            with self._blocked():
+                if self.use_compiled_loop:
+                    self._loop_put(method, *args)
+                    return list(self._loop_drain(keep_last=True, timeout=timeout))
+                refs = [getattr(s, method).remote(*args) for s in self.shards]
+                return ray.get(refs, timeout=timeout)
 
     def prefill(self, block_table, tokens, start_pos, handle, take,
                 lora_slot: int = 0) -> None:

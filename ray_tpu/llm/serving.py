@@ -19,6 +19,7 @@ import time
 import uuid
 from collections import OrderedDict
 
+from ..observability.tracing import annotate
 from .engine import InferenceEngine, Request
 from .tokenizer import ByteTokenizer
 
@@ -259,16 +260,27 @@ class LLMDeployment:
     def _engine_loop(self) -> None:
         while self._running:
             if not self.engine.has_work:
-                time.sleep(0.002)
+                # one event per 50 ms without a request, not one per poll:
+                # a capture then shows WHY the device sat idle (no work),
+                # and an event that outlasts a capture's window is lost
+                with annotate("serving.idle"):
+                    for _ in range(25):
+                        time.sleep(0.002)
+                        if self.engine.has_work or not self._running:
+                            break
                 continue
-            for event in self.engine.step():
-                q = self._token_queues.get(event["request_id"])
-                if q is not None:
-                    q.put(event)
-                if event["done"]:
-                    done = self._events.pop(event["request_id"], None)
-                    if done is not None:
-                        done.set()
+            events = self.engine.step()
+            if not events:
+                continue
+            with annotate("serving.push", events=len(events)):
+                for event in events:
+                    q = self._token_queues.get(event["request_id"])
+                    if q is not None:
+                        q.put(event)
+                    if event["done"]:
+                        done = self._events.pop(event["request_id"], None)
+                        if done is not None:
+                            done.set()
 
     def close(self) -> None:
         """Stop the engine loop (for in-process reuse — tests, notebooks)."""

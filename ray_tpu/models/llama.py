@@ -206,34 +206,40 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
 
     from jax.ad_checkpoint import checkpoint_name
 
-    h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps)
-    q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
-    k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
-    v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
-    q = apply_rope(q, positions, theta=c.rope_theta)
-    k = apply_rope(k, positions, theta=c.rope_theta)
-    q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
-    k = checkpoint_name(k, "k")
-    v = checkpoint_name(v, "v")
-    attn = checkpoint_name(_attention(q, k, v, c, mesh), "attn_out")
-    attn_out = jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
-    x = x + sc(attn_out, ("batch", "seq", "embed_act"))
+    # scopes are metadata: they name the device ops after the part of the
+    # block that issued them (op_name on the profiler's op line), and
+    # leave the compiled program as it was
+    with jax.named_scope("attn"):
+        h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps)
+        q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
+        k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
+        v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
+        q = apply_rope(q, positions, theta=c.rope_theta)
+        k = apply_rope(k, positions, theta=c.rope_theta)
+        q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
+        k = checkpoint_name(k, "k")
+        v = checkpoint_name(v, "v")
+        attn = checkpoint_name(_attention(q, k, v, c, mesh), "attn_out")
+        attn_out = jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
+        x = x + sc(attn_out, ("batch", "seq", "embed_act"))
 
-    h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if c.moe_experts > 0:
-        from .moe import moe_block
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps)
+        aux = jnp.zeros((), jnp.float32)
+        if c.moe_experts > 0:
+            from .moe import moe_block
 
-        down, aux = moe_block(h, layer, top_k=c.moe_top_k, ep_axis=ep_axis,
-                              n_experts_global=c.moe_experts,
-                              capacity_factor=c.moe_capacity_factor)
-    else:
-        gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"])
-        up = jnp.einsum("bse,em->bsm", h, layer["w_up"])
-        ff = jax.nn.silu(gate.astype(jnp.float32)).astype(c.dtype) * up
-        ff = sc(ff, ("batch", "seq", "mlp"))
-        down = jnp.einsum("bsm,me->bse", ff, layer["w_down"])
-    return x + sc(down, ("batch", "seq", "embed_act")), aux
+            down, aux = moe_block(h, layer, top_k=c.moe_top_k, ep_axis=ep_axis,
+                                  n_experts_global=c.moe_experts,
+                                  capacity_factor=c.moe_capacity_factor)
+        else:
+            gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"])
+            up = jnp.einsum("bse,em->bsm", h, layer["w_up"])
+            ff = jax.nn.silu(gate.astype(jnp.float32)).astype(c.dtype) * up
+            ff = sc(ff, ("batch", "seq", "mlp"))
+            down = jnp.einsum("bsm,me->bse", ff, layer["w_down"])
+        x = x + sc(down, ("batch", "seq", "embed_act"))
+    return x, aux
 
 
 def _apply_remat(block, c: LlamaConfig):
@@ -268,7 +274,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
-    x = params["embed"][tokens].astype(c.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(c.dtype)
     if mesh is not None:
         # Two-hop resharding. The gather's output inherits the table's
         # embed=fsdp sharding; jumping straight to batch=(dcn,dp,fsdp)
@@ -374,45 +381,46 @@ def loss_fn(
         hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
     else:
         hidden = forward_hidden(params, tokens, config, mesh=mesh)
-    targets = tokens[:, 1:]
-    hidden = hidden[:, :-1]
-    mask = batch.get("mask")
-    mask = (jnp.ones_like(targets, jnp.float32) if mask is None
-            else mask[:, 1:].astype(jnp.float32))
+    with jax.named_scope("lm_head_loss"):
+        targets = tokens[:, 1:]
+        hidden = hidden[:, :-1]
+        mask = batch.get("mask")
+        mask = (jnp.ones_like(targets, jnp.float32) if mask is None
+                else mask[:, 1:].astype(jnp.float32))
 
-    b, s, e = hidden.shape
-    n = b * s
-    flat_h = hidden.reshape(n, e)
-    flat_t = targets.reshape(n)
-    flat_m = mask.reshape(n)
-    chunk = min(chunk_tokens, n)
-    if n % chunk:
-        pad = chunk - n % chunk
-        flat_h = jnp.pad(flat_h, ((0, pad), (0, 0)))
-        flat_t = jnp.pad(flat_t, (0, pad))
-        flat_m = jnp.pad(flat_m, (0, pad))
-        n += pad
-    nc = n // chunk
-    lm_head = params["lm_head"]
+        b, s, e = hidden.shape
+        n = b * s
+        flat_h = hidden.reshape(n, e)
+        flat_t = targets.reshape(n)
+        flat_m = mask.reshape(n)
+        chunk = min(chunk_tokens, n)
+        if n % chunk:
+            pad = chunk - n % chunk
+            flat_h = jnp.pad(flat_h, ((0, pad), (0, 0)))
+            flat_t = jnp.pad(flat_t, (0, pad))
+            flat_m = jnp.pad(flat_m, (0, pad))
+            n += pad
+        nc = n // chunk
+        lm_head = params["lm_head"]
 
-    @jax.checkpoint
-    def chunk_loss(xs):
-        h, t, m = xs
-        logits = jnp.einsum(
-            "ce,ev->cv", h, lm_head, preferred_element_type=jnp.float32
+        @jax.checkpoint
+        def chunk_loss(xs):
+            h, t, m = xs
+            logits = jnp.einsum(
+                "ce,ev->cv", h, lm_head, preferred_element_type=jnp.float32
+            )
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            ll = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0] - lse
+            return (ll * m).sum()
+
+        def body(carry, xs):
+            return carry + chunk_loss(xs), None
+
+        total, _ = lax.scan(
+            body,
+            jnp.zeros((), jnp.float32),
+            (flat_h.reshape(nc, chunk, e), flat_t.reshape(nc, chunk),
+             flat_m.reshape(nc, chunk)),
         )
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        ll = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0] - lse
-        return (ll * m).sum()
-
-    def body(carry, xs):
-        return carry + chunk_loss(xs), None
-
-    total, _ = lax.scan(
-        body,
-        jnp.zeros((), jnp.float32),
-        (flat_h.reshape(nc, chunk, e), flat_t.reshape(nc, chunk),
-         flat_m.reshape(nc, chunk)),
-    )
-    ce = -total / jnp.maximum(flat_m.sum(), 1.0)
+        ce = -total / jnp.maximum(flat_m.sum(), 1.0)
     return ce + config.moe_aux_weight * aux

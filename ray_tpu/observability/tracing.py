@@ -18,6 +18,12 @@ Recording goes through the worker's ``TaskEventBuffer`` (status
 processes without a core worker (standalone engine in tests, the GCS
 itself) fall back to a bounded process-local buffer readable via
 ``local_spans()``.
+
+Those spans are on the wall clock. The profiler's trace has a clock of
+its own, and ``annotate`` is the door to it: a host event on the trace of
+whatever capture is running in this process (``observability/profile.py``
+reads them back, and maps the wall-clock spans onto the same clock
+through the capture's ``capture_window`` event).
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ from __future__ import annotations
 import contextlib
 import os
 import random
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -148,13 +155,55 @@ def make_span(name: str, kind: str, start: float, end: float,
     }
 
 
-def _tracing_enabled() -> bool:
-    try:
-        from ..core.config import get_config
+class _NoAnnotation:
+    """What ``annotate`` gives a process that never imported jax."""
 
-        return bool(get_config().enable_tracing)
-    except Exception:
-        return True
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+# The stat every ``annotate`` event carries: what ``profile.summarize``
+# tells the program's spans from XLA's own host events by (on a CPU trace
+# those are named alike: ``dot_general.56``).
+MARK = "ray_tpu"  # spelled out as a keyword in ``annotate``
+
+
+def annotate(name: str, **attrs):
+    """A host event named ``name`` on the profiler's trace, around a
+    ``with`` block: a ``jax.profiler.TraceAnnotation`` where jax is already
+    imported in this process, and nothing where it is not (a core worker
+    that never computes must not import jax for this). ``attrs`` (numbers
+    and short strings: what was processed, how much) become the event's
+    stats; ``.set_metadata(**more)`` inside the block adds what is known
+    only at its end. With no capture running it costs well under a
+    microsecond, so no site is behind a flag. Names are ``<layer>.<what>``;
+    the event is marked ``MARK`` so a reader needs no list of layers."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(name, ray_tpu=1, **attrs)
+
+
+_enabled: bool | None = None  # read once per process: a span must stay cheap
+
+
+def _tracing_enabled() -> bool:
+    global _enabled
+    if _enabled is None:
+        try:
+            from ..core.config import get_config
+
+            _enabled = bool(get_config().enable_tracing)
+        except Exception:
+            _enabled = True
+    return _enabled
 
 
 def record_span(span_dict: dict) -> None:
@@ -193,14 +242,15 @@ def span(name: str, kind: str = "app", attrs: dict | None = None,
     context (or a fresh root trace when there is none or ``root=True``)
     and installs itself as the current context for the duration, so
     anything submitted inside — tasks, actor calls, engine requests —
-    chains under it."""
+    chains under it. The block is also an ``annotate`` event, so it shows
+    on a profiler capture taken meanwhile."""
     parent = None if root else current()
     if parent is None:
         ctx = TraceContext(new_trace_id(), new_span_id())
     else:
         ctx = parent.child()
     start = time.time()
-    with use_context(ctx):
+    with use_context(ctx), annotate(name):
         try:
             yield ctx
         finally:

@@ -22,7 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .trace_log import note_kernel_trace
+from .trace_log import note_flash_cost, note_kernel_trace
 
 NEG_INF = -1e30
 
@@ -142,6 +142,7 @@ def _flash_forward(
         o = mha_reference(q, k, v, causal=causal, sm_scale=scale)
         return (o, None) if save_residuals else o
     note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
+    note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals)
     n_q, n_k = sq // block_q, sk // block_k
 
     grid = (b, hq, n_q, n_k)
@@ -182,6 +183,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return result
 
@@ -298,6 +300,9 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
         (b, hq, sq, 128),
     )
 
+    note_flash_cost("flash_bwd_dq", q, k, causal=causal)
+    note_flash_cost("flash_bwd_dkdv", q, k, causal=causal)
+
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
     lm_spec = pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, ki, qi: (bi, hi, qi, 0))
@@ -318,6 +323,7 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -338,6 +344,7 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, g, lse, delta)
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2).astype(k.dtype)

@@ -89,7 +89,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .trace_log import note_kernel_trace
+from .trace_log import note_kernel_cost, note_kernel_trace
 
 NEG_INF = -1e30
 
@@ -326,6 +326,14 @@ def paged_decode_attention(
         pages_per_block = max(1, min(covered, 256 // page_size, 8))
     ppb = min(pages_per_block, covered)
     n_blocks = -(-covered // ppb)
+    # per call, from the shapes: every slot attends over the live_pages
+    # bound of pool context plus the staging rows (QK^T and PV), and reads
+    # that much K and V once; what a slot really holds is a run-time value
+    ctx = covered * page_size + sc
+    note_kernel_cost(
+        "paged_decode", 4.0 * n * kh * g * d * ctx,
+        2 * n * kh * g * d * q.dtype.itemsize
+        + 2 * n * kh * ctx * d * k_pages.dtype.itemsize)
 
     def _call(q, block_tables, base, sl, layer, k_stage, v_stage,
               k_pages, v_pages):
@@ -388,6 +396,7 @@ def paged_decode_attention(
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n, kh, gp, d), q.dtype),
             interpret=interpret,
+            name="paged_decode",
         )(block_tables, base, sl, layer,
           q, k_stage, v_stage,
           *([k_pages] * ppb), *([v_pages] * ppb))

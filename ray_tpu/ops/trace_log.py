@@ -6,18 +6,26 @@ Those decisions are taken in Python while a jitted program is traced, so
 the compiled program cannot be asked afterwards; this record can. A
 worker reports it (train metrics, ``LLMDeployment.engine_metrics``) and
 ``chip_smoke.py`` fails when a chip run shows anything but ``pallas``.
+
+The same moment knows the shapes, so the record also carries what one
+call of each Pallas kernel costs (``kernel_costs``): the program's half
+of a roofline share, whose other half is the kernel's seconds under the
+same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkdv``, ``paged_decode``).
 """
 
 from __future__ import annotations
 
 import collections
 import logging
+import math
 import threading
 
 logger = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _counts: collections.Counter = collections.Counter()
+_costs: dict[str, dict] = {}
 
 
 def note_kernel_trace(kernel: str, path: str) -> None:
@@ -35,3 +43,46 @@ def kernel_traces() -> dict[str, int]:
     """``{"<kernel>:<path>": times traced}`` for this process."""
     with _lock:
         return dict(_counts)
+
+
+def note_kernel_cost(kernel: str, flops: float, nbytes: float) -> None:
+    """Record what ONE call of the Pallas kernel named ``kernel`` costs at
+    the shapes it was just traced with (the last trace wins)."""
+    with _lock:
+        traced = _costs.get(kernel, {}).get("traced", 0) + 1
+        _costs[kernel] = {"traced": traced, "flops": float(flops),
+                          "bytes": float(nbytes)}
+
+
+def kernel_costs() -> dict[str, dict]:
+    """``{"<kernel name>": {"traced": n, "flops": ..., "bytes": ...}}``:
+    per call, at the shapes of the kernel's latest trace in this process."""
+    with _lock:
+        return {k: dict(v) for k, v in _costs.items()}
+
+
+_FLASH_MATMULS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkdv": 4}
+
+
+def note_flash_cost(kernel: str, q, k, *, causal: bool,
+                    residuals: bool = True) -> None:
+    """Record one call of a flash kernel on q [B,Hq,Sq,D], k/v [B,Hkv,Sk,D].
+    Each [Sq,Sk] x D matmul is 2*B*Hq*Sq*Sk*D FLOPs: the forward has two
+    (QK^T, PV), dQ three (QK^T, dO V^T, dS K), dK/dV four (QK^T, P^T dO,
+    dO V^T, dS^T Q); causal masking halves them. Bytes are the operands
+    and results once: q-shaped arrays (q, o, dO, dQ), k and v, the
+    float32 [B,Hq,Sq,128] rows (lse; delta), and dK/dV, which leave the
+    kernel at the query-head count."""
+    b, hq, sq, d = q.shape
+    sk = k.shape[2]
+    flops = _FLASH_MATMULS[kernel] * 2.0 * b * hq * sq * sk * d / (2 if causal else 1)
+    q_b = b * hq * sq * d * q.dtype.itemsize
+    kv_b = 2 * math.prod(k.shape) * k.dtype.itemsize
+    rows = b * hq * sq * 128 * 4
+    nbytes = {
+        "flash_fwd": 2 * q_b + kv_b + (rows if residuals else 0),
+        "flash_bwd_dq": 3 * q_b + kv_b + 2 * rows,
+        "flash_bwd_dkdv": 2 * q_b + kv_b + 2 * rows
+        + 2 * b * hq * sk * d * k.dtype.itemsize,
+    }[kernel]
+    note_kernel_cost(kernel, flops, nbytes)

@@ -38,6 +38,8 @@ import threading
 import time
 import uuid
 
+from ..observability.tracing import annotate
+
 logger = logging.getLogger(__name__)
 
 GCS_KEY_PREFIX = "resilience:ckpt:"
@@ -206,7 +208,8 @@ class AsyncCheckpointManager:
         milliseconds the CALLER was blocked (snapshot only — the contract
         the non-blocking test asserts)."""
         t0 = time.perf_counter()
-        snapshot = _snapshot(tree)
+        with annotate("train.ckpt.snapshot", step=int(step), kind="async"):
+            snapshot = _snapshot(tree)
         with self._cv:
             if self._closed:
                 raise RuntimeError("AsyncCheckpointManager is closed")
@@ -251,7 +254,8 @@ class AsyncCheckpointManager:
                 self._pending = None
                 self._writing = True
             try:
-                self._commit(step, snapshot, metrics)
+                with annotate("train.ckpt.commit", step=step):
+                    self._commit(step, snapshot, metrics)
             except Exception:
                 self.metrics["commit_errors"] += 1
                 logger.exception("async checkpoint commit of step %d failed", step)

@@ -148,10 +148,11 @@ def leased_devices() -> list:
 
 
 def device_report() -> dict:
-    """What this process's JAX holds and which attention paths it traced:
-    what a worker sends back so its caller can tell a chip run from a
-    quiet CPU one. Platform, kind and count are as JAX reports them."""
-    from .ops.trace_log import kernel_traces
+    """What this process's JAX holds, which attention paths it traced and
+    what one call of each Pallas kernel costs at its traced shapes: what a
+    worker sends back so its caller can tell a chip run from a quiet CPU
+    one. Platform, kind and count are as JAX reports them."""
+    from .ops.trace_log import kernel_costs, kernel_traces
 
     devices = leased_devices()
     stats = [d.memory_stats() or {} for d in devices]
@@ -162,6 +163,7 @@ def device_report() -> dict:
         "bytes_in_use": [int(m.get("bytes_in_use", 0)) for m in stats],
         "peak_bytes_in_use": [int(m.get("peak_bytes_in_use", 0)) for m in stats],
         "kernel_traces": kernel_traces(),
+        "kernel_costs": kernel_costs(),
     }
 
 

@@ -16,6 +16,7 @@ import time
 import uuid
 from typing import Any
 
+from ..observability.tracing import annotate
 from .checkpoint import Checkpoint
 
 # Per-step training gauges pushed through the metrics pipeline from each
@@ -113,6 +114,13 @@ class _Session:
 
     def report(self, metrics: dict, checkpoint: Checkpoint | None = None,
                state=None) -> None:
+        # on the profiler's trace while a capture runs: the whole call, and
+        # inside it the part of a checkpoint that blocks the step
+        with annotate("train.report", step=self._step,
+                      rank=self.context.world_rank):
+            self._report(metrics, checkpoint, state)
+
+    def _report(self, metrics: dict, checkpoint: Checkpoint | None, state) -> None:
         entry: dict[str, Any] = {"metrics": dict(metrics or {}), "rank": self.context.world_rank}
         self._export_step_metrics(entry["metrics"])
         if state is not None and self._async_ckpt is not None:
@@ -127,7 +135,9 @@ class _Session:
                 f"checkpoint_{self._step:06d}_{uuid.uuid4().hex[:6]}",
             )
             if os.path.abspath(checkpoint.path) != dest:
-                shutil.copytree(checkpoint.path, dest, dirs_exist_ok=True)
+                with annotate("train.ckpt.snapshot", step=self._step,
+                              kind="directory"):
+                    shutil.copytree(checkpoint.path, dest, dirs_exist_ok=True)
             entry["checkpoint_path"] = dest
         self._step += 1
         with self._lock:

@@ -208,18 +208,57 @@ def memory_summary() -> dict:
     return _gcs("MemorySummary")["summary"]
 
 
-def capture_profile(node_id: str | None = None, duration: float = 2.0,
-                    worker_id: str | None = None) -> dict:
-    """Trigger an on-demand ``jax.profiler`` trace capture on a worker of
-    ``node_id`` (prefix match; default: this node) and return the artifact
-    info (``{"path", "worker_id", "node_id", "duration"}`` or
-    ``{"error"}``). The artifact is also registered under
-    ``list_profiles()`` / dashboard ``/api/profiles``."""
-    import asyncio
+def _profile_target(worker_id: str | None, actor: str | None) -> tuple[str, str] | dict:
+    """(worker_id, node_id) of the worker a capture is meant for: the one
+    named, or the one that holds chips. ``{"error"}`` if none is found."""
+    if actor:
+        rows = [a for a in list_actors() if a.get("state") == "ALIVE"
+                and (actor in (a.get("name") or "") or a["actor_id"].startswith(actor))]
+        if len(rows) != 1:
+            return {"error": f"{len(rows)} alive actors match {actor!r}"}
+        return rows[0]["worker_id"], rows[0]["node_id"]
+    workers = [w for w in list_workers() if w.get("address")]
+    if worker_id:
+        rows = [w for w in workers if w["worker_id"].startswith(worker_id)]
+    else:  # whoever leased TPU chips is the one worth tracing
+        rows = [w for w in workers if (w.get("lease") or {}).get("TPU", 0) > 0]
+    if not rows:
+        return {"error": f"no worker matching {worker_id!r}" if worker_id
+                else "no worker holds a TPU lease"}
+    return rows[0]["worker_id"], rows[0]["node_id"]
 
+
+def capture_profile(node_id: str | None = None, duration: float = 2.0,
+                    worker_id: str | None = None, actor: str | None = None,
+                    summary: bool = False) -> dict:
+    """Trigger an on-demand ``jax.profiler`` capture and return the artifact
+    info (``{"path", "worker_id", "node_id", "duration"}`` or ``{"error"}``).
+    Only the process that holds a chip can trace it, so the target is a
+    worker: ``worker_id`` (a prefix), or ``actor`` (a named actor's name or
+    part of it, e.g. ``train_worker_<experiment>_0``, or an actor id
+    prefix); with neither, the worker whose lease holds ``TPU``, and
+    failing that a worker of ``node_id`` (default: this node). The
+    artifact is registered under ``list_profiles()`` / ``/api/profiles``.
+
+    ``summary=True`` adds ``reply["summary"]``, reduced where the file is
+    (``observability.profile.summarize``): the window (the capture's own
+    ``capture_window`` event), busy and idle seconds per device, top device
+    ops and kernels, the program's ``annotate`` spans, and idle gaps by the
+    innermost span that covers them. It is a second call, made once the
+    capture is back and registered; if it fails the reply keeps the path
+    and says why under ``"summary_error"``. A busy process takes many times
+    ``duration`` to stop and export its trace, so both calls wait
+    ``profile.limit_s(duration)``."""
     from ..core.rpc import RpcClient
+    from ..observability import profile
 
     worker = global_worker()
+    if worker_id or actor or not node_id:
+        target = _profile_target(worker_id, actor)
+        if not isinstance(target, dict):
+            worker_id, node_id = target
+        elif worker_id or actor:
+            return target  # the one asked for is not there; else: any worker
     nodes = [n for n in list_nodes() if n["state"] == "ALIVE"]
     if node_id:
         nodes = [n for n in nodes if n["node_id"].startswith(node_id)]
@@ -229,13 +268,27 @@ def capture_profile(node_id: str | None = None, duration: float = 2.0,
         nodes = [n for n in nodes if n["node_id"] == worker.node_id] or nodes
     node = nodes[0]
 
+    limit = profile.limit_s(duration)  # for the capture's end, and for its reading
+
     async def _call():
         client = RpcClient(node["address"])
         try:
-            return await client.call(
+            reply = await client.call(
                 "CaptureProfile",
                 {"duration": duration, "worker_id": worker_id or ""},
-                timeout=duration + 150.0)
+                timeout=duration + limit + 30.0)
+            if summary and reply.get("path"):
+                try:
+                    read = await client.call(
+                        "SummarizeProfile", {"path": reply["path"], "timeout": limit},
+                        timeout=limit + 30.0)
+                except Exception as e:
+                    read = {"error": f"{type(e).__name__}: {e}"}
+                if "summary" in read:
+                    reply["summary"] = read["summary"]
+                else:
+                    reply["summary_error"] = read.get("error", "no summary")
+            return reply
         finally:
             await client.close()
 

@@ -53,6 +53,9 @@ def test_multihost_engine_token_parity(ray_cluster, small_cfg):
                               executor=executor, seed=0)
         got = [eng.generate(list(p), max_new_tokens=6) for p in prompts]
         assert got == expected
+        # the wait for the shards is the step's sync, as the device's on one host
+        assert 0 < eng.metrics["step_sync_ms_sum"] <= executor.sync_s * 1e3
+        assert eng.metrics["step_host_ms_sum"] > 0
     finally:
         executor.shutdown()
 
@@ -88,6 +91,7 @@ def test_multihost_compiled_loop_token_parity(ray_cluster, small_cfg):
         # engine surfaces the count
         assert executor.loop_ticks > 0
         assert eng.metrics["dag_loop_ticks"] == executor.loop_ticks
+        assert 0 < eng.metrics["step_sync_ms_sum"] <= executor.sync_s * 1e3
     finally:
         executor.shutdown()
 
