@@ -25,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ..ops import (flash_attention, mha_reference, ring_attention, rms_norm,
@@ -155,6 +156,13 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
 
 
 def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
+    """Attention output [B, Hq, S, D], under the checkpoint name
+    ``attn_out``. ``flash_attention``'s own vjp rule gives that name to the
+    kernel's output (and ``attn_lse`` to its logsumexp), so only what
+    does not come out of it is named here. Ulysses is left to the rule:
+    what a saving policy keeps on an sp>1 mesh is the per-shard output
+    BEFORE the all-to-all, which the flash backward reads; the all-to-all
+    runs again in the backward pass for ``wo``."""
     if (config.attn_impl in ("ring", "ulysses") and mesh is not None
             and mesh.shape["sp"] > 1):
         from jax import shard_map
@@ -167,12 +175,14 @@ def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )
-        return fn(q, k, v)
+        o = fn(q, k, v)
+        return checkpoint_name(o, "attn_out") if config.attn_impl == "ring" else o
     if config.attn_impl == "reference":
-        return mha_reference(q, k, v, causal=True)
+        return checkpoint_name(mha_reference(q, k, v, causal=True), "attn_out")
     if config.attn_impl == "none":  # ablation: identity attention
         g = q.shape[1] // k.shape[1]
-        return (q.reshape(q.shape[0], k.shape[1], g, *q.shape[2:]) * v[:, :, None]).reshape(q.shape)
+        o = (q.reshape(q.shape[0], k.shape[1], g, *q.shape[2:]) * v[:, :, None]).reshape(q.shape)
+        return checkpoint_name(o, "attn_out")
     batch_axes = ("dcn", "dp", "fsdp")
     if mesh is not None and mesh.size > 1:
         n_batch = math.prod(mesh.shape[a] for a in batch_axes)
@@ -204,8 +214,6 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
     def sc(t, axes):
         return shard_constraint(t, mesh, axes) if mesh is not None else t
 
-    from jax.ad_checkpoint import checkpoint_name
-
     # scopes are metadata: they name the device ops after the part of the
     # block that issued them (op_name on the profiler's op line), and
     # leave the compiled program as it was
@@ -219,7 +227,7 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
         q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
         k = checkpoint_name(k, "k")
         v = checkpoint_name(v, "v")
-        attn = checkpoint_name(_attention(q, k, v, c, mesh), "attn_out")
+        attn = _attention(q, k, v, c, mesh)
         attn_out = jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
         x = x + sc(attn_out, ("batch", "seq", "embed_act"))
 
@@ -251,14 +259,19 @@ def _apply_remat(block, c: LlamaConfig):
             block, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
     if c.remat_policy == "attn":
-        # save the attention path (q/k/v projections + kernel output,
-        # ~2.7 GB at 8x2048 for 1b) so the backward's recompute skips
-        # the attention forward entirely — the best HBM/FLOPs trade on
-        # a 16 GB chip
+        # save the attention path: the q/k/v projections and the two
+        # residuals flash_attention's vjp rule names, the kernel's output
+        # and its compact logsumexp (~2.7 GB at 8x2048 for 1b), so the
+        # backward's recompute skips the attention forward entirely — the
+        # best HBM/FLOPs trade on a 16 GB chip. On a v5e, internlm2-1.8b
+        # at 2x4096: flash_fwd runs 24 times a step, not 48 (36 ms of an
+        # 869 ms step), and with the backward kernels reading lse as rows
+        # the step takes 816.8 ms; the saved lse is 12.6 MB in all, its
+        # slice costs 0.09 ms a layer (PERF.md, PR 25)
         return jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "q", "k", "v", "attn_out"
+                "q", "k", "v", "attn_out", "attn_lse"
             ),
         )
     return jax.checkpoint(block)

@@ -18,6 +18,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -100,8 +101,8 @@ def _flash_kernel(
     def _final():
         o_ref[0, 0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
         if lse_ref is not None:
-            # logsumexp residual for the backward kernels, replicated
-            # along lanes (the jax TPU flash layout: [B,H,S,128]).
+            # logsumexp for the backward kernels, replicated along lanes
+            # as m and l are ([B,H,S,128]); the vjp rule keeps one lane.
             lse_ref[0, 0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
 
 
@@ -188,9 +189,30 @@ def _flash_forward(
     return result
 
 
+def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start):
+    """One [block_k, block_q] tile of the backward pass, k-major: P^T and
+    dS^T. Scores are taken transposed (K Q^T) so that the per-query
+    statistics ``lse`` and ``delta`` are [1, block_q] ROWS, broadcast
+    along sublanes: compact in HBM, where a column per query would be
+    padded to 128 lanes (67 MB a layer at 2 x 16 x 4096, not 0.5)."""
+    s_t = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    if causal:
+        k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+        q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
+        s_t = jnp.where(q_ids >= k_ids, s_t, NEG_INF)
+    p_t = jnp.exp(s_t - lse)  # masked entries underflow to 0
+    dp_t = jax.lax.dot_general(
+        v, g, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds_t = (p_t * (dp_t - delta) * sm_scale).astype(q.dtype)
+    return p_t, ds_t
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, sm_scale, causal, block_q, block_k, n_k):
-    """dQ: for one q block, accumulate ds @ K over all k blocks (k axis
+    """dQ: for one q block, accumulate dS @ K over all k blocks (k axis
     innermost → sequential on-core, acc lives in VMEM)."""
     ki = pl.program_id(3)
     qi = pl.program_id(2)
@@ -205,25 +227,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, 0]
         k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        g = g_ref[0, 0]
-        lse = lse_ref[0, 0]      # [bq, 128] lanes-replicated
-        delta = delta_ref[0, 0]  # [bq, 128]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal:
-            q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp(s - lse[:, :1])  # masked entries underflow to 0
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta[:, :1]) * sm_scale).astype(q.dtype)
-        acc_ref[:] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+        _, ds_t = _bwd_probs_t(
+            q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0], lse_ref[0, 0, 0],
+            delta_ref[0, 0, 0], sm_scale=sm_scale, causal=causal,
+            q_start=q_start, k_start=k_start)
+        acc_ref[:] += jax.lax.dot_general(
+            ds_t, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )                                                  # [bq, d]
 
     @pl.when(ki == n_k - 1)
     def _final():
@@ -234,8 +245,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc,
                      *, sm_scale, causal, block_q, block_k, n_q):
     """dK/dV: for one k block, accumulate over all q blocks (q axis
-    innermost). p/ds are computed q-major and contracted over the q dim
-    (dot_general) — no transposes materialize."""
+    innermost). P^T and dS^T come out k-major, so both products are
+    plain [bk, bq] @ [bq, d] — no transposes materialize."""
     qi = pl.program_id(3)
     ki = pl.program_id(2)
 
@@ -251,30 +262,13 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     @pl.when(needed)
     def _compute():
         q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
         g = g_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal:
-            q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        p = jnp.exp(s - lse[:, :1])                       # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                  # [bk, d]
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta[:, :1]) * sm_scale).astype(q.dtype)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                  # [bk, d]
+        p_t, ds_t = _bwd_probs_t(
+            q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
+            sm_scale=sm_scale, causal=causal, q_start=q_start, k_start=k_start)
+        dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g,
+                                 preferred_element_type=jnp.float32)  # [bk, d]
+        dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_q - 1)
     def _final():
@@ -284,8 +278,9 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
                     interpret):
-    """Pallas dq/dk/dv. K/V stay at kv-head count (GQA via index maps);
-    dk/dv come out at q-head count and are reduced by the caller."""
+    """Pallas dq/dk/dv. ``lse`` is the compact f32 [B, Hq, S] residual.
+    K/V stay at kv-head count (GQA via index maps); dk/dv come out at
+    q-head count and are reduced by the caller."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     rep = hq // hkv
@@ -293,19 +288,19 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
     n_q, n_k = sq // block_q, sk // block_k
-    # delta = rowsum(dO * O), lanes-replicated like lse.
-    delta = jnp.broadcast_to(
-        jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                keepdims=True),
-        (b, hq, sq, 128),
-    )
+    # Per-query statistics as one [1, block_q] row per q block (a block
+    # whose trailing dims are the array's own fits any block size): lse,
+    # and delta = rowsum(dO * O).
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    lse = lse.reshape(b, hq, n_q, 1, block_q)
+    delta = delta.reshape(b, hq, n_q, 1, block_q)
 
     note_flash_cost("flash_bwd_dq", q, k, causal=causal)
     note_flash_cost("flash_bwd_dkdv", q, k, causal=causal)
 
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
-    lm_spec = pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, ki, qi: (bi, hi, qi, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=scale, causal=causal,
@@ -316,8 +311,8 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -330,7 +325,7 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
         functools.partial(_bwd_dkdv_kernel, sm_scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_q=n_q),
         grid=(b, hq, n_k, n_q),  # q innermost
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, lm_spec, lm_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
@@ -414,7 +409,16 @@ def _blocks_fit(sq, sk, block_q, block_k) -> bool:
 def _make_flash(causal, sm_scale, block_q, block_k, interpret):
     """custom_vjp wrapper: Pallas kernels for BOTH directions (forward
     saves the logsumexp residual; dq and dk/dv are dedicated kernels).
-    Ragged shapes fall back to the jnp blocked paths."""
+    Ragged shapes fall back to the jnp blocked paths.
+
+    The fwd rule names what the backward kernels need beyond q/k/v:
+    the output is ``attn_out`` (one variable serves as primal output and
+    residual, so a policy that saves the name keeps one copy) and the
+    logsumexp is ``attn_lse``, compact f32 [B, Hq, S], which is also the
+    form the backward kernels read. A ``jax.checkpoint`` policy that
+    saves both names (remat ``attn`` in models/llama.py) runs
+    ``flash_fwd`` once a layer; one that saves neither recomputes it in
+    the backward pass, as before."""
 
     @jax.custom_vjp
     def f(q, k, v):
@@ -425,12 +429,17 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret):
 
     def fwd(q, k, v):
         if not _blocks_fit(q.shape[2], k.shape[2], block_q, block_k):
-            return f(q, k, v), (q, k, v, None, None)
+            return checkpoint_name(f(q, k, v), "attn_out"), (q, k, v, None, None)
         o, lse = _flash_forward(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             save_residuals=True,
         )
+        o = checkpoint_name(o, "attn_out")
+        # The kernel writes lse replicated over 128 lanes; one lane is the
+        # residual and what the backward kernels read (S minor: a trailing
+        # 1 would be padded back to 128 lanes in HBM).
+        lse = checkpoint_name(lse[..., 0], "attn_lse")
         return o, (q, k, v, o, lse)
 
     def bwd(res, g):

@@ -71,18 +71,20 @@ def note_flash_cost(kernel: str, q, k, *, causal: bool,
     (QK^T, PV), dQ three (QK^T, dO V^T, dS K), dK/dV four (QK^T, P^T dO,
     dO V^T, dS^T Q); causal masking halves them. Bytes are the operands
     and results once: q-shaped arrays (q, o, dO, dQ), k and v, the
-    float32 [B,Hq,Sq,128] rows (lse; delta), and dK/dV, which leave the
-    kernel at the query-head count."""
+    per-query float32 statistics (the forward writes lse replicated over
+    128 lanes, [B,Hq,Sq,128]; the backward kernels read lse and delta
+    compact, [B,Hq,Sq] each), and dK/dV, which leave the kernel at the
+    query-head count."""
     b, hq, sq, d = q.shape
     sk = k.shape[2]
     flops = _FLASH_MATMULS[kernel] * 2.0 * b * hq * sq * sk * d / (2 if causal else 1)
     q_b = b * hq * sq * d * q.dtype.itemsize
     kv_b = 2 * math.prod(k.shape) * k.dtype.itemsize
-    rows = b * hq * sq * 128 * 4
+    stats = b * hq * sq * 4
     nbytes = {
-        "flash_fwd": 2 * q_b + kv_b + (rows if residuals else 0),
-        "flash_bwd_dq": 3 * q_b + kv_b + 2 * rows,
-        "flash_bwd_dkdv": 2 * q_b + kv_b + 2 * rows
+        "flash_fwd": 2 * q_b + kv_b + (128 * stats if residuals else 0),
+        "flash_bwd_dq": 3 * q_b + kv_b + 2 * stats,
+        "flash_bwd_dkdv": 2 * q_b + kv_b + 2 * stats
         + 2 * b * hq * sk * d * k.dtype.itemsize,
     }[kernel]
     note_kernel_cost(kernel, flops, nbytes)

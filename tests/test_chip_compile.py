@@ -87,6 +87,10 @@ CASES = {
     "flash-fwd-d128": lambda c: _flash(c, 4, 32, 8, 2048, 128, backward=False),
     "flash-bwd-d128": lambda c: _flash(c, 4, 32, 8, 2048, 128, backward=True),
     "paged-staging-d128": lambda c: _paged_staging(c, 32, 8, 4, 128, 64),
+    # q blocks the default 1024 does not divide: 768 (six lane tiles) and
+    # 688 (no multiple of 128): the backward's [1, block_q] statistic rows
+    "flash-bwd-s1536": lambda c: _flash(c, 1, 8, 4, 1536, 128, backward=True),
+    "flash-bwd-s2064": lambda c: _flash(c, 1, 8, 4, 2064, 128, backward=True),
 }
 
 
@@ -94,3 +98,25 @@ CASES = {
 def test_kernel_compiles_for_the_chip(chip, case):
     program = CASES[case](chip).compile().as_text()
     assert "tpu_custom_call" in program
+
+
+@pytest.mark.parametrize("names,kernels", [
+    (("q", "k", "v", "attn_out", "attn_lse"), 3), ((), 4)], ids=["saved", "recomputed"])
+def test_saved_residual_names_decide_the_forward_kernel_count(chip, names, kernels):
+    """internlm2-1.8b's attention at the benchmark's 2 x 4096: with the
+    rule's residual names saved (remat ``attn``) the chip's program holds
+    the forward kernel once beside dQ and dK/dV; with nothing saved it
+    holds it twice. The backward kernels' [1, block_q] lse and delta rows
+    compile too."""
+    q = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 4096, 128), jnp.bfloat16, sharding=chip)
+    attend = jax.checkpoint(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False),
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+    def loss(q, k, v):
+        # a nonlinear tail, so that the backward pass needs the output
+        return (attend(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    program = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert program.count('custom_call_target="tpu_custom_call"') == kernels

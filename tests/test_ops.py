@@ -4,6 +4,7 @@ full attention, rope/rmsnorm sanity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 try:
     from jax import shard_map
 except ImportError:  # jax < 0.6: keep the kernel tests collectable
@@ -113,6 +114,39 @@ def test_flash_attention_grads_match_reference():
                   argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("hq,hkv,seq", [(4, 2, 256), (4, 4, 256), (4, 2, 100)],
+                         ids=["gqa", "mha", "ragged-fallback"])
+def test_flash_attention_grads_under_saved_residual_names(hq, hkv, seq):
+    """The vjp rule names its residuals (``attn_out``, compact ``attn_lse``):
+    a ``jax.checkpoint`` policy that saves them hands the backward kernels
+    copies, not a recomputation, so the gradients are the same bits as
+    without ``jax.checkpoint`` — and the forward kernel is traced once."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (2, hq, seq, 32))
+    k = jax.random.normal(ks[1], (2, hkv, seq, 32))
+    v = jax.random.normal(ks[2], (2, hkv, seq, 32))
+
+    def attend(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal=True, block_q=128, block_k=128)
+
+    def loss(f):
+        return lambda q_, k_, v_: (f(q_, k_, v_) ** 2).sum()
+
+    saved = jax.checkpoint(attend, policy=jax.checkpoint_policies.save_only_these_names(
+        "q", "k", "v", "attn_out", "attn_lse"))
+    plain = jax.grad(loss(attend), argnums=(0, 1, 2))
+    under = jax.grad(loss(saved), argnums=(0, 1, 2))
+    ref = jax.grad(loss(lambda a, b, c: mha_reference(a, b, c, causal=True)),
+                   argnums=(0, 1, 2))
+    for got, same, close in zip(under(q, k, v), plain(q, k, v), ref(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+        np.testing.assert_allclose(got, close, atol=2e-4, rtol=2e-4)
+    if seq % 16 == 0:  # on the kernels: saved, the forward runs once; unsaved, twice
+        count = lambda f: str(jax.make_jaxpr(f)(q, k, v)).count("name=flash_fwd")
+        assert count(under) == 1
+        assert count(jax.grad(loss(jax.checkpoint(attend)), argnums=(0, 1, 2))) == 2
 
 
 def test_flash_block_fits_seq_divisors():
