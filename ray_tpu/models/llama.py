@@ -54,11 +54,19 @@ class LlamaConfig:
     # for ~1 forward less recompute per step.
     remat_policy: str = "full"
     # MoE: when n_experts > 0 the MLP becomes a top-k routed expert layer
-    # sharded over the ``ep`` mesh axis.
+    # (models/moe.py: dropless), ``intermediate`` is ONE expert's width and
+    # the experts shard over the ``ep`` mesh axis. These are facts of an
+    # architecture, not knobs: whether the k chosen gates are renormalised
+    # (Mixtral: yes; OLMoE: no) and the weights of the two auxiliary terms
+    # (load balance; router z-loss), each the mean over layers.
     moe_experts: int = 0
     moe_top_k: int = 2
+    moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
-    moe_capacity_factor: float = 1.25
+    moe_z_weight: float = 0.0
+    # OLMoE: an RMSNorm with a learned weight over the whole q and the whole
+    # k projection, before the split into heads and before rope.
+    qk_norm: bool = False
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
 
@@ -80,10 +88,10 @@ PRESETS: dict[str, LlamaConfig] = {
     # MoE family (Mixtral-style top-2 routing)
     "llama-moe-debug": LlamaConfig(vocab_size=256, hidden=64, n_layers=2, n_heads=4,
                                    n_kv_heads=2, intermediate=128, head_dim=16,
-                                   moe_experts=4),
+                                   moe_experts=4, moe_norm_topk=True),
     "mixtral-8x7b-ish": LlamaConfig(hidden=4096, n_layers=32, n_heads=32,
                                     n_kv_heads=8, intermediate=14_336, head_dim=128,
-                                    moe_experts=8),
+                                    moe_experts=8, moe_norm_topk=True),
 }
 
 
@@ -99,10 +107,13 @@ def param_axes(config: LlamaConfig):
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         }
+    qk_axes = ({"q_norm": ("layers", "norm"), "k_norm": ("layers", "norm")}
+               if config.qk_norm else {})
     return {
         "embed": ("vocab_in", "embed"),
         "layers": {
             "attn_norm": ("layers", "norm"),
+            **qk_axes,
             "wq": ("layers", "embed", "heads", "head_dim"),
             "wk": ("layers", "embed", "kv_heads", "head_dim"),
             "wv": ("layers", "embed", "kv_heads", "head_dim"),
@@ -139,10 +150,13 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
             "w_up": norm_init(keys[6], (L, E, M), E),
             "w_down": norm_init(keys[7], (L, M, E), M),
         }
+    qk_params = ({"q_norm": jnp.ones((L, H * D), c.dtype),
+                  "k_norm": jnp.ones((L, KH * D), c.dtype)} if c.qk_norm else {})
     return {
         "embed": norm_init(keys[0], (c.vocab_size, E), E),
         "layers": {
             "attn_norm": jnp.ones((L, E), c.dtype),
+            **qk_params,
             "wq": norm_init(keys[1], (L, E, H, D), E),
             "wk": norm_init(keys[2], (L, E, KH, D), E),
             "wv": norm_init(keys[3], (L, E, KH, D), E),
@@ -204,11 +218,23 @@ def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
     return flash_attention(q, k, v, causal=True)
 
 
+def _norm_over_heads(t, weight, eps):
+    """RMSNorm of t [B, H, S, D] over all H*D features of a position (the
+    whole projection, as OLMoE normalises q and k), weight [H*D]; f32
+    statistics."""
+    _, h, _, d = t.shape
+    f = t.astype(jnp.float32)
+    var = jnp.mean(jnp.square(f), axis=(1, 3), keepdims=True)
+    w = weight.astype(jnp.float32).reshape(1, h, 1, d)
+    return (f * lax.rsqrt(var + eps) * w).astype(t.dtype)
+
+
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
            ep_axis: str | None = None):
-    """One decoder block. x: [B, S, E] in config.dtype. ``ep_axis`` is set
-    only when running per-device inside the pipeline shard_map (expert
-    shard + psum combine)."""
+    """One decoder block: x [B, S, E] in config.dtype -> (x, aux). ``aux``
+    is ``{}`` for a dense MLP and ``moe_block``'s for a routed one.
+    ``ep_axis`` is set only when running per-device inside the pipeline
+    shard_map (expert shard + psum combine)."""
     c = config
 
     def sc(t, axes):
@@ -222,6 +248,9 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
         q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
         k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
         v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
+        if c.qk_norm:
+            q = _norm_over_heads(q, layer["q_norm"], c.norm_eps)
+            k = _norm_over_heads(k, layer["k_norm"], c.norm_eps)
         q = apply_rope(q, positions, theta=c.rope_theta)
         k = apply_rope(k, positions, theta=c.rope_theta)
         q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
@@ -233,13 +262,12 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps)
-        aux = jnp.zeros((), jnp.float32)
+        aux = {}
         if c.moe_experts > 0:
             from .moe import moe_block
 
-            down, aux = moe_block(h, layer, top_k=c.moe_top_k, ep_axis=ep_axis,
-                                  n_experts_global=c.moe_experts,
-                                  capacity_factor=c.moe_capacity_factor)
+            down, aux = moe_block(h, layer, top_k=c.moe_top_k,
+                                  norm_topk=c.moe_norm_topk, ep_axis=ep_axis)
         else:
             gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"])
             up = jnp.einsum("bse,em->bsm", h, layer["w_up"])
@@ -268,10 +296,21 @@ def _apply_remat(block, c: LlamaConfig):
         # 869 ms step), and with the backward kernels reading lse as rows
         # the step takes 816.8 ms; the saved lse is 12.6 MB in all, its
         # slice costs 0.09 ms a layer (PERF.md, PR 25)
+        # A routed MLP adds what its routing produced (models/moe.py's
+        # ROUTE_NAMES: the softmax, the gates, the sort and the group
+        # sizes, ~6 MB a layer at 16k tokens), so the backward pass
+        # neither routes nor sorts again. The sorted expert inputs and
+        # the gate and up products (``moe_xs``, ``moe_gate``, ``moe_up``:
+        # 537 + 2 x 268 MB a layer there) are recomputed, one row gather
+        # and two grouped matmuls a layer: by the chip compiler's count
+        # OLMoE at 3 layers and 4 x 4096 is 13.79 GB so, and 17.02 GB
+        # with gate and up saved (PERF.md, PR 26).
+        from .moe import ROUTE_NAMES
+
         return jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "q", "k", "v", "attn_out", "attn_lse"
+                "q", "k", "v", "attn_out", "attn_lse", *ROUTE_NAMES,
             ),
         )
     return jax.checkpoint(block)
@@ -281,9 +320,12 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
                    return_aux: bool = False):
     """tokens [B, S] int32 -> final hidden states [B, S, E] in config.dtype.
 
-    ``return_aux=True`` additionally returns the summed MoE load-balancing
-    loss (always 0.0 for dense configs and on the pipelined path, which
-    does not thread aux through the schedule yet)."""
+    ``return_aux=True`` additionally returns what the routed layers
+    counted in the same pass: ``load_balance`` and ``z`` (the auxiliary
+    terms, each the mean over layers), ``rows_per_expert`` [L, X] int32
+    and ``rows_dropped`` (0: the dispatch is dropless). ``{}`` for dense
+    configs and on the pipelined path, which does not thread it through
+    the schedule yet."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -333,21 +375,20 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
             param_specs=param_specs,
         )
         out = rms_norm(x, params["final_norm"], eps=c.norm_eps)
-        return (out, jnp.zeros((), jnp.float32)) if return_aux else out
+        return (out, {}) if return_aux else out
 
     block = _apply_remat(
         functools.partial(_block, positions=positions, config=c, mesh=mesh), c
     )
 
-    def scan_body(carry, layer):
-        new_x, aux = block(carry, layer)
-        return new_x, aux
-
-    x, aux_per_layer = lax.scan(scan_body, x, params["layers"])
+    x, per_layer = lax.scan(block, x, params["layers"])
     out = rms_norm(x, params["final_norm"], eps=c.norm_eps)
-    if return_aux:
-        return out, jnp.sum(aux_per_layer)
-    return out
+    if not return_aux:
+        return out
+    return out, {"load_balance": jnp.mean(per_layer["load_balance"]),
+                 "z": jnp.mean(per_layer["z"]),
+                 "rows_per_expert": per_layer["rows"],
+                 "rows_dropped": jnp.sum(per_layer["dropped"])} if per_layer else {}
 
 
 def forward(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None):
@@ -361,7 +402,8 @@ def forward(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None):
 def train_flops_per_token(config: LlamaConfig, seq: int) -> float:
     """Model FLOPs per trained token (6N active-param matmul + causal
     attention), the numerator of MFU. Embedding gather excluded (standard
-    accounting); MoE counts the top_k ACTIVE experts plus the router."""
+    accounting); a routed MLP counts what the dropless dispatch computes
+    for a token: its top_k experts' three matrices, plus the router."""
     c = config
     if c.moe_experts > 0:
         mlp = c.moe_top_k * 3 * c.hidden * c.intermediate + c.hidden * c.moe_experts
@@ -381,19 +423,20 @@ def loss_fn(
     *,
     mesh: Mesh | None = None,
     chunk_tokens: int = 512,
+    return_aux: bool = False,
 ):
-    """Next-token cross entropy. batch: {"tokens": [B,S], "mask": [B,S]}.
+    """Next-token cross entropy, plus a routed model's auxiliary terms
+    (``moe_aux_weight`` x load balance + ``moe_z_weight`` x router z-loss).
+    batch: {"tokens": [B,S], "mask": [B,S]}. ``return_aux=True`` returns
+    ``(loss, aux)`` for ``value_and_grad(has_aux=True)``: ``ce`` and, for a
+    routed model, ``forward_hidden``'s counters from the same pass.
 
     The lm_head matmul is fused into a rematerialized scan over token
     chunks so the [B,S,vocab] logits tensor never exists in HBM — at 128k
     vocab that tensor alone would OOM a v5e chip at batch 8 × 2048.
     """
     tokens = batch["tokens"]
-    aux = jnp.zeros((), jnp.float32)
-    if config.moe_experts > 0:
-        hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
-    else:
-        hidden = forward_hidden(params, tokens, config, mesh=mesh)
+    hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
     with jax.named_scope("lm_head_loss"):
         targets = tokens[:, 1:]
         hidden = hidden[:, :-1]
@@ -436,4 +479,8 @@ def loss_fn(
              flat_m.reshape(nc, chunk)),
         )
         ce = -total / jnp.maximum(flat_m.sum(), 1.0)
-    return ce + config.moe_aux_weight * aux
+    loss = ce
+    if aux:
+        loss = (ce + config.moe_aux_weight * aux["load_balance"]
+                + config.moe_z_weight * aux["z"])
+    return (loss, {"ce": ce, **aux}) if return_aux else loss

@@ -1,7 +1,8 @@
-"""Which path each attention call took, recorded when it was TRACED.
+"""Which path each kernel call took, recorded when it was TRACED.
 
 The Pallas entry points pick native lowering or the interpreter from the
-backend, and flash attention swaps in ``mha_reference`` for ragged shapes.
+backend, flash attention swaps in ``mha_reference`` for ragged shapes, and
+the grouped matmul ``lax.ragged_dot`` for a row count no tile divides.
 Those decisions are taken in Python while a jitted program is traced, so
 the compiled program cannot be asked afterwards; this record can. A
 worker reports it (train metrics, ``LLMDeployment.engine_metrics``) and
@@ -11,7 +12,7 @@ The same moment knows the shapes, so the record also carries what one
 call of each Pallas kernel costs (``kernel_costs``): the program's half
 of a roofline share, whose other half is the kernel's seconds under the
 same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
-``flash_bwd_dkdv``, ``paged_decode``).
+``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``).
 """
 
 from __future__ import annotations
@@ -30,7 +31,8 @@ _costs: dict[str, dict] = {}
 
 def note_kernel_trace(kernel: str, path: str) -> None:
     """Count one trace of ``kernel`` down ``path`` (``"pallas"``,
-    ``"interpret"`` or ``"mha_reference"``); log the first of each."""
+    ``"interpret"``, ``"mha_reference"`` or ``"ragged_dot"``); log the
+    first of each."""
     key = f"{kernel}:{path}"
     with _lock:
         _counts[key] += 1

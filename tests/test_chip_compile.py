@@ -19,6 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
@@ -78,6 +79,24 @@ def _paged_staging(chip, layers, kh, g, d, page, slots=8, max_len=2560, k_steps=
     return jax.jit(fn).lower(q, pool, pool, tables, pos, stage, stage, scalar, scalar)
 
 
+def _grouped(chip, k, n, backward, rows=131072, groups=64):
+    """OLMoE's expert products at 4 x 4096 tokens x 8 experts a token: the
+    sorted rows against 64 matrices; backward adds the same product against
+    the transposed matrices and the transposed product (``moe_tgmm``)."""
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=chip)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16, sharding=chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=chip)
+
+    def fwd(lhs, rhs, sizes):
+        return grouped_matmul(lhs, rhs, sizes, interpret=False)
+
+    def loss(lhs, rhs, sizes):
+        return (fwd(lhs, rhs, sizes).astype(jnp.float32) ** 2).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1)) if backward else fwd
+    return jax.jit(fn).lower(lhs, rhs, sizes)
+
+
 CASES = {
     # llama3-1b widths: 32 q / 8 kv heads of 64, the train batch
     "flash-fwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=False),
@@ -91,6 +110,11 @@ CASES = {
     # 688 (no multiple of 128): the backward's [1, block_q] statistic rows
     "flash-bwd-s1536": lambda c: _flash(c, 1, 8, 4, 1536, 128, backward=True),
     "flash-bwd-s2064": lambda c: _flash(c, 1, 8, 4, 2064, 128, backward=True),
+    # OLMoE-1B-7B's grouped matmuls at the benchmark's 131,072 rows: gate/up
+    # (2048 -> 1024) and down (1024 -> 2048), each with its two gradients
+    "moe-gmm-up": lambda c: _grouped(c, 2048, 1024, backward=False),
+    "moe-gmm-up-grad": lambda c: _grouped(c, 2048, 1024, backward=True),
+    "moe-gmm-down-grad": lambda c: _grouped(c, 1024, 2048, backward=True),
 }
 
 

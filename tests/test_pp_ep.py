@@ -52,7 +52,7 @@ def test_pipeline_grads_match():
 
 
 def _moe_reference(x, params, top_k):
-    """Per-token loop reference for the dense dispatch path."""
+    """Per-token loop reference for the routed MLP (renormalised gates)."""
     b, s, e = x.shape
     tokens = np.asarray(x, np.float32).reshape(-1, e)
     router = np.asarray(params["router"], np.float32)
@@ -75,9 +75,9 @@ def test_moe_block_matches_reference():
     key = jax.random.PRNGKey(0)
     params = init_moe_params(key, hidden=16, expert_mlp=32, n_experts=4, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16), jnp.float32)
-    # capacity large enough that nothing is dropped
-    out, aux = moe_block(x, params, top_k=2, capacity_factor=4.0)
-    assert float(aux) >= 1.0
+    out, aux = moe_block(x, params, top_k=2, norm_topk=True)
+    assert float(aux["load_balance"]) >= 1.0
+    assert int(aux["dropped"]) == 0 and int(aux["rows"].sum()) == 2 * 8 * 2
     ref = _moe_reference(x, params, top_k=2)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-4)
 
@@ -105,9 +105,7 @@ def test_pp_ep_composed():
     cfg = dataclasses.replace(
         PRESETS["llama-moe-debug"], attn_impl="reference", dtype=jnp.float32,
         remat=False, pipeline_microbatches=2,
-        # no token drops: per-microbatch capacity differs from the global
-        # one, so equivalence needs headroom
-        moe_capacity_factor=4.0,
+        # the dispatch is dropless, so a microbatch routes as the whole does
         # the pipelined path does not thread the aux loss yet; zero it for
         # exact equivalence with the scan path
         moe_aux_weight=0.0,
