@@ -23,8 +23,8 @@ attacking the owner→raylet→GCS hot path (PERF.md "core task path").
 
 Sizes are env-tunable (``RAY_TPU_CORE_BENCH_{TASKS,ACTORS,CALLS,OBJECTS}``);
 the defaults finish in a couple of minutes on a laptop-class node. Run
-standalone via ``python -m ray_tpu.cli bench core`` or as part of
-``bench.py``.
+via ``python -m ray_tpu.cli bench core``. Host rates only: nothing here
+touches a device.
 """
 
 from __future__ import annotations

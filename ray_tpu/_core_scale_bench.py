@@ -1,6 +1,6 @@
 """Cluster-scale core bench: many raylets, one GCS, one host (ROADMAP 4).
 
-The single-node suite (``_core_bench.py``) measures the owner→raylet hot
+The single-node suite (``ray_tpu._core_bench``) measures the owner→raylet hot
 path; this one stands up a MANY-RAYLET harness (``cluster_utils.Cluster``
 — raylets are real asyncio services, workers are real subprocesses) and
 drives the reference's cluster-scale shape: a task storm spilling across

@@ -28,8 +28,7 @@ Two phases, guarded by ``ray_tpu.bench_check``:
     absence as intentional, never as a silent regression.
 
 Sizes are env-tunable (``RAY_TPU_DAG_BENCH_{TICKS,DECODE_BURSTS}``). Run
-standalone via ``python -m ray_tpu.cli bench dag`` or as part of
-``bench.py``.
+via ``python -m ray_tpu.cli bench dag``.
 """
 
 from __future__ import annotations

@@ -7,15 +7,15 @@ round-5 ``flash_fwdbwd_tflops_s4096`` 26.16 → 22.99 slide, or a metric
 silently VANISHING (round 5's ``serve_p50_ttft_ms``, lost to a replica
 startup failure), gets flagged at PR time instead of two rounds later.
 
-Accepts either a bare metrics object (what ``bench.py`` prints) or the
-driver's ``BENCH_rNN.json`` wrapper (metrics under ``"parsed"``).
+Accepts either a bare metrics object (what ``cli bench core`` / ``cli
+bench dag`` print) or a ``BENCH_rNN.json`` wrapper (metrics under ``"parsed"``).
 
 Direction is inferred from the metric name: ``*_ms`` / ``*_pct`` /
 latency-like metrics regress UP, throughput-like metrics regress DOWN;
 bookkeeping fields (counts, config echoes, error strings) are skipped.
-``bench.py`` runs this automatically against the most recent
-``BENCH_r*.json`` in the working directory (report-only — the bench
-still records its numbers; CI decides what to do with the exit code).
+``cli bench core|dag --check-against FILE`` runs this over that suite's
+slice of a recorded file. These are host rates; the device's numbers are
+``benchmark/``'s and are compared by the driver, not here.
 """
 
 from __future__ import annotations
@@ -149,9 +149,9 @@ def _tracked(name: str, value) -> bool:
 
 def _skip_prefixes(new: dict) -> tuple:
     """``<prefix>_skipped: true`` markers: the run declares it
-    INTENTIONALLY skipped every ``<prefix>*`` metric (e.g. a serve-matrix
-    cell filtered out via RAY_TPU_SERVE_MATRIX_CELLS). Such metrics are
-    reported as skipped, never as silently vanished."""
+    INTENTIONALLY skipped every ``<prefix>*`` metric (e.g.
+    ``core_scale_skipped`` under RAY_TPU_BENCH_SKIP_CORE_SCALE=1). Such
+    metrics are reported as skipped, never as silently vanished."""
     return tuple(k[: -len("_skipped")] for k, v in new.items()
                  if k.endswith("_skipped") and v)
 
@@ -236,8 +236,7 @@ def format_report(result: dict, old_path: str = "old", new_path: str = "new",
 
 
 def latest_bench_json(directory: str = ".") -> str | None:
-    """Most recent driver-recorded BENCH_r*.json, for bench.py's
-    self-check after a run."""
+    """Most recent recorded BENCH_r*.json in ``directory``."""
     paths = sorted(glob.glob(os.path.join(directory, "BENCH_r[0-9]*.json")))
     return paths[-1] if paths else None
 

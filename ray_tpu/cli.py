@@ -73,96 +73,6 @@ def _cmd_bench(args) -> int:
         result = run_dag_bench(ticks=args.ticks, bursts=args.bursts)
         ok = bool(result.get("dag_tick_dispatch_overhead_us"))
         prefixes = ("dag_", "pp_decode_", "loop_obs_")
-    elif args.bench_cmd == "recovery":
-        from ray_tpu._recovery_bench import run_recovery_bench
-
-        result = run_recovery_bench(train_steps=args.train_steps,
-                                    grace_s=args.grace)
-        ok = bool(result.get("recovery_train_resume_s") is not None
-                  or result.get("recovery_serve_reroute_s") is not None)
-        prefixes = ("recovery_",)
-    elif args.bench_cmd == "migration":
-        from ray_tpu._migration_bench import run_migration_bench
-
-        result = run_migration_bench(samples=args.samples)
-        ok = bool(result.get("serve_ttft_migrated_ms") is not None)
-        prefixes = ("serve_ttft_migrated", "serve_ttft_cold",
-                    "kv_migration_")
-    elif args.bench_cmd == "overload":
-        from ray_tpu._overload_bench import run_overload_bench
-
-        result = run_overload_bench(storm_s=args.storm,
-                                    deadline_ms=args.deadline_ms)
-        on = result.get("serve_goodput_frac")
-        off = result.get("serve_goodput_frac_unprotected")
-        # Acceptance: protection ON strictly beats the unprotected
-        # baseline cell, and admitted work keeps byte parity.
-        ok = bool(on is not None and off is not None and on > off
-                  and result.get("serve_overload_parity", 1.0) == 1.0)
-        prefixes = ("serve_goodput_", "serve_shed_", "serve_admitted_",
-                    "serve_overload_")
-    elif args.bench_cmd == "train":
-        from ray_tpu._train_loop_bench import run_train_loop_bench
-
-        result = run_train_loop_bench(ticks=args.ticks, steps=args.steps)
-        # Acceptance: the compiled loop kills ≥ 5x of the eager per-step
-        # dispatch, keeps MFU no worse, and genuinely overlaps the
-        # checkpoint commit with step compute.
-        eager_us = result.get("train_step_dispatch_overhead_eager_us")
-        loop_us = result.get("train_step_dispatch_overhead_us")
-        ok = bool(
-            eager_us and loop_us and eager_us >= 5.0 * loop_us
-            and result.get("train_mfu_loop", 0)
-            >= 0.95 * result.get("train_mfu_eager", 0)
-            and (result.get("train_ckpt_overlap_frac") or 0) > 0.5
-        ) or bool(result.get("train_mfu_skipped"))
-        prefixes = ("train_mfu", "train_step_dispatch_", "train_ckpt_",
-                    "train_loop_", "train_eager_")
-    elif args.bench_cmd == "speculative":
-        from ray_tpu._speculative_bench import run_speculative_bench
-
-        result = run_speculative_bench(slots=args.slots,
-                                       max_new=args.new_tokens,
-                                       draft_k=args.draft_k)
-        # Acceptance: speculation amortizes target forwards (> 1 token
-        # per slot per verify dispatch) AND stays lossless.
-        ok = bool(result.get("spec_tokens_per_dispatch", 0) > 1.0
-                  and result.get("spec_parity", 1.0) == 1.0) \
-            or bool(result.get("decode_tok_s_speculative_skipped"))
-        prefixes = ("decode_tok_s_", "spec_")
-    elif args.bench_cmd == "tenancy":
-        from ray_tpu._tenancy_bench import run_tenancy_bench
-
-        result = run_tenancy_bench(storm_s=args.storm)
-        # Acceptance (ISSUE 16): mixed-adapter decode is byte-exact AND
-        # one dispatch carries the whole adapter mix (dispatch count
-        # flat vs a single-adapter batch); the noisy tenant's storm
-        # moves the quiet tenant's p95 TTFT ≤ 15%; per-tenant goodput
-        # under the mixed hot/cold storm is recorded.
-        solo = result.get("tenant_quiet_p95_ttft_ms_solo")
-        noisy = result.get("tenant_quiet_p95_ttft_ms_noisy")
-        ok = bool(
-            result.get("tenant_mixed_batch_parity", 0.0) == 1.0
-            and result.get("tenant_mixed_dispatch_parity", 0.0) == 1.0
-            and solo and noisy is not None and noisy <= 1.15 * solo
-            and result.get("tenant_goodput_frac_hot") is not None
-            and result.get("tenant_goodput_frac_cold") is not None
-        ) or bool(result.get("tenant_mixed_batch_parity_skipped"))
-        prefixes = ("tenant_", "adapter_")
-    elif args.bench_cmd == "fleet":
-        from ray_tpu._fleet_bench import run_fleet_bench
-
-        result = run_fleet_bench(step_s=args.step)
-        # Acceptance (ISSUE 19): standby promotion ≥ 10× faster than a
-        # cold replica start, the fan-out weight broadcast is
-        # byte-identical to direct load, and goodput through the 10×
-        # offered-rate step is recorded.
-        ok = bool(
-            result.get("serve_replica_promote_speedup", 0.0) >= 10.0
-            and result.get("fleet_broadcast_parity", 0.0) == 1.0
-            and result.get("fleet_goodput_frac_step") is not None
-        ) or bool(result.get("fleet_skipped"))
-        prefixes = ("fleet_", "serve_replica_")
     elif args.bench_cmd == "core" and getattr(args, "scale", False):
         import os
 
@@ -316,128 +226,6 @@ def main(argv: list[str] | None = None) -> int:
     bdag.add_argument("--check-against", default=None, metavar="BENCH_JSON",
                       help="run ray_tpu.bench_check against a recorded "
                            "BENCH_r*.json and exit non-zero on regression")
-    brec = bench_sub.add_parser(
-        "recovery", help="preemption recovery SLO suite: preempt-mid-train "
-                         "and preempt-mid-serve through the real notice→"
-                         "drain→kill path (recovery_train_resume_s, "
-                         "recovery_serve_reroute_s, recovery_ckpt_lag_steps;"
-                         " *_skipped markers where a scenario can't run)")
-    brec.add_argument("--train-steps", type=int, default=None,
-                      help="train steps in the preempt-mid-train scenario "
-                           "(default $RAY_TPU_RECOVERY_BENCH_TRAIN_STEPS "
-                           "or 24)")
-    brec.add_argument("--grace", type=float, default=None,
-                      help="preemption grace window in seconds (default "
-                           "$RAY_TPU_RECOVERY_BENCH_GRACE_S or 0.5)")
-    brec.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                      help="run ray_tpu.bench_check against a recorded "
-                           "BENCH_r*.json and exit non-zero on regression")
-    bmig = bench_sub.add_parser(
-        "migration", help="KV-migration cells: migrated vs cold TTFT at "
-                          "the 2k-prompt cell (serve_ttft_migrated_ms must "
-                          "beat 0.7x serve_ttft_cold_ms), greedy byte "
-                          "parity, and raw page-transfer throughput "
-                          "(kv_migration_mb_s); *_skipped markers where "
-                          "a cell can't run")
-    bmig.add_argument("--samples", type=int, default=None,
-                      help="cold/migrated prompt pairs (default "
-                           "$RAY_TPU_MIGRATION_SAMPLES or 3)")
-    bmig.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                      help="run ray_tpu.bench_check against a recorded "
-                           "BENCH_r*.json and exit non-zero on regression")
-    bovl = bench_sub.add_parser(
-        "overload", help="overload-protection cells: a 2x-capacity "
-                         "thundering herd with request deadlines + "
-                         "bounded queues vs an unprotected baseline "
-                         "(serve_goodput_frac must strictly beat "
-                         "serve_goodput_frac_unprotected; "
-                         "serve_shed_fast_fail_p95_ms is the time-to-503;"
-                         " admitted requests keep greedy byte parity)")
-    bovl.add_argument("--storm", type=float, default=None,
-                      help="storm window in seconds (default "
-                           "$RAY_TPU_OVERLOAD_STORM_S or 8)")
-    bovl.add_argument("--deadline-ms", type=float, default=None,
-                      help="per-request deadline in the protected phase "
-                           "(default $RAY_TPU_OVERLOAD_DEADLINE_MS or "
-                           "2500)")
-    bovl.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                      help="run ray_tpu.bench_check against a recorded "
-                           "BENCH_r*.json and exit non-zero on regression")
-    btrain = bench_sub.add_parser(
-        "train", help="train compiled-loop cells: per-step dispatch "
-                      "overhead eager vs compiled "
-                      "(train_step_dispatch_overhead{_eager,}_us, "
-                      "compiled must be ≥ 5x lower), real-step MFU both "
-                      "ways (train_mfu_{eager,loop}, loop ≥ eager), and "
-                      "the checkpoint-commit overlap fraction "
-                      "(train_ckpt_overlap_frac > 0.5); "
-                      "RAY_TPU_BENCH_SKIP_TRAIN_LOOP=1 emits *_skipped "
-                      "markers")
-    btrain.add_argument("--loop", action="store_true",
-                        help="run the compiled-loop suite (the default — "
-                             "the suite always measures BOTH drive modes; "
-                             "the flag documents intent)")
-    btrain.add_argument("--ticks", type=int, default=None,
-                        help="dispatch-overhead steps per mode (default "
-                             "$RAY_TPU_TRAIN_LOOP_BENCH_TICKS or 150)")
-    btrain.add_argument("--steps", type=int, default=None,
-                        help="MFU-phase train steps per mode (default "
-                             "$RAY_TPU_TRAIN_LOOP_BENCH_STEPS or 24)")
-    btrain.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                        help="run ray_tpu.bench_check against a recorded "
-                             "BENCH_r*.json and exit non-zero on regression")
-    bspec = bench_sub.add_parser(
-        "speculative", help="speculative-decoding cells: plain vs "
-                            "draft-K/verify decode tok/s on repetitive "
-                            "traffic (decode_tok_s_{plain,speculative}), "
-                            "n-gram drafter accept rate, tokens per slot "
-                            "per verify dispatch (must beat 1.0), and "
-                            "greedy byte parity (spec_parity must be "
-                            "1.0); *_skipped markers via "
-                            "RAY_TPU_BENCH_SKIP_SPECULATIVE=1")
-    bspec.add_argument("--slots", type=int, default=None,
-                       help="batch slots (default $RAY_TPU_SPEC_BENCH_SLOTS "
-                            "or 8)")
-    bspec.add_argument("--new-tokens", type=int, default=None,
-                       help="generated tokens per request (default "
-                            "$RAY_TPU_SPEC_BENCH_NEW or 96)")
-    bspec.add_argument("--draft-k", type=int, default=None,
-                       help="drafted tokens per verify dispatch (default "
-                            "$RAY_TPU_SPEC_BENCH_K or 6)")
-    bspec.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                       help="run ray_tpu.bench_check against a recorded "
-                            "BENCH_r*.json and exit non-zero on regression")
-    bten = bench_sub.add_parser(
-        "tenancy", help="multi-tenant multiplexing cells: quiet-tenant "
-                        "TTFT p95 solo vs under a quota-shed noisy "
-                        "storm (must move ≤ 15%), per-tenant goodput "
-                        "with a hot (resident) vs cold (LRU hot-load) "
-                        "adapter under a mixed 2x storm, mixed-adapter "
-                        "greedy byte parity + one-dispatch decode "
-                        "(tenant_mixed_{batch,dispatch}_parity must be "
-                        "1.0), and adapter_hot_load_ms; *_skipped "
-                        "markers via RAY_TPU_BENCH_SKIP_TENANCY=1")
-    bten.add_argument("--storm", type=float, default=None,
-                      help="mixed hot/cold storm seconds (default "
-                           "$RAY_TPU_TENANCY_STORM_S or 6)")
-    bten.add_argument("--check-against", default=None, metavar="BENCH_JSON",
-                      help="run ray_tpu.bench_check against a recorded "
-                           "BENCH_r*.json and exit non-zero on regression")
-    bfleet = bench_sub.add_parser(
-        "fleet", help="always-warm fleet cells: standby promotion vs "
-                      "cold replica start (serve_replica_promote_s, "
-                      "speedup must be ≥ 10x), fan-out weight-broadcast "
-                      "byte parity (fleet_broadcast_parity must be 1.0), "
-                      "and goodput through a 10x offered-rate step "
-                      "against a 1-running + 1-standby deployment; "
-                      "*_skipped markers via RAY_TPU_BENCH_SKIP_FLEET=1")
-    bfleet.add_argument("--step", type=float, default=None,
-                        help="traffic-step seconds (default "
-                             "$RAY_TPU_FLEET_STEP_S or 6)")
-    bfleet.add_argument("--check-against", default=None,
-                        metavar="BENCH_JSON",
-                        help="run ray_tpu.bench_check against a recorded "
-                             "BENCH_r*.json and exit non-zero on regression")
     serve_p = sub.add_parser(
         "serve", help="Serve control-plane inspection")
     serve_sub = serve_p.add_subparsers(dest="serve_cmd", required=True)

@@ -21,6 +21,7 @@ fault tolerance (``redis_store_client.h:107``).
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import time
 from typing import Any
@@ -124,6 +125,26 @@ class Publisher:
                     return {}
 
 
+def durable(handler):
+    """Marks a handler whose reply acknowledges a change to a durable
+    table (kv, jobs, actors, named actors, placement groups). Its reply
+    leaves only after a snapshot that holds the change is stored, so what
+    a client was told has happened survives a GCS crash: an acknowledged
+    function export that the 200 ms snapshot window lost is never sent
+    again (its owner holds it exported) and every later actor of that
+    class dies at creation; a lost job id is handed out twice. What the
+    GCS changes on its own (an actor going ALIVE, a group PLACED) is
+    nobody's acknowledgement and rides the periodic snapshot."""
+
+    @functools.wraps(handler)
+    async def committed(self, p: dict) -> dict:
+        reply = await handler(self, p)
+        await self._commit()
+        return reply
+
+    return committed
+
+
 class GcsServer:
     def __init__(self, host: str = "127.0.0.1", port: int = 0, storage=None,
                  session_dir: str | None = None):
@@ -138,6 +159,7 @@ class GcsServer:
 
         self._storage = storage or MemoryStorage()
         self._last_snapshot: bytes = b""
+        self._commit_waiters: asyncio.Future | None = None
         self._persist_task: asyncio.Task | None = None
         # Every background coroutine (actor creation, PG scheduling) is
         # tracked so crash()/stop() can cancel them — a "dead" GCS must not
@@ -237,7 +259,12 @@ class GcsServer:
         """Die WITHOUT a final flush — simulates abrupt GCS process death
         for fault-tolerance tests (only snapshots the persist loop already
         wrote survive)."""
+        from .gcs_storage import MemoryStorage
+
         self._cancel_bg()
+        # A dead process writes nothing: handlers still unwinding on the
+        # shared test loop must not snapshot over the restarted GCS's file.
+        self._storage = MemoryStorage()
         await self._server.stop(grace=0.0)
 
     @property
@@ -255,21 +282,50 @@ class GcsServer:
             "placement_groups": self._placement_groups,
         }
 
-    def _flush(self) -> None:
-        """Snapshot the durable tables if they changed. Change detection by
+    def _snapshot(self) -> None:
+        """Store the durable tables if they changed. Change detection by
         comparing the packed blob — cheaper than instrumenting every
         mutation site and can never miss one."""
-        if not self._storage.persistent:
-            return
         from .gcs_storage import pack_tables
 
+        blob = pack_tables(self._tables())
+        if blob != self._last_snapshot:
+            self._storage.save_blob(blob)
+            self._last_snapshot = blob
+
+    def _flush(self) -> None:
+        if not self._storage.persistent:
+            return
         try:
-            blob = pack_tables(self._tables())
-            if blob != self._last_snapshot:
-                self._storage.save_blob(blob)
-                self._last_snapshot = blob
+            self._snapshot()
         except Exception:
             logger.exception("GCS table snapshot failed")
+
+    async def _commit(self) -> None:
+        """Return once a snapshot of the tables as they are now is stored
+        (``durable`` handlers, before they reply); raise if it could not
+        be. Group commit: the handlers that finish in one turn of the
+        event loop share one snapshot, so a storm of registrations writes
+        the tables once per turn, not once per registration."""
+        if not self._storage.persistent:
+            return
+        if self._commit_waiters is None:
+            loop = asyncio.get_running_loop()
+            self._commit_waiters = loop.create_future()
+            loop.call_soon(self._commit_now)
+        # shielded: one cancelled waiter must not cancel the others' future
+        await asyncio.shield(self._commit_waiters)
+
+    def _commit_now(self) -> None:
+        waiters, self._commit_waiters = self._commit_waiters, None
+        try:
+            if self._storage.persistent:  # not crashed meanwhile
+                self._snapshot()
+        except Exception as e:
+            logger.exception("GCS table snapshot failed")
+            waiters.set_exception(e)
+        else:
+            waiters.set_result(None)
 
     def _restore(self) -> None:
         tables = self._storage.load()
@@ -475,6 +531,7 @@ class GcsServer:
                 await self._restart_or_kill_actor(actor, f"node {node_id[:8]} died")
 
     # ---------------------------------------------------------- job manager
+    @durable
     async def handle_AddJob(self, p: dict) -> dict:
         job_id = self._next_job
         self._next_job += 1
@@ -486,6 +543,7 @@ class GcsServer:
         }
         return {"job_id": job_id}
 
+    @durable
     async def handle_FinishJob(self, p: dict) -> dict:
         job = self._jobs.get(str(p["job_id"]))
         if job:
@@ -497,6 +555,7 @@ class GcsServer:
         return {"jobs": list(self._jobs.values())}
 
     # ------------------------------------------------------------ internal KV
+    @durable
     async def handle_KvPut(self, p: dict) -> dict:
         key = p["key"]
         overwrite = p.get("overwrite", True)
@@ -510,6 +569,7 @@ class GcsServer:
         value = self._kv.get(p["key"])
         return {"value": value, "found": value is not None}
 
+    @durable
     async def handle_KvDel(self, p: dict) -> dict:
         existed = self._kv.pop(p["key"], None) is not None
         return {"deleted": existed}
@@ -851,6 +911,7 @@ class GcsServer:
         return {"messages": out}
 
     # ---------------------------------------------------------- actor manager
+    @durable
     async def handle_RegisterActor(self, p: dict) -> dict:
         """Register + asynchronously create an actor (gcs_actor_manager.cc:389,475)."""
         spec = p["spec"]
@@ -1062,6 +1123,7 @@ class GcsServer:
             ]
         }
 
+    @durable
     async def handle_ReportActorDeath(self, p: dict) -> dict:
         """Raylet/worker reports an actor's process died (OnWorkerDead)."""
         record = self._actors.get(p["actor_id"])
@@ -1085,6 +1147,7 @@ class GcsServer:
         await self._restart_or_kill_actor(record, p.get("reason", "worker died"))
         return {}
 
+    @durable
     async def handle_KillActor(self, p: dict) -> dict:
         record = self._actors.get(p["actor_id"])
         if record is None:
@@ -1135,6 +1198,7 @@ class GcsServer:
             await self._publish_actor(record)
 
     # ------------------------------------------------------ placement groups
+    @durable
     async def handle_CreatePlacementGroup(self, p: dict) -> dict:
         pg_id = p["pg_id"].hex() if isinstance(p["pg_id"], bytes) else p["pg_id"]
         record = {
@@ -1266,6 +1330,7 @@ class GcsServer:
         record = self._placement_groups.get(p["pg_id"])
         return {"found": record is not None, "pg": record}
 
+    @durable
     async def handle_RemovePlacementGroup(self, p: dict) -> dict:
         record = self._placement_groups.pop(p["pg_id"], None)
         if record and record["state"] == "PENDING":

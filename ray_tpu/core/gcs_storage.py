@@ -4,9 +4,13 @@ Equivalent of the reference's GCS fault-tolerance storage
 (``src/ray/gcs/store_client/redis_store_client.h:107``): cluster metadata
 (KV, jobs, actors, named actors, placement groups) survives a GCS
 restart. Redesign: instead of an external Redis, a local atomic-rename
-snapshot (msgpack) flushed by a dirty-flag loop — the GCS is the only
-writer, so a WAL buys nothing over cheap whole-table snapshots at this
-metadata volume, and there is no external service to operate.
+snapshot (msgpack), written by a 200 ms loop and, before the reply, by
+every handler that acknowledges a change to these tables (``gcs.durable``,
+group-committed per turn of the event loop) — the GCS is the only writer
+and there is no external service to operate. A snapshot costs O(state):
+about 2 ms an acknowledged change at 100 actors and 200 KiB of KV, 4 ms
+at 2 MiB (CPU, PR 29); a deployment that writes the KV hot wants an
+append log here.
 """
 
 from __future__ import annotations

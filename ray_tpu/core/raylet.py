@@ -153,6 +153,8 @@ class WorkerHandle:
     # leases only match workers with the same env (worker_pool.h:524
     # runtime-env-hash matching).
     env_hash: str = ""
+    # Where this worker's stdout and stderr go ("" = not spawned here).
+    log_path: str = ""
     lease_resources: ResourceSet = field(default_factory=ResourceSet)
     # Host chip indices this worker's TPU lease owns (exported into its
     # environment at spawn; returned when the lease's fence completes).
@@ -223,6 +225,11 @@ class Raylet:
         self.object_store_capacity = object_store_capacity
 
         self._workers: dict[str, WorkerHandle] = {}
+        # Log file -> bytes forwarded, for the workers THIS raylet spawned:
+        # the session directory may be shared with other raylets and other
+        # clusters on the host, and the log monitor forwards only its own.
+        # An entry goes once its worker is dead and its file is forwarded.
+        self._log_offsets: dict[str, int] = {}
         self._idle: list[str] = []
         self._lease_waiters: list[asyncio.Future] = []
         # Resource-admission queue: (priority, seq)-ordered waiters; the
@@ -1071,6 +1078,7 @@ class Raylet:
     def _start_worker(self, runtime_env: dict | None = None) -> WorkerHandle:
         worker_id = WorkerID.from_random().hex()
         log_path = os.path.join(self._session_dir, f"worker-{worker_id[:12]}.out")
+        self._log_offsets[log_path] = 0
         env_hash = self._env_hash(runtime_env)
         if get_config().enable_worker_zygote and self._zygote_eligible(runtime_env):
             # Fork from the env-keyed warm zygote image (~ms) instead of
@@ -1084,7 +1092,7 @@ class Raylet:
             if pid is not None:
                 handle = WorkerHandle(worker_id=worker_id, pid=pid,
                                       proc=PidHandle(pid), env_hash=env_hash,
-                                      spawn_mode="pooled",
+                                      log_path=log_path, spawn_mode="pooled",
                                       spawn_started_at=time.monotonic())
                 handle.registered = (
                     asyncio.get_running_loop().create_future() if _in_loop() else None)
@@ -1127,7 +1135,8 @@ class Raylet:
             stderr=subprocess.STDOUT,
         )
         handle = WorkerHandle(worker_id=worker_id, pid=proc.pid, proc=proc,
-                              env_hash=env_hash, spawn_mode="cold",
+                              env_hash=env_hash, log_path=log_path,
+                              spawn_mode="cold",
                               spawn_started_at=time.monotonic())
         handle.registered = asyncio.get_running_loop().create_future() if _in_loop() else None
         self._workers[worker_id] = handle
@@ -1985,21 +1994,21 @@ class Raylet:
         """Tail this node's worker log files and forward new lines to the
         GCS log channel (reference ``log_monitor.py``: per-node agent
         tailing worker logs for the driver)."""
-        import glob
-
-        offsets: dict[str, int] = {}
+        offsets = self._log_offsets
         period = get_config().log_monitor_poll_ms / 1000.0
         while True:
             await asyncio.sleep(period)
             batch = []
             staged: dict[str, int] = {}  # offsets commit only after publish
-            for path in glob.glob(os.path.join(self._session_dir, "worker-*.out")):
+            live = {w.log_path for w in self._workers.values()}
+            for path, start in list(offsets.items()):
                 try:
                     size = os.path.getsize(path)
                 except OSError:
-                    continue
-                start = offsets.get(path, 0)
+                    size = 0
                 if size <= start:
+                    if path not in live:
+                        del offsets[path]  # dead, and all it wrote is forwarded
                     continue
                 try:
                     with open(path, "rb") as f:
@@ -2008,9 +2017,10 @@ class Raylet:
                 except OSError:
                     continue
                 # forward whole lines only; carry partial tails to next tick
+                # (a dead worker's tail is final: forward it as it is)
                 cut = chunk.rfind(b"\n") + 1
                 if cut == 0:
-                    if len(chunk) < 256 * 1024:
+                    if len(chunk) < 256 * 1024 and path in live:
                         continue
                     cut = len(chunk)  # giant single line: forward truncated
                 worker_tag = os.path.basename(path)[len("worker-"):-len(".out")]

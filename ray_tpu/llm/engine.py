@@ -1958,9 +1958,13 @@ class InferenceEngine:
         return covered
 
     def release_export_pins(self, r: Request) -> None:
-        """Drop the per-page refs ``pin_for_export`` took at retire; the
-        pages become ordinary evictable cache entries."""
+        """The exporter is done with ``r``: drop the per-page refs
+        ``pin_for_export`` took at retire (the pages become ordinary
+        evictable cache entries), and take none if ``r`` has not retired
+        yet — an exporter whose stream ends first (reader gone, channel
+        dead, abort) would otherwise leave them pinned for good."""
         with self._lock:
+            r.pin_for_export = False
             pins, r.export_pinned = r.export_pinned, []
             for pid in pins:
                 self.allocator.release(pid)

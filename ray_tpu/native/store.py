@@ -122,6 +122,17 @@ class ShmStore:
         self._lock = threading.Lock()
         self._mm = ShmClient(path, capacity)
 
+    def _native(self, fn, *args):
+        """One native call under the lock, on a live handle. After
+        ``close`` the handle is NULL and the native code would dereference
+        it: a stopped raylet still runs the tails of its coroutines (the
+        ``finally`` that releases a pushed object), and that call has to
+        be an exception in this process, not a segmentation fault."""
+        with self._lock:
+            if not self._handle:
+                raise ShmStoreError(f"store {self.path} is closed")
+            return fn(self._handle, *args)
+
     def create(self, object_id: bytes, data_size: int, meta_size: int = 0) -> int:
         """Allocate space; returns byte offset into the arena."""
         from ..core.rpc import get_chaos
@@ -133,10 +144,8 @@ class ShmStore:
             raise StoreFullError(
                 f"chaos-injected store-full creating {object_id.hex()}")
         offset = ctypes.c_uint64()
-        with self._lock:
-            rc = self._lib.store_create_object(
-                self._handle, object_id, len(object_id), data_size, meta_size, ctypes.byref(offset)
-            )
+        rc = self._native(self._lib.store_create_object, object_id, len(object_id),
+                          data_size, meta_size, ctypes.byref(offset))
         if rc == -1:
             raise ObjectExistsError(object_id.hex())
         if rc == -2:
@@ -147,66 +156,52 @@ class ShmStore:
         return offset.value
 
     def seal(self, object_id: bytes) -> None:
-        with self._lock:
-            rc = self._lib.store_seal(self._handle, object_id, len(object_id))
+        rc = self._native(self._lib.store_seal, object_id, len(object_id))
         if rc != 0:
             raise ShmStoreError(f"seal({object_id.hex()}) rc={rc}")
 
     def get_info(self, object_id: bytes) -> tuple[int, int, int] | None:
         """Return (offset, data_size, meta_size) for a sealed object, else None."""
         off, dsz, msz = ctypes.c_uint64(), ctypes.c_uint64(), ctypes.c_uint64()
-        with self._lock:
-            rc = self._lib.store_get(
-                self._handle, object_id, len(object_id),
-                ctypes.byref(off), ctypes.byref(dsz), ctypes.byref(msz),
-            )
+        rc = self._native(self._lib.store_get, object_id, len(object_id),
+                          ctypes.byref(off), ctypes.byref(dsz), ctypes.byref(msz))
         if rc != 0:
             return None
         return off.value, dsz.value, msz.value
 
     def add_ref(self, object_id: bytes) -> None:
-        with self._lock:
-            self._lib.store_add_ref(self._handle, object_id, len(object_id))
+        self._native(self._lib.store_add_ref, object_id, len(object_id))
 
     def release(self, object_id: bytes) -> None:
-        with self._lock:
-            self._lib.store_release(self._handle, object_id, len(object_id))
+        self._native(self._lib.store_release, object_id, len(object_id))
 
     def delete(self, object_id: bytes, force: bool = False) -> bool:
-        with self._lock:
-            return self._lib.store_delete(self._handle, object_id, len(object_id), int(force)) == 0
+        return self._native(self._lib.store_delete, object_id, len(object_id), int(force)) == 0
 
     def contains(self, object_id: bytes) -> int:
         """0 = absent, 1 = created/unsealed, 2 = sealed."""
-        with self._lock:
-            return self._lib.store_contains(self._handle, object_id, len(object_id))
+        return self._native(self._lib.store_contains, object_id, len(object_id))
 
     def pin(self, object_id: bytes) -> None:
         """Exclude a primary copy from LRU eviction (reference
         ``local_object_manager.h:110`` pinned-object semantics)."""
-        with self._lock:
-            self._lib.store_pin(self._handle, object_id, len(object_id))
+        self._native(self._lib.store_pin, object_id, len(object_id))
 
     def unpin(self, object_id: bytes) -> None:
-        with self._lock:
-            self._lib.store_unpin(self._handle, object_id, len(object_id))
+        self._native(self._lib.store_unpin, object_id, len(object_id))
 
     def ref_count(self, object_id: bytes) -> int:
         """-1 if absent."""
-        with self._lock:
-            return self._lib.store_ref_count(self._handle, object_id, len(object_id))
+        return self._native(self._lib.store_ref_count, object_id, len(object_id))
 
     def evict(self, nbytes: int) -> int:
-        with self._lock:
-            return self._lib.store_evict(self._handle, nbytes)
+        return self._native(self._lib.store_evict, nbytes)
 
     def used(self) -> int:
-        with self._lock:
-            return self._lib.store_used(self._handle)
+        return self._native(self._lib.store_used)
 
     def num_objects(self) -> int:
-        with self._lock:
-            return self._lib.store_num_objects(self._handle)
+        return self._native(self._lib.store_num_objects)
 
     # -- direct data access (owner process shares the same mmap) ------------
     def write(self, offset: int, data: bytes | memoryview) -> None:

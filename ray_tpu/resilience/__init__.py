@@ -9,11 +9,11 @@ into a measured event (ROADMAP item 6). Three pieces compose:
   * :mod:`ray_tpu.resilience.preemption` — the notice plumbing: hazard
     views over the GCS node table + the ``node_preempted`` ErrorEvent
     channel, consumed by the serve controller (proactive replica
-    eviction) and the recovery bench;
+    eviction);
   * the wiring that lives in the subsystems themselves: raylet draining
     (``core/raylet.py``), the ``preempt_slice`` FaultPlan kind
-    (``chaos/plan.py``), train controller resume
-    (``train/controller.py``), and ``bench.py run_recovery_bench``.
+    (``chaos/plan.py``) and train controller resume
+    (``train/controller.py``).
 """
 
 from .checkpoint import (

@@ -11,8 +11,8 @@ A preemption flows through the cluster as:
 
 :func:`hazard_nodes` merges both signals (table flags + error events)
 into one ``node_id -> PreemptionNotice`` view. The serve controller uses
-it to evict replicas proactively; the recovery bench uses the notice
-clocks to measure ``recovery_*_s`` SLOs. Clocks are chaos-clock stamps
+it to evict replicas proactively; the notice clocks are what a
+recovery time is measured from. Clocks are chaos-clock stamps
 (:mod:`ray_tpu.chaos.clock`), so a VirtualClock run measures virtual
 seconds.
 """

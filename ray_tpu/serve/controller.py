@@ -95,7 +95,7 @@ class _DeploymentState:
         # Proactive preemption evictions (resilience): one row per replica
         # removed because its NODE got a preemption notice — `reroute_s`
         # (notice -> eviction+table push, chaos-clock) is the serve half
-        # of the recovery SLO bench.
+        # of the recovery time.
         self.preemption_evictions: list[dict] = []
         # Aggregated prefix-group residency from the replicas' probe
         # rows (affinity hit rates in status; empty = no LLM engines).

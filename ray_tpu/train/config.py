@@ -95,12 +95,12 @@ class Result:
     metrics_history: list[dict] = dataclasses.field(default_factory=list)
     # One entry per group restart (resilience): chaos-clock stamps of the
     # failure and of the first resumed report, plus the resume path — the
-    # recovery bench derives `recovery_train_resume_s` from these.
+    # time to resume is the difference of the two stamps.
     recovery_events: list[dict] = dataclasses.field(default_factory=list)
     # Compiled-loop mode only (train/loop.py): per-run drive statistics —
     # mode, per-step wall, checkpoint-commit windows and
     # `train_ckpt_overlap_frac` (fraction of checkpoint commit time that
-    # overlapped step compute; the bench records it as a guarded cell).
+    # overlapped step compute).
     loop_stats: dict | None = None
 
     @property

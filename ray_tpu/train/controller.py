@@ -167,8 +167,8 @@ class TrainController:
         self._experiment_name: str = ""
         # Recovery accounting (resilience subsystem): one entry per
         # group restart, chaos-clock stamped at the failure and at the
-        # first report of the resumed attempt — the recovery bench and
-        # tests derive `recovery_train_resume_s` from these.
+        # first report of the resumed attempt — the tests
+        # derive the time to resume from these.
         self.recovery_events: list[dict] = []
         self._pending_recovery: dict | None = None
 

@@ -254,7 +254,7 @@ class TrainLoopRunner:
         # Steady-state window: end of step 0 → end of the last step.
         # Excludes the first step's jit compile and the loop's one-time
         # channel/park setup, so per-step numbers measure the DRIVE, not
-        # warmup (the bench's dispatch-overhead and MFU cells use this).
+        # warmup.
         steady_steps = max(0, len(step_windows) - 1)
         steady_wall = (step_windows[-1][1] - step_windows[0][1]
                        if steady_steps else 0.0)

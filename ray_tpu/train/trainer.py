@@ -80,5 +80,5 @@ class JaxTrainer(DataParallelTrainer):
     ``train.report(metrics, state=...)`` — rank 0 commits it atomically
     from a background thread and registers each version with the GCS, so
     a preempted slice restarts from the latest committed step
-    (``ray_tpu/resilience/``; recovery SLOs in ``cli bench recovery``).
+    (``ray_tpu/resilience/``).
     """

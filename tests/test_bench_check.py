@@ -362,15 +362,16 @@ def test_overload_metrics_directions_and_markers():
 
 
 def test_overload_skip_markers_honored():
-    """RAY_TPU_BENCH_SKIP_OVERLOAD leaves `*_skipped` markers: the
-    overload cells read as intentionally skipped, never as silently
-    vanished."""
-    from ray_tpu._overload_bench import SKIP_MARKERS
-
+    """`*_skipped` markers: the overload cells read as intentionally
+    skipped, never as silently vanished (one marker covers the
+    `_unprotected` variant of its metric too)."""
     old = {"serve_goodput_frac": 0.62, "serve_goodput_frac_unprotected": 0.2,
            "serve_shed_fast_fail_p95_ms": 40.0,
            "serve_admitted_p95_ttft_ms": 600.0}
-    result = bench_check.compare(old, dict(SKIP_MARKERS))
+    new = {"serve_goodput_frac_skipped": True,
+           "serve_shed_fast_fail_p95_ms_skipped": True,
+           "serve_admitted_p95_ttft_ms_skipped": True}
+    result = bench_check.compare(old, new)
     assert not result["missing"], result["missing"]
     assert {r["metric"] for r in result["skipped"]} == set(old)
 
@@ -415,7 +416,7 @@ def test_spec_accept_rate_compares_in_points():
 
 
 def test_speculative_skip_markers_honored():
-    """RAY_TPU_BENCH_SKIP_SPECULATIVE=1 leaves *_skipped markers: the
+    """`*_skipped` markers: the
     absent cells land in the skipped bucket, never in missing; draft
     volume / dispatch counts are untracked bookkeeping."""
     old = {"decode_tok_s_plain": 600.0, "decode_tok_s_speculative": 380.0,
@@ -481,8 +482,7 @@ def test_mfu_compares_in_points():
 
 
 def test_train_loop_skip_markers_honored():
-    """RAY_TPU_BENCH_SKIP_TRAIN_LOOP=1 leaves the three *_skipped
-    markers; every train-loop cell lands in skipped, never missing."""
+    """The three `*_skipped` markers: every train-loop cell lands in skipped, never missing."""
     old = {"train_step_dispatch_overhead_eager_us": 6400.0,
            "train_step_dispatch_overhead_us": 320.0,
            "train_mfu_eager": 5e-05, "train_mfu_loop": 6e-05,
@@ -543,10 +543,9 @@ def test_tenancy_parity_and_goodput_compare_in_points():
 
 
 def test_tenancy_skip_markers_honored():
-    """RAY_TPU_BENCH_SKIP_TENANCY=1 leaves the module's SKIP_MARKERS:
-    every tenancy cell lands in skipped, never missing."""
-    from ray_tpu._tenancy_bench import SKIP_MARKERS
-
+    """`*_skipped` markers: every tenancy cell lands in skipped, never
+    missing (a marker covers the `_solo`/`_noisy`, `_hot`/`_cold`
+    variants of its metric)."""
     old = {"tenant_quiet_p95_ttft_ms_solo": 60.0,
            "tenant_quiet_p95_ttft_ms_noisy": 66.0,
            "tenant_goodput_frac_hot": 0.9,
@@ -554,7 +553,12 @@ def test_tenancy_skip_markers_honored():
            "tenant_mixed_batch_parity": 1.0,
            "tenant_mixed_dispatch_parity": 1.0,
            "adapter_hot_load_ms": 50.0}
-    result = bench_check.compare(old, dict(SKIP_MARKERS))
+    new = {"tenant_quiet_p95_ttft_ms_skipped": True,
+           "tenant_goodput_frac_skipped": True,
+           "tenant_mixed_batch_parity_skipped": True,
+           "tenant_mixed_dispatch_parity_skipped": True,
+           "adapter_hot_load_ms_skipped": True}
+    result = bench_check.compare(old, new)
     assert not result["missing"] and not result["regressions"]
     assert {r["metric"] for r in result["skipped"]} == set(old)
 
@@ -646,16 +650,18 @@ def test_fleet_parity_and_goodput_compare_in_points():
 
 
 def test_fleet_skip_markers_honored():
-    """RAY_TPU_BENCH_SKIP_FLEET=1 leaves the module's SKIP_MARKERS: the
-    fleet_ prefix marker covers every fleet_* cell and the per-metric
-    markers cover the serve_replica_* cells — skipped, never missing."""
-    from ray_tpu._fleet_bench import SKIP_MARKERS
-
+    """The `fleet_skipped` prefix marker covers every fleet_* cell and
+    the per-metric markers cover the serve_replica_* cells — skipped,
+    never missing."""
     old = {"serve_replica_cold_start_s": 3.4,
            "serve_replica_promote_s": 0.004,
            "serve_replica_promote_speedup": 800.0,
            "fleet_broadcast_parity": 1.0,
            "fleet_goodput_frac_step": 0.3}
-    result = bench_check.compare(old, dict(SKIP_MARKERS))
+    new = {"fleet_skipped": True,
+           "serve_replica_cold_start_s_skipped": True,
+           "serve_replica_promote_s_skipped": True,
+           "serve_replica_promote_speedup_skipped": True}
+    result = bench_check.compare(old, new)
     assert not result["missing"] and not result["regressions"]
     assert {r["metric"] for r in result["skipped"]} == set(old)

@@ -42,6 +42,74 @@ def ft_cluster():
     c.shutdown()
 
 
+def test_what_was_acknowledged_survives_a_crash_at_once(ft_cluster):
+    """A reply that acknowledges a change to a durable table leaves only
+    after the snapshot that holds it (``gcs.durable``): the GCS is killed
+    right after the acks, inside the 200 ms window the periodic snapshot
+    would have needed, and every acknowledged change is there after the
+    restart — a put, a delete, a job id handed out (a lost one is handed
+    out twice), a named actor's registration."""
+    from ray_tpu.core.worker import global_worker
+
+    call = global_worker()._gcs_call
+
+    @ray_tpu.remote
+    class Named:
+        def ping(self):
+            return "pong"
+
+    call("KvPut", {"key": "gone", "value": b"x", "overwrite": True})
+    time.sleep(0.5)  # "gone" is in a snapshot: only an acked delete removes it
+    call("KvPut", {"key": "kept", "value": b"v", "overwrite": True})
+    assert call("KvDel", {"key": "gone"})["deleted"]
+    job_id = call("AddJob", {"driver_address": ""})["job_id"]
+    Named.options(name="acked", lifetime="detached", num_cpus=0.1).remote()
+    ft_cluster.crash_gcs()
+    ft_cluster.restart_gcs()
+    ft_cluster.wait_for_nodes(2, timeout=30)
+
+    assert call("KvGet", {"key": "kept"})["value"] == b"v"
+    assert not call("KvGet", {"key": "gone"})["found"]
+    assert call("AddJob", {"driver_address": ""})["job_id"] == job_id + 1
+    assert ray_tpu.get(ray_tpu.get_actor("acked").ping.remote(), timeout=120) == "pong"
+
+
+def test_concurrent_acknowledgements_share_a_snapshot(tmp_path):
+    """Group commit: the ``durable`` handlers that finish in one turn of
+    the event loop wait for ONE snapshot, so sixteen clients writing at
+    once cost far fewer whole-table writes than writes acknowledged
+    (2,400 against 196 puts a second at 2 MiB of state, PR 29), and every
+    acknowledged write is in the file."""
+    import asyncio
+
+    from ray_tpu.core.gcs import GcsServer
+    from ray_tpu.core.rpc import RpcClient
+
+    async def storm():
+        storage = FileStorage(str(tmp_path / "snap.msgpack"))
+        gcs = GcsServer(storage=storage)
+        await gcs.start()
+        saves = []
+        save_blob = storage.save_blob
+        storage.save_blob = lambda blob: (saves.append(1), save_blob(blob))
+        clients = [RpcClient(gcs.address) for _ in range(16)]
+
+        async def puts(client, j):
+            for i in range(20):
+                await client.call("KvPut", {"key": f"k:{j}:{i}", "value": b"v",
+                                            "overwrite": True}, 30.0)
+
+        await asyncio.gather(*[puts(c, j) for j, c in enumerate(clients)])
+        for c in clients:
+            await c.close()
+        await gcs.crash()  # no final flush: only what the acks waited for
+        return len(saves), storage.load()
+
+    saves, tables = asyncio.run(storm())
+    assert len(tables["kv"]) == 320
+    assert saves < 160, f"{saves} snapshots for 320 acknowledged puts"
+
+
 def test_gcs_restart_recovers_cluster(ft_cluster):
     """Named detached actor, KV (function exports), and node membership all
     survive a GCS crash + restart; new work schedules afterwards."""

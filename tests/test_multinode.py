@@ -52,6 +52,48 @@ def test_spread_across_nodes(cluster):
     assert len(seen) == 2, f"spread used only {seen}"
 
 
+def test_worker_log_forwarded_once_by_its_own_raylet(tmp_path, capfd):
+    """The raylets of a host share one session directory. A worker's line
+    reaches the driver ONCE, from the raylet that spawned the worker, and
+    a log file some other cluster left in the directory is nobody's to
+    forward: a raylet that tails the whole directory re-reads every file
+    ever written there at each start, which is what loaded the suite.
+    The shared directory here is the test's own (``tmp_path``): nothing
+    is planted where another run's raylets would find it."""
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    session_dir = str(tmp_path)
+    c = Cluster(initialize_head=True,
+                head_node_args={"num_cpus": 2, "session_dir": session_dir})
+    c.add_node(num_cpus=2, session_dir=session_dir)
+    (tmp_path / "worker-planted00000.out").write_text(
+        "log-line-of-another-cluster\n")
+    ray_tpu.init(address=c.address, num_cpus=0)
+
+    @ray_tpu.remote
+    def speak():
+        print("log-line-said-once")
+        return True
+
+    try:
+        assert ray_tpu.get(speak.remote(), timeout=60)
+        seen = ""
+        deadline = time.time() + 15
+        while time.time() < deadline:
+            seen += capfd.readouterr().err
+            if "log-line-said-once" in seen:
+                break
+            time.sleep(0.25)
+        assert "log-line-said-once" in seen
+        time.sleep(1.5)  # three polls of every raylet's log monitor
+        seen += capfd.readouterr().err
+    finally:
+        ray_tpu.shutdown()
+        c.shutdown()
+    assert seen.count("log-line-said-once") == 1, seen
+    assert "log-line-of-another-cluster" not in seen
+
+
 def test_cross_node_object_fetch(cluster):
     """Large return lives in plasma on the executing node; the driver's node
     pulls it chunk-by-chunk (PullManager path, raylet FetchObjectChunk)."""

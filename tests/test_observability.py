@@ -160,8 +160,11 @@ def test_leased_transition_recorded():
     assert ray_tpu.get(leased_probe.remote(), timeout=60) == 1
 
     def _find():
+        # the owner, the raylet and the worker each flush their events on
+        # their own timer: wait until all three stamps have arrived
         tasks = [t for t in state.list_tasks() if t["name"] == "leased_probe"
-                 and t["state"] == "FINISHED" and "LEASED" in t["events"]]
+                 and t["state"] == "FINISHED"
+                 and {"SUBMITTED", "LEASED", "FINISHED"} <= set(t["events"])]
         return tasks
 
     tasks = _poll(_find)
@@ -399,3 +402,34 @@ def test_worker_logs_stream_to_driver(ray_cluster, capfd):
         time.sleep(0.25)
     assert "log-monitor-test-line" in seen
     assert "node=" in seen.split("log-monitor-test-line")[0].rsplit("(", 1)[-1]
+
+
+def test_dead_workers_last_words_are_forwarded_then_its_log_is_dropped(ray_cluster, capfd):
+    """A worker that dies mid-line (no newline after its last words) will
+    write no more: the tail is forwarded as it is, and once all it wrote
+    is forwarded the raylet stops polling that file (a long-lived raylet
+    with worker churn would otherwise stat every dead worker's log at
+    every tick, for ever)."""
+    import os
+    import time
+
+    from ray_tpu.core import api
+
+    @ray_tpu.remote(max_retries=0)
+    def last_words():
+        os.write(1, b"a whole line\nlast-words-without-newline")
+        os._exit(1)
+
+    with pytest.raises(Exception):
+        ray_tpu.get(last_words.remote(), timeout=60)
+    raylet = api._node.raylet
+    seen = ""
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        seen += capfd.readouterr().err
+        live = {w.log_path for w in raylet._workers.values()}
+        if "last-words-without-newline" in seen and set(raylet._log_offsets) <= live:
+            break
+        time.sleep(0.25)
+    assert "last-words-without-newline" in seen
+    assert set(raylet._log_offsets) <= {w.log_path for w in raylet._workers.values()}

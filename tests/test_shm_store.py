@@ -83,3 +83,36 @@ def test_cross_process_view(store):
     offset, dsz, _ = store.get_info(oid(9))
     assert bytes(client.read(offset, dsz)) == data
     client.close()
+
+
+def test_a_call_after_close_raises_and_does_not_crash(tmp_path):
+    """A raylet that was stopped still runs the tails of its coroutines
+    (a push's ``finally`` releases its object): on a closed store that is
+    a Python exception, never a NULL handle handed to the native code
+    (which took the whole pytest worker down with a segmentation fault)."""
+    from ray_tpu.native.store import ShmStoreError
+
+    s = ShmStore(str(tmp_path / "closed_store"), 1 << 20)
+    oid = b"x" * 20
+    s.put_sealed(oid, b"payload")
+    s.add_ref(oid)
+    s.close()
+    for call in (lambda: s.release(oid), lambda: s.add_ref(oid),
+                 lambda: s.unpin(oid), lambda: s.contains(oid),
+                 lambda: s.ref_count(oid), lambda: s.used(),
+                 lambda: s.create(b"y" * 20, 8), lambda: s.evict(1)):
+        with pytest.raises(ShmStoreError, match="closed"):
+            call()
+    s.close()  # idempotent
+
+
+def test_a_live_segment_is_not_a_starting_sessions_to_clear(store):
+    """``conftest.pytest_sessionstart`` clears leaked ``/dev/shm/raytpu_*``
+    segments, and only those no live process maps: a store that is open
+    (another run's cluster, this one's) is seen as mapped, a closed one
+    is not."""
+    from conftest import _mapped_shm_paths
+
+    assert store.path in _mapped_shm_paths()
+    store.close()
+    assert store.path not in _mapped_shm_paths()

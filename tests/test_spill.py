@@ -168,9 +168,14 @@ def test_oom_killer_kills_newest_retriable_lease_and_task_retries(tmp_path):
     try:
         raylet = _raylet()
         fired = []
+        marker = str(tmp_path / "attempts")
 
         def fake_usage():
-            if not fired and any(
+            # High once, and only when the first attempt is inside its
+            # body (its mark is written): a kill between the lease and
+            # the task's first line leaves no mark, and the retry, which
+            # nothing kills, then looks like a first attempt.
+            if not fired and os.path.exists(marker) and any(
                 w.state == "leased" and w.retriable for w in raylet._workers.values()
             ):
                 fired.append(1)
@@ -178,8 +183,6 @@ def test_oom_killer_kills_newest_retriable_lease_and_task_retries(tmp_path):
             return 0.0
 
         raylet._memory_usage_fn = fake_usage
-
-        marker = str(tmp_path / "attempts")
 
         @ray_tpu.remote(max_retries=2)
         def flaky():

@@ -140,7 +140,9 @@ def train_capture(tmp_path_factory):
             session.report({"step": step, "loss": loss},
                            checkpoint=Checkpoint(str(tmp / "ckpt")),
                            state={"w": jnp.ones(4)})
-        manager.wait(10.0)
+        # the commit's span has to END inside the capture: on a loaded
+        # host the write has taken longer than 10 s and did arrive
+        assert manager.wait(120.0), "the async commit never finished"
 
     try:
         path = _captured(work, tmp / "trace")

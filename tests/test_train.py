@@ -301,14 +301,24 @@ def test_elastic_scaling_downscale_on_node_death(tmp_path):
     ray_tpu.init(address=c.address, num_cpus=0)
     try:
         deadline = time.time() + 30
-        while ray_tpu.cluster_resources().get("CPU", 0) < 3 and time.time() < deadline:
-            time.sleep(0.2)  # node2 must be visible so the run STARTS at 3
+        # The policy sizes the first attempt from what is AVAILABLE, which
+        # a node reports after it has registered its total: node2's CPUs
+        # must be available, not only counted, so the run STARTS at 3.
+        while ray_tpu.available_resources().get("CPU", 0) < 3 and time.time() < deadline:
+            time.sleep(0.2)
+        assert ray_tpu.available_resources().get("CPU", 0) >= 3
+        marks = tmp_path / "in_train_fn"
+        marks.mkdir()
+
         def train_fn(config):
             import os
             import tempfile
             import time as _t
 
             ctx = train.get_context()
+            open(os.path.join(str(marks),
+                              f"{ctx.get_world_size()}-{ctx.get_world_rank()}"),
+                 "w").close()
             start = 0
             ckpt = train.get_checkpoint()
             if ckpt is not None:
@@ -329,8 +339,13 @@ def test_elastic_scaling_downscale_on_node_death(tmp_path):
         trainer = DataParallelTrainer(
             train_fn,
             scaling_config=scaling,
+            # max_failures bounds a hang, it is not what is asserted.
+            # Between the workers' death and the GCS declaring their node
+            # dead the controller sizes each restart from a view that
+            # still holds the node, and every attempt made against that
+            # view is a failure spent (two were seen on a loaded host).
             run_config=RunConfig(name="elastic_down", storage_path=str(tmp_path),
-                                 failure_config=FailureConfig(max_failures=2)),
+                                 failure_config=FailureConfig(max_failures=5)),
             scaling_policy=ElasticScalingPolicy(
                 scaling, check_interval_s=2.0, clock=_CallCountClock()),
         )
@@ -340,16 +355,19 @@ def test_elastic_scaling_downscale_on_node_death(tmp_path):
         box = {}
         t = threading.Thread(target=lambda: box.update(result=trainer.fit()))
         t.start()
-        # Wait for EVIDENCE the 3-worker attempt is underway (its first
-        # checkpoint landing in storage) instead of a wall-clock sleep —
-        # under full-suite load on one core a fixed sleep races the
-        # worker-group start and flakes.
+        # Wait for EVIDENCE the 3-worker attempt is underway instead of a
+        # wall-clock sleep: its first checkpoint in storage AND every rank
+        # inside train_fn. Rank 0 alone is not enough: on a slow host it
+        # checkpoints while the other ranks' actors are still being
+        # created, and a node lost then fails the attempt at its start,
+        # before the controller has taken a single world-3 report.
         import glob as _glob
 
-        deadline = time.time() + 60
+        deadline = time.time() + 120
         while time.time() < deadline:
             if _glob.glob(str(tmp_path / "elastic_down" / "**" / "step.txt"),
-                          recursive=True):
+                          recursive=True) \
+                    and len(_glob.glob(str(marks / "3-*"))) == 3:
                 break
             time.sleep(0.2)
         else:
@@ -359,8 +377,16 @@ def test_elastic_scaling_downscale_on_node_death(tmp_path):
         assert not t.is_alive(), "fit() did not finish after node loss"
         result = box["result"]
         assert result.error is None, result.error
+        # Started at 3, ended at 1. That it started at 3 is read from the
+        # ranks that were inside train_fn, not from the first report the
+        # controller took (replaces `worlds[0] == 3`): a node lost while
+        # the controller still waits for the last `run_train_fn` reply
+        # fails the attempt "at its start" with every rank already
+        # stepping, and the reports of that attempt are never polled.
+        ran = set(os.listdir(marks))
+        assert {"3-0", "3-1", "3-2", "1-0"} <= ran, ran
         worlds = [m["world"] for m in result.metrics_history]
-        assert worlds[0] == 3 and worlds[-1] == 1, worlds
+        assert worlds[-1] == 1 and set(worlds) <= {1, 3}, worlds
         assert result.metrics["step"] == 15, result.metrics
     finally:
         ray_tpu.shutdown()
