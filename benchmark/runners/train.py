@@ -13,7 +13,7 @@ import time
 
 import numpy as np
 
-from .. import flops, layer_metrics, stats, traffic
+from .. import flops, layer_metrics, pauses, stats, traffic
 from . import (Context, RunFailure, capture_trace, check_device, kernel_native,
                lease, llama_config, reduce_trace, start_cluster, stop_cluster)
 
@@ -139,7 +139,7 @@ def _loop(config: dict) -> None:
         one_step(next_batch())
     mark("warm_steps")
 
-    losses, step_ms, wait_ms, report_ms = [], [], [], []
+    losses, step_t_a, step_ms, wait_ms, report_ms = [], [], [], [], []
 
     def timed_step():
         t_a = time.monotonic()
@@ -149,6 +149,7 @@ def _loop(config: dict) -> None:
         t_c = time.monotonic()
         train.report({"step": len(losses), "loss": losses[-1]})
         t_d = time.monotonic()
+        step_t_a.append(t_a)
         wait_ms.append((t_b - t_a) * 1e3)
         step_ms.append((t_c - t_b) * 1e3)
         report_ms.append((t_d - t_c) * 1e3)
@@ -170,7 +171,8 @@ def _loop(config: dict) -> None:
                                           config["unions"])
     train.report({"bench": {
         "t_window_start_wall": t_w0_wall, "window_s": window_s,
-        "steps": len(losses), "losses": losses, "step_ms": step_ms,
+        "t_window_start_mono": t_w0, "clock_id": pauses.clock_id(),
+        "steps": len(losses), "losses": losses, "step_t_a": step_t_a, "step_ms": step_ms,
         "data_wait_ms": wait_ms, "report_ms": report_ms,
         "compile_s": compile_s, "program_bytes": program_bytes, "marks": marks,
         "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
@@ -193,9 +195,10 @@ def run(ctx: Context) -> dict:
     rows = traffic.train_rows(mix, cfg["model"]["vocab_size"], sizes["batch"],
                               ctx.seed, seq=seq)
     marks = [("process_start", ctx.t_start_wall), ("parent_imports_and_rows", time.time())]
-    start_cluster(ctx)
-    marks.append(("cluster", time.time()))
+    watcher = pauses.Watcher()  # beside set-up and the window; stopped after it
     try:
+        start_cluster(ctx)
+        marks.append(("cluster", time.time()))
         resources, runtime_env = lease(ctx)
         result = JaxTrainer(
             _loop,
@@ -213,6 +216,7 @@ def run(ctx: Context) -> dict:
             datasets={"train": data.from_numpy(rows, column="tokens")},
         ).fit()
     finally:
+        watched = watcher.stop()
         stop_cluster()
     if result.error is not None:
         raise result.error
@@ -222,7 +226,8 @@ def run(ctx: Context) -> dict:
     device = m["device"]
     check_device(device, ctx)
     chips = ctx.cell.chips
-    tok_s_chip = m["steps"] * tokens_per_step / m["window_s"] / chips
+    window = pauses.window_report(m, watched, tokens_per_step=tokens_per_step,
+                                  chips=chips, seconds=ctx.seconds)
     checks = {
         "losses_finite": all(math.isfinite(x) for x in m["losses"]),
         "logits_match_reference": m["logit_err_max"] <= LOGIT_RTOL,
@@ -240,6 +245,7 @@ def run(ctx: Context) -> dict:
              "check_tokens": m["check_tokens"],
              "steps": m["steps"], "window_s": m["window_s"],
              "step_ms_quartiles": quart(m["step_ms"]), "compile_s": m["compile_s"],
+             **window["said"],
              "loss_first_last": [m["losses"][0], m["losses"][-1]],
              "program_bytes": m["program_bytes"],
              "peak_bytes_in_use": device["peak_bytes_in_use"],
@@ -254,7 +260,7 @@ def run(ctx: Context) -> dict:
                       "memory_peak_bytes": max(max(device["peak_bytes_in_use"]),
                                                m["program_bytes"])}}
     if not ctx.trace:
-        values = {"train_tok_s_chip": tok_s_chip,
+        values = {"train_tok_s_chip": window["train_tok_s_chip"],
                   "setup_s": m["t_window_start_wall"] - ctx.t_start_wall}
     else:
         summary = m["trace"]
@@ -265,8 +271,8 @@ def run(ctx: Context) -> dict:
                  "modules": summary["modules"]})
         peak = (ctx.rehearse["assumed_peak_flops_per_s"] if ctx.rehearse
                 else flops.peaks(device["kind"])["bf16_flops_per_s"])
-        obs = {"timers": {"data_wait_ms": stats.mean(m["data_wait_ms"]),
-                          "report_ms": stats.mean(m["report_ms"]),
+        obs = {"timers": {"data_wait_ms": window["data_wait_ms"],
+                          "report_ms": window["report_ms"],
                           "step_ms_median": stats.percentile(m["step_ms"], 50)},
                # from the median step, not the window: the capture's own
                # start, stop and reduction sit inside a traced window

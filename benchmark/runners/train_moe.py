@@ -29,7 +29,7 @@ import time
 
 import numpy as np
 
-from .. import flops, flops_moe, layer_metrics, stats, trace_reduce, traffic
+from .. import flops, flops_moe, layer_metrics, pauses, stats, trace_reduce, traffic
 from ..manifest import HERE
 from . import (Context, RunFailure, capture_trace, check_device, kernel_native,
                lease, reduce_trace, start_cluster, stop_cluster)
@@ -283,7 +283,8 @@ def _loop(config: dict) -> None:
     mark("reference_check")
 
     rows_per_step = sizes["batch"] * first.shape[1] * cfg.moe_top_k
-    losses, load, rows_wrong, step_ms, wait_ms, report_ms = [], [], [], [], [], []
+    losses, load, rows_wrong = [], [], []
+    step_t_a, step_ms, wait_ms, report_ms = [], [], [], []
 
     def one_step(tokens):
         nonlocal params, opt_state
@@ -309,6 +310,7 @@ def _loop(config: dict) -> None:
         train.report({"step": len(losses), "loss": loss,
                       "moe_load_max_over_mean": max_over_mean})
         t_d = time.monotonic()
+        step_t_a.append(t_a)
         wait_ms.append((t_b - t_a) * 1e3)
         step_ms.append((t_c - t_b) * 1e3)
         report_ms.append((t_d - t_c) * 1e3)
@@ -331,7 +333,8 @@ def _loop(config: dict) -> None:
                                           config["unions"])
     train.report({"bench": {
         "t_window_start_wall": t_w0_wall, "window_s": window_s,
-        "steps": len(losses), "losses": losses, "step_ms": step_ms,
+        "t_window_start_mono": t_w0, "clock_id": pauses.clock_id(),
+        "steps": len(losses), "losses": losses, "step_t_a": step_t_a, "step_ms": step_ms,
         "data_wait_ms": wait_ms, "report_ms": report_ms,
         "compile_s": compile_s, "program_bytes": program_bytes, "marks": marks,
         "tpu_custom_calls": compiled.as_text().count("tpu_custom_call"),
@@ -364,9 +367,10 @@ def run(ctx: Context) -> dict:
     rows = traffic.train_rows(mix, cfg["model"]["vocab_size"], sizes["batch"],
                               ctx.seed, seq=seq)
     marks = [("process_start", ctx.t_start_wall), ("parent_imports_and_rows", time.time())]
-    start_cluster(ctx)
-    marks.append(("cluster", time.time()))
+    watcher = pauses.Watcher()  # beside set-up and the window; stopped after it
     try:
+        start_cluster(ctx)
+        marks.append(("cluster", time.time()))
         resources, runtime_env = lease(ctx)
         result = JaxTrainer(
             _loop,
@@ -384,6 +388,7 @@ def run(ctx: Context) -> dict:
             datasets={"train": data.from_numpy(rows, column="tokens")},
         ).fit()
     finally:
+        watched = watcher.stop()
         stop_cluster()
     if result.error is not None:
         raise result.error
@@ -393,7 +398,8 @@ def run(ctx: Context) -> dict:
     device = m["device"]
     check_device(device, ctx)
     chips = ctx.cell.chips
-    tok_s_chip = m["steps"] * tokens_per_step / m["window_s"] / chips
+    window = pauses.window_report(m, watched, tokens_per_step=tokens_per_step,
+                                  chips=chips, seconds=ctx.seconds)
     whole, layer, traces = m["whole"], m["layer"], device["kernel_traces"]
     n_layers = cfg["model"]["num_hidden_layers"]
     checks = {
@@ -431,15 +437,7 @@ def run(ctx: Context) -> dict:
         "load_max_over_mean_quartiles": quart(m["load_max_over_mean"]),
         "steps": m["steps"], "window_s": m["window_s"],
         "step_ms_quartiles": quart(m["step_ms"]), "compile_s": m["compile_s"],
-        # where a stall inside the window sits: the three slowest steps as
-        # [index, step ms, data wait ms, report ms], and the window's time
-        # outside the three timers
-        "slowest_steps": [[i, m["step_ms"][i], m["data_wait_ms"][i], m["report_ms"][i]]
-                          for i in sorted(range(m["steps"]),
-                                          key=lambda i: -m["step_ms"][i] - m["data_wait_ms"][i]
-                                          - m["report_ms"][i])[:3]],
-        "window_s_outside_timers": m["window_s"] - (
-            sum(m["step_ms"]) + sum(m["data_wait_ms"]) + sum(m["report_ms"])) / 1e3,
+        **window["said"],
         "loss_first_last": [m["losses"][0], m["losses"][-1]],
         "program_bytes": m["program_bytes"],
         "peak_bytes_in_use": device["peak_bytes_in_use"],
@@ -454,7 +452,7 @@ def run(ctx: Context) -> dict:
                       "memory_peak_bytes": max(max(device["peak_bytes_in_use"]),
                                                m["program_bytes"])}}
     if not ctx.trace:
-        values = {"train_tok_s_chip": tok_s_chip,
+        values = {"train_tok_s_chip": window["train_tok_s_chip"],
                   "setup_s": m["t_window_start_wall"] - ctx.t_start_wall}
     else:
         summary = m["trace"]
@@ -474,8 +472,8 @@ def run(ctx: Context) -> dict:
                             if share else (0.0, 0))
         first, last = m["traced_steps"]
         ctx.say({"moe_gmm_calls": gmm_calls, "moe_gmm_seconds": gmm_s})
-        obs = {"timers": {"data_wait_ms": stats.mean(m["data_wait_ms"]),
-                          "report_ms": stats.mean(m["report_ms"]),
+        obs = {"timers": {"data_wait_ms": window["data_wait_ms"],
+                          "report_ms": window["report_ms"],
                           "step_ms_median": stats.percentile(m["step_ms"], 50)},
                # from the median step, not the window: the capture's own
                # start, stop and reduction sit inside a traced window
