@@ -416,6 +416,67 @@ def train_flops_per_token(config: LlamaConfig, seq: int) -> float:
     return 6.0 * n_params + attn
 
 
+def _head_chunks(mesh, hs, lm_head, ts, ms, denom, with_grads: bool):
+    """One scan over token chunks of the head's cross entropy.
+
+    hs [nc, c, E], ts / ms [nc, c], denom a float32 scalar. Returns the
+    mean cross entropy ``-sum(ms * log p(ts)) / denom`` and, when
+    ``with_grads``, its gradients ``(d_hs [nc, c, E], d_lm_head [E, V])``
+    from the same logits. One ``[c, V]`` float32 block exists at a time."""
+    contract = lambda a, b, i, j: lax.dot_general(
+        a, b, (((i,), (j,)), ((), ())), preferred_element_type=jnp.float32)
+
+    def chunk(carry, xs):
+        h, t, m = xs
+        logits = jnp.einsum("ce,ev->cv", h, lm_head, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0] - lse
+        if not with_grads:
+            return carry + (ll * m).sum(), None
+        total, d_w = carry
+        # float32 into both products, as autodiff hands it to them; the
+        # weights' gradient is rounded and summed over chunks in the
+        # weights' type, as a scan's transpose sums a cotangent
+        dlogits = ((jnp.exp(logits - lse[:, None])
+                    - jax.nn.one_hot(t, logits.shape[-1], dtype=jnp.float32))
+                   * (m / denom)[:, None])
+        d_h = contract(dlogits, lm_head, 1, 1).astype(h.dtype)
+        d_w = d_w + contract(h, dlogits, 0, 0).astype(d_w.dtype)
+        return (total + (ll * m).sum(), d_w), d_h
+
+    zero = jnp.zeros((), jnp.float32)
+    if not with_grads:
+        total, _ = lax.scan(chunk, zero, (hs, ts, ms))
+        return -total / denom
+    d_w = jnp.zeros_like(lm_head)
+    if mesh is not None:
+        d_w = shard_constraint(d_w, mesh, ("embed", "vocab"))
+    (total, d_w), d_hs = lax.scan(chunk, (zero, d_w), (hs, ts, ms))
+    return -total / denom, (d_hs, d_w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _head_loss(mesh, hs, lm_head, ts, ms, denom):
+    """Chunked mean cross entropy of ``hs @ lm_head`` against ``ts`` under
+    the weights ``ms / denom``. Differentiated, the pass that makes the
+    loss makes its gradients too (``_head_chunks``): the backward rule
+    only scales them. Not differentiated, it is the loss alone."""
+    return _head_chunks(mesh, hs, lm_head, ts, ms, denom, False)
+
+
+def _head_loss_fwd(mesh, hs, lm_head, ts, ms, denom):
+    return _head_chunks(mesh, hs, lm_head, ts, ms, denom, True)
+
+
+def _head_loss_bwd(mesh, grads, g):
+    d_hs, d_w = grads
+    return ((g * d_hs).astype(d_hs.dtype), (g * d_w).astype(d_w.dtype),
+            None, None, None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 def loss_fn(
     params,
     batch,
@@ -431,9 +492,16 @@ def loss_fn(
     ``(loss, aux)`` for ``value_and_grad(has_aux=True)``: ``ce`` and, for a
     routed model, ``forward_hidden``'s counters from the same pass.
 
-    The lm_head matmul is fused into a rematerialized scan over token
-    chunks so the [B,S,vocab] logits tensor never exists in HBM — at 128k
-    vocab that tensor alone would OOM a v5e chip at batch 8 × 2048.
+    The lm_head matmul runs inside a scan over chunks of ``chunk_tokens``
+    tokens, so the [B,S,vocab] logits tensor never exists in HBM — at 128k
+    vocab that tensor alone would OOM a v5e chip at batch 8 × 2048. The
+    scan is one ``custom_vjp`` (``_head_loss``): under ``grad`` each chunk
+    computes its logits once and from them the loss, ``softmax - onehot``
+    and straight away the gradients of the hidden rows and of ``lm_head``,
+    three products over the vocabulary a chunk where a rematerialized
+    chunk ran four (the logits twice). A call that is not differentiated
+    computes no gradient: one product a chunk. The mask and the tokens get
+    no cotangent.
     """
     tokens = batch["tokens"]
     hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
@@ -457,28 +525,10 @@ def loss_fn(
             flat_m = jnp.pad(flat_m, (0, pad))
             n += pad
         nc = n // chunk
-        lm_head = params["lm_head"]
-
-        @jax.checkpoint
-        def chunk_loss(xs):
-            h, t, m = xs
-            logits = jnp.einsum(
-                "ce,ev->cv", h, lm_head, preferred_element_type=jnp.float32
-            )
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            ll = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0] - lse
-            return (ll * m).sum()
-
-        def body(carry, xs):
-            return carry + chunk_loss(xs), None
-
-        total, _ = lax.scan(
-            body,
-            jnp.zeros((), jnp.float32),
-            (flat_h.reshape(nc, chunk, e), flat_t.reshape(nc, chunk),
-             flat_m.reshape(nc, chunk)),
-        )
-        ce = -total / jnp.maximum(flat_m.sum(), 1.0)
+        ce = _head_loss(
+            mesh, flat_h.reshape(nc, chunk, e), params["lm_head"],
+            flat_t.reshape(nc, chunk), flat_m.reshape(nc, chunk),
+            jnp.maximum(flat_m.sum(), 1.0))
     loss = ce
     if aux:
         loss = (ce + config.moe_aux_weight * aux["load_balance"]
