@@ -1,9 +1,13 @@
-"""Model zoo. Flagship: Llama-3-family decoder built TPU-first — scanned
-layers, bf16 params with f32 statistics, logical-axis shardings from
-``ray_tpu.parallel``, Pallas flash attention / ring attention. With
-``moe_experts > 0`` the MLP is a routed expert layer (``moe.py``: dropless,
-the (token, expert) rows sorted by expert over a Pallas grouped matmul);
-with ``qk_norm`` q and k are normalised before rope. OLMoE-1B-7B is both."""
+"""Model zoo. One pre-norm decoder built TPU-first and described by layer
+kinds (``llama.py``, ``kinds.py``): a scan over periods of the stack, bf16
+params with f32 statistics, logical-axis shardings from ``ray_tpu.parallel``.
+Token mixers: softmax attention through the Pallas flash kernels or ring
+attention (with its variants: q/k norms, partial rope, an output gate), and
+Gated DeltaNet (``gdn.py``: a chunked delta-rule scan as Pallas kernels).
+MLPs: dense SwiGLU, or with ``moe_experts > 0`` a routed expert layer
+(``moe.py``: dropless, the (token, expert) rows sorted by expert over a
+Pallas grouped matmul, a shared expert, a chip's share of the experts).
+Llama-3, InternLM2, Mistral, OLMoE-1B-7B and Qwen3-Next are configurations."""
 
 from .llama import (
     LlamaConfig,

@@ -1,9 +1,20 @@
-"""Llama-3-family decoder, TPU-first.
+"""A pre-norm decoder described by layer kinds, TPU-first.
+
+A block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack knows no
+more than that: a token mixer (``attn``: softmax attention, below;
+``gdn``: Gated DeltaNet, ``models/gdn.py``) and an MLP (``dense``, below;
+``moe``: the routed experts, ``models/moe.py``) are ``LayerKind``s
+(``models/kinds.py``) that own their leaves, logical axes, init, FLOPs,
+counters and the names a remat policy may save. ``layer_pattern`` lists
+the mixers of one PERIOD of the stack. Llama-3, InternLM2, Mistral, Mixtral
+and OLMoE are a period of one attention block; Qwen3-Next is three
+DeltaNet blocks and one of gated attention.
 
 Design choices (vs. a torch port):
-- Layers are **stacked and scanned** (`lax.scan`): one compiled block body
-  regardless of depth; `jax.checkpoint` on the block body trades FLOPs for
-  HBM (rematerialisation).
+- Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
+  the pattern's blocks in order, the leaves of each position of the period
+  stacked over the periods, so one compiled body regardless of depth;
+  `jax.checkpoint` on each block trades FLOPs for HBM (rematerialisation).
 - Params are a plain pytree of jnp arrays; ``param_axes(config)`` returns a
   matching tree of logical-axis tuples consumed by
   ``ray_tpu.parallel.sharding`` — strategy changes never touch this file.
@@ -31,10 +42,17 @@ from jax.sharding import Mesh
 from ..ops import (flash_attention, mha_reference, ring_attention, rms_norm,
                    apply_rope, ulysses_attention)
 from ..parallel.sharding import shard_constraint
+from .gdn import GDN
+from .kinds import LayerKind
+from .moe import MOE
 
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    """One decoder: its widths, the kinds of its layers and the facts of its
+    architecture. The name is from when the file held one family; the
+    defaults are still llama-3-8b's."""
+
     vocab_size: int = 128_256
     hidden: int = 4096
     n_layers: int = 32
@@ -67,8 +85,43 @@ class LlamaConfig:
     # OLMoE: an RMSNorm with a learned weight over the whole q and the whole
     # k projection, before the split into heads and before rope.
     qk_norm: bool = False
+    # The token mixers of one period of the stack, in order ("attn" | "gdn");
+    # n_layers is a multiple of its length. Qwen3-Next: gdn gdn gdn attn.
+    layer_pattern: tuple[str, ...] = ("attn",)
+    # Facts of an architecture, as above. Every RMSNorm multiplies by
+    # ``1 + w`` and starts ``w`` at zero; q and k are normalised per head,
+    # over head_dim, before rope; rope turns only the first ``rotary_dim``
+    # features of a head (0: all of them); the q projection also makes a
+    # per-head gate, and attention's output is multiplied by its sigmoid.
+    norm_plus_one: bool = False
+    head_qk_norm: bool = False
+    rotary_dim: int = 0
+    attn_out_gate: bool = False
+    # Gated DeltaNet mixer: key heads, value heads (a multiple), the size of
+    # both kinds of head, the causal conv's width.
+    gdn_key_heads: int = 16
+    gdn_value_heads: int = 32
+    gdn_head_dim: int = 128
+    gdn_conv: int = 4
+    # MoE: width of the always-on shared expert (0: none), and which of the
+    # ``moe_experts`` the router scores this program holds, as (first, count)
+    # (None: all): one chip's share of an expert-parallel deployment, on the
+    # plain jit path, with no exchange (models/moe.py).
+    moe_shared: int = 0
+    moe_held: tuple[int, int] | None = None
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
+
+    @property
+    def norm_offset(self) -> float:
+        return 1.0 if self.norm_plus_one else 0.0
+
+    @property
+    def n_periods(self) -> int:
+        if self.n_layers % len(self.layer_pattern):
+            raise ValueError(f"{self.n_layers} layers are no whole number of "
+                             f"periods of {self.layer_pattern}")
+        return self.n_layers // len(self.layer_pattern)
 
 
 PRESETS: dict[str, LlamaConfig] = {
@@ -92,79 +145,120 @@ PRESETS: dict[str, LlamaConfig] = {
     "mixtral-8x7b-ish": LlamaConfig(hidden=4096, n_layers=32, n_heads=32,
                                     n_kv_heads=8, intermediate=14_336, head_dim=128,
                                     moe_experts=8, moe_norm_topk=True),
+    # a hybrid period for tests: three DeltaNet blocks and one of gated
+    # attention (partial rope, 1 + w norms), a shared expert, 2 of 8 experts
+    "hybrid-debug": LlamaConfig(vocab_size=256, hidden=64, n_layers=4, n_heads=4,
+                                n_kv_heads=2, intermediate=32, head_dim=32,
+                                norm_eps=1e-6, layer_pattern=("gdn", "gdn", "gdn", "attn"),
+                                norm_plus_one=True, head_qk_norm=True, rotary_dim=8,
+                                attn_out_gate=True, gdn_key_heads=2, gdn_value_heads=4,
+                                gdn_head_dim=16, moe_experts=8, moe_top_k=3,
+                                moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
+                                moe_aux_weight=0.001),
 }
+
+
+def _attn_axes(c: LlamaConfig) -> dict:
+    axes = {
+        "wq": ("embed", "heads", "head_dim"),
+        "wk": ("embed", "kv_heads", "head_dim"),
+        "wv": ("embed", "kv_heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+    if c.qk_norm or c.head_qk_norm:
+        axes.update(q_norm=("norm",), k_norm=("norm",))
+    return axes
+
+
+def _attn_init(c: LlamaConfig, keys, lead, normal) -> dict:
+    H, KH, D, E = c.n_heads, c.n_kv_heads, c.head_dim, c.hidden
+    params = {
+        # with ``attn_out_gate`` a head's projection is its query, then its gate
+        "wq": normal(keys[0], lead + (E, H, D * (2 if c.attn_out_gate else 1)), E),
+        "wk": normal(keys[1], lead + (E, KH, D), E),
+        "wv": normal(keys[2], lead + (E, KH, D), E),
+        "wo": normal(keys[3], lead + (H, D, E), H * D),
+    }
+    if c.qk_norm:
+        params.update(q_norm=jnp.ones(lead + (H * D,), c.dtype),
+                      k_norm=jnp.ones(lead + (KH * D,), c.dtype))
+    if c.head_qk_norm:
+        fill = jnp.zeros if c.norm_plus_one else jnp.ones
+        params.update(q_norm=fill(lead + (D,), c.dtype), k_norm=fill(lead + (D,), c.dtype))
+    return params
+
+
+def _dense_axes(c: LlamaConfig) -> dict:
+    return {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
+            "w_down": ("mlp", "embed")}
+
+
+def _dense_init(c: LlamaConfig, keys, lead, normal) -> dict:
+    E, M = c.hidden, c.intermediate
+    return {"w_gate": normal(keys[0], lead + (E, M), E),
+            "w_up": normal(keys[1], lead + (E, M), E),
+            "w_down": normal(keys[2], lead + (M, E), M)}
+
+
+def _mlp_kind(c: LlamaConfig) -> LayerKind:
+    return MOE if c.moe_experts > 0 else DENSE
+
+
+def _per_position(c: LlamaConfig, make):
+    """``make(i, mixer)`` for each position of the period, laid out as
+    ``params["layers"]`` is: the block's own tree for a period of one block
+    (the layout every checkpoint and the serving engine know), else a
+    sub-tree ``slot<i>`` a position."""
+    if len(c.layer_pattern) == 1:
+        return make(0, c.layer_pattern[0])
+    return {f"slot{i}": make(i, m) for i, m in enumerate(c.layer_pattern)}
 
 
 def param_axes(config: LlamaConfig):
     """Tree of logical-axis tuples matching ``init_params`` output."""
-    if config.moe_experts > 0:
-        from .moe import moe_param_axes
+    c = config
 
-        mlp_axes = moe_param_axes(prefix=("layers",))
-    else:
-        mlp_axes = {
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        }
-    qk_axes = ({"q_norm": ("layers", "norm"), "k_norm": ("layers", "norm")}
-               if config.qk_norm else {})
+    def block(_, mixer: str) -> dict:
+        axes = {"attn_norm": ("norm",), **MIXERS[mixer].axes(c),
+                "mlp_norm": ("norm",), **_mlp_kind(c).axes(c)}
+        return {k: ("layers",) + v for k, v in axes.items()}
+
     return {
         "embed": ("vocab_in", "embed"),
-        "layers": {
-            "attn_norm": ("layers", "norm"),
-            **qk_axes,
-            "wq": ("layers", "embed", "heads", "head_dim"),
-            "wk": ("layers", "embed", "kv_heads", "head_dim"),
-            "wv": ("layers", "embed", "kv_heads", "head_dim"),
-            "wo": ("layers", "heads", "head_dim", "embed"),
-            "mlp_norm": ("layers", "norm"),
-            **mlp_axes,
-        },
+        "layers": _per_position(c, block),
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
     }
 
 
 def init_params(config: LlamaConfig, key: jax.Array) -> dict:
-    """Random init (truncated-normal fan-in scaling), stacked over layers."""
+    """Random init (truncated-normal fan-in scaling), stacked over the
+    periods of the stack."""
     c = config
     keys = jax.random.split(key, 9)
-    L, H, E = c.n_layers, c.n_heads, c.hidden
-    KH, D, M = c.n_kv_heads, c.head_dim, c.intermediate
+    lead, E = (c.n_periods,), c.hidden
 
     def norm_init(k, shape, fan_in):
         return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(c.dtype)
 
-    if c.moe_experts > 0:
-        from .moe import init_moe_params
+    norm_fill = jnp.zeros if c.norm_plus_one else jnp.ones
 
-        mlp_params = init_moe_params(
-            keys[5], hidden=E, expert_mlp=M, n_experts=c.moe_experts,
-            dtype=c.dtype, n_layers=L,
-        )
-    else:
-        mlp_params = {
-            "w_gate": norm_init(keys[5], (L, E, M), E),
-            "w_up": norm_init(keys[6], (L, E, M), E),
-            "w_down": norm_init(keys[7], (L, M, E), M),
+    def block(i: int, mixer: str) -> dict:
+        # a period of one block draws from ``key`` as it always has
+        ks = keys if len(c.layer_pattern) == 1 else jax.random.split(
+            jax.random.fold_in(key, i), 9)
+        return {
+            "attn_norm": norm_fill(lead + (E,), c.dtype),
+            **MIXERS[mixer].init(c, ks[1:5], lead, norm_init),
+            "mlp_norm": norm_fill(lead + (E,), c.dtype),
+            **_mlp_kind(c).init(c, ks[5:8], lead, norm_init),
         }
-    qk_params = ({"q_norm": jnp.ones((L, H * D), c.dtype),
-                  "k_norm": jnp.ones((L, KH * D), c.dtype)} if c.qk_norm else {})
+
     return {
         "embed": norm_init(keys[0], (c.vocab_size, E), E),
-        "layers": {
-            "attn_norm": jnp.ones((L, E), c.dtype),
-            **qk_params,
-            "wq": norm_init(keys[1], (L, E, H, D), E),
-            "wk": norm_init(keys[2], (L, E, KH, D), E),
-            "wv": norm_init(keys[3], (L, E, KH, D), E),
-            "wo": norm_init(keys[4], (L, H, D, E), H * D),
-            "mlp_norm": jnp.ones((L, E), c.dtype),
-            **mlp_params,
-        },
-        "final_norm": jnp.ones((E,), c.dtype),
+        "layers": _per_position(c, block),
+        "final_norm": norm_fill((E,), c.dtype),
         "lm_head": norm_init(keys[8], (E, c.vocab_size), E),
     }
 
@@ -229,8 +323,74 @@ def _norm_over_heads(t, weight, eps):
     return (f * lax.rsqrt(var + eps) * w).astype(t.dtype)
 
 
+def _gate_output(attn, gate):
+    """attn * sigmoid(gate), element by element, in float32."""
+    return (attn.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
+
+
+def _attn_mixer(h, layer, *, config: LlamaConfig, positions, mesh: Mesh | None):
+    """Softmax attention on the normed input h [B, S, E] -> [B, S, E]."""
+    c = config
+
+    def sc(t, axes):
+        return shard_constraint(t, mesh, axes) if mesh is not None else t
+
+    q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
+    k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
+    v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
+    if c.attn_out_gate:
+        q, gate = q[..., :c.head_dim], q[..., c.head_dim:]
+    if c.qk_norm:
+        q = _norm_over_heads(q, layer["q_norm"], c.norm_eps)
+        k = _norm_over_heads(k, layer["k_norm"], c.norm_eps)
+    if c.head_qk_norm:
+        # per head, over its head_dim features, weight [D]
+        q = rms_norm(q, layer["q_norm"], eps=c.norm_eps, offset=c.norm_offset)
+        k = rms_norm(k, layer["k_norm"], eps=c.norm_eps, offset=c.norm_offset)
+    q = apply_rope(q, positions, theta=c.rope_theta, rotary_dim=c.rotary_dim or None)
+    k = apply_rope(k, positions, theta=c.rope_theta, rotary_dim=c.rotary_dim or None)
+    q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
+    k = checkpoint_name(k, "k")
+    v = checkpoint_name(v, "v")
+    attn = _attention(q, k, v, c, mesh)
+    if c.attn_out_gate:
+        with jax.named_scope("attn_gate"):
+            attn = _gate_output(attn, checkpoint_name(gate, "attn_gate"))
+    return jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
+
+
+def _attn_matmul_params(c: LlamaConfig) -> float:
+    gate = c.n_heads if c.attn_out_gate else 0
+    return c.hidden * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2 + gate)
+
+
+def _dense_mlp(h, layer, *, config: LlamaConfig, mesh: Mesh | None, ep_axis=None):
+    c = config
+    gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"])
+    up = jnp.einsum("bse,em->bsm", h, layer["w_up"])
+    ff = jax.nn.silu(gate.astype(jnp.float32)).astype(c.dtype) * up
+    if mesh is not None:
+        ff = shard_constraint(ff, mesh, ("batch", "seq", "mlp"))
+    return jnp.einsum("bsm,me->bse", ff, layer["w_down"]), {}
+
+
+ATTN = LayerKind(
+    axes=_attn_axes, init=_attn_init, apply=_attn_mixer,
+    matmul_params=_attn_matmul_params,
+    # causal scores and values, forward: 2 products x 2 H D seq / 2
+    mixing_flops=lambda c, seq: 2.0 * c.n_heads * c.head_dim * seq,
+    # what ``flash_attention``'s vjp rule reads: the projections, and the
+    # two residuals it names itself; the output gate where there is one
+    save_names=("q", "k", "v", "attn_out", "attn_lse", "attn_gate"))
+DENSE = LayerKind(
+    axes=_dense_axes, init=_dense_init, apply=_dense_mlp,
+    matmul_params=lambda c: 3.0 * c.hidden * c.intermediate)
+MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN}
+
+
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
-           ep_axis: str | None = None):
+           ep_axis: str | None = None, mixer: str = "attn"):
     """One decoder block: x [B, S, E] in config.dtype -> (x, aux). ``aux``
     is ``{}`` for a dense MLP and ``moe_block``'s for a routed one.
     ``ep_axis`` is set only when running per-device inside the pipeline
@@ -244,41 +404,18 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
     # block that issued them (op_name on the profiler's op line), and
     # leave the compiled program as it was
     with jax.named_scope("attn"):
-        h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps)
-        q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
-        k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
-        v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
-        if c.qk_norm:
-            q = _norm_over_heads(q, layer["q_norm"], c.norm_eps)
-            k = _norm_over_heads(k, layer["k_norm"], c.norm_eps)
-        q = apply_rope(q, positions, theta=c.rope_theta)
-        k = apply_rope(k, positions, theta=c.rope_theta)
-        q = checkpoint_name(sc(q, ("batch", "heads", "seq", "head_dim")), "q")
-        k = checkpoint_name(k, "k")
-        v = checkpoint_name(v, "v")
-        attn = _attention(q, k, v, c, mesh)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
-        x = x + sc(attn_out, ("batch", "seq", "embed_act"))
+        h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps, offset=c.norm_offset)
+        mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh)
+        x = x + sc(mixed, ("batch", "seq", "embed_act"))
 
     with jax.named_scope("mlp"):
-        h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps)
-        aux = {}
-        if c.moe_experts > 0:
-            from .moe import moe_block
-
-            down, aux = moe_block(h, layer, top_k=c.moe_top_k,
-                                  norm_topk=c.moe_norm_topk, ep_axis=ep_axis)
-        else:
-            gate = jnp.einsum("bse,em->bsm", h, layer["w_gate"])
-            up = jnp.einsum("bse,em->bsm", h, layer["w_up"])
-            ff = jax.nn.silu(gate.astype(jnp.float32)).astype(c.dtype) * up
-            ff = sc(ff, ("batch", "seq", "mlp"))
-            down = jnp.einsum("bsm,me->bse", ff, layer["w_down"])
+        h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
+        down, aux = _mlp_kind(c).apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis)
         x = x + sc(down, ("batch", "seq", "embed_act"))
     return x, aux
 
 
-def _apply_remat(block, c: LlamaConfig):
+def _apply_remat(block, c: LlamaConfig, mixer: str = "attn"):
     """Wrap a decoder block with the configured rematerialisation policy."""
     if not c.remat:
         return block
@@ -305,12 +442,16 @@ def _apply_remat(block, c: LlamaConfig):
         # and two grouped matmuls a layer: by the chip compiler's count
         # OLMoE at 3 layers and 4 x 4096 is 13.79 GB so, and 17.02 GB
         # with gate and up saved (PERF.md, PR 26).
-        from .moe import ROUTE_NAMES
-
+        # A DeltaNet mixer saves what its backward reads and cannot cheaply
+        # remake (models/gdn.py's SAVE_NAMES: q, k and v as the conv takes
+        # them, the gates, the scan's output and z, ~0.54 GB a layer at 16k
+        # tokens); the conv, the chunk-local operands and the chunk states
+        # are made again.
+        # The names are the kinds' own: the block's mixer and its MLP.
         return jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "q", "k", "v", "attn_out", "attn_lse", *ROUTE_NAMES,
+                *MIXERS[mixer].save_names, *_mlp_kind(c).save_names,
             ),
         )
     return jax.checkpoint(block)
@@ -322,10 +463,12 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
 
     ``return_aux=True`` additionally returns what the routed layers
     counted in the same pass: ``load_balance`` and ``z`` (the auxiliary
-    terms, each the mean over layers), ``rows_per_expert`` [L, X] int32
-    and ``rows_dropped`` (0: the dispatch is dropless). ``{}`` for dense
-    configs and on the pipelined path, which does not thread it through
-    the schedule yet."""
+    terms, each the mean over layers), ``rows_per_expert`` [L, X] int32,
+    ``rows_dropped`` (0: the dispatch is dropless) and, where the program
+    holds a share of the experts (``moe_held``), ``rows_per_held_expert``
+    [L, count] and ``held_share`` [L] (their sum over all rows). ``{}`` for
+    dense configs and on the pipelined path, which does not thread it
+    through the schedule yet."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -355,6 +498,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
 
         from ..parallel.pipeline import pipeline_apply
 
+        if len(c.layer_pattern) > 1:
+            raise NotImplementedError("the pipeline schedule takes a period of one block")
         ep_axis = "ep" if c.moe_experts > 0 and mesh.shape.get("ep", 1) > 1 else None
         raw_block = functools.partial(
             _block, positions=positions, config=c, mesh=None, ep_axis=ep_axis
@@ -374,21 +519,42 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
             mesh=mesh, n_microbatches=c.pipeline_microbatches,
             param_specs=param_specs,
         )
-        out = rms_norm(x, params["final_norm"], eps=c.norm_eps)
+        out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
         return (out, {}) if return_aux else out
 
-    block = _apply_remat(
-        functools.partial(_block, positions=positions, config=c, mesh=mesh), c
-    )
+    blocks = [
+        _apply_remat(functools.partial(_block, positions=positions, config=c,
+                                       mesh=mesh, mixer=mixer), c, mixer)
+        for mixer in c.layer_pattern]
+    # a period of one block keeps its leaves unnested (``_per_position``): it
+    # is the period whose one position is that tree
+    layers = params["layers"] if len(blocks) > 1 else {"slot0": params["layers"]}
 
-    x, per_layer = lax.scan(block, x, params["layers"])
-    out = rms_norm(x, params["final_norm"], eps=c.norm_eps)
+    def period(x, layers):
+        auxes = []
+        for i, block in enumerate(blocks):
+            x, aux = block(x, layers[f"slot{i}"])
+            auxes.append(aux)
+        return x, auxes
+
+    # one aux a position of the period, each [periods, ...] -> [L, ...] in
+    # layer order: position i of period p is layer p * len(blocks) + i
+    x, auxes = lax.scan(period, x, layers)
+    per_layer = auxes[0] if len(auxes) == 1 else jax.tree.map(
+        lambda *a: jnp.stack(a, axis=1).reshape((c.n_layers,) + a[0].shape[1:]), *auxes)
+    out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
     if not return_aux:
         return out
-    return out, {"load_balance": jnp.mean(per_layer["load_balance"]),
-                 "z": jnp.mean(per_layer["z"]),
-                 "rows_per_expert": per_layer["rows"],
-                 "rows_dropped": jnp.sum(per_layer["dropped"])} if per_layer else {}
+    if not per_layer:
+        return out, {}
+    aux = {"load_balance": jnp.mean(per_layer["load_balance"]),
+           "z": jnp.mean(per_layer["z"]),
+           "rows_per_expert": per_layer["rows"],
+           "rows_dropped": jnp.sum(per_layer["dropped"])}
+    if "rows_held" in per_layer:
+        aux.update(rows_per_held_expert=per_layer["rows_held"],
+                   held_share=per_layer["held_share"])
+    return out, aux
 
 
 def forward(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None):
@@ -400,20 +566,20 @@ def forward(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None):
 
 
 def train_flops_per_token(config: LlamaConfig, seq: int) -> float:
-    """Model FLOPs per trained token (6N active-param matmul + causal
-    attention), the numerator of MFU. Embedding gather excluded (standard
-    accounting); a routed MLP counts what the dropless dispatch computes
-    for a token: its top_k experts' three matrices, plus the router."""
+    """Model FLOPs per trained token (6N active-param matmul + what the
+    mixers compute that is no parameter product: causal attention, the
+    delta rule's chunks), the numerator of MFU; each kind counts its own.
+    Embedding gather excluded (standard accounting); a routed MLP counts
+    what the dropless dispatch computes for a token: its top_k experts'
+    three matrices (with ``moe_held``, the held experts' share of them in
+    expectation), the shared expert, plus the router."""
     c = config
-    if c.moe_experts > 0:
-        mlp = c.moe_top_k * 3 * c.hidden * c.intermediate + c.hidden * c.moe_experts
-    else:
-        mlp = 3 * c.hidden * c.intermediate
-    n_params = c.n_layers * (
-        c.hidden * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2) + mlp
+    mlp = _mlp_kind(c).matmul_params(c)
+    n_params = c.n_periods * sum(
+        MIXERS[m].matmul_params(c) + mlp for m in c.layer_pattern
     ) + c.hidden * c.vocab_size
-    attn = 6 * c.n_layers * c.n_heads * c.head_dim * seq  # causal fwd+bwd
-    return 6.0 * n_params + attn
+    mixing = c.n_periods * sum(MIXERS[m].mixing_flops(c, seq) for m in c.layer_pattern)
+    return 6.0 * n_params + 3 * mixing
 
 
 def _head_chunks(mesh, hs, lm_head, ts, ms, denom, with_grads: bool):

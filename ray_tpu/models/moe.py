@@ -27,6 +27,25 @@ is applied to ``silu(gate) * up`` in float32 before the down projection,
 which is linear, so the result equals the equation's; the backward pass
 then needs no output of the down projection.
 
+A shared expert (Qwen3-Next, DeepSeek: leaves ``w_shared_*`` present) is a
+SwiGLU every token passes through, scaled by ``sigmoid(h w_shared_scale)``
+and added to the routed sum; it has its own scope, ``moe_shared``.
+
+One chip's share of the experts on the plain ``jit`` path (``held`` =
+(first, count), static): the router keeps its published width and routes
+over all X experts, the expert leaves hold ``count`` consecutive experts
+from ``first``, and the layer returns what THOSE experts add (plus the
+shared expert, which every chip computes alike). Rows of absent experts
+cost no grouped-matmul tile and add nothing; nothing is exchanged and
+nothing stands in for the exchange. The held rows are a contiguous range
+of the sorted rows, and the dispatch gathers that range alone: ``cap`` rows
+from its first, ``cap`` a static ``HELD_CAPACITY`` times the range's even
+share, the results added into their tokens (a scatter-add of ``cap`` rows
+where the whole permutation would gather N*k). No row is dropped whatever
+the skew: a step whose range is longer than ``cap`` takes, under a
+``lax.cond``, the path that gathers all N*k rows and computes the range
+by ``row_offset``.
+
 Expert parallelism inside a ``shard_map`` (``ep_axis``): routing is
 global (the router is replicated), the sort is the same on every device,
 each device holds X/ep consecutive experts and therefore one contiguous
@@ -43,6 +62,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import grouped_matmul
+from .kinds import LayerKind
 
 # what a remat policy may save of the routing: a few MB a layer against a
 # router product, a softmax, a top-k, two sorts and a bincount in the
@@ -52,34 +72,55 @@ from ..ops import grouped_matmul
 ROUTE_NAMES = ("moe_probs", "moe_gates", "moe_order", "moe_inv", "moe_sizes")
 
 
-def moe_param_axes(prefix: tuple = ()):
+def moe_param_axes(prefix: tuple = (), shared: bool = False):
     """Logical axes; ``prefix`` prepends e.g. ("layers",) for stacked use."""
-    return {
+    axes = {
         "router": prefix + ("embed", "experts"),
         "w_gate": prefix + ("experts", "embed", "expert_mlp"),
         "w_up": prefix + ("experts", "embed", "expert_mlp"),
         "w_down": prefix + ("experts", "expert_mlp", "embed"),
     }
+    if shared:
+        axes.update({
+            "w_shared_scale": prefix + ("embed",),
+            "w_shared_gate": prefix + ("embed", "mlp"),
+            "w_shared_up": prefix + ("embed", "mlp"),
+            "w_shared_down": prefix + ("mlp", "embed"),
+        })
+    return axes
 
 
 def init_moe_params(key, hidden: int, expert_mlp: int, n_experts: int, dtype,
-                    n_layers: int | None = None):
+                    n_layers: int | None = None, *, held: int | None = None,
+                    shared_mlp: int = 0):
     """The single source of MoE init (llama.py stacks it per layer via
-    ``n_layers``)."""
+    ``n_layers``). ``held``: how many of the ``n_experts`` the router scores
+    have leaves here (all of them when None); ``shared_mlp``: the width of
+    the shared expert, 0 for none."""
     ks = jax.random.split(key, 4)
     lead = () if n_layers is None else (n_layers,)
+    held = n_experts if held is None else held
 
     def init(k, shape, fan_in, out_dtype=dtype):
         return (jax.random.truncated_normal(k, -2, 2, lead + shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(out_dtype)
 
-    return {
+    params = {
         # router stays f32: routing logits are precision-sensitive
         "router": init(ks[0], (hidden, n_experts), hidden, jnp.float32),
-        "w_gate": init(ks[1], (n_experts, hidden, expert_mlp), hidden),
-        "w_up": init(ks[2], (n_experts, hidden, expert_mlp), hidden),
-        "w_down": init(ks[3], (n_experts, expert_mlp, hidden), expert_mlp),
+        "w_gate": init(ks[1], (held, hidden, expert_mlp), hidden),
+        "w_up": init(ks[2], (held, hidden, expert_mlp), hidden),
+        "w_down": init(ks[3], (held, expert_mlp, hidden), expert_mlp),
     }
+    if shared_mlp:
+        ss = jax.random.split(jax.random.fold_in(key, 1), 4)
+        params.update({
+            "w_shared_scale": init(ss[0], (hidden,), hidden),
+            "w_shared_gate": init(ss[1], (hidden, shared_mlp), hidden),
+            "w_shared_up": init(ss[2], (hidden, shared_mlp), hidden),
+            "w_shared_down": init(ss[3], (shared_mlp, hidden), shared_mlp),
+        })
+    return params
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -150,17 +191,131 @@ def route(tokens, router, *, top_k: int, norm_topk: bool):
     }
 
 
+def shared_expert(tokens, params):
+    """The always-on expert on tokens [N, E]: a SwiGLU scaled, token by
+    token, by ``sigmoid(h . w_shared_scale)``."""
+    scale = jax.nn.sigmoid(jnp.einsum(
+        "ne,e->n", tokens, params["w_shared_scale"], preferred_element_type=jnp.float32))
+    gate = jnp.einsum("ne,em->nm", tokens, params["w_shared_gate"])
+    up = jnp.einsum("ne,em->nm", tokens, params["w_shared_up"])
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+           * scale[:, None]).astype(tokens.dtype)
+    return jnp.einsum("nm,me->ne", act, params["w_shared_down"])
+
+
+# rows the compact path of a held range is compiled for, as a multiple of
+# the range's even share N k count / X: with seeded weights and uniform ids a
+# range's share stays within a few percent of even
+HELD_CAPACITY = 2.0
+
+
+def _held_capacity(n_rows: int, held, n_experts: int) -> int | None:
+    """Static row count of the compact path for ``held`` = (first, count) of
+    ``n_experts``, or None where it would save nothing (no held range, or a
+    bound of at least half of all rows)."""
+    if held is None:
+        return None
+    cap = int(HELD_CAPACITY * n_rows * held[1] / n_experts)
+    cap = -(-cap // 512) * 512 if cap >= 512 else -(-cap // 16) * 16
+    return cap if 2 * cap <= n_rows else None
+
+
+def _experts(xs, row_gates, weights, gmm):
+    """The three grouped products on sorted rows xs [M, E], the gate applied
+    to the activation in float32: [M, E]."""
+    gate = checkpoint_name(gmm(xs, weights["w_gate"]), "moe_gate")
+    up = checkpoint_name(gmm(xs, weights["w_up"]), "moe_up")
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+           * row_gates[:, None]).astype(xs.dtype)
+    return gmm(act, weights["w_down"])
+
+
+def _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset):
+    """Every one of the N*k sorted rows is gathered; the grouped matmul
+    computes the groups' range of them (all, when ``offset`` is None)."""
+    n, e = tokens.shape
+    gmm = functools.partial(grouped_matmul, group_sizes=sizes, row_offset=offset)
+    with jax.named_scope("moe_dispatch"):
+        xs = checkpoint_name(_dispatch(tokens, order, inv, top_k), "moe_xs")
+        row_gates = _permute(gates.reshape(n * top_k), order, inv)
+    with jax.named_scope("moe_experts"):
+        ys = _experts(xs, row_gates, weights, gmm)
+    with jax.named_scope("moe_combine"):
+        out = _permute(ys, inv, order).reshape(n, top_k, e)
+        return out.astype(jnp.float32).sum(axis=1).astype(tokens.dtype)
+
+
+def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
+    """Only the held experts' rows: the ``cap`` sorted rows from the held
+    range's first. Rows past the range's end belong to no group, come back
+    zero and are added nowhere."""
+    n, e = tokens.shape
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    valid = rows < jnp.sum(sizes)
+    pair = order[jnp.minimum(offset + rows, n * top_k - 1)]  # token * k + choice
+    token = pair // top_k
+    gmm = functools.partial(grouped_matmul, group_sizes=sizes,
+                            row_offset=jnp.zeros((), jnp.int32))
+    with jax.named_scope("moe_dispatch"):
+        xs = checkpoint_name(tokens[token], "moe_xs")
+        row_gates = jnp.where(valid, gates.reshape(n * top_k)[pair], 0.0)
+    with jax.named_scope("moe_experts"):
+        ys = _experts(xs, row_gates, weights, gmm)
+    with jax.named_scope("moe_combine"):
+        ys = jnp.where(valid[:, None], ys, 0).astype(jnp.float32)
+        return jnp.zeros((n, e), jnp.float32).at[token].add(ys).astype(tokens.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_or_all_rows(top_k, cap, tokens, weights, gates, order, inv, sizes, offset):
+    """The held range by the compact path, or, in a step whose range is
+    longer than ``cap``, by the path that gathers every row: dropless
+    whatever the skew. One ``lax.cond`` forward and one backward, each
+    running the branch taken: differentiating a plain ``cond`` keeps BOTH
+    branches' residuals (4.3 GB more at 16k tokens x 10, by the chip
+    compiler's count), so the backward rule runs its branch again instead."""
+    return jax.lax.cond(
+        jnp.sum(sizes) <= cap,
+        lambda: _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset),
+        lambda: _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset))
+
+
+def _held_or_all_fwd(top_k, cap, *args):
+    return _held_or_all_rows(top_k, cap, *args), args
+
+
+def _held_or_all_bwd(top_k, cap, args, g):
+    tokens, weights, gates, order, inv, sizes, offset = args
+
+    def back(path):
+        return lambda: jax.vjp(path, tokens, weights, gates)[1](g)
+
+    d = jax.lax.cond(
+        jnp.sum(sizes) <= cap,
+        back(lambda t, w, gt: _held_rows(top_k, cap, t, w, gt, order, sizes, offset)),
+        back(lambda t, w, gt: _all_rows(top_k, t, w, gt, order, inv, sizes, offset)))
+    return (*d, None, None, None, None)
+
+
+_held_or_all_rows.defvjp(_held_or_all_fwd, _held_or_all_bwd)
+
+
 def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
-              ep_axis: str | None = None):
+              ep_axis: str | None = None, held: tuple[int, int] | None = None):
     """x: [B, S, E] -> ([B, S, E], aux). Routing in f32; experts in x.dtype
     with f32 accumulation.
 
     ``aux``: ``load_balance`` and ``z`` (scalars, this layer's auxiliary
-    terms), ``rows`` [X] int32 (rows computed per expert) and ``dropped``
-    (N*k less their sum: 0 by construction).
+    terms), ``rows`` [X] int32 (rows routed to each expert the router
+    scores), ``dropped`` (N*k less their sum: 0 by construction) and,
+    with ``held``, ``rows_held`` [count] int32 (rows computed by each
+    expert held here) and ``held_share`` (their sum over N*k).
 
     ``ep_axis`` (inside a ``shard_map``): ``params`` hold this device's
-    X/ep consecutive experts and the whole router; see the module's text.
+    X/ep consecutive experts and the whole router. ``held`` = (first,
+    count), static, on the plain path: ``params`` hold those experts and
+    the whole router, and the result is their part of the routed sum. See
+    the module's text for both.
     """
     b, s, e = x.shape
     n = b * s
@@ -168,26 +323,64 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     with jax.named_scope("moe_route"):
         r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk)
     sizes, offset = r["sizes"], None
-    if ep_axis is not None:
+    if ep_axis is not None or held is not None:
         local = params["w_gate"].shape[0]
-        first = jax.lax.axis_index(ep_axis) * local
+        if held is not None:
+            first, count = held
+            if count != local:
+                raise ValueError(f"held says {count} experts, the leaves hold {local}")
+        else:
+            first = jax.lax.axis_index(ep_axis) * local
         offset = jnp.sum(jnp.where(jnp.arange(sizes.shape[0]) < first, sizes, 0))
         sizes = jax.lax.dynamic_slice_in_dim(sizes, first, local)
-    gmm = functools.partial(grouped_matmul, group_sizes=sizes, row_offset=offset)
-    with jax.named_scope("moe_dispatch"):
-        xs = checkpoint_name(_dispatch(tokens, r["order"], r["inv"], top_k), "moe_xs")
-        row_gates = _permute(r["gates"].reshape(n * top_k), r["order"], r["inv"])
-    with jax.named_scope("moe_experts"):
-        gate = checkpoint_name(gmm(xs, params["w_gate"]), "moe_gate")
-        up = checkpoint_name(gmm(xs, params["w_up"]), "moe_up")
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-               * row_gates[:, None]).astype(x.dtype)
-        ys = gmm(act, params["w_down"])
-    with jax.named_scope("moe_combine"):
-        out = _permute(ys, r["inv"], r["order"]).reshape(n, top_k, e)
-        out = out.astype(jnp.float32).sum(axis=1).astype(x.dtype)
-        if ep_axis is not None:
+    weights = {k: params[k] for k in ("w_gate", "w_up", "w_down")}
+    cap = _held_capacity(n * top_k, held, r["sizes"].shape[0])
+    if cap is None:
+        out = _all_rows(top_k, tokens, weights, r["gates"], r["order"], r["inv"],
+                        sizes, offset)
+    else:
+        out = _held_or_all_rows(top_k, cap, tokens, weights, r["gates"], r["order"],
+                                r["inv"], sizes, offset)
+    if ep_axis is not None:
+        with jax.named_scope("moe_combine"):
             out = jax.lax.psum(out, ep_axis)
+    if "w_shared_gate" in params:
+        with jax.named_scope("moe_shared"):
+            out = out + shared_expert(tokens, params)
     aux = {"load_balance": r["load_balance"], "z": r["z"], "rows": r["sizes"],
            "dropped": n * top_k - jnp.sum(r["sizes"])}
+    if held is not None:
+        aux["rows_held"] = sizes
+        aux["held_share"] = jnp.sum(sizes).astype(jnp.float32) / (n * top_k)
     return out.reshape(b, s, e), aux
+
+
+def _moe_axes(c) -> dict:
+    return moe_param_axes(shared=c.moe_shared > 0)
+
+
+def _moe_init(c, keys, lead, normal) -> dict:
+    return init_moe_params(
+        keys[0], hidden=c.hidden, expert_mlp=c.intermediate, n_experts=c.moe_experts,
+        dtype=c.dtype, n_layers=lead[0] if lead else None,
+        held=c.moe_held[1] if c.moe_held else None, shared_mlp=c.moe_shared)
+
+
+def _moe_apply(h, layer, *, config, mesh=None, ep_axis=None):
+    c = config
+    return moe_block(h, layer, top_k=c.moe_top_k, norm_topk=c.moe_norm_topk,
+                     ep_axis=ep_axis, held=c.moe_held)
+
+
+def _moe_matmul_params(c) -> float:
+    """What a token passes through: the router, the shared expert, and its
+    ``top_k`` experts' three matrices; with a held range, the held experts'
+    share of them in expectation (``top_k x count / X``)."""
+    share = c.moe_held[1] / c.moe_experts if c.moe_held else 1.0
+    return (c.hidden * c.moe_experts + 3 * c.hidden * c.moe_shared
+            + (c.hidden if c.moe_shared else 0)
+            + c.moe_top_k * share * 3 * c.hidden * c.intermediate)
+
+
+MOE = LayerKind(axes=_moe_axes, init=_moe_init, apply=_moe_apply,
+                matmul_params=_moe_matmul_params, save_names=ROUTE_NAMES)

@@ -1,11 +1,12 @@
 """TPU compute ops: Pallas kernels and the JAX ops the models are built on.
 
-The hot paths (attention, the experts' grouped matmul) are Pallas TPU
-kernels; everything elementwise is left to XLA fusion. Sequence/context parallelism (ring attention) is
+The hot paths (attention, the experts' grouped matmul, the gated delta
+rule's scan over chunks) are Pallas TPU kernels; everything elementwise is left to XLA fusion. Sequence/context parallelism (ring attention) is
 green-field — the reference has none (SURVEY.md §5.7).
 """
 
 from .attention import flash_attention, mha_reference
+from .gated_delta import gated_delta_rule
 from .grouped_matmul import grouped_matmul
 from .ring_attention import ring_attention
 from .ulysses import ulysses_attention
@@ -14,6 +15,7 @@ from .rope import apply_rope, rope_frequencies
 
 __all__ = [
     "flash_attention",
+    "gated_delta_rule",
     "grouped_matmul",
     "mha_reference",
     "ring_attention",

@@ -7,10 +7,13 @@ import jax
 import jax.numpy as jnp
 
 
-def rms_norm(x, weight, *, eps: float = 1e-6):
-    """Llama-style RMSNorm, f32 statistics regardless of input dtype."""
+def rms_norm(x, weight, *, eps: float = 1e-6, offset: float = 0.0):
+    """Llama-style RMSNorm, f32 statistics regardless of input dtype.
+    ``offset`` 1 is the family that multiplies by ``1 + weight`` and
+    starts the weight at zero (Qwen3-Next, Gemma)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     x = x * jax.lax.rsqrt(var + eps)
-    return (x * weight.astype(jnp.float32)).astype(dtype)
+    weight = weight.astype(jnp.float32)
+    return (x * (weight + offset if offset else weight)).astype(dtype)

@@ -12,12 +12,18 @@ def rope_frequencies(head_dim: int, *, theta: float = 500_000.0):
     )
 
 
-def apply_rope(x, positions, *, theta: float = 500_000.0):
+def apply_rope(x, positions, *, theta: float = 500_000.0,
+               rotary_dim: int | None = None):
     """Rotate q or k. x: [B, H, S, D]; positions: [B, S] or [S] int32.
 
     Uses the split-halves convention (rotate_half), matching Llama.
-    Computed in f32, cast back to the input dtype.
+    Computed in f32, cast back to the input dtype. ``rotary_dim`` rotates
+    the first that many features of a head (their own split halves, their
+    own frequencies) and passes the rest through.
     """
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        rotated = apply_rope(x[..., :rotary_dim], positions, theta=theta)
+        return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     b, h, s, d = x.shape
     inv_freq = rope_frequencies(d, theta=theta)
     if positions.ndim == 1:

@@ -12,7 +12,8 @@ The same moment knows the shapes, so the record also carries what one
 call of each Pallas kernel costs (``kernel_costs``): the program's half
 of a roofline share, whose other half is the kernel's seconds under the
 same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
-``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``).
+``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``,
+``gdn_fwd``, ``gdn_bwd``).
 """
 
 from __future__ import annotations

@@ -19,6 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
@@ -97,6 +98,23 @@ def _grouped(chip, k, n, backward, rows=131072, groups=64):
     return jax.jit(fn).lower(lhs, rhs, sizes)
 
 
+def _gdn(chip, backward, b=2, h=32, t=8192, d=128):
+    """Qwen3-Next's DeltaNet scan at the benchmark's 2 x 8192: 32 value
+    heads of 128, 128 chunks a row; backward keeps the 128 chunk states in
+    VMEM (8 MB of scratch)."""
+    x = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16, sharding=chip)
+    gate = jax.ShapeDtypeStruct((b, h, t), jnp.float32, sharding=chip)
+
+    def fwd(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta, interpret=False)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else fwd
+    return jax.jit(fn).lower(x, x, x, gate, gate)
+
+
 CASES = {
     # llama3-1b widths: 32 q / 8 kv heads of 64, the train batch
     "flash-fwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=False),
@@ -115,6 +133,16 @@ CASES = {
     "moe-gmm-up": lambda c: _grouped(c, 2048, 1024, backward=False),
     "moe-gmm-up-grad": lambda c: _grouped(c, 2048, 1024, backward=True),
     "moe-gmm-down-grad": lambda c: _grouped(c, 1024, 2048, backward=True),
+    # Qwen3-Next: the delta rule's two kernels, and flash at head_dim 256
+    # with 16 q : 2 kv heads at the benchmark's 2 x 8192 (1024 x 1024 blocks
+    # still fit the scoped VMEM at D = 256)
+    "gdn-fwd": lambda c: _gdn(c, backward=False),
+    "gdn-bwd": lambda c: _gdn(c, backward=True),
+    "flash-fwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=False),
+    "flash-bwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=True),
+    # Qwen3-Next's held experts: 163,840 sorted rows of which a range is
+    # computed, 64 experts of 2048 x 512
+    "moe-gmm-held-grad": lambda c: _grouped(c, 2048, 512, backward=True, rows=163840),
 }
 
 

@@ -1,0 +1,114 @@
+"""The chunked gated delta rule (``ops/gated_delta.py``), in plain ``jnp``
+and as the two Pallas kernels (interpreted here), against the rule token by
+token: the output and every gradient, at rows of 1, 3 and 5 chunks, with
+decays near 0 (nothing crosses a chunk) and near 1 (everything does)."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import gated_delta as gd
+from ray_tpu.ops import trace_log
+
+IMPLS = {"jnp": gd.chunked_jnp,
+         "kernels": functools.partial(gd.gated_delta_rule, interpret=True)}
+# mean log decay a step: e^-20 forgets within a position, e^-0.001 keeps
+# 94% of the state over a whole chunk
+DECAYS = {"near-0": 20.0, "middle": 1.0, "near-1": 1e-3}
+
+
+def token_by_token(q, k, v, g, beta):
+    """S = e^g S; d = beta (v - S^T k); S += k d^T; o = S^T q, a head at a time."""
+    def head(q, k, v, g, beta):
+        def step(state, x):
+            q_t, k_t, v_t, g_t, b_t = x
+            state = jnp.exp(g_t) * state
+            state = state + jnp.outer(k_t, b_t * (v_t - state.T @ k_t))
+            return state, state.T @ q_t
+
+        zero = jnp.zeros((q.shape[-1], v.shape[-1]), jnp.float32)
+        return jax.lax.scan(step, zero, (q, k, v, g, beta))[1]
+
+    return jax.vmap(jax.vmap(head))(q, k, v, g, beta)
+
+
+def operands(t, scale, *, dk=32, dv=32, b=1, h=2, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, h, t, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, h, t, dk)))
+    v = jax.random.normal(ks[2], (b, h, t, dv))
+    g = -scale * jax.nn.softplus(jax.random.normal(ks[3], (b, h, t)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, t)))
+    return q, k, v, g, beta
+
+
+def value_and_grads(fn, xs):
+    ct = jax.random.normal(jax.random.PRNGKey(9), xs[2].shape)
+    with jax.default_matmul_precision("highest"):
+        grads = jax.grad(lambda *a: (fn(*a) * ct).sum(), argnums=(0, 1, 2, 3, 4))(*xs)
+        return (fn(*xs),) + grads
+
+
+@pytest.mark.parametrize("decay", list(DECAYS))
+@pytest.mark.parametrize("chunks", [1, 3, 5])
+@pytest.mark.parametrize("impl", list(IMPLS))
+def test_output_and_every_gradient_match_the_rule_token_by_token(impl, chunks, decay):
+    xs = operands(chunks * gd.CHUNK, DECAYS[decay])
+    want = value_and_grads(token_by_token, xs)
+    got = value_and_grads(IMPLS[impl], xs)
+    for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, atol=1e-4 * scale, rtol=0, err_msg=name)
+
+
+def test_state_crosses_chunk_boundaries_only_where_the_decay_lets_it():
+    """Zeroing the first chunk's values changes later chunks' output when
+    the decay is near 1 and leaves it alone when it is near 0: the
+    comparison above would not see a broken inter-chunk recurrence under a
+    decay that forgets everything."""
+    for decay, moved in (("near-1", True), ("near-0", False)):
+        q, k, v, g, beta = operands(3 * gd.CHUNK, DECAYS[decay])
+        cut = v.at[:, :, :gd.CHUNK].set(0.0)
+        a = IMPLS["kernels"](q, k, v, g, beta)[:, :, gd.CHUNK:]
+        b = IMPLS["kernels"](q, k, cut, g, beta)[:, :, gd.CHUNK:]
+        assert bool(jnp.abs(a - b).max() > 1e-3) is moved, decay
+
+
+def test_a_bfloat16_state_reads_far_from_the_rule_and_a_float32_one_does_not():
+    """What the benchmark's SCAN_RTOL separates, on bf16-valued q, k and v
+    with the output left in float32 (a bf16 output's own rounding, 0.16%,
+    would hide it): the state in float32 against the state rounded to
+    bfloat16 after every chunk."""
+    xs = operands(16 * gd.CHUNK, 0.02, dk=64, dv=64)
+    xs = tuple(x.astype(jnp.bfloat16).astype(jnp.float32) for x in xs[:3]) + xs[3:]
+    want = token_by_token(*xs)
+    rel = lambda o: float(jnp.sqrt(jnp.mean((o - want) ** 2) / jnp.mean(want ** 2)))  # noqa: E731
+    exact = rel(IMPLS["kernels"](*xs))
+    rounded = rel(gd.chunked_jnp(*xs, state_dtype=jnp.bfloat16))
+    assert exact < 1e-5 and rounded > 6e-4, (exact, rounded)
+
+
+def test_rows_must_be_whole_chunks_and_the_kernels_record_their_cost():
+    with pytest.raises(ValueError, match="multiple of 64"):
+        gd.gated_delta_rule(*operands(100, 1.0))
+    b, h, t, d = 1, 2, 128, 32
+    gd.gated_delta_rule(*operands(t, 1.0), interpret=True)
+    costs, rows = trace_log.kernel_costs(), b * h * t
+    assert costs["gdn_fwd"]["flops"] == 3 * 2 * rows * d * d + 2 * rows * gd.CHUNK * d
+    assert costs["gdn_bwd"]["flops"] == 10 * 2 * rows * d * d + 3 * 2 * rows * gd.CHUNK * d
+    operand_bytes = rows * (4 * d + gd.CHUNK) * 4 + rows // gd.CHUNK * d * 4
+    assert costs["gdn_fwd"]["bytes"] == operand_bytes + rows * d * 4
+    assert costs["gdn_bwd"]["bytes"] == 3 * operand_bytes + rows * d * 4
+    assert trace_log.kernel_traces()["gdn:interpret"] >= 1
+
+
+def test_the_inverse_of_a_unit_lower_triangle_is_exact():
+    a = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (3, 64, 64)), -1) * 0.3
+    with jax.default_matmul_precision("highest"):
+        got = gd._inverse_unit_lower(a)
+        eye = jnp.eye(64)
+        np.testing.assert_allclose(got @ (eye + a), jnp.broadcast_to(eye, a.shape), atol=2e-4)
