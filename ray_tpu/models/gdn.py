@@ -19,9 +19,9 @@ layer on the trace. ``SAVE_NAMES`` is what the backward pass reads and
 cannot cheaply remake: the projection's q, k and v as the conv takes them
 (its backward needs its input, and the conv, the activation and the norms
 are elementwise passes to make again where the projection is a 1.1 TFLOP
-product), the gates, the scan's output, ``z`` and the chunks' inverses
-(``ops/gated_delta.py`` names them: 134 MB a layer at 16k tokens against
-ten batched products). The rest of ``_prepare`` runs again.
+product), the gates, the scan's output and ``z``. The rule's chunk-local
+half (``gdn_wy_fwd``) runs again, and its backward kernel makes the chunks'
+inverses again in VMEM: nothing of a chunk's matrices is saved.
 """
 
 from __future__ import annotations
@@ -35,7 +35,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops.gated_delta import CHUNK, chunked_jnp, gated_delta_rule
 from .kinds import LayerKind
 
-SAVE_NAMES = ("gdn_qkv", "gdn_g", "gdn_beta", "gdn_o", "gdn_z", "gdn_tinv")
+SAVE_NAMES = ("gdn_qkv", "gdn_g", "gdn_beta", "gdn_o", "gdn_z")
 
 
 def _widths(c):

@@ -13,7 +13,8 @@ call of each Pallas kernel costs (``kernel_costs``): the program's half
 of a roofline share, whose other half is the kernel's seconds under the
 same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``,
-``gdn_fwd``, ``gdn_bwd``).
+``gdn_wy_fwd``, ``gdn_wy_bwd``, ``gdn_fwd``, ``gdn_bwd``; the delta rule's
+chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``).
 """
 
 from __future__ import annotations

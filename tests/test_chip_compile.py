@@ -19,6 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops import gated_delta
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -115,6 +116,24 @@ def _gdn(chip, backward, b=2, h=32, t=8192, d=128):
     return jax.jit(fn).lower(x, x, x, gate, gate)
 
 
+def _gdn_wy(chip, backward, b=2, h=32, t=8192, d=128):
+    """The rule's chunk-local half alone at the same shape: 8,192 chunk-heads,
+    eight a grid step; backward takes a cotangent for each of the six
+    operands, as ``gdn_bwd`` hands them over."""
+    x = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16, sharding=chip)
+    gate = jax.ShapeDtypeStruct((b, h, t), jnp.float32, sharding=chip)
+    wy = gated_delta._make_wy(False)
+    if not backward:
+        return jax.jit(wy).lower(x, x, x, gate, gate)
+    cotangents = tuple(jax.ShapeDtypeStruct(o.shape, o.dtype, sharding=chip)
+                       for o in jax.eval_shape(wy, x, x, x, gate, gate))
+
+    def pull_back(cts, *a):
+        return jax.vjp(wy, *a)[1](cts)
+
+    return jax.jit(pull_back).lower(cotangents, x, x, x, gate, gate)
+
+
 CASES = {
     # llama3-1b widths: 32 q / 8 kv heads of 64, the train batch
     "flash-fwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=False),
@@ -138,6 +157,8 @@ CASES = {
     # still fit the scoped VMEM at D = 256)
     "gdn-fwd": lambda c: _gdn(c, backward=False),
     "gdn-bwd": lambda c: _gdn(c, backward=True),
+    "gdn-wy-fwd": lambda c: _gdn_wy(c, backward=False),
+    "gdn-wy-bwd": lambda c: _gdn_wy(c, backward=True),
     "flash-fwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=False),
     "flash-bwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=True),
     # Qwen3-Next's held experts: 163,840 sorted rows of which a range is

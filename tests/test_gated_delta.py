@@ -1,7 +1,10 @@
 """The chunked gated delta rule (``ops/gated_delta.py``), in plain ``jnp``
-and as the two Pallas kernels (interpreted here), against the rule token by
-token: the output and every gradient, at rows of 1, 3 and 5 chunks, with
-decays near 0 (nothing crosses a chunk) and near 1 (everything does)."""
+and as its four Pallas kernels (interpreted here: ``gdn_wy_fwd`` /
+``gdn_wy_bwd`` for the chunk-local half, ``gdn_fwd`` / ``gdn_bwd`` for the
+recurrence), against the rule token by token: the output and every
+gradient, at rows of 1, 3 and 5 chunks, with decays near 0 (nothing
+crosses a chunk) and near 1 (everything does). The chunk-local pair alone
+is held to ``_prepare``, the plain ``jnp`` it replaces."""
 
 import functools
 
@@ -65,6 +68,40 @@ def test_output_and_every_gradient_match_the_rule_token_by_token(impl, chunks, d
         np.testing.assert_allclose(a, b, atol=1e-4 * scale, rtol=0, err_msg=name)
 
 
+OPERANDS = ("qg", "kd", "w", "u", "aqk", "a")
+GRADIENTS = ("dq", "dk", "dv", "dg", "dbeta")
+
+
+@pytest.mark.parametrize("decay", list(DECAYS))
+@pytest.mark.parametrize("chunks", [1, 3, 5])
+@pytest.mark.parametrize("side", ["operands", "gradients"])
+def test_the_chunk_local_kernels_make_what_prepare_makes(side, chunks, decay):
+    """``gdn_wy_fwd``'s six operands against ``_prepare``'s, and
+    ``gdn_wy_bwd`` alone: a random cotangent on each operand pulled back
+    through the pair and through ``jax.vjp`` of ``_prepare``. Within 5e-5 of
+    an array's largest entry (the pair's inverse runs three bf16 passes
+    here too, ``_prepare``'s on the CPU whole float32 products); g's
+    gradient within 5e-4: under the decay near 0 gamma reaches -1,300 and a
+    float32 running sum is good to 1e-4 there, whatever order it takes."""
+    xs = operands(chunks * gd.CHUNK, DECAYS[decay])
+    pair = gd._make_wy(True)
+    with jax.default_matmul_precision("highest"):
+        want, pull_back = jax.vjp(gd._prepare, *xs)
+    if side == "operands":
+        names, got = OPERANDS, pair(*xs)
+    else:
+        cts = tuple(jax.random.normal(jax.random.PRNGKey(i), w.shape)
+                    for i, w in enumerate(want))
+        with jax.default_matmul_precision("highest"):
+            names, want = GRADIENTS, pull_back(cts)
+        got = jax.vjp(pair, *xs)[1](cts)
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        tol = 5e-4 if name == "dg" else 5e-5
+        np.testing.assert_allclose(a, b, atol=tol * float(jnp.abs(b).max()), rtol=0,
+                                   err_msg=name)
+
+
 def test_state_crosses_chunk_boundaries_only_where_the_decay_lets_it():
     """Zeroing the first chunk's values changes later chunks' output when
     the decay is near 1 and leaves it alone when it is near 0: the
@@ -104,6 +141,19 @@ def test_rows_must_be_whole_chunks_and_the_kernels_record_their_cost():
     assert costs["gdn_fwd"]["bytes"] == operand_bytes + rows * d * 4
     assert costs["gdn_bwd"]["bytes"] == 3 * operand_bytes + rows * d * 4
     assert trace_log.kernel_traces()["gdn:interpret"] >= 1
+    # the chunk-local pair: K K^T and Q K^T, the inverse's ten products, W and
+    # U forward; those again, dT, dKb and dVb, dA's two products and four
+    # products into dK and dQ backward
+    c = gd.CHUNK
+    forward = 2 * 2 * rows * c * d + 10 * 2 * rows * c * c + 2 * rows * c * 2 * d
+    assert costs["gdn_wy_fwd"]["flops"] == forward
+    assert costs["gdn_wy_bwd"]["flops"] == (
+        forward + 2 * rows * c * 2 * d + 2 * 2 * rows * c * c + 4 * 2 * rows * c * d)
+    rule_bytes = rows * (3 * d * 4 + 2 * 4)             # q, k, v, g, beta
+    six_bytes = rows * (4 * d + c) * 4 + rows // c * 4
+    assert costs["gdn_wy_fwd"]["bytes"] == rule_bytes + six_bytes
+    assert costs["gdn_wy_bwd"]["bytes"] == 2 * rule_bytes + six_bytes
+    assert trace_log.kernel_traces()["gdn_wy:interpret"] >= 1
 
 
 def test_the_inverse_of_a_unit_lower_triangle_is_exact():

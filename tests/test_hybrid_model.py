@@ -238,6 +238,10 @@ def test_kinds_own_their_names_and_the_policy_saves_them():
         assert f"name={name}" in text, name
     # with its output and operands saved, the forward kernel runs once a layer
     assert text.count("name=gdn_fwd") == 3 and text.count("name=gdn_bwd") == 3
+    # the chunk-local half runs again under remat for the operands ``gdn_bwd``
+    # takes, and no chunk's inverse is saved for it
+    assert text.count("name=gdn_wy_fwd") == 6 and text.count("name=gdn_wy_bwd") == 3
+    assert "gdn_tinv" not in text
     assert text.count("name=flash_fwd") == 1
 
 
