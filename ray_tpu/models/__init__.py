@@ -3,11 +3,15 @@ kinds (``llama.py``, ``kinds.py``): a scan over periods of the stack, bf16
 params with f32 statistics, logical-axis shardings from ``ray_tpu.parallel``.
 Token mixers: softmax attention through the Pallas flash kernels or ring
 attention (with its variants: q/k norms, partial rope, an output gate), and
-Gated DeltaNet (``gdn.py``: a chunked delta-rule scan as Pallas kernels).
-MLPs: dense SwiGLU, or with ``moe_experts > 0`` a routed expert layer
-(``moe.py``: dropless, the (token, expert) rows sorted by expert over a
-Pallas grouped matmul, a shared expert, a chip's share of the experts).
-Llama-3, InternLM2, Mistral, OLMoE-1B-7B and Qwen3-Next are configurations."""
+Gated DeltaNet (``gdn.py``: a chunked delta-rule scan as Pallas kernels),
+latent attention (``mla.py``: low-rank q and kv, keys chosen by a learned
+indexer or a causal window, a head-wise gate). MLPs: dense SwiGLU, or with
+``moe_experts > 0`` a routed expert layer (``moe.py``: dropless, the
+(token, expert) rows sorted by expert over a Pallas grouped matmul, a softmax
+or a sigmoid router with its selection bias, a shared expert, a chip's share
+of the experts); leading layers may have an MLP kind of their own.
+Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next and dots3-note-prev are
+configurations."""
 
 from .llama import (
     LlamaConfig,
@@ -16,6 +20,7 @@ from .llama import (
     forward,
     loss_fn,
     param_axes,
+    update_buffers,
 )
 
 __all__ = [
@@ -25,4 +30,5 @@ __all__ = [
     "forward",
     "loss_fn",
     "param_axes",
+    "update_buffers",
 ]

@@ -2,13 +2,17 @@
 
 A block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack knows no
 more than that: a token mixer (``attn``: softmax attention, below;
-``gdn``: Gated DeltaNet, ``models/gdn.py``) and an MLP (``dense``, below;
-``moe``: the routed experts, ``models/moe.py``) are ``LayerKind``s
-(``models/kinds.py``) that own their leaves, logical axes, init, FLOPs,
-counters and the names a remat policy may save. ``layer_pattern`` lists
-the mixers of one PERIOD of the stack. Llama-3, InternLM2, Mistral, Mixtral
-and OLMoE are a period of one attention block; Qwen3-Next is three
-DeltaNet blocks and one of gated attention.
+``gdn``: Gated DeltaNet, ``models/gdn.py``; ``mla`` / ``mla_win``: latent
+attention under a learned selection of keys or a window, ``models/mla.py``)
+and an MLP (``dense``, below; ``moe``: the routed experts,
+``models/moe.py``) are ``LayerKind``s (``models/kinds.py``) that own their
+leaves, logical axes, init, FLOPs, counters and the names a remat policy
+may save. ``layer_pattern`` lists the mixers of one PERIOD of the stack,
+``lead_pattern`` those of the leading layers before the periods, outside
+the scan, whose MLP is a dense one of its own width. Llama-3, InternLM2,
+Mistral, Mixtral and OLMoE are a period of one attention block; Qwen3-Next
+is three DeltaNet blocks and one of gated attention; dots3-note-prev is a
+leading dense layer, then an indexed and three window layers.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -44,7 +48,8 @@ from ..ops import (flash_attention, mha_reference, ring_attention, rms_norm,
 from ..parallel.sharding import shard_constraint
 from .gdn import GDN
 from .kinds import LayerKind
-from .moe import MOE
+from .mla import MLA, MLA_WINDOW, LatentAttention
+from .moe import MOE, bias_step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +114,25 @@ class LlamaConfig:
     # plain jit path, with no exchange (models/moe.py).
     moe_shared: int = 0
     moe_held: tuple[int, int] | None = None
+    # MoE, facts of an architecture again. How the router scores: "softmax",
+    # or "sigmoid" (DeepSeek-V3's: the k experts are the top-k of score +
+    # bias, the gates the chosen scores, and the balance term is taken a
+    # sequence at a time); whether the router has that selection bias, a
+    # leaf no gradient moves and ``update_buffers`` steps by ``moe_bias_rate``
+    # from a step's own counts; whether the shared expert is scaled by a
+    # sigmoid of its own (Qwen's is; DeepSeek's is not).
+    moe_score: str = "softmax"
+    moe_bias_rate: float = 0.0
+    moe_shared_gate: bool = True
+    # Latent attention (models/mla.py): the widths of the mixer kinds "mla"
+    # (keys chosen by an indexer) and "mla_win" (a causal window).
+    mla: LatentAttention | None = None
+    mla_window: LatentAttention | None = None
+    # Leading layers before the periods, outside the scan: their mixers, in
+    # order, and the width of their dense MLP (DeepSeek's
+    # ``first_k_dense_replace``). ``n_layers`` counts them.
+    lead_pattern: tuple[str, ...] = ()
+    lead_intermediate: int = 0
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
 
@@ -118,10 +142,11 @@ class LlamaConfig:
 
     @property
     def n_periods(self) -> int:
-        if self.n_layers % len(self.layer_pattern):
-            raise ValueError(f"{self.n_layers} layers are no whole number of "
+        scanned = self.n_layers - len(self.lead_pattern)
+        if scanned % len(self.layer_pattern):
+            raise ValueError(f"{scanned} layers are no whole number of "
                              f"periods of {self.layer_pattern}")
-        return self.n_layers // len(self.layer_pattern)
+        return scanned // len(self.layer_pattern)
 
 
 PRESETS: dict[str, LlamaConfig] = {
@@ -155,6 +180,23 @@ PRESETS: dict[str, LlamaConfig] = {
                                 gdn_head_dim=16, moe_experts=8, moe_top_k=3,
                                 moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
                                 moe_aux_weight=0.001),
+    # latent attention at test size: a leading dense layer under an indexed
+    # mixer, then a period of one indexed and three window layers; top-4 of
+    # the keys and a window of 5, so both drop keys at 16+ positions; a
+    # sigmoid router with its bias, 2 of 8 experts held, a plain shared expert
+    "latent-sparse-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=5, n_heads=4, n_kv_heads=4, intermediate=32,
+        head_dim=16, layer_pattern=("mla", "mla_win", "mla_win", "mla_win"),
+        lead_pattern=("mla",), lead_intermediate=96,
+        mla=LatentAttention(heads=4, q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
+                            v_dim=16, rope_theta=1e4, index_heads=2, index_dim=16,
+                            index_top_k=4, rescale=True, gate=True),
+        mla_window=LatentAttention(heads=2, q_rank=32, kv_rank=32, nope_dim=24, rope_dim=8,
+                                   v_dim=16, rope_theta=1e3, window=5, rescale=True,
+                                   gate=True),
+        moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
+        moe_score="sigmoid", moe_bias_rate=0.001, moe_shared_gate=False,
+        moe_aux_weight=0.0001),
 }
 
 
@@ -193,14 +235,17 @@ def _dense_axes(c: LlamaConfig) -> dict:
             "w_down": ("mlp", "embed")}
 
 
-def _dense_init(c: LlamaConfig, keys, lead, normal) -> dict:
-    E, M = c.hidden, c.intermediate
+def _dense_init(c: LlamaConfig, keys, lead, normal, width: str = "intermediate") -> dict:
+    E, M = c.hidden, getattr(c, width)
     return {"w_gate": normal(keys[0], lead + (E, M), E),
             "w_up": normal(keys[1], lead + (E, M), E),
             "w_down": normal(keys[2], lead + (M, E), M)}
 
 
-def _mlp_kind(c: LlamaConfig) -> LayerKind:
+def _mlp_kind(c: LlamaConfig, lead: bool = False) -> LayerKind:
+    """The MLP kind of the periods' blocks, or of the leading layers."""
+    if lead:
+        return LEAD_DENSE
     return MOE if c.moe_experts > 0 else DENSE
 
 
@@ -214,17 +259,26 @@ def _per_position(c: LlamaConfig, make):
     return {f"slot{i}": make(i, m) for i, m in enumerate(c.layer_pattern)}
 
 
+def _lead_layers(c: LlamaConfig, make) -> dict:
+    """``{"lead_layers": {"layer<i>": make(i, mixer)}}`` for the leading
+    layers, under a name of their own and unstacked; ``{}`` without any."""
+    if not c.lead_pattern:
+        return {}
+    return {"lead_layers": {f"layer{i}": make(i, m) for i, m in enumerate(c.lead_pattern)}}
+
+
 def param_axes(config: LlamaConfig):
     """Tree of logical-axis tuples matching ``init_params`` output."""
     c = config
 
-    def block(_, mixer: str) -> dict:
+    def block(_, mixer: str, lead: bool = False) -> dict:
         axes = {"attn_norm": ("norm",), **MIXERS[mixer].axes(c),
-                "mlp_norm": ("norm",), **_mlp_kind(c).axes(c)}
-        return {k: ("layers",) + v for k, v in axes.items()}
+                "mlp_norm": ("norm",), **_mlp_kind(c, lead).axes(c)}
+        return axes if lead else {k: ("layers",) + v for k, v in axes.items()}
 
     return {
         "embed": ("vocab_in", "embed"),
+        **_lead_layers(c, lambda i, mixer: block(i, mixer, lead=True)),
         "layers": _per_position(c, block),
         "final_norm": ("norm",),
         "lm_head": ("embed", "vocab"),
@@ -244,19 +298,22 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
 
     norm_fill = jnp.zeros if c.norm_plus_one else jnp.ones
 
-    def block(i: int, mixer: str) -> dict:
-        # a period of one block draws from ``key`` as it always has
-        ks = keys if len(c.layer_pattern) == 1 else jax.random.split(
-            jax.random.fold_in(key, i), 9)
+    def block(i: int, mixer: str, leading: bool = False) -> dict:
+        # a period of one block draws from ``key`` as it always has; a leading
+        # layer is one layer, unstacked
+        ks = keys if len(c.layer_pattern) == 1 and not leading else jax.random.split(
+            jax.random.fold_in(key, i + (1000 if leading else 0)), 9)
+        stack = () if leading else lead
         return {
-            "attn_norm": norm_fill(lead + (E,), c.dtype),
-            **MIXERS[mixer].init(c, ks[1:5], lead, norm_init),
-            "mlp_norm": norm_fill(lead + (E,), c.dtype),
-            **_mlp_kind(c).init(c, ks[5:8], lead, norm_init),
+            "attn_norm": norm_fill(stack + (E,), c.dtype),
+            **MIXERS[mixer].init(c, ks[1:5], stack, norm_init),
+            "mlp_norm": norm_fill(stack + (E,), c.dtype),
+            **_mlp_kind(c, leading).init(c, ks[5:8], stack, norm_init),
         }
 
     return {
         "embed": norm_init(keys[0], (c.vocab_size, E), E),
+        **_lead_layers(c, lambda i, mixer: block(i, mixer, leading=True)),
         "layers": _per_position(c, block),
         "final_norm": norm_fill((E,), c.dtype),
         "lm_head": norm_init(keys[8], (E, c.vocab_size), E),
@@ -386,15 +443,22 @@ ATTN = LayerKind(
 DENSE = LayerKind(
     axes=_dense_axes, init=_dense_init, apply=_dense_mlp,
     matmul_params=lambda c: 3.0 * c.hidden * c.intermediate)
-MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN}
+LEAD_DENSE = LayerKind(
+    axes=_dense_axes, init=functools.partial(_dense_init, width="lead_intermediate"),
+    apply=_dense_mlp, matmul_params=lambda c: 3.0 * c.hidden * c.lead_intermediate)
+MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW}
 
 
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
-           ep_axis: str | None = None, mixer: str = "attn"):
-    """One decoder block: x [B, S, E] in config.dtype -> (x, aux). ``aux``
-    is ``{}`` for a dense MLP and ``moe_block``'s for a routed one.
+           ep_axis: str | None = None, mixer: str = "attn", lead: bool = False,
+           return_selection: bool = False):
+    """One decoder block: x [B, S, E] in config.dtype -> (x, aux, mixed_aux).
+    ``aux`` is ``{}`` for a dense MLP and ``moe_block``'s for a routed one;
+    ``mixed_aux`` what the mixer counted beside its output (``{}`` for most).
     ``ep_axis`` is set only when running per-device inside the pipeline
-    shard_map (expert shard + psum combine)."""
+    shard_map (expert shard + psum combine); ``lead``: a leading layer,
+    whose MLP is the leading kind; ``return_selection`` asks an indexed mixer
+    for its key sets too (comparisons only)."""
     c = config
 
     def sc(t, axes):
@@ -405,17 +469,19 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
     # leave the compiled program as it was
     with jax.named_scope("attn"):
         h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps, offset=c.norm_offset)
-        mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh)
+        mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh,
+                                    **({"return_selection": True} if return_selection else {}))
+        mixed, mixed_aux = mixed if isinstance(mixed, tuple) else (mixed, {})
         x = x + sc(mixed, ("batch", "seq", "embed_act"))
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
-        down, aux = _mlp_kind(c).apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis)
+        down, aux = _mlp_kind(c, lead).apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis)
         x = x + sc(down, ("batch", "seq", "embed_act"))
-    return x, aux
+    return x, aux, mixed_aux
 
 
-def _apply_remat(block, c: LlamaConfig, mixer: str = "attn"):
+def _apply_remat(block, c: LlamaConfig, mixer: str = "attn", lead: bool = False):
     """Wrap a decoder block with the configured rematerialisation policy."""
     if not c.remat:
         return block
@@ -451,14 +517,14 @@ def _apply_remat(block, c: LlamaConfig, mixer: str = "attn"):
         return jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(
-                *MIXERS[mixer].save_names, *_mlp_kind(c).save_names,
+                *MIXERS[mixer].save_names, *_mlp_kind(c, lead).save_names,
             ),
         )
     return jax.checkpoint(block)
 
 
 def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None,
-                   return_aux: bool = False):
+                   return_aux: bool = False, return_selection: bool = False):
     """tokens [B, S] int32 -> final hidden states [B, S, E] in config.dtype.
 
     ``return_aux=True`` additionally returns what the routed layers
@@ -468,7 +534,11 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     holds a share of the experts (``moe_held``), ``rows_per_held_expert``
     [L, count] and ``held_share`` [L] (their sum over all rows). ``{}`` for
     dense configs and on the pipelined path, which does not thread it
-    through the schedule yet."""
+    through the schedule yet. An indexed mixer (``mla``) adds ``index_loss``
+    and ``attn_selected_share``, each the mean over the indexed layers, and
+    with ``return_selection`` the key sets themselves, ``selection``
+    [indexed layers, B, S, S] int8 in layer order (for a comparison; a
+    training step does not ask)."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -522,38 +592,69 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
         out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
         return (out, {}) if return_aux else out
 
+    # leading layers: outside the scan, each its own block, their MLP the
+    # leading kind (a dense one: no aux)
+    mixed_auxes = []
+    for i, mixer in enumerate(c.lead_pattern):
+        x, _, mixed_aux = _apply_remat(
+            functools.partial(_block, positions=positions, config=c, mesh=mesh,
+                              mixer=mixer, lead=True, return_selection=return_selection),
+            c, mixer, lead=True)(
+            x, params["lead_layers"][f"layer{i}"])
+        mixed_auxes.append(mixed_aux)
+
     blocks = [
-        _apply_remat(functools.partial(_block, positions=positions, config=c,
-                                       mesh=mesh, mixer=mixer), c, mixer)
+        _apply_remat(functools.partial(_block, positions=positions, config=c, mesh=mesh,
+                                       mixer=mixer, return_selection=return_selection),
+                     c, mixer)
         for mixer in c.layer_pattern]
     # a period of one block keeps its leaves unnested (``_per_position``): it
     # is the period whose one position is that tree
     layers = params["layers"] if len(blocks) > 1 else {"slot0": params["layers"]}
 
     def period(x, layers):
-        auxes = []
+        auxes, mixed = [], []
         for i, block in enumerate(blocks):
-            x, aux = block(x, layers[f"slot{i}"])
+            x, aux, mixed_aux = block(x, layers[f"slot{i}"])
             auxes.append(aux)
-        return x, auxes
+            mixed.append(mixed_aux)
+        return x, (auxes, mixed)
 
     # one aux a position of the period, each [periods, ...] -> [L, ...] in
-    # layer order: position i of period p is layer p * len(blocks) + i
-    x, auxes = lax.scan(period, x, layers)
+    # layer order: position i of period p is scanned layer p * len(blocks) + i
+    x, (auxes, mixed) = lax.scan(period, x, layers)
+    scanned = c.n_layers - len(c.lead_pattern)
     per_layer = auxes[0] if len(auxes) == 1 else jax.tree.map(
-        lambda *a: jnp.stack(a, axis=1).reshape((c.n_layers,) + a[0].shape[1:]), *auxes)
+        lambda *a: jnp.stack(a, axis=1).reshape((scanned,) + a[0].shape[1:]), *auxes)
     out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
     if not return_aux:
         return out
-    if not per_layer:
-        return out, {}
-    aux = {"load_balance": jnp.mean(per_layer["load_balance"]),
-           "z": jnp.mean(per_layer["z"]),
-           "rows_per_expert": per_layer["rows"],
-           "rows_dropped": jnp.sum(per_layer["dropped"])}
-    if "rows_held" in per_layer:
-        aux.update(rows_per_held_expert=per_layer["rows_held"],
-                   held_share=per_layer["held_share"])
+    aux = {}
+    if per_layer:
+        aux = {"load_balance": jnp.mean(per_layer["load_balance"]),
+               "z": jnp.mean(per_layer["z"]),
+               "rows_per_expert": per_layer["rows"],
+               "rows_dropped": jnp.sum(per_layer["dropped"])}
+        if "rows_held" in per_layer:
+            aux.update(rows_per_held_expert=per_layer["rows_held"],
+                       held_share=per_layer["held_share"])
+    # what the mixers counted: a leading layer's is a scalar, a period
+    # position's [periods]; the mean over every layer that counted it
+    indexed = [m for m in mixed_auxes + mixed if m]
+    if indexed:
+        both = lambda name: jnp.mean(jnp.concatenate(  # noqa: E731
+            [jnp.ravel(m[name]) for m in indexed]))
+        aux.update(index_loss=both("index_loss"),
+                   attn_selected_share=both("selected_share"))
+        if return_selection:
+            # a leading layer's [B, S, S]; a period position's [periods, B, S, S],
+            # its layers ``len(blocks)`` apart
+            sets = [m["selection"][None] for m in mixed_auxes if m]
+            scanned_sets = [m["selection"] for m in mixed if m]
+            if scanned_sets:
+                sets.append(jnp.stack(scanned_sets, axis=1).reshape(
+                    (-1,) + scanned_sets[0].shape[1:]))
+            aux["selection"] = jnp.concatenate(sets)
     return out, aux
 
 
@@ -575,10 +676,13 @@ def train_flops_per_token(config: LlamaConfig, seq: int) -> float:
     expectation), the shared expert, plus the router."""
     c = config
     mlp = _mlp_kind(c).matmul_params(c)
+    lead_mlp = _mlp_kind(c, lead=True).matmul_params(c)
     n_params = c.n_periods * sum(
         MIXERS[m].matmul_params(c) + mlp for m in c.layer_pattern
-    ) + c.hidden * c.vocab_size
-    mixing = c.n_periods * sum(MIXERS[m].mixing_flops(c, seq) for m in c.layer_pattern)
+    ) + sum(MIXERS[m].matmul_params(c) + lead_mlp for m in c.lead_pattern
+            ) + c.hidden * c.vocab_size
+    mixing = c.n_periods * sum(MIXERS[m].mixing_flops(c, seq) for m in c.layer_pattern
+                               ) + sum(MIXERS[m].mixing_flops(c, seq) for m in c.lead_pattern)
     return 6.0 * n_params + 3 * mixing
 
 
@@ -696,7 +800,31 @@ def loss_fn(
             flat_t.reshape(nc, chunk), flat_m.reshape(nc, chunk),
             jnp.maximum(flat_m.sum(), 1.0))
     loss = ce
-    if aux:
+    if "load_balance" in aux:
         loss = (ce + config.moe_aux_weight * aux["load_balance"]
                 + config.moe_z_weight * aux["z"])
+    if "index_loss" in aux:
+        # reaches the indexers' leaves and no other: their inputs are cut
+        # from the graph, and the model's loss passes no gradient to a top-k.
+        # No weight: the two terms' gradients touch disjoint leaves
+        with jax.named_scope("index_loss"):
+            loss = loss + aux["index_loss"]
     return (loss, {"ce": ce, **aux}) if return_aux else loss
+
+
+def update_buffers(params, aux, config: LlamaConfig):
+    """The leaves no gradient moves, stepped from a step's own counters
+    (``loss_fn(..., return_aux=True)``'s ``aux``), after the optimizer's
+    update: the routers' selection bias (``models/moe.py::bias_step``), a
+    layer's from that layer's rows per expert. Params come back unchanged
+    where the model has no such leaf."""
+    c = config
+    if not (c.moe_experts and c.moe_bias_rate):
+        return params
+    rows = aux["rows_per_expert"].reshape(c.n_periods, len(c.layer_pattern), -1)
+    layers = params["layers"] if len(c.layer_pattern) > 1 else {"slot0": params["layers"]}
+    stepped = {
+        slot: {**layer, "router_bias": bias_step(
+            layer["router_bias"], rows[:, int(slot[4:])], c.moe_bias_rate)}
+        for slot, layer in layers.items()}
+    return {**params, "layers": stepped if len(c.layer_pattern) > 1 else stepped["slot0"]}

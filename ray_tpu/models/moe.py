@@ -29,7 +29,20 @@ then needs no output of the down projection.
 
 A shared expert (Qwen3-Next, DeepSeek: leaves ``w_shared_*`` present) is a
 SwiGLU every token passes through, scaled by ``sigmoid(h w_shared_scale)``
-and added to the routed sum; it has its own scope, ``moe_shared``.
+where that leaf is present (Qwen's; DeepSeek's has none and is plain), and
+added to the routed sum; it has its own scope, ``moe_shared``.
+
+A sigmoid router (DeepSeek-V3, arXiv 2412.19437; ``score="sigmoid"``):
+
+    sc     = sigmoid_f32(h W_router)                    X independent scores
+    e      = top_k(sc + b)                              b: ``router_bias``
+    g      = sc[e] / sum(sc[e])  if ``norm_topk``
+
+``b`` enters the choice alone, so no gradient reaches it; after a step it
+moves by ``bias_step``: ``b += rate sign(mean rows - rows_x)``, from that
+step's rows per expert. The balance term is taken a SEQUENCE at a time:
+``mean over sequences of sum_x (X rows_x(seq) / (k S)) mean_t sc'[t, x]``,
+``sc' = sc / sum_x sc``; there is no z term.
 
 One chip's share of the experts on the plain ``jit`` path (``held`` =
 (first, count), static): the router keeps its published width and routes
@@ -72,7 +85,8 @@ from .kinds import LayerKind
 ROUTE_NAMES = ("moe_probs", "moe_gates", "moe_order", "moe_inv", "moe_sizes")
 
 
-def moe_param_axes(prefix: tuple = (), shared: bool = False):
+def moe_param_axes(prefix: tuple = (), shared: bool = False, *, shared_gate: bool = True,
+                   bias: bool = False):
     """Logical axes; ``prefix`` prepends e.g. ("layers",) for stacked use."""
     axes = {
         "router": prefix + ("embed", "experts"),
@@ -87,16 +101,21 @@ def moe_param_axes(prefix: tuple = (), shared: bool = False):
             "w_shared_up": prefix + ("embed", "mlp"),
             "w_shared_down": prefix + ("mlp", "embed"),
         })
+        if not shared_gate:
+            del axes["w_shared_scale"]
+    if bias:
+        axes["router_bias"] = prefix + ("experts",)
     return axes
 
 
 def init_moe_params(key, hidden: int, expert_mlp: int, n_experts: int, dtype,
                     n_layers: int | None = None, *, held: int | None = None,
-                    shared_mlp: int = 0):
+                    shared_mlp: int = 0, shared_gate: bool = True, bias: bool = False):
     """The single source of MoE init (llama.py stacks it per layer via
     ``n_layers``). ``held``: how many of the ``n_experts`` the router scores
     have leaves here (all of them when None); ``shared_mlp``: the width of
-    the shared expert, 0 for none."""
+    the shared expert, 0 for none, ``shared_gate`` whether it has a sigmoid
+    scale; ``bias``: the router's selection bias, float32, zero."""
     ks = jax.random.split(key, 4)
     lead = () if n_layers is None else (n_layers,)
     held = n_experts if held is None else held
@@ -120,6 +139,10 @@ def init_moe_params(key, hidden: int, expert_mlp: int, n_experts: int, dtype,
             "w_shared_up": init(ss[2], (hidden, shared_mlp), hidden),
             "w_shared_down": init(ss[3], (shared_mlp, hidden), shared_mlp),
         })
+        if not shared_gate:
+            del params["w_shared_scale"]
+    if bias:
+        params["router_bias"] = jnp.zeros(lead + (n_experts,), jnp.float32)
     return params
 
 
@@ -156,10 +179,13 @@ _permute.defvjp(lambda values, perm, inv_perm: (values[perm], (inv_perm,)),
                 lambda res, g: (g[res[0]], None, None))
 
 
-def route(tokens, router, *, top_k: int, norm_topk: bool):
+def route(tokens, router, *, top_k: int, norm_topk: bool, score: str = "softmax",
+          bias=None, n_seqs: int = 1):
     """Routing in float32: tokens [N, E] -> a dict of the gates [N, k], the
     sort ``order`` of the N*k rows by expert with its inverse, the rows per
-    expert ``sizes`` [X] and the two auxiliary terms."""
+    expert ``sizes`` [X] and the two auxiliary terms. ``score`` "sigmoid"
+    with the selection ``bias`` [X] and the tokens' ``n_seqs`` sequences:
+    the module's text."""
     n, n_experts = tokens.shape[0], router.shape[1]
     # float32 in earnest: on a TPU a float32 product runs as one bf16 pass
     # unless asked otherwise, and a router logit off by 2^-8 reorders the
@@ -167,8 +193,14 @@ def route(tokens, router, *, top_k: int, norm_topk: bool):
     # product is 2 * N * hidden * X FLOPs, a thousandth of the experts'
     logits = jnp.einsum("nd,dx->nx", tokens.astype(jnp.float32), router,
                         precision=jax.lax.Precision.HIGHEST)
-    probs = checkpoint_name(jax.nn.softmax(logits, axis=-1), "moe_probs")
-    gates, expert_idx = jax.lax.top_k(probs, top_k)
+    if score == "sigmoid":
+        probs = checkpoint_name(jax.nn.sigmoid(logits), "moe_probs")
+        biased = probs if bias is None else probs + jax.lax.stop_gradient(bias)
+        expert_idx = jax.lax.top_k(jax.lax.stop_gradient(biased), top_k)[1]
+        gates = jnp.take_along_axis(probs, expert_idx, axis=-1)
+    else:
+        probs = checkpoint_name(jax.nn.softmax(logits, axis=-1), "moe_probs")
+        gates, expert_idx = jax.lax.top_k(probs, top_k)
     if norm_topk:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     gates = checkpoint_name(gates, "moe_gates")
@@ -181,19 +213,45 @@ def route(tokens, router, *, top_k: int, norm_topk: bool):
                     dtype=jnp.int32)
     sizes = checkpoint_name(sizes, "moe_sizes")
     frac_rows = jax.lax.stop_gradient(sizes.astype(jnp.float32)) / (n * top_k)
+    if score == "sigmoid":
+        per_seq = n // n_seqs
+        chosen = jnp.sum(jax.nn.one_hot(expert_idx.reshape(n_seqs, per_seq * top_k),
+                                        n_experts, dtype=jnp.float32), axis=1)
+        share = (probs / jnp.sum(probs, axis=-1, keepdims=True)).reshape(
+            n_seqs, per_seq, n_experts)
+        balance = jnp.mean(jnp.sum(
+            n_experts * chosen / (per_seq * top_k) * jnp.mean(share, axis=1), axis=-1))
+        z = jnp.zeros((), jnp.float32)
+    else:
+        balance = n_experts * jnp.sum(frac_rows * jnp.mean(probs, axis=0))
+        z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return {
         "gates": gates,
         "order": checkpoint_name(order, "moe_order"),
         "inv": checkpoint_name(inv, "moe_inv"),
         "sizes": sizes,
-        "load_balance": n_experts * jnp.sum(frac_rows * jnp.mean(probs, axis=0)),
-        "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+        "load_balance": balance,
+        "z": z,
     }
+
+
+def bias_step(bias, rows, rate: float):
+    """The selection bias after a step: up by ``rate`` for an expert that got
+    fewer rows than the mean, down for one that got more. ``bias`` and
+    ``rows`` [..., X]."""
+    rows = rows.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(rows, axis=-1, keepdims=True) - rows)
 
 
 def shared_expert(tokens, params):
     """The always-on expert on tokens [N, E]: a SwiGLU scaled, token by
-    token, by ``sigmoid(h . w_shared_scale)``."""
+    token, by ``sigmoid(h . w_shared_scale)`` where it has that leaf."""
+    if "w_shared_scale" not in params:
+        gate = jnp.einsum("ne,em->nm", tokens, params["w_shared_gate"])
+        up = jnp.einsum("ne,em->nm", tokens, params["w_shared_up"])
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(tokens.dtype)
+        return jnp.einsum("nm,me->ne", act, params["w_shared_down"])
     scale = jax.nn.sigmoid(jnp.einsum(
         "ne,e->n", tokens, params["w_shared_scale"], preferred_element_type=jnp.float32))
     gate = jnp.einsum("ne,em->nm", tokens, params["w_shared_gate"])
@@ -301,7 +359,8 @@ _held_or_all_rows.defvjp(_held_or_all_fwd, _held_or_all_bwd)
 
 
 def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
-              ep_axis: str | None = None, held: tuple[int, int] | None = None):
+              ep_axis: str | None = None, held: tuple[int, int] | None = None,
+              score: str = "softmax"):
     """x: [B, S, E] -> ([B, S, E], aux). Routing in f32; experts in x.dtype
     with f32 accumulation.
 
@@ -321,7 +380,8 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     n = b * s
     tokens = x.reshape(n, e)
     with jax.named_scope("moe_route"):
-        r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk)
+        r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk, score=score,
+                  bias=params.get("router_bias"), n_seqs=b)
     sizes, offset = r["sizes"], None
     if ep_axis is not None or held is not None:
         local = params["w_gate"].shape[0]
@@ -356,20 +416,22 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
 
 
 def _moe_axes(c) -> dict:
-    return moe_param_axes(shared=c.moe_shared > 0)
+    return moe_param_axes(shared=c.moe_shared > 0, shared_gate=c.moe_shared_gate,
+                          bias=c.moe_bias_rate > 0)
 
 
 def _moe_init(c, keys, lead, normal) -> dict:
     return init_moe_params(
         keys[0], hidden=c.hidden, expert_mlp=c.intermediate, n_experts=c.moe_experts,
         dtype=c.dtype, n_layers=lead[0] if lead else None,
-        held=c.moe_held[1] if c.moe_held else None, shared_mlp=c.moe_shared)
+        held=c.moe_held[1] if c.moe_held else None, shared_mlp=c.moe_shared,
+        shared_gate=c.moe_shared_gate, bias=c.moe_bias_rate > 0)
 
 
 def _moe_apply(h, layer, *, config, mesh=None, ep_axis=None):
     c = config
     return moe_block(h, layer, top_k=c.moe_top_k, norm_topk=c.moe_norm_topk,
-                     ep_axis=ep_axis, held=c.moe_held)
+                     ep_axis=ep_axis, held=c.moe_held, score=c.moe_score)
 
 
 def _moe_matmul_params(c) -> float:
@@ -378,7 +440,7 @@ def _moe_matmul_params(c) -> float:
     share of them in expectation (``top_k x count / X``)."""
     share = c.moe_held[1] / c.moe_experts if c.moe_held else 1.0
     return (c.hidden * c.moe_experts + 3 * c.hidden * c.moe_shared
-            + (c.hidden if c.moe_shared else 0)
+            + (c.hidden if c.moe_shared and c.moe_shared_gate else 0)
             + c.moe_top_k * share * 3 * c.hidden * c.intermediate)
 
 

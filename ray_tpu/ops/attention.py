@@ -23,15 +23,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .trace_log import note_flash_cost, note_kernel_trace
+from .trace_log import note_attention_cost, note_flash_cost, note_kernel_trace
 
 NEG_INF = -1e30
 
 
-def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None):
+def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
+                  window: int | None = None, mask=None):
     """Pure-jnp attention; ground truth for kernel tests and the CPU path.
 
-    Shapes: q [B, Hq, S, D], k/v [B, Hkv, S, D]; GQA when Hq > Hkv.
+    Shapes: q [B, Hq, S, D], k [B, Hkv, S, D], v [B, Hkv, S, Dv]; GQA when
+    Hq > Hkv. ``window``: query t sees keys t - window + 1 .. t; ``mask``
+    [B, Sq, Sk] (non-zero: allowed), one key set a query row for all heads.
     """
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
@@ -41,17 +44,49 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
         v = jnp.repeat(v, hq // hkv, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
+    key_mask = mask
     if causal:
         sk = k.shape[2]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         logits = jnp.where(mask, logits, NEG_INF)
+    if window is not None:
+        t = jnp.arange(sq)[:, None] + (k.shape[2] - sq) - jnp.arange(k.shape[2])[None, :]
+        logits = jnp.where(t < window, logits, NEG_INF)
+    if key_mask is not None:
+        logits = jnp.where(key_mask[:, None] != 0, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(probs.dtype)).astype(q.dtype)
 
 
+def _band_first(i, block_i, block_j, back: int):
+    """First j block that rows of i block ``i`` reach ``back`` positions behind."""
+    return jnp.maximum(i * block_i - back, 0) // block_j
+
+
+def _band_steps(n_i: int, block_i: int, block_j: int, back: int, ahead: int, n_j: int) -> int:
+    """Most j blocks any i block's band [first - back, last + ahead] meets."""
+    return max(min((i * block_i + block_i - 1 + ahead) // block_j, n_j - 1)
+               - max(i * block_i - back, 0) // block_j + 1 for i in range(n_i))
+
+
+def _allowed(s, q_start, k_start, causal, window, mask, q_axis: int):
+    """Scores with what a query may not see set to NEG_INF. ``s`` is
+    [block_q, block_k] (``q_axis`` 0) or its transpose; ``mask`` a block of
+    the key sets in the same orientation, or None."""
+    if causal:
+        q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+        s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(q_ids - k_ids < window, s, NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask.astype(jnp.int32) != 0, s, NEG_INF)
+    return s
+
+
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale, causal, block_q, block_k, n_k
+    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, sm_scale, causal, block_q, block_k, n_k, window=None, n_steps=None
 ):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
@@ -63,11 +98,17 @@ def _flash_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q_start = qi * block_q
-    k_start = ki * block_k
-    # causal: skip blocks strictly above the diagonal
-    needed = jnp.logical_or(
-        jnp.logical_not(causal), k_start <= q_start + block_q - 1
-    )
+    if window is None:
+        k_start = ki * block_k
+        # causal: skip blocks strictly above the diagonal
+        needed = jnp.logical_or(
+            jnp.logical_not(causal), k_start <= q_start + block_q - 1
+        )
+    else:
+        # the grid's last axis walks only the band's k blocks
+        kb = _band_first(qi, block_q, block_k, window - 1) + ki
+        k_start = kb * block_k
+        needed = k_start <= q_start + block_q - 1
 
     @pl.when(needed)
     def _compute():
@@ -80,10 +121,13 @@ def _flash_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         s = s * sm_scale
-        if causal:
+        if causal and window is None and mask_ref is None:
             q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_ids >= k_ids, s, NEG_INF)
+        else:
+            s = _allowed(s, q_start, k_start, causal, window,
+                         None if mask_ref is None else mask_ref[0], 0)
         m_prev = m_ref[:]
         m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1] -> broadcast over lanes
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
@@ -97,7 +141,7 @@ def _flash_kernel(
         )
         m_ref[:] = m_new
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(ki == (n_k if n_steps is None else n_steps) - 1)
     def _final():
         o_ref[0, 0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
         if lse_ref is not None:
@@ -117,10 +161,29 @@ def _fit_block(requested: int, seq: int) -> int:
     return max(b, 16)
 
 
+def _variant(window, mask) -> str | None:
+    """What tells a kernel variant's calls apart on the op line: None for the
+    plain kernels (``flash_fwd`` ...), ``win`` for a window (``attn_win_fwd``
+    ...), ``sel`` for a key set a query row (``attn_sel_fwd`` ...)."""
+    return "sel" if mask is not None else "win" if window is not None else None
+
+
+def _kept_pairs(sq: int, sk: int, causal: bool, window, top_k) -> float:
+    """(query, key) pairs a head computes usefully: the causal triangle, a
+    window's band, or ``top_k`` keys a query where it has more."""
+    if not causal:
+        return float(sq * sk)
+    width = sk if window is None else window
+    if top_k is not None:
+        width = min(width, top_k)
+    return float(sum(min(t + 1 + sk - sq, width) for t in range(sq)))
+
+
 def _flash_forward(
     q,
     k,
     v,
+    mask=None,
     *,
     causal: bool,
     sm_scale: float | None,
@@ -128,68 +191,98 @@ def _flash_forward(
     block_k: int,
     interpret: bool,
     save_residuals: bool = False,
+    window: int | None = None,
+    top_k: int | None = None,
 ):
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     sk = k.shape[2]
+    dv = v.shape[3]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     rep = hq // hkv
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
+    variant = _variant(window, mask)
     # fallback for shapes the TPU tiling can't take: ragged blocks or blocks
     # not multiple of the bf16 sublane tile (16)
     if sq % block_q or sk % block_k or block_q % 16 or block_k % 16:
         note_kernel_trace("flash_attention", "mha_reference")
-        o = mha_reference(q, k, v, causal=causal, sm_scale=scale)
+        o = mha_reference(q, k, v, causal=causal, sm_scale=scale, window=window, mask=mask)
         return (o, None) if save_residuals else o
     note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
-    note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals)
+    if variant is None and dv == d:
+        note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals)
+    else:
+        note_attention_cost("fwd", variant, q, k, v,
+                            _kept_pairs(sq, sk, causal, window, top_k),
+                            residuals=save_residuals, masked=mask is not None)
     n_q, n_k = sq // block_q, sk // block_k
 
-    grid = (b, hq, n_q, n_k)
-    kernel = functools.partial(
+    n_steps = None if window is None else _band_steps(
+        n_q, block_q, block_k, window - 1, 0, n_k)
+    grid = (b, hq, n_q, n_k if window is None else n_steps)
+    inner = functools.partial(
         _flash_kernel,
         sm_scale=scale,
         causal=causal,
         block_q=block_q,
         block_k=block_k,
         n_k=n_k,
+        window=window,
+        n_steps=n_steps,
     )
-    if not save_residuals:
-        def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   _inner=kernel):
-            _inner(q_ref, k_ref, v_ref, o_ref, None, acc_ref, m_ref, l_ref)
 
-    out_specs = [pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    def kernel(q_ref, k_ref, v_ref, *refs):
+        mask_ref, refs = (refs[0], refs[1:]) if mask is not None else (None, refs)
+        o_ref, lse_ref, refs = ((refs[0], refs[1], refs[2:]) if save_residuals
+                                else (refs[0], None, refs[1:]))
+        inner(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *refs)
+
+    if window is None:
+        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
+    else:
+        def kv_index(bi, hi, qi, ki):
+            kb = _band_first(qi, block_q, block_k, window - 1) + ki
+            return (bi, hi // rep, jnp.minimum(kb, n_k - 1), 0)
+
+    out_specs = [pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)]
     if save_residuals:
         out_specs.append(
             pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, qi, ki: (bi, hi, qi, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32))
+    in_specs = [
+        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        # GQA: map query head to its kv head in the index_map — no
+        # repeated K/V materialization in HBM
+        pl.BlockSpec((1, 1, block_k, d), kv_index),
+        pl.BlockSpec((1, 1, block_k, dv), kv_index),
+    ]
+    operands = (q, k, v)
+    if mask is not None:
+        # one key set a query row, shared by the heads of a batch row
+        in_specs.append(pl.BlockSpec((1, block_q, block_k),
+                                     lambda bi, hi, qi, ki: (bi, qi, ki)))
+        operands += (mask,)
     result = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            # GQA: map query head to its kv head in the index_map — no
-            # repeated K/V materialization in HBM
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs if save_residuals else out_specs[0],
         out_shape=out_shape if save_residuals else out_shape[0],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
-    )(q, k, v)
+        name="flash_fwd" if variant is None else f"attn_{variant}_fwd",
+    )(*operands)
     return result
 
 
-def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start):
+def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start,
+                 window=None, mask_t=None):
     """One [block_k, block_q] tile of the backward pass, k-major: P^T and
     dS^T. Scores are taken transposed (K Q^T) so that the per-query
     statistics ``lse`` and ``delta`` are [1, block_q] ROWS, broadcast
@@ -198,7 +291,9 @@ def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start):
     s_t = jax.lax.dot_general(
         k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale
-    if causal:
+    if window is not None or mask_t is not None:
+        s_t = _allowed(s_t, q_start, k_start, causal, window, mask_t, 1)
+    elif causal:
         k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
         q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
         s_t = jnp.where(q_ids >= k_ids, s_t, NEG_INF)
@@ -210,8 +305,9 @@ def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start):
     return p_t, ds_t
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, sm_scale, causal, block_q, block_k, n_k):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref, dq_ref,
+                   acc_ref, *, sm_scale, causal, block_q, block_k, n_k,
+                   window=None, n_steps=None):
     """dQ: for one q block, accumulate dS @ K over all k blocks (k axis
     innermost → sequential on-core, acc lives in VMEM)."""
     ki = pl.program_id(3)
@@ -222,8 +318,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     q_start = qi * block_q
-    k_start = ki * block_k
-    needed = jnp.logical_or(jnp.logical_not(causal), k_start <= q_start + block_q - 1)
+    if window is None:
+        k_start = ki * block_k
+        needed = jnp.logical_or(jnp.logical_not(causal), k_start <= q_start + block_q - 1)
+    else:
+        k_start = (_band_first(qi, block_q, block_k, window - 1) + ki) * block_k
+        needed = k_start <= q_start + block_q - 1
 
     @pl.when(needed)
     def _compute():
@@ -231,19 +331,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         _, ds_t = _bwd_probs_t(
             q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0], lse_ref[0, 0, 0],
             delta_ref[0, 0, 0], sm_scale=sm_scale, causal=causal,
-            q_start=q_start, k_start=k_start)
+            q_start=q_start, k_start=k_start, window=window,
+            mask_t=None if mask_ref is None else mask_ref[0])
         acc_ref[:] += jax.lax.dot_general(
             ds_t, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )                                                  # [bq, d]
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(ki == (n_k if n_steps is None else n_steps) - 1)
     def _final():
         dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, sm_scale, causal, block_q, block_k, n_q):
+                     *, sm_scale, causal, block_q, block_k, n_q,
+                     window=None, n_steps=None):
     """dK/dV: for one k block, accumulate over all q blocks (q axis
     innermost). P^T and dS^T come out k-major, so both products are
     plain [bk, bq] @ [bq, d] — no transposes materialize."""
@@ -255,9 +357,16 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    needed = jnp.logical_or(jnp.logical_not(causal), q_start + block_q - 1 >= k_start)
+    if window is None:
+        q_start = qi * block_q
+        k_start = ki * block_k
+        needed = jnp.logical_or(jnp.logical_not(causal), q_start + block_q - 1 >= k_start)
+    else:
+        # the grid's last axis walks only the q blocks whose band meets this k block
+        k_start = ki * block_k
+        q_start = (_band_first(ki, block_k, block_q, 0) + qi) * block_q
+        needed = q_start <= jnp.minimum(k_start + block_k - 1 + window - 1,
+                                        (n_q - 1) * block_q)
 
     @pl.when(needed)
     def _compute():
@@ -265,29 +374,32 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         g = g_ref[0, 0]
         p_t, ds_t = _bwd_probs_t(
             q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
-            sm_scale=sm_scale, causal=causal, q_start=q_start, k_start=k_start)
+            sm_scale=sm_scale, causal=causal, q_start=q_start, k_start=k_start,
+            window=window, mask_t=None if mask_ref is None else mask_ref[0])
         dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g,
-                                 preferred_element_type=jnp.float32)  # [bk, d]
+                                 preferred_element_type=jnp.float32)  # [bk, dv]
         dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(qi == (n_q if n_steps is None else n_steps) - 1)
     def _final():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
-                    interpret):
+def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q, block_k,
+                    interpret, window=None, top_k=None):
     """Pallas dq/dk/dv. ``lse`` is the compact f32 [B, Hq, S] residual.
     K/V stay at kv-head count (GQA via index maps); dk/dv come out at
     q-head count and are reduced by the caller."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
+    dv_width = v.shape[3]
     rep = hq // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     block_q = _fit_block(block_q, sq)
     block_k = _fit_block(block_k, sk)
     n_q, n_k = sq // block_q, sk // block_k
+    variant = _variant(window, mask)
     # Per-query statistics as one [1, block_q] row per q block (a block
     # whose trailing dims are the array's own fits any block size): lse,
     # and delta = rowsum(dO * O).
@@ -295,55 +407,100 @@ def _flash_backward(q, k, v, o, lse, g, *, causal, sm_scale, block_q, block_k,
     lse = lse.reshape(b, hq, n_q, 1, block_q)
     delta = delta.reshape(b, hq, n_q, 1, block_q)
 
-    note_flash_cost("flash_bwd_dq", q, k, causal=causal)
-    note_flash_cost("flash_bwd_dkdv", q, k, causal=causal)
+    if variant is None and dv_width == d:
+        note_flash_cost("flash_bwd_dq", q, k, causal=causal)
+        note_flash_cost("flash_bwd_dkdv", q, k, causal=causal)
+    else:
+        pairs = _kept_pairs(sq, sk, causal, window, top_k)
+        note_attention_cost("bwd_dq", variant, q, k, v, pairs, masked=mask is not None)
+        note_attention_cost("bwd_dkdv", variant, q, k, v, pairs, masked=mask is not None)
+    prefix = "flash" if variant is None else f"attn_{variant}"
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
+    operands = (q, k, v, g, lse, delta)
+    mask_spec_qk, mask_spec_kq = [], []
+    if mask is not None:
+        # k-major, as the tiles are: the transpose is an XLA pass over int8
+        operands += (jnp.swapaxes(mask, 1, 2),)
+        mask_spec_qk = [pl.BlockSpec((1, block_k, block_q),
+                                     lambda bi, hi, qi, ki: (bi, ki, qi))]
+        mask_spec_kq = [pl.BlockSpec((1, block_k, block_q),
+                                     lambda bi, hi, ki, qi: (bi, ki, qi))]
+
+    def with_mask(kernel, n_in=6):
+        if mask is not None:
+            return kernel
+        return lambda *refs: kernel(*refs[:n_in], None, *refs[n_in:])
+
+    if window is None:
+        dq_steps = dkdv_steps = None
+        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
+    else:
+        dq_steps = _band_steps(n_q, block_q, block_k, window - 1, 0, n_k)
+        dkdv_steps = _band_steps(n_k, block_k, block_q, 0, window - 1, n_q)
+
+        def kv_index(bi, hi, qi, ki):
+            kb = _band_first(qi, block_q, block_k, window - 1) + ki
+            return (bi, hi // rep, jnp.minimum(kb, n_k - 1), 0)
+
+    def q_of(ki, qi):
+        if window is None:
+            return qi
+        return jnp.minimum(_band_first(ki, block_k, block_q, 0) + qi, n_q - 1)
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0))
+    g_spec = pl.BlockSpec((1, 1, block_q, dv_width),
+                          lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
+    v_spec = pl.BlockSpec((1, 1, block_k, dv_width),
+                          lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
+                            lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0, 0))
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k),
-        grid=(b, hq, n_q, n_k),  # k innermost
+        with_mask(functools.partial(
+            _bwd_dq_kernel, sm_scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, n_k=n_k, window=window, n_steps=dq_steps)),
+        grid=(b, hq, n_q, n_k if window is None else dq_steps),  # k innermost
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kv_index),
+            pl.BlockSpec((1, 1, block_k, dv_width), kv_index),
+            pl.BlockSpec((1, 1, block_q, dv_width), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
             pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
-        ],
+        ] + mask_spec_qk,
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
-    )(q, k, v, g, lse, delta)
+        name=f"{prefix}_bwd_dq",
+    )(*operands)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, sm_scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q),
-        grid=(b, hq, n_k, n_q),  # q innermost
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        with_mask(functools.partial(
+            _bwd_dkdv_kernel, sm_scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, n_q=n_q, window=window,
+            n_steps=dkdv_steps)),
+        grid=(b, hq, n_k, n_q if window is None else dkdv_steps),  # q innermost
+        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec] + mask_spec_kq,
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, dv_width), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hq, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hq, sk, dv_width), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv_width), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_bwd_dkdv",
-    )(q, k, v, g, lse, delta)
+        name=f"{prefix}_bwd_dkdv",
+    )(*operands)
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2).astype(k.dtype)
-        dv = dv.reshape(b, hkv, rep, sk, d).sum(axis=2).astype(v.dtype)
+        dv = dv.reshape(b, hkv, rep, sk, dv_width).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -406,7 +563,8 @@ def _blocks_fit(sq, sk, block_q, block_k) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash(causal, sm_scale, block_q, block_k, interpret):
+def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_k=None,
+                masked=False, with_lse=False):
     """custom_vjp wrapper: Pallas kernels for BOTH directions (forward
     saves the logsumexp residual; dq and dk/dv are dedicated kernels).
     Ragged shapes fall back to the jnp blocked paths.
@@ -418,37 +576,44 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret):
     form the backward kernels read. A ``jax.checkpoint`` policy that
     saves both names (remat ``attn`` in models/llama.py) runs
     ``flash_fwd`` once a layer; one that saves neither recomputes it in
-    the backward pass, as before."""
+    the backward pass, as before.
+
+    ``masked``: the function takes a fourth operand, the key sets [B, Sq,
+    Sk] int8, which gets no cotangent. ``with_lse``: it returns ``(o, lse)``,
+    the logsumexp [B, Hq, S] as a constant (its cotangent is dropped)."""
+    static = dict(causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                  interpret=interpret, window=window, top_k=top_k)
 
     @jax.custom_vjp
-    def f(q, k, v):
-        return _flash_forward(
-            q, k, v, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-        )
+    def f(q, k, v, *mask):
+        if with_lse:
+            o, lse = _flash_forward(q, k, v, *mask, save_residuals=True, **static)
+            return o, lse[..., 0]
+        return _flash_forward(q, k, v, *mask, **static)
 
-    def fwd(q, k, v):
+    def fwd(q, k, v, *mask):
         if not _blocks_fit(q.shape[2], k.shape[2], block_q, block_k):
-            return checkpoint_name(f(q, k, v), "attn_out"), (q, k, v, None, None)
-        o, lse = _flash_forward(
-            q, k, v, causal=causal, sm_scale=sm_scale,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            save_residuals=True,
-        )
+            if with_lse:
+                raise NotImplementedError("the logsumexp comes from blocks that fit")
+            return checkpoint_name(f(q, k, v, *mask), "attn_out"), (q, k, v, None, None, mask)
+        o, lse = _flash_forward(q, k, v, *mask, save_residuals=True, **static)
         o = checkpoint_name(o, "attn_out")
         # The kernel writes lse replicated over 128 lanes; one lane is the
         # residual and what the backward kernels read (S minor: a trailing
         # 1 would be padded back to 128 lanes in HBM).
         lse = checkpoint_name(lse[..., 0], "attn_lse")
-        return o, (q, k, v, o, lse)
+        return ((o, lse) if with_lse else o), (q, k, v, o, lse, mask)
 
     def bwd(res, g):
-        q, k, v, o, lse = res
+        q, k, v, o, lse, mask = res
+        if with_lse:
+            g = g[0]
+        no_grad = tuple(None for _ in mask)
         if lse is not None:
-            return _flash_backward(
-                q, k, v, o, lse, g, causal=causal, sm_scale=sm_scale,
-                block_q=block_q, block_k=block_k, interpret=interpret,
-            )
+            return _flash_backward(q, k, v, o, lse, g, *mask, **static) + no_grad
+        if window is not None or masked or v.shape[3] != q.shape[3]:
+            raise NotImplementedError(
+                "a window, a key set or a narrower value head needs blocks that fit")
         # Ragged fallback: blocked-recompute backward in plain JAX.
         hq, hkv = q.shape[1], k.shape[1]
         if hq != hkv:
@@ -480,15 +645,38 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: bool | None = None,
+    window: int | None = None,
+    mask=None,
+    top_k: int | None = None,
+    return_lse: bool = False,
 ):
-    """Tiled attention. q [B,Hq,S,D], k/v [B,Hkv,S,D] (GQA folded by repeat).
+    """Tiled attention. q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv] (GQA
+    folded by repeat; the value head may be narrower than the key head).
 
     Differentiable (custom VJP); falls back to the interpreter off-TPU so
     tests run on the CPU mesh. Default 1024x1024 blocks: measured on v5e
     at head_dim 64 they run the fwd+bwd ~14% faster at seq 2k and ~46%
     faster at seq 32k than 512x512 (fewer per-block VPU rescales); 2048
     blocks exceed the 16 MiB scoped-VMEM stack limit.
+
+    Three static facts give other kernels, told apart on the op line by
+    their names; with none of them the kernels are the plain ones:
+    ``window`` (causal only): query t sees keys t - window + 1 .. t, and the
+    grid walks only the blocks the band meets (``attn_win_*``); ``mask``
+    [B, Sq, Sk] int8: one key set a query row, shared by the heads of a
+    batch row, under which the whole causal triangle is walked
+    (``attn_sel_*``; ``top_k``, the most keys a set holds, only sizes the
+    useful work that ``kernel_costs()`` records). ``return_lse`` also
+    returns each query's logsumexp over its keys, [B, Hq, S] float32.
     """
     if interpret is None:
         interpret = not on_tpu()
-    return _make_flash(causal, sm_scale, block_q, block_k, interpret)(q, k, v)
+    if window is not None and not causal:
+        raise ValueError("a window is a causal window")
+    if mask is None and not return_lse:
+        if window is None:
+            return _make_flash(causal, sm_scale, block_q, block_k, interpret)(q, k, v)
+        return _make_flash(causal, sm_scale, block_q, block_k, interpret, window)(q, k, v)
+    masks = () if mask is None else (jax.lax.stop_gradient(mask),)
+    return _make_flash(causal, sm_scale, block_q, block_k, interpret, window, top_k,
+                       mask is not None, return_lse)(q, k, v, *masks)

@@ -14,7 +14,11 @@ of a roofline share, whose other half is the kernel's seconds under the
 same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``,
 ``gdn_wy_fwd``, ``gdn_wy_bwd``, ``gdn_fwd``, ``gdn_bwd``; the delta rule's
-chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``).
+chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``; the
+attention kernels under a window or a key set ``attn_win_*`` / ``attn_sel_*``,
+traced as ``flash_attention`` like the plain ones; the indexer's
+``dsa_index_fwd`` / ``dsa_index_bwd_dq`` / ``dsa_index_bwd_dk``, traced as
+``dsa_index``, and ``dsa_probs``).
 """
 
 from __future__ import annotations
@@ -92,3 +96,35 @@ def note_flash_cost(kernel: str, q, k, *, causal: bool,
         + 2 * b * hq * sk * d * k.dtype.itemsize,
     }[kernel]
     note_kernel_cost(kernel, flops, nbytes)
+
+
+_ATTENTION_WIDTHS = {"fwd": (1, 1), "bwd_dq": (2, 1), "bwd_dkdv": (2, 2)}
+
+
+def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *,
+                        residuals: bool = True, masked: bool = False) -> None:
+    """Record one call of an attention kernel variant (``attn_win_*``: a
+    window; ``attn_sel_*``: a key set a query row; a plain kernel whose value
+    head is narrower than its key head keeps its ``flash_*`` name). FLOPs are
+    those of the ``pairs`` (query, key) pairs a head KEEPS, whatever the
+    kernel walks: a pair costs 2 D for each product over the key head's
+    width (QK^T; in the backward kernels also dS K or dS^T Q) and 2 Dv for
+    each over the value head's (PV; dO V^T; P^T dO). Bytes as
+    ``note_flash_cost``, with v, o and dO at the value head's width and, where
+    ``masked``, the int8 key sets once."""
+    b, hq, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    n_d, n_dv = _ATTENTION_WIDTHS[part]
+    flops = 2.0 * b * hq * pairs * (n_d * d + n_dv * dv)
+    q_b = b * hq * sq * d * q.dtype.itemsize
+    o_b = b * hq * sq * dv * q.dtype.itemsize
+    kv_b = (math.prod(k.shape) + math.prod(v.shape)) * k.dtype.itemsize
+    stats = b * hq * sq * 4
+    nbytes = {
+        "fwd": q_b + o_b + kv_b + (128 * stats if residuals else 0),
+        "bwd_dq": 2 * q_b + o_b + kv_b + 2 * stats,
+        "bwd_dkdv": q_b + o_b + kv_b + 2 * stats
+        + b * hq * sk * (d + dv) * k.dtype.itemsize,
+    }[part] + (b * sq * sk if masked else 0)
+    name = f"flash_{part}" if variant is None else f"attn_{variant}_{part}"
+    note_kernel_cost(name, flops, nbytes)

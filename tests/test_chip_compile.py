@@ -19,7 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops import gated_delta
+from ray_tpu.ops import gated_delta, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -134,6 +134,42 @@ def _gdn_wy(chip, backward, b=2, h=32, t=8192, d=128):
     return jax.jit(pull_back).lower(cotangents, x, x, x, gate, gate)
 
 
+def _latent(chip, kind, backward, b=2, t=8192):
+    """dots3-note-prev's attention at the benchmark's 2 x 8192: ``sel`` the
+    full layers' (128 heads, a 192-wide key head and a 128-wide value head,
+    under an int8 key set a query row, the logsumexp returned), ``win`` the
+    window layers' (64 heads, 256 / 128, a 513-wide band in 512-blocks)."""
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    if kind == "sel":
+        args = (sd((b, 128, t, 192)), sd((b, 128, t, 192)), sd((b, 128, t, 128)),
+                sd((b, t, t), jnp.int8))
+        fwd = lambda q, k, v, m: flash_attention(  # noqa: E731
+            q, k, v, mask=m, top_k=2048, return_lse=True, interpret=False)[0]
+    else:
+        args = (sd((b, 64, t, 256)), sd((b, 64, t, 256)), sd((b, 64, t, 128)))
+        fwd = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, window=513, block_q=512, block_k=512, interpret=False)
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+                  ) if backward else fwd
+    return jax.jit(fn).lower(*args)
+
+
+def _indexer(chip, what, b=2, t=8192):
+    """Its indexer's kernels there: 64 index heads of 128 summed in VMEM
+    (forward; the loss's gradient: two kernels), and attention's head-summed
+    probabilities from q, k and the logsumexp."""
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    if what == "probs":
+        q = sd((b, 128, t, 192))
+        return jax.jit(lambda q, k, lse: sparse_index.head_summed_probs(
+            q, k, lse, sm_scale=192 ** -0.5, interpret=False)).lower(
+            q, q, sd((b, 128, t), jnp.float32))
+    args = (sd((b, 64, t, 128)), sd((b, t, 128)), sd((b, t, 64), jnp.float32))
+    scores = lambda *a: sparse_index.index_scores(*a, interpret=False)  # noqa: E731
+    fn = jax.grad(lambda *a: scores(*a).sum(), argnums=(0, 1, 2)) if what == "bwd" else scores
+    return jax.jit(fn).lower(*args)
+
+
 CASES = {
     # llama3-1b widths: 32 q / 8 kv heads of 64, the train batch
     "flash-fwd-1b": lambda c: _flash(c, 8, 32, 8, 2048, 64, backward=False),
@@ -164,6 +200,15 @@ CASES = {
     # Qwen3-Next's held experts: 163,840 sorted rows of which a range is
     # computed, 64 experts of 2048 x 512
     "moe-gmm-held-grad": lambda c: _grouped(c, 2048, 512, backward=True, rows=163840),
+    # dots3-note-prev: attention under a key set and under a window, value
+    # heads narrower than key heads (192 / 128, 256 / 128), the indexer
+    "attn-sel-fwd-8k": lambda c: _latent(c, "sel", backward=False),
+    "attn-sel-bwd-8k": lambda c: _latent(c, "sel", backward=True),
+    "attn-win-fwd-8k": lambda c: _latent(c, "win", backward=False),
+    "attn-win-bwd-8k": lambda c: _latent(c, "win", backward=True),
+    "dsa-index-fwd-8k": lambda c: _indexer(c, "fwd"),
+    "dsa-index-bwd-8k": lambda c: _indexer(c, "bwd"),
+    "dsa-probs-8k": lambda c: _indexer(c, "probs"),
 }
 
 
@@ -193,3 +238,54 @@ def test_saved_residual_names_decide_the_forward_kernel_count(chip, names, kerne
 
     program = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
     assert program.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+def _without_locations(lowered_text: str) -> str:
+    """A lowered program's text with every Mosaic call's bytecode replaced by
+    its module's assembly WITHOUT debug info: the bytecode holds source lines,
+    which move with every edit of the file."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return "BODY<<" + module.operation.get_asm(enable_debug_info=False) + ">>BODY"
+
+    text = re.sub(r"loc\([^)]*\)", "", lowered_text)
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+PARENT_FLASH_KERNELS = "0eff5c1ee0ab1f2c71c166e8b7910b0b129da9fb2a87bdf78d310850dfab8be6"
+
+
+def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(chip):
+    """internlm2-1.8b's attention at the benchmark's 2 x 4096, forward and both
+    backward kernels: the lowered program, its three Mosaic modules printed
+    without source locations, is what the commit before the window, the key
+    set and the narrower value head was (PR 33's tree, 2376b56, lowered here
+    the same way: the three modules' text, 74,932 characters, by its SHA-256). A kernel variant must leave the plain
+    path's traced instructions, their order included, as they were."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 4096, 128), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False).astype(jnp.float32).sum()
+
+    import re
+
+    text = _without_locations(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).as_text(debug_info=False))
+    kernels = re.findall(r"BODY<<.*?>>BODY", text, flags=re.S)
+    assert len(kernels) == 3
+    assert hashlib.sha256("".join(kernels).encode()).hexdigest() == PARENT_FLASH_KERNELS, (
+        [len(k) for k in kernels])
