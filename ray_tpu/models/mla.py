@@ -32,7 +32,9 @@ scores over them) and ``selected_share`` (keys attended over causal keys).
 ``SAVE_NAMES`` is what a backward pass reads and cannot cheaply remake: the
 latents and not the per-head q, k and v made from them (3,712 numbers a
 token against 65,536 in a full layer), the kernel's output and logsumexp,
-the gate and the key sets (int8, 67 MB a row of 8k).
+the gate, the key sets (int8, 67 MB a row of 8k) and the two statistics a query
+that the indexer's loss hands its backward kernel (``dsa_kl_z``, ``dsa_kl_lse``,
+named inside ``index_kl``'s rule: saved, the loss's forward kernel runs once).
 """
 
 from __future__ import annotations
@@ -45,10 +47,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import apply_rope, flash_attention, rms_norm
-from ..ops.sparse_index import head_summed_probs, index_loss, index_scores, select_top_k
+from ..ops.sparse_index import index_kl, index_scores, select_top_k
 from .kinds import LayerKind
 
-SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask")
+SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask",
+              "dsa_kl_z", "dsa_kl_lse")
 INDEX_NORM_EPS = 1e-6  # the index key's LayerNorm
 
 
@@ -186,8 +189,7 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
         attn, lse = flash_attention(q, k, v, sm_scale=sm_scale, mask=mask,
                                     top_k=a.index_top_k, return_lse=True)
         with jax.named_scope("dsa_loss"):
-            probs = head_summed_probs(q, k, lse, sm_scale=sm_scale)
-            aux["index_loss"] = index_loss(scores, probs, mask)
+            aux["index_loss"] = index_kl(q, k, lse, scores, mask, sm_scale=sm_scale)
             aux["selected_share"] = (jnp.sum(mask, dtype=jnp.float32)
                                      / (b * s * (s + 1) / 2))
         if return_selection:

@@ -7,20 +7,28 @@ For a batch row, J index heads of Di features, queries t and keys s <= t:
     I[t, s] = sum_j w[t, j] relu(q_j[t] . k[s])                ``index_scores``
     S_t     = the ``top_k`` largest I[t, .] (every causal key while t < top_k;
               a tie at the last place keeps every tied key)    ``select_top_k``
-    p[t, s] = sum_h softmax_h(attention's scores over S_t)[s]  ``head_summed_probs``
-    L_I     = mean_t KL(p[t, S_t] / sum || softmax(I[t, S_t]))  ``index_loss``
+    p[t, s] = sum_h softmax_h(attention's scores over S_t)[s]
+    L_I     = mean_t KL(p[t, S_t] / sum || softmax(I[t, S_t]))  ``index_kl``
 
 A ``[B, J, T, T]`` tensor is 17 GB in float32 at 8k, so the scores are three
 Pallas kernels that keep a head's [block, block] tile in VMEM and sum over
 the heads there: ``dsa_index_fwd`` and, for the loss's gradient,
 ``dsa_index_bwd_dq`` (the index queries' and the head weights') and
-``dsa_index_bwd_dk`` (the index key's). ``dsa_probs`` makes attention's
-head-summed probabilities from q, k and the saved logsumexp. The index
-product takes bfloat16 operands and accumulates in float32; the ReLU, the
-head weights and the sum over heads are float32. What stays XLA: the
-threshold (32 counting passes over the bit patterns of a row's scores: a
-k-th largest with no sort), the mask and the KL itself, all elementwise or
-row reductions over ``[B, T, T]``.
+``dsa_index_bwd_dk`` (the index key's). The index product takes bfloat16
+operands and accumulates in float32; the ReLU, the head weights and the sum
+over heads are float32.
+
+The loss is two kernels more, and p never leaves VMEM: ``dsa_probs`` sums
+exp(q_h . k_h scale - lse_h) over attention's heads into a tile (from q, k
+and the saved logsumexp), masks it by the key set and gathers each query's
+statistics over its key blocks (sum p, sum p (log p - I), the kept scores'
+logsumexp), which give its KL; ``dsa_probs_bwd`` makes the tile again and
+writes the loss's gradient with respect to I, the one [B, T, T] float32 the
+pair leaves in HBM. What stays XLA: the threshold (32 counting passes over
+the bit patterns of a row's scores: a k-th largest with no sort) and the
+mask, elementwise or row reductions over ``[B, T, T]``; and, for a length
+no block divides, the plain form ``index_loss`` of
+``head_summed_probs_reference``.
 """
 
 from __future__ import annotations
@@ -29,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -273,57 +282,227 @@ def select_top_k(scores, top_k: int):
     return (causal & (keys >= threshold[..., None])).astype(jnp.int8)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, o_ref, *, sm_scale, block_q, block_k):
-    """Key-major tiles, heads innermost: P^T += exp(K Q^T scale - lse)."""
-    ki, qi, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+_NEG = -1e30  # below every score; finite, so that exp(_NEG - _NEG) is 1 and no NaN
 
+
+def _walked(qi, ki, hi, heads):
+    """The key block and head whose operands a grid step reads: its own
+    inside the causal triangle; above it those of the row's last step
+    inside, so that a skipped step fetches nothing."""
+    needed = ki <= qi
+    return jnp.where(needed, ki, qi), jnp.where(needed, hi, heads - 1)
+
+
+def _sum_heads(q_ref, k_ref, lse_ref, acc, hi, sm_scale):
+    """One head of a tile, KEY-major (the logsumexp is a row there):
+    acc += exp(k_h q_h^T scale - lse_h)."""
     @pl.when(hi == 0)
     def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
+        acc[:] = jnp.zeros_like(acc)
 
-    @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
+    s_t = _head_scores(k_ref[0, 0], q_ref[0, 0]) * sm_scale
+    # a key outside the row's set can score above its logsumexp: it is
+    # masked at the last head; keep it finite here
+    acc[:] += jnp.exp(jnp.minimum(s_t - lse_ref[0, 0, 0], 0.0))
+
+
+def _kept_probs(acc, mask_ref, qi, ki, block):
+    """The finished tile query-major, zero outside the row's key set."""
+    kept = (mask_ref[0].astype(jnp.int32) != 0) & _causal_tile(
+        qi, ki, block, block, (block, block), 0)
+    return kept, jnp.where(kept, acc[:].T, 0.0)
+
+
+def _kl_fwd_kernel(q_ref, k_ref, lse_ref, s_ref, mask_ref, kl_ref, z_ref, lse_i_ref,
+                   acc, z_acc, a_acc, m_acc, l_acc, *, sm_scale, block):
+    """Grid (b, query block, key block, head). Over a row's key blocks:
+    Z = sum p, A = sum p (log p - I), and the online max / sum of the kept
+    scores' logsumexp; at the last, KL_t = A / Z - log Z + lse_I."""
+    qi, ki, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last_head = hi == pl.num_programs(3) - 1
+
+    @pl.when((ki == 0) & (hi == 0))
+    def _init():
+        z_acc[:] = jnp.zeros_like(z_acc)
+        a_acc[:] = jnp.zeros_like(a_acc)
+        m_acc[:] = jnp.full_like(m_acc, _NEG)
+        l_acc[:] = jnp.zeros_like(l_acc)
+
+    @pl.when(ki <= qi)
     def _compute():
-        s_t = _head_scores(k_ref[0, 0], q_ref[0, 0]) * sm_scale
-        # a key outside the row's set can score above its logsumexp: the
-        # caller masks; keep it finite here
-        o_ref[0] += jnp.exp(jnp.minimum(s_t - lse_ref[0, 0, 0], 0.0))
+        _sum_heads(q_ref, k_ref, lse_ref, acc, hi, sm_scale)
+
+    @pl.when((ki <= qi) & last_head)
+    def _statistics():
+        kept, p = _kept_probs(acc, mask_ref, qi, ki, block)
+        scores = s_ref[0]
+        z_acc[:] += jnp.sum(p, axis=1, keepdims=True)
+        a_acc[:] += jnp.sum(jnp.where(
+            p > 0.0, p * (jnp.log(jnp.maximum(p, 1e-38)) - scores), 0.0), axis=1, keepdims=True)
+        m_new = jnp.maximum(m_acc[:], jnp.max(jnp.where(kept, scores, _NEG), axis=1,
+                                              keepdims=True))
+        l_acc[:] = l_acc[:] * jnp.exp(m_acc[:] - m_new) + jnp.sum(
+            jnp.where(kept, jnp.exp(scores - m_new), 0.0), axis=1, keepdims=True)
+        m_acc[:] = m_new
+
+    @pl.when((ki == pl.num_programs(2) - 1) & last_head)
+    def _final():
+        z = z_acc[:]
+        safe = jnp.maximum(z, 1e-30)
+        lse_i = m_acc[:] + jnp.log(jnp.maximum(l_acc[:], 1e-30))
+        kl_ref[0] = (a_acc[:] + z * (lse_i - jnp.log(safe))) / safe
+        z_ref[0] = z
+        lse_i_ref[0] = lse_i
 
 
-def head_summed_probs_reference(q, k, lse, sm_scale):
-    s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32) * sm_scale
-    return jnp.sum(jnp.exp(jnp.minimum(s - lse[..., None], 0.0)), axis=1)
+def _kl_bwd_kernel(q_ref, k_ref, lse_ref, s_ref, mask_ref, lse_i_ref, soft_ref, prob_ref,
+                   d_ref, acc, *, sm_scale, block):
+    """The same sweep makes p again; the tile of dI is soft_t exp(I - lse_I)
+    - prob_t p on the row's keys, 0 elsewhere (the two columns carry the
+    cotangent, the mean and 1 / Z)."""
+    qi, ki, hi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last_head = hi == pl.num_programs(3) - 1
+
+    @pl.when(ki <= qi)
+    def _compute():
+        _sum_heads(q_ref, k_ref, lse_ref, acc, hi, sm_scale)
+
+    @pl.when((ki <= qi) & last_head)
+    def _tile():
+        kept, p = _kept_probs(acc, mask_ref, qi, ki, block)
+        d_ref[0] = jnp.where(
+            kept, soft_ref[0] * jnp.exp(s_ref[0] - lse_i_ref[0]) - prob_ref[0] * p, 0.0)
+
+    @pl.when((ki > qi) & last_head)
+    def _skip():
+        d_ref[0] = jnp.zeros_like(d_ref[0])
 
 
-def head_summed_probs(q, k, lse, *, sm_scale: float, block: int = 1024,
-                      interpret: bool | None = None):
-    """sum over heads of exp(q_h . k_h scale - lse_h) as [B, T, T] float32,
-    for q, k [B, H, T, D] and the attention kernel's logsumexp [B, H, T]:
-    a head's attention probabilities wherever the key was in the row's set
-    (elsewhere the number means nothing: the caller masks). No
-    gradient: the indexer's target is held constant."""
+def _column(block):
+    """A query block's rows of a [B, T, 1] array: one number a query."""
+    return pl.BlockSpec((1, block, 1), lambda bi, qi, ki, hi: (bi, qi, 0))
+
+
+def _kl_sweep(kernel, name, operands, columns, out_specs, out_shape, scratch, block, interpret):
+    """One sweep of either kernel over (b, query block, key block, head):
+    q, k, the logsumexp as rows, the scores' and the key sets' tiles, then
+    ``columns`` [B, T, 1]; a [block, block] float32 accumulator in VMEM."""
+    q, k, lse, scores, mask = operands
+    b, h, t, d = q.shape
+    n = t // block
+
+    def head_rows(of_query):
+        def index(bi, qi, ki, hi):
+            key, head = _walked(qi, ki, hi, h)
+            return bi, head, (qi if of_query else key), 0
+        return pl.BlockSpec((1, 1, block, d), index)
+
+    def tile(bi, qi, ki, hi):
+        return bi, qi, _walked(qi, ki, hi, h)[0]
+
+    return pl.pallas_call(
+        kernel, grid=(b, n, n, h),
+        in_specs=[head_rows(True), head_rows(False),
+                  pl.BlockSpec((1, 1, 1, 1, block),
+                               lambda bi, qi, ki, hi: (bi, _walked(qi, ki, hi, h)[1], qi, 0, 0)),
+                  pl.BlockSpec((1, block, block), tile), pl.BlockSpec((1, block, block), tile)]
+        + [_column(block)] * len(columns),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)] + scratch,
+        interpret=interpret, name=name, **({} if interpret else _PARAMS),
+    )(q, k, lse.reshape(b, h, n, 1, block), scores, mask, *columns)
+
+
+def _kl_cost(name: str, q, tiles: int) -> None:
+    """One call: a [T, T] x D product a head over the causal triangle; bytes
+    are q, k and the logsumexp, ``tiles`` [T, T] float32 tiles (the scores
+    read; dI written) and the int8 key sets."""
+    b, h, t, d = q.shape
+    note_kernel_cost(name, 2.0 * b * h * d * t * (t + 1) / 2,
+                     2 * b * h * t * d * q.dtype.itemsize + b * h * t * 4
+                     + tiles * b * t * t * 4 + b * t * t)
+
+
+def _kl_forward(operands, sm_scale, block, interpret):
+    """Each query's KL, its probabilities' sum Z and its kept scores'
+    logsumexp, [B, T] float32 each."""
+    b, _, t, _ = operands[0].shape
+    _kl_cost("dsa_probs", operands[0], 1)
+    out = _kl_sweep(
+        functools.partial(_kl_fwd_kernel, sm_scale=sm_scale, block=block), "dsa_probs",
+        operands, (), [_column(block)] * 3, [jax.ShapeDtypeStruct((b, t, 1), jnp.float32)] * 3,
+        [pltpu.VMEM((block, 1), jnp.float32)] * 4, block, interpret)
+    return tuple(x[..., 0] for x in out)
+
+
+def _kl_backward(operands, z, lse_i, g, sm_scale, block, interpret):
+    """g d(mean_t KL_t) / dI, [B, T, T] float32, zero outside the key sets."""
+    b, _, t, _ = operands[0].shape
+    _kl_cost("dsa_probs_bwd", operands[0], 2)
+    safe = jnp.maximum(z, 1e-30)
+    mean = g / (b * t)
+    return _kl_sweep(
+        functools.partial(_kl_bwd_kernel, sm_scale=sm_scale, block=block), "dsa_probs_bwd",
+        operands, tuple(x[..., None] for x in (lse_i, mean * z / safe, mean / safe)),
+        pl.BlockSpec((1, block, block), lambda bi, qi, ki, hi: (bi, qi, ki)),
+        jax.ShapeDtypeStruct((b, t, t), jnp.float32), [], block, interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_kl(sm_scale: float, block: int, interpret: bool):
+    """The loss with a gradient rule of its own. The rule names what the
+    backward kernel needs beyond its operands, ``dsa_kl_z`` and
+    ``dsa_kl_lse`` ([B, T] float32 each): a ``jax.checkpoint`` policy that
+    saves both (remat ``attn``: ``models/mla.py``'s ``SAVE_NAMES``) runs the
+    forward kernel once, and its second run of the block finds the call dead."""
+    static = (sm_scale, block, interpret)
+
+    @jax.custom_vjp
+    def f(*operands):
+        return jnp.mean(_kl_forward(operands, *static)[0])
+
+    def fwd(*operands):
+        kl, z, lse_i = _kl_forward(operands, *static)
+        return jnp.mean(kl), (operands, checkpoint_name(z, "dsa_kl_z"),
+                              checkpoint_name(lse_i, "dsa_kl_lse"))
+
+    def bwd(residuals, g):
+        # the target is a constant, the key sets are no numbers
+        return None, None, None, _kl_backward(*residuals, g, *static), None
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def index_kl(q, k, lse, scores, mask, *, sm_scale: float, block: int = 1024,
+             interpret: bool | None = None):
+    """The indexer's loss, a scalar: mean over queries of KL(p || softmax(I)),
+    both over the row's key set ``mask`` [B, T, T] int8, where p is
+    attention's head-summed probabilities there (from q, k [B, H, T, D] and
+    the attention kernel's logsumexp [B, H, T]) normalised to sum 1 a row, a
+    constant, and ``scores`` [B, T, T] the index scores, the one operand with
+    a gradient. ``index_loss`` of ``head_summed_probs_reference``, made tile
+    by tile: no [B, T, T] probabilities leave VMEM. A length the block does
+    not divide takes that plain form."""
     if interpret is None:
         interpret = not on_tpu()
     q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
-    b, h, t, d = q.shape
-    bq = _fit_block(block, t)
-    if t % bq or bq % 128 and bq != t:
+    t = q.shape[2]
+    block = _fit_block(block, t)
+    if t % block or block % 128 and block != t:
         note_kernel_trace("dsa_probs", "reference")
-        return head_summed_probs_reference(q, k, lse, sm_scale)
+        return index_loss(scores, head_summed_probs_reference(q, k, lse, sm_scale), mask)
     note_kernel_trace("dsa_probs", "interpret" if interpret else "pallas")
-    note_kernel_cost("dsa_probs", 2.0 * b * h * d * t * (t + 1) / 2,
-                     2 * b * h * t * d * q.dtype.itemsize + b * h * t * 4 + b * t * t * 4)
-    n = t // bq
-    p_t = pl.pallas_call(
-        functools.partial(_probs_kernel, sm_scale=sm_scale, block_q=bq, block_k=bq),
-        grid=(b, n, n, h),
-        in_specs=[pl.BlockSpec((1, 1, bq, d), lambda bi, ki, qi, hi: (bi, hi, qi, 0)),
-                  pl.BlockSpec((1, 1, bq, d), lambda bi, ki, qi, hi: (bi, hi, ki, 0)),
-                  pl.BlockSpec((1, 1, 1, 1, bq), lambda bi, ki, qi, hi: (bi, hi, qi, 0, 0))],
-        out_specs=pl.BlockSpec((1, bq, bq), lambda bi, ki, qi, hi: (bi, ki, qi)),
-        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32),
-        interpret=interpret, name="dsa_probs",
-    )(q, k, lse.reshape(b, h, n, 1, bq))
-    return jnp.swapaxes(p_t, 1, 2)
+    return _make_kl(sm_scale, block, interpret)(q, k, lse, scores, mask)
+
+
+def head_summed_probs_reference(q, k, lse, sm_scale):
+    """sum over heads of exp(q_h . k_h scale - lse_h) as [B, T, T] float32,
+    for q, k [B, H, T, D] and the attention kernel's logsumexp [B, H, T]:
+    a head's attention probabilities wherever the key was in the row's set
+    (elsewhere the number means nothing: ``index_loss`` masks)."""
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k, preferred_element_type=jnp.float32) * sm_scale
+    return jnp.sum(jnp.exp(jnp.minimum(s - lse[..., None], 0.0)), axis=1)
 
 
 def index_loss(scores, probs, mask):

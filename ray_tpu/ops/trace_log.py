@@ -18,7 +18,8 @@ chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``; the
 attention kernels under a window or a key set ``attn_win_*`` / ``attn_sel_*``,
 traced as ``flash_attention`` like the plain ones; the indexer's
 ``dsa_index_fwd`` / ``dsa_index_bwd_dq`` / ``dsa_index_bwd_dk``, traced as
-``dsa_index``, and ``dsa_probs``).
+``dsa_index``, and the loss's ``dsa_probs`` / ``dsa_probs_bwd``, traced as
+``dsa_probs``).
 """
 
 from __future__ import annotations

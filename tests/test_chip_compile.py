@@ -156,14 +156,19 @@ def _latent(chip, kind, backward, b=2, t=8192):
 
 def _indexer(chip, what, b=2, t=8192):
     """Its indexer's kernels there: 64 index heads of 128 summed in VMEM
-    (forward; the loss's gradient: two kernels), and attention's head-summed
-    probabilities from q, k and the logsumexp."""
+    (forward; the loss's gradient: two kernels), and the loss itself: the KL
+    made where attention's head-summed probabilities are, from q, k, the
+    logsumexp, the scores and the key sets (``probs``), and its gradient
+    (``probs_bwd``: the forward kernel for its two statistics, then the
+    rule's backward kernel)."""
     sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
-    if what == "probs":
+    if what.startswith("probs"):
         q = sd((b, 128, t, 192))
-        return jax.jit(lambda q, k, lse: sparse_index.head_summed_probs(
-            q, k, lse, sm_scale=192 ** -0.5, interpret=False)).lower(
-            q, q, sd((b, 128, t), jnp.float32))
+        kl = lambda q, k, lse, scores, mask: sparse_index.index_kl(  # noqa: E731
+            q, k, lse, scores, mask, sm_scale=192 ** -0.5, interpret=False)
+        fn = jax.grad(kl, argnums=3) if what == "probs_bwd" else kl
+        return jax.jit(fn).lower(q, q, sd((b, 128, t), jnp.float32),
+                                 sd((b, t, t), jnp.float32), sd((b, t, t), jnp.int8))
     args = (sd((b, 64, t, 128)), sd((b, t, 128)), sd((b, t, 64), jnp.float32))
     scores = lambda *a: sparse_index.index_scores(*a, interpret=False)  # noqa: E731
     fn = jax.grad(lambda *a: scores(*a).sum(), argnums=(0, 1, 2)) if what == "bwd" else scores
@@ -209,6 +214,7 @@ CASES = {
     "dsa-index-fwd-8k": lambda c: _indexer(c, "fwd"),
     "dsa-index-bwd-8k": lambda c: _indexer(c, "bwd"),
     "dsa-probs-8k": lambda c: _indexer(c, "probs"),
+    "dsa-probs-bwd-8k": lambda c: _indexer(c, "probs_bwd"),
 }
 
 
@@ -216,6 +222,8 @@ CASES = {
 def test_kernel_compiles_for_the_chip(chip, case):
     program = CASES[case](chip).compile().as_text()
     assert "tpu_custom_call" in program
+    if case.startswith("dsa-probs"):  # the loss's forward kernel; with its gradient, both
+        assert program.count('custom_call_target="tpu_custom_call"') == 1 + ("bwd" in case)
 
 
 @pytest.mark.parametrize("names,kernels", [

@@ -118,16 +118,91 @@ def test_index_scores_and_selection_match_plain_jnp(monkeypatch):
     assert int(sparse_index.select_top_k(tied, 16)[0, -1].sum()) == 20
 
 
-def test_head_summed_probs_match_plain_jnp():
+KL_CASES = {
+    # two batch rows in 128-blocks of 256: the statistics gather over two key
+    # blocks, the tile above the diagonal is skipped
+    "two_rows_two_key_blocks": dict(b=2, h=3, t=256, d=48, block=128, top_k=16),
+    # every row shorter than top_k: every causal key is in its set
+    "every_causal_key_kept": dict(b=1, h=2, t=128, d=16, block=128, top_k=300),
+    "a_tie_at_the_threshold": dict(b=2, h=2, t=256, d=16, block=128, top_k=16, tie=True),
+    # a key outside the set scores above its row's logsumexp (over the set)
+    "a_key_above_the_logsumexp": dict(b=1, h=2, t=128, d=16, block=128, top_k=4, clamp=True),
+    "one_block": dict(b=2, h=2, t=32, d=16, block=1024, top_k=4),
+    # no block divides 200: index_loss of head_summed_probs_reference itself
+    "a_length_no_block_fits": dict(b=1, h=2, t=200, d=16, block=1024, top_k=8, path="reference"),
+}
+
+
+@pytest.mark.parametrize("case", list(KL_CASES))
+def test_index_kl_and_its_gradient_match_the_plain_form(case):
+    """The loss made tile by tile against ``index_loss`` of the plain
+    head-summed probabilities, and its gradient by the backward kernel
+    against ``jax.grad`` of that form."""
+    from ray_tpu.ops import trace_log
+
+    c = KL_CASES[case]
+    b, h, t, d, top_k = (c[x] for x in ("b", "h", "t", "d", "top_k"))
     key = jax.random.PRNGKey(5)
-    b, h, t, d = 2, 3, 256, 48
-    q = jax.random.normal(key, (b, h, t, d))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, h, t, d))
-    lse = jax.random.normal(jax.random.fold_in(key, 2), (b, h, t)) + 6.0
+    q, k = (jax.random.normal(jax.random.fold_in(key, i), (b, h, t, d)) for i in (0, 1))
     causal = jnp.tril(jnp.ones((t, t), bool))
-    got = jnp.where(causal, sparse_index.head_summed_probs(q, k, lse, sm_scale=0.1, block=128), 0)
-    want = jnp.where(causal, sparse_index.head_summed_probs_reference(q, k, lse, 0.1), 0)
-    assert rel(got, want) < 1e-5
+    scores = jnp.where(causal, 2.0 * jax.random.normal(jax.random.fold_in(key, 2), (b, t, t)), 0.0)
+    if c.get("tie"):
+        scores = scores.at[:, -1, :20].set(7.0).at[:, -1, 20:].set(-1.0)
+    mask = sparse_index.select_top_k(scores, top_k)
+    if c.get("tie"):
+        assert int(mask[0, -1].sum()) == 20
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * 0.3
+    kept = mask[:, None] != 0
+    lse = jax.nn.logsumexp(jnp.where(kept, s, -jnp.inf), axis=-1)
+    if c.get("clamp"):
+        assert float(jnp.max(jnp.where(~kept & causal, s - lse[..., None], -jnp.inf))) > 1.0
+    before = trace_log.kernel_traces().get("dsa_probs:" + c.get("path", "interpret"), 0)
+    want, want_g = jax.value_and_grad(lambda x: sparse_index.index_loss(
+        x, sparse_index.head_summed_probs_reference(q, k, lse, 0.3), mask))(scores)
+    got, (*others, got_g) = jax.value_and_grad(lambda q, k, lse, x: sparse_index.index_kl(
+        q, k, lse, x, mask, sm_scale=0.3, block=c["block"]), (0, 1, 2, 3))(q, k, lse, scores)
+    assert trace_log.kernel_traces()["dsa_probs:" + c.get("path", "interpret")] > before
+    assert float(want) > 0.01 and abs(float(got) - float(want)) < 1e-5 * float(want)
+    assert rel(got_g, want_g) < 1e-5
+    # nothing outside the key sets, and no cotangent but the scores'
+    assert not np.asarray(jnp.where(mask != 0, 0.0, got_g)).any()
+    assert not any(np.asarray(x).any() for x in others)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, a
+    Pallas kernel's own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_the_indexers_loss_is_one_kernel_each_way_and_none_again_under_remat(params):
+    """The differentiated, remat-ed stack (a leading full layer and a scanned
+    one): the forward kernel once a full layer, the backward kernel once, no
+    second run of the forward under remat (its statistics are saved by
+    name), and the KL's exponentials and logarithms nowhere in XLA."""
+    assert {"dsa_kl_z", "dsa_kl_lse"} <= set(MIXERS["mla"].save_names)
+    assert CFG.remat_policy == "attn"
+    tokens = jnp.zeros((1, 48), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16)))(params)
+    equations = list(_equations(jaxpr.jaxpr))
+    kernels = [str(e.params["name"]) for e in equations if e.primitive.name == "pallas_call"]
+    assert kernels.count("dsa_probs") == 2 and kernels.count("dsa_probs_bwd") == 2
+    # what else an indexed layer runs, for scale: the scores again under remat
+    assert kernels.count("dsa_index_fwd") == 4 and kernels.count("attn_sel_fwd") == 2
+    named = {e.params["name"] for e in equations if e.primitive.name == "name"}
+    assert {"dsa_kl_z", "dsa_kl_lse", "dsa_mask"} <= named
+    square = [e.primitive.name for e in equations
+              if any(getattr(v.aval, "shape", None) == (1, 48, 48) for v in e.invars)]
+    assert square and not {"exp", "log", "exp2", "log1p", "logistic"} & set(square), square
 
 
 @pytest.mark.parametrize("kind", ["mla", "mla_win"])
