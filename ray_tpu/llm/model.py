@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.llama import LlamaConfig
+from ..observability.tracing import device_scope
 from ..ops import apply_rope, rms_norm
 from ..ops.paged_attention import paged_decode_attention, stage_rows
 
@@ -537,12 +538,12 @@ def mixed_dispatch(params, pages: dict, prefill_ops, block_tables, tokens,
     # can be split into prefill and decode; they are metadata only
     hiddens = []
     for (p_bt, p_tokens, p_start), lp in zip(prefill_ops, prefill_live_pages):
-        with jax.named_scope("prefill_chunk"):
+        with device_scope("prefill_chunk"):
             pages, hidden = prefill_chunk.__wrapped__(
                 params, pages, p_bt, p_tokens, p_start,
                 config=config, page_size=page_size, live_pages=lp)
         hiddens.append(hidden)
-    with jax.named_scope("decode_step"):
+    with device_scope("decode_step"):
         toks, key, pages = decode_loop.__wrapped__(
             params, pages, block_tables, tokens, pos, temps, eos_ids, remaining,
             key, config=config, page_size=page_size, n_steps=n_steps, paged=paged,

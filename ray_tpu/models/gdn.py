@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..observability.tracing import device_scope
 from ..ops.gated_delta import CHUNK, chunked_jnp, gated_delta_rule
 from .kinds import LayerKind
 
@@ -111,7 +112,7 @@ def gdn_mixer(h, layer, *, config, positions=None, mesh=None, scan=None,
     kw, vw = _widths(c)
     kh, vh, d = c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim
     b, s, _ = h.shape
-    with jax.named_scope("gdn_proj"):
+    with device_scope("gdn_proj"):
         qkvz = jnp.einsum("bse,ef->bsf", h, layer["w_qkvz"])
         ba = jnp.einsum("bse,ef->bsf", h, layer["w_ba"],
                         preferred_element_type=jnp.float32)
@@ -120,7 +121,7 @@ def gdn_mixer(h, layer, *, config, positions=None, mesh=None, scan=None,
         beta = jax.nn.sigmoid(ba[..., :vh])
         g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., vh:] + layer["dt_bias"].astype(jnp.float32))
-    with jax.named_scope("gdn_conv"):
+    with device_scope("gdn_conv"):
         qkv = jax.nn.silu(causal_conv(qkv, layer["conv_w"]))
         heads = lambda t, n: t.reshape(b, s, n, d).transpose(0, 2, 1, 3)  # noqa: E731
         q = _l2norm(heads(qkv[..., :kw], kh)) * d ** -0.5
@@ -132,10 +133,10 @@ def gdn_mixer(h, layer, *, config, positions=None, mesh=None, scan=None,
         v = heads(qkv[..., 2 * kw:], vh).astype(c.dtype)
         g = checkpoint_name(g.transpose(0, 2, 1), "gdn_g")
         beta = checkpoint_name(beta.transpose(0, 2, 1), "gdn_beta")
-    with jax.named_scope("gdn_scan"):
+    with device_scope("gdn_scan"):
         o = checkpoint_name((scan or gated_delta_rule)(q, k, v, g, beta), "gdn_o")
     seen = {"q": q, "k": k, "v": v, "g": g, "beta": beta, "o": o}
-    with jax.named_scope("gdn_out"):
+    with device_scope("gdn_out"):
         o = _gated_norm(o.transpose(0, 2, 1, 3), z.reshape(b, s, vh, d),
                         layer["gdn_norm"], c.norm_eps).astype(c.dtype)
         y = jnp.einsum("bsf,fe->bse", o.reshape(b, s, vw), layer["w_out"])

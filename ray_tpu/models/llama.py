@@ -43,6 +43,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from ..observability.tracing import device_scope
 from ..ops import (flash_attention, mha_reference, ring_attention, rms_norm,
                    apply_rope, ulysses_attention)
 from ..parallel.sharding import shard_constraint
@@ -412,7 +413,7 @@ def _attn_mixer(h, layer, *, config: LlamaConfig, positions, mesh: Mesh | None):
     v = checkpoint_name(v, "v")
     attn = _attention(q, k, v, c, mesh)
     if c.attn_out_gate:
-        with jax.named_scope("attn_gate"):
+        with device_scope("attn_gate"):
             attn = _gate_output(attn, checkpoint_name(gate, "attn_gate"))
     return jnp.einsum("bhsd,hde->bse", attn, layer["wo"])
 
@@ -464,17 +465,18 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
     def sc(t, axes):
         return shard_constraint(t, mesh, axes) if mesh is not None else t
 
-    # scopes are metadata: they name the device ops after the part of the
-    # block that issued them (op_name on the profiler's op line), and
-    # leave the compiled program as it was
-    with jax.named_scope("attn"):
+    # scopes are text: ``rt_scope="stack/attn"`` in each device op's own
+    # name on the profiler's op line (``tracing.device_scope``; ``op_name``
+    # in the compiled text's metadata too), and leave the compiled program
+    # as it was
+    with device_scope("attn"):
         h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps, offset=c.norm_offset)
         mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh,
                                     **({"return_selection": True} if return_selection else {}))
         mixed, mixed_aux = mixed if isinstance(mixed, tuple) else (mixed, {})
         x = x + sc(mixed, ("batch", "seq", "embed_act"))
 
-    with jax.named_scope("mlp"):
+    with device_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
         down, aux = _mlp_kind(c, lead).apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis)
         x = x + sc(down, ("batch", "seq", "embed_act"))
@@ -542,7 +544,7 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
-    with jax.named_scope("embed"):
+    with device_scope("embed"):
         x = params["embed"][tokens].astype(c.dtype)
     if mesh is not None:
         # Two-hop resharding. The gather's output inherits the table's
@@ -584,11 +586,12 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
                    else P("pp"))
             for name in param_axes(c)["layers"]
         }
-        x = pipeline_apply(
-            block, params["layers"], x,
-            mesh=mesh, n_microbatches=c.pipeline_microbatches,
-            param_specs=param_specs,
-        )
+        with device_scope("stack"):
+            x = pipeline_apply(
+                block, params["layers"], x,
+                mesh=mesh, n_microbatches=c.pipeline_microbatches,
+                param_specs=param_specs,
+            )
         out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
         return (out, {}) if return_aux else out
 
@@ -596,11 +599,12 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     # leading kind (a dense one: no aux)
     mixed_auxes = []
     for i, mixer in enumerate(c.lead_pattern):
-        x, _, mixed_aux = _apply_remat(
-            functools.partial(_block, positions=positions, config=c, mesh=mesh,
-                              mixer=mixer, lead=True, return_selection=return_selection),
-            c, mixer, lead=True)(
-            x, params["lead_layers"][f"layer{i}"])
+        with device_scope("stack"):
+            x, _, mixed_aux = _apply_remat(
+                functools.partial(_block, positions=positions, config=c, mesh=mesh,
+                                  mixer=mixer, lead=True, return_selection=return_selection),
+                c, mixer, lead=True)(
+                x, params["lead_layers"][f"layer{i}"])
         mixed_auxes.append(mixed_aux)
 
     blocks = [
@@ -622,7 +626,10 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
 
     # one aux a position of the period, each [periods, ...] -> [L, ...] in
     # layer order: position i of period p is scanned layer p * len(blocks) + i
-    x, (auxes, mixed) = lax.scan(period, x, layers)
+    # ``stack``: what the scan itself costs (the saved residuals stacked by
+    # ``dynamic-update-slice``, the slices of the layer stack) has a name
+    with device_scope("stack"):
+        x, (auxes, mixed) = lax.scan(period, x, layers)
     scanned = c.n_layers - len(c.lead_pattern)
     per_layer = auxes[0] if len(auxes) == 1 else jax.tree.map(
         lambda *a: jnp.stack(a, axis=1).reshape((scanned,) + a[0].shape[1:]), *auxes)
@@ -775,7 +782,7 @@ def loss_fn(
     """
     tokens = batch["tokens"]
     hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
-    with jax.named_scope("lm_head_loss"):
+    with device_scope("lm_head_loss"):
         targets = tokens[:, 1:]
         hidden = hidden[:, :-1]
         mask = batch.get("mask")
@@ -807,7 +814,7 @@ def loss_fn(
         # reaches the indexers' leaves and no other: their inputs are cut
         # from the graph, and the model's loss passes no gradient to a top-k.
         # No weight: the two terms' gradients touch disjoint leaves
-        with jax.named_scope("index_loss"):
+        with device_scope("index_loss"):
             loss = loss + aux["index_loss"]
     return (loss, {"ce": ce, **aux}) if return_aux else loss
 

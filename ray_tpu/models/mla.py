@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..observability.tracing import device_scope
 from ..ops import apply_rope, flash_attention, rms_norm
 from ..ops.sparse_index import index_kl, index_scores, select_top_k
 from .kinds import LayerKind
@@ -162,13 +163,13 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
         x = rms_norm(x, weight, eps=c.norm_eps)
         return x if scale == 1.0 else (x.astype(jnp.float32) * scale).astype(x.dtype)
 
-    with jax.named_scope("mla_q"):
+    with device_scope("mla_q"):
         c_q = checkpoint_name(
             latent(jnp.einsum("bse,er->bsr", h, layer["w_dq"]), layer["q_a_norm"], s_q),
             "mla_cq")
         q = _rope_tail(jnp.einsum("bsr,rhd->bhsd", c_q, layer["w_uq"]), positions,
                        a.rope_theta, a.rope_dim)
-    with jax.named_scope("mla_kv"):
+    with device_scope("mla_kv"):
         down = jnp.einsum("bse,er->bsr", h, layer["w_dkv"])
         c_kv = checkpoint_name(latent(down[..., :a.kv_rank], layer["kv_a_norm"], s_kv),
                                "mla_ckv")
@@ -181,14 +182,14 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
     sm_scale = a.qk_dim ** -0.5
     aux = {}
     if a.index_heads:
-        with jax.named_scope("dsa_index"):
+        with device_scope("dsa_index"):
             scores = index_scores(*index_inputs(h, c_q, layer, a, positions))
-        with jax.named_scope("dsa_select"):
+        with device_scope("dsa_select"):
             mask = checkpoint_name(
                 select_top_k(jax.lax.stop_gradient(scores), a.index_top_k), "dsa_mask")
         attn, lse = flash_attention(q, k, v, sm_scale=sm_scale, mask=mask,
                                     top_k=a.index_top_k, return_lse=True)
-        with jax.named_scope("dsa_loss"):
+        with device_scope("dsa_loss"):
             aux["index_loss"] = index_kl(q, k, lse, scores, mask, sm_scale=sm_scale)
             aux["selected_share"] = (jnp.sum(mask, dtype=jnp.float32)
                                      / (b * s * (s + 1) / 2))
@@ -199,12 +200,12 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
         attn = flash_attention(q, k, v, sm_scale=sm_scale, window=a.window or None,
                                block_q=512, block_k=512)
     if a.gate:
-        with jax.named_scope("attn_gate"):
+        with device_scope("attn_gate"):
             gate = checkpoint_name(jax.nn.sigmoid(jnp.einsum(
                 "bse,eh->bhs", h, layer["w_attn_gate"],
                 preferred_element_type=jnp.float32)), "attn_gate")
             attn = (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
-    with jax.named_scope("mla_out"):
+    with device_scope("mla_out"):
         return jnp.einsum("bhsd,hde->bse", attn, layer["wo"]), aux
 
 

@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..observability.tracing import device_scope
 from ..ops import grouped_matmul
 from .kinds import LayerKind
 
@@ -293,12 +294,12 @@ def _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset):
     computes the groups' range of them (all, when ``offset`` is None)."""
     n, e = tokens.shape
     gmm = functools.partial(grouped_matmul, group_sizes=sizes, row_offset=offset)
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         xs = checkpoint_name(_dispatch(tokens, order, inv, top_k), "moe_xs")
         row_gates = _permute(gates.reshape(n * top_k), order, inv)
-    with jax.named_scope("moe_experts"):
+    with device_scope("moe_experts"):
         ys = _experts(xs, row_gates, weights, gmm)
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         out = _permute(ys, inv, order).reshape(n, top_k, e)
         return out.astype(jnp.float32).sum(axis=1).astype(tokens.dtype)
 
@@ -314,12 +315,12 @@ def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
     token = pair // top_k
     gmm = functools.partial(grouped_matmul, group_sizes=sizes,
                             row_offset=jnp.zeros((), jnp.int32))
-    with jax.named_scope("moe_dispatch"):
+    with device_scope("moe_dispatch"):
         xs = checkpoint_name(tokens[token], "moe_xs")
         row_gates = jnp.where(valid, gates.reshape(n * top_k)[pair], 0.0)
-    with jax.named_scope("moe_experts"):
+    with device_scope("moe_experts"):
         ys = _experts(xs, row_gates, weights, gmm)
-    with jax.named_scope("moe_combine"):
+    with device_scope("moe_combine"):
         ys = jnp.where(valid[:, None], ys, 0).astype(jnp.float32)
         return jnp.zeros((n, e), jnp.float32).at[token].add(ys).astype(tokens.dtype)
 
@@ -379,7 +380,7 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     b, s, e = x.shape
     n = b * s
     tokens = x.reshape(n, e)
-    with jax.named_scope("moe_route"):
+    with device_scope("moe_route"):
         r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk, score=score,
                   bias=params.get("router_bias"), n_seqs=b)
     sizes, offset = r["sizes"], None
@@ -402,10 +403,10 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
         out = _held_or_all_rows(top_k, cap, tokens, weights, r["gates"], r["order"],
                                 r["inv"], sizes, offset)
     if ep_axis is not None:
-        with jax.named_scope("moe_combine"):
+        with device_scope("moe_combine"):
             out = jax.lax.psum(out, ep_axis)
     if "w_shared_gate" in params:
-        with jax.named_scope("moe_shared"):
+        with device_scope("moe_shared"):
             out = out + shared_expert(tokens, params)
     aux = {"load_balance": r["load_balance"], "z": r["z"], "rows": r["sizes"],
            "dropped": n * top_k - jnp.sum(r["sizes"])}

@@ -159,13 +159,6 @@ def get_stall_ring(loop_id: str, stage: str,
         return ring
 
 
-def stall_snapshots(loop_id: str) -> dict[str, dict]:
-    """All of this process's stage snapshots for one loop."""
-    with _rings_lock:
-        items = [(k[1], r) for k, r in _rings.items() if k[0] == loop_id]
-    return {stage: ring.snapshot() for stage, ring in items}
-
-
 # ------------------------------------------------------ request flight recorder
 
 EV_ADMIT = 1          # value: prompt length

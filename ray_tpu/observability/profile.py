@@ -9,10 +9,12 @@ puts it there); its ``wall_s`` / ``mono_s`` attributes are that instant on
 too. Program spans are the ``tracing.annotate`` events, which carry the
 ``tracing.MARK`` stat (so a site in a new layer needs no list here);
 device ops are named by their HLO text, which starts with the
-instruction's name (``%flash_fwd.18 = ...``). A device plane's
-lines cover the same time and its op line nests, so busy time is a union
-and an op's time its self time. Independent of ``benchmark/``, which
-keeps its own reducer.
+instruction's name (``%flash_fwd.18 = ...``) and holds its frontend
+attributes, among them the ``rt_scope="stack/attn"`` of the
+``tracing.device_scope`` that issued it: the step by scope is read off the
+event names alone. A device plane's lines cover the same time and its op
+line nests, so busy time is a union and an op's time its self time.
+Independent of ``benchmark/``, which keeps its own reducer.
 """
 
 from __future__ import annotations
@@ -23,8 +25,11 @@ import re
 import time
 from collections import defaultdict
 
+from .tracing import MARK, SCOPE_ATTR
+
 WINDOW = "capture_window"
 _DEVICE = re.compile(r"^/device:(TPU|GPU):\d+$")
+_SCOPE = re.compile(SCOPE_ATTR + r'="([^"]*)"')
 _NS = 1e-9
 
 
@@ -130,8 +135,6 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
     clock through the window's ``wall_s``."""
     from jax.profiler import ProfileData
 
-    from .tracing import MARK
-
     if os.path.isdir(path):
         path = max(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True),
                    key=os.path.getmtime)
@@ -164,7 +167,7 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
     mapped = [(s["name"], s["start"] + shift, s["end"] + shift, s.get("attrs"))
               for s in spans or () if s["end"] + shift > lo and s["start"] + shift < hi]
     out = {"window": {"start_s": lo, "seconds": hi - lo, **clocks},
-           "devices": [], "spans": [], "ops": [], "kernels": [],
+           "devices": [], "spans": [], "ops": [], "kernels": [], "scopes": {},
            "idle_by_span": [], "idle_uncovered": [], "gaps_over_1ms": 0}
     totals = defaultdict(lambda: [0.0, 0])
     for name, s, e, _ in program + mapped:
@@ -181,15 +184,26 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
             continue  # op tables and gaps: the first device
         gaps = dev_gaps
         by_name, kernels = defaultdict(lambda: [0.0, 0]), defaultdict(lambda: [0.0, 0])
+        # the step by scope: every path, and rolled up to a path's first
+        # component (``stack``) and to its last (``mla_q``); "" holds no scope
+        scopes = {k: defaultdict(lambda: [0.0, 0]) for k in ("path", "first", "last")}
         for op, (sec, n) in _self_times(ops).items():
             inst = op.partition(" = ")[0]
             groups = [by_name[re.sub(r"[.\d]+$", "", inst)]]
             if 'custom_call_target="tpu_custom_call"' in op:
                 groups.append(kernels[re.sub(r"^%|[.\d]+$", "", inst)])
+            scope = _SCOPE.search(op)
+            path = scope.group(1) if scope else ""
+            groups += [scopes["path"][path], scopes["first"][path.partition("/")[0]],
+                       scopes["last"][path.rpartition("/")[2]]]
             for g in groups:
                 g[0] += sec
                 g[1] += n
         out.update(ops=_top(by_name, top), kernels=_top(kernels, top))
+        # every row, not the top: the rows partition the busy time
+        out["scopes"] = {k: [[*row, 100.0 * row[1] / busy if busy else 0.0]
+                             for row in _top(table, len(table))]
+                         for k, table in scopes.items()}
     by_span, uncovered = defaultdict(float), defaultdict(float)
     gaps.sort(key=lambda g: g[0] - g[1])
     for s, e in gaps[:300]:  # the longest; the rest are microseconds between ops
@@ -215,6 +229,12 @@ def render(summary: dict) -> str:
     for title, key in (("device ops (self s, n)", "ops"), ("kernels", "kernels")):
         if summary[key]:
             lines += [title] + [f"  {r[0]:<44} {r[1]:.5f} {r[2]}" for r in summary[key]]
+    if any(r[0] for r in summary["scopes"].get("path", ())):  # a scope besides ""
+        for title, key in (("device time by scope path (self s, n, % of busy)", "path"),
+                           ("by a path's first scope", "first"),
+                           ("by a path's last scope", "last")):
+            lines += [title] + [f"  {r[0] or '(no scope)':<44} {r[1]:.5f} {r[2]:>6} {r[3]:6.2f}"
+                                for r in summary["scopes"][key]]
     lines += ["program spans (n, total ms, mean ms)"] + [
         f"  {s['name']:<28} {s['count']:>6} {s['total_ms']:>10.3f} {s['mean_ms']:>9.4f}"
         for s in summary["spans"]]

@@ -191,6 +191,38 @@ def annotate(name: str, **attrs):
     return jax.profiler.TraceAnnotation(name, ray_tpu=1, **attrs)
 
 
+# The frontend attribute every op lowered inside a ``device_scope`` carries.
+SCOPE_ATTR = "rt_scope"
+
+
+@contextlib.contextmanager
+def device_scope(name: str):
+    """Name the device ops a ``with`` block issues after the part of the
+    model that issued them: a ``jax.named_scope`` (the instruction's
+    ``op_name``, which only the compiled text keeps) AND the frontend
+    attribute ``rt_scope="<path>"``, which is printed in the instruction's
+    own HLO text, so it is on the profiler's op line, where an event is
+    named by that text without its metadata. ``<path>`` is the ``/``-joined
+    names of the scopes open around the block (``stack/attn/mla_q``). The
+    open path is jax's own metadata context, not a variable beside it: jax
+    carries that through ``jvp``, ``transpose``, ``lax.scan`` and
+    ``jax.checkpoint``'s recomputation, installs the CALL's again around a
+    ``custom_vjp``'s backward rule (so a scope opened inside a rule nests
+    under the call's), keys ``jit``'s cache on it and keeps it thread-local;
+    a fusion inherits its root's. Text in the executable: nothing at run
+    time, a dict merge while tracing. Names are lower case (the attribute's
+    value is lowered), without ``/`` or ``"``. ``profile.summarize`` reads
+    a capture's table by scope from it."""
+    import jax
+    from jax._src.xla_metadata_lib import current_xla_metadata
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    outer = (current_xla_metadata() or {}).get(SCOPE_ATTR)
+    path = f"{outer}/{name}" if outer else name
+    with jax.named_scope(name), set_xla_metadata(**{SCOPE_ATTR: path}):
+        yield path
+
+
 _enabled: bool | None = None  # read once per process: a span must stay cheap
 
 

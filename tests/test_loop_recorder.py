@@ -66,8 +66,7 @@ def test_stall_ring_registry_bounded():
     r1 = loop_recorder.get_stall_ring("loop-x", "s0", capacity=4)
     assert loop_recorder.get_stall_ring("loop-x", "s0") is r1
     r1.record(0.0, 1.0, 0.0)
-    snaps = loop_recorder.stall_snapshots("loop-x")
-    assert snaps["s0"]["ticks"] == 1
+    assert loop_recorder.get_stall_ring("loop-x", "s0").snapshot()["ticks"] == 1
     # the registry never grows without bound (LRU-drops the oldest key)
     for i in range(loop_recorder._RINGS_MAX + 8):
         loop_recorder.get_stall_ring(f"loop-fill-{i}", "s")
