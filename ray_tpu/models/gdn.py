@@ -22,6 +22,19 @@ are elementwise passes to make again where the projection is a 1.1 TFLOP
 product), the gates, the scan's output and ``z``. The rule's chunk-local
 half (``gdn_wy_fwd``) runs again, and its backward kernel makes the chunks'
 inverses again in VMEM: nothing of a chunk's matrices is saved.
+
+The elementwise passes hold no matrix product, and the transposes between
+the projections' token-major ``[B, S, features]`` and the rule's head-major
+``[B, VH, S, D]`` end every XLA fusion. On the chip, where a head is whole
+lane tiles, ``ops/gdn_elementwise.py`` makes them tile by tile in VMEM and
+its ``BlockSpec``s do the transposition: ``conv_heads`` (``gdn_conv_fwd`` /
+``gdn_conv_bwd``: conv, SiLU, norms, the heads, each key head written for
+every value head it serves) and ``gated_norm`` (``gdn_norm_fwd`` /
+``gdn_norm_bwd``), each twice forward (remat) and once backward a layer on
+what ``SAVE_NAMES`` keeps. Which path runs is ``_in_vmem``'s to say, from the
+backend and the shapes; everywhere else ``causal_conv``, ``_l2norm`` and
+``_gated_norm`` below ARE the mixer (the tests plant faults in them by name),
+and the kernels' tests hold the two paths equal.
 """
 
 from __future__ import annotations
@@ -33,7 +46,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.tracing import device_scope
+from ..ops import gdn_elementwise
 from ..ops.gated_delta import CHUNK, chunked_jnp, gated_delta_rule
+from ..ops.trace_log import note_kernel_trace
+from ..tpu import on_tpu
 from .kinds import LayerKind
 
 SAVE_NAMES = ("gdn_qkv", "gdn_g", "gdn_beta", "gdn_o", "gdn_z")
@@ -101,6 +117,30 @@ def _gated_norm(o, z, weight, eps):
     return o * weight.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
 
 
+def _in_vmem(c, rows: int) -> bool:
+    """Whether the elementwise passes run as ``ops/gdn_elementwise.py``'s
+    kernels: on the chip, where a head is whole lane tiles, the rows come in
+    whole units and the conv is the width they are written for. Elsewhere the
+    plain functions above are the mixer."""
+    return on_tpu() and gdn_elementwise.fits(c.gdn_head_dim, rows, c.gdn_conv)
+
+
+def _conv_heads(qkv, conv_w, c):
+    """The plain path from the projection's qkv [B, S, C] to the rule's
+    q, k, v [B, VH, S, D]: conv, SiLU, the heads, q's and k's norms, each key
+    head once for every value head it serves."""
+    kw, _ = _widths(c)
+    kh, vh, d = c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim
+    b, s, _ = qkv.shape
+    qkv = jax.nn.silu(causal_conv(qkv, conv_w))
+    heads = lambda t, n: t.reshape(b, s, n, d).transpose(0, 2, 1, 3)  # noqa: E731
+    q = _l2norm(heads(qkv[..., :kw], kh)) * d ** -0.5
+    k = _l2norm(heads(qkv[..., kw:2 * kw], kh))
+    q = jnp.repeat(q.astype(c.dtype), vh // kh, axis=1)
+    k = jnp.repeat(k.astype(c.dtype), vh // kh, axis=1)
+    return q, k, heads(qkv[..., 2 * kw:], vh).astype(c.dtype)
+
+
 def gdn_mixer(h, layer, *, config, positions=None, mesh=None, scan=None,
               return_scan: bool = False):
     """h [B, S, E] (normed) -> y [B, S, E]. ``scan`` swaps the kernels for
@@ -122,24 +162,26 @@ def gdn_mixer(h, layer, *, config, positions=None, mesh=None, scan=None,
         g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., vh:] + layer["dt_bias"].astype(jnp.float32))
     with device_scope("gdn_conv"):
-        qkv = jax.nn.silu(causal_conv(qkv, layer["conv_w"]))
-        heads = lambda t, n: t.reshape(b, s, n, d).transpose(0, 2, 1, 3)  # noqa: E731
-        q = _l2norm(heads(qkv[..., :kw], kh)) * d ** -0.5
-        k = _l2norm(heads(qkv[..., kw:2 * kw], kh))
-        rep = vh // kh
-        # a key head serves ``rep`` value heads
-        q = jnp.repeat(q.astype(c.dtype), rep, axis=1)
-        k = jnp.repeat(k.astype(c.dtype), rep, axis=1)
-        v = heads(qkv[..., 2 * kw:], vh).astype(c.dtype)
+        if _in_vmem(c, s):
+            q, k, v = gdn_elementwise.conv_heads(
+                qkv, layer["conv_w"], key_heads=kh, value_heads=vh, out_dtype=c.dtype)
+        else:
+            note_kernel_trace("gdn_conv", "jnp")
+            q, k, v = _conv_heads(qkv, layer["conv_w"], c)
         g = checkpoint_name(g.transpose(0, 2, 1), "gdn_g")
         beta = checkpoint_name(beta.transpose(0, 2, 1), "gdn_beta")
     with device_scope("gdn_scan"):
         o = checkpoint_name((scan or gated_delta_rule)(q, k, v, g, beta), "gdn_o")
     seen = {"q": q, "k": k, "v": v, "g": g, "beta": beta, "o": o}
     with device_scope("gdn_out"):
-        o = _gated_norm(o.transpose(0, 2, 1, 3), z.reshape(b, s, vh, d),
-                        layer["gdn_norm"], c.norm_eps).astype(c.dtype)
-        y = jnp.einsum("bsf,fe->bse", o.reshape(b, s, vw), layer["w_out"])
+        if _in_vmem(c, s):
+            o = gdn_elementwise.gated_norm(o, z, layer["gdn_norm"], eps=c.norm_eps,
+                                           out_dtype=c.dtype)
+        else:
+            note_kernel_trace("gdn_norm", "jnp")
+            o = _gated_norm(o.transpose(0, 2, 1, 3), z.reshape(b, s, vh, d),
+                            layer["gdn_norm"], c.norm_eps).astype(c.dtype).reshape(b, s, vw)
+        y = jnp.einsum("bsf,fe->bse", o, layer["w_out"])
     return (y, seen) if return_scan else y
 
 

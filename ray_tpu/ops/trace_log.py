@@ -15,6 +15,9 @@ same name on a profiler trace (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkdv``, ``paged_decode``, ``moe_gmm``, ``moe_tgmm``,
 ``gdn_wy_fwd``, ``gdn_wy_bwd``, ``gdn_fwd``, ``gdn_bwd``; the delta rule's
 chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``; the
+DeltaNet mixer's elementwise pairs ``gdn_conv_fwd`` / ``gdn_conv_bwd`` and
+``gdn_norm_fwd`` / ``gdn_norm_bwd``, traced as ``gdn_conv`` and ``gdn_norm``
+(``pallas`` / ``interpret``, or ``jnp`` where the mixer's plain functions ran); the
 attention kernels under a window or a key set ``attn_win_*`` / ``attn_sel_*``,
 traced as ``flash_attention`` like the plain ones; the indexer's
 ``dsa_index_fwd`` / ``dsa_index_bwd_dq`` / ``dsa_index_bwd_dk``, traced as
@@ -38,8 +41,8 @@ _costs: dict[str, dict] = {}
 
 def note_kernel_trace(kernel: str, path: str) -> None:
     """Count one trace of ``kernel`` down ``path`` (``"pallas"``,
-    ``"interpret"``, ``"mha_reference"`` or ``"ragged_dot"``); log the
-    first of each."""
+    ``"interpret"``, ``"mha_reference"``, ``"ragged_dot"`` or ``"jnp"``); log
+    the first of each."""
     key = f"{kernel}:{path}"
     with _lock:
         _counts[key] += 1

@@ -19,7 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops import gated_delta, sparse_index
+from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -134,6 +134,28 @@ def _gdn_wy(chip, backward, b=2, h=32, t=8192, d=128):
     return jax.jit(pull_back).lower(cotangents, x, x, x, gate, gate)
 
 
+def _gdn_elementwise(chip, what, backward, b=2, t=8192, kh=16, vh=32, d=128):
+    """The DeltaNet mixer's elementwise kernels at the benchmark's 2 x 8192:
+    ``conv`` from the projection's ``[2, 8192, 8192]`` and the four taps to q,
+    k, v ``[2, 32, 8192, 128]`` (16 key heads, each written twice), ``norm``
+    from the rule's output and z to the token-major input of ``w_out``;
+    backward alone (the pull-back needs no forward call: the residuals are
+    the inputs)."""
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)  # noqa: E731
+    heads, tokens = sd((b, vh, t, d)), sd((b, t, vh * d))
+    if what == "conv":
+        args, cotangent = (sd((b, t, (2 * kh + vh) * d)), sd(((2 * kh + vh) * d, 4))), (heads,) * 3
+        fwd = lambda x, w: gdn_elementwise.conv_heads(  # noqa: E731
+            x, w, key_heads=kh, value_heads=vh, out_dtype=jnp.bfloat16, interpret=False)
+    else:
+        args, cotangent = (heads, tokens, sd((d,))), tokens
+        fwd = lambda o, z, w: gdn_elementwise.gated_norm(  # noqa: E731
+            o, z, w, eps=1e-6, out_dtype=jnp.bfloat16, interpret=False)
+    if not backward:
+        return jax.jit(fwd).lower(*args)
+    return jax.jit(lambda ct, *a: jax.vjp(fwd, *a)[1](ct)).lower(cotangent, *args)
+
+
 def _latent(chip, kind, backward, b=2, t=8192):
     """dots3-note-prev's attention at the benchmark's 2 x 8192: ``sel`` the
     full layers' (128 heads, a 192-wide key head and a 128-wide value head,
@@ -200,6 +222,10 @@ CASES = {
     "gdn-bwd": lambda c: _gdn(c, backward=True),
     "gdn-wy-fwd": lambda c: _gdn_wy(c, backward=False),
     "gdn-wy-bwd": lambda c: _gdn_wy(c, backward=True),
+    "gdn-conv-fwd": lambda c: _gdn_elementwise(c, "conv", backward=False),
+    "gdn-conv-bwd": lambda c: _gdn_elementwise(c, "conv", backward=True),
+    "gdn-norm-fwd": lambda c: _gdn_elementwise(c, "norm", backward=False),
+    "gdn-norm-bwd": lambda c: _gdn_elementwise(c, "norm", backward=True),
     "flash-fwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=False),
     "flash-bwd-d256-8k": lambda c: _flash(c, 2, 16, 2, 8192, 256, backward=True),
     # Qwen3-Next's held experts: 163,840 sorted rows of which a range is
@@ -224,6 +250,8 @@ def test_kernel_compiles_for_the_chip(chip, case):
     assert "tpu_custom_call" in program
     if case.startswith("dsa-probs"):  # the loss's forward kernel; with its gradient, both
         assert program.count('custom_call_target="tpu_custom_call"') == 1 + ("bwd" in case)
+    if case.startswith(("gdn-conv", "gdn-norm")):  # one kernel each way
+        assert program.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("names,kernels", [
@@ -301,7 +329,8 @@ def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(ch
 
 # ------------------------------------------ the scopes, by the chip's compiler
 KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul",
-                  "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta")
+                  "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta",
+                  "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn")
 
 
 def _tiny_step(chip, preset):
@@ -320,6 +349,10 @@ def _tiny_step(chip, preset):
 
 
 @pytest.mark.parametrize("preset,scopes", [
+    # ``hybrid-debug``'s DeltaNet heads are 16 wide, no lane tile: its conv and
+    # gated norm take the plain functions by their shape, steered or not, so no
+    # kernel reads under ``gdn_conv`` or ``gdn_out`` here (the cell's widths:
+    # the ``gdn-conv-*`` / ``gdn-norm-*`` cases above)
     ("hybrid-debug", {"stack/attn", "stack/attn/gdn_scan", "stack/mlp/moe_experts"}),
     ("latent-sparse-debug", {"stack/attn", "stack/attn/dsa_index", "stack/attn/dsa_select",
                              "stack/attn/dsa_loss", "stack/mlp/moe_experts"})])

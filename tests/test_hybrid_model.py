@@ -245,6 +245,27 @@ def test_kinds_own_their_names_and_the_policy_saves_them():
     assert text.count("name=flash_fwd") == 1
 
 
+def test_the_elementwise_kernels_run_twice_forward_and_once_backward_a_layer(monkeypatch):
+    """At a head width of 128 with the dispatch's predicate patched: the
+    differentiated, remat-ed step holds ``gdn_conv_fwd`` and ``gdn_norm_fwd``
+    twice a DeltaNet layer (forward, and again under remat) and each backward
+    kernel once, and no float32 value of the projection's ``[B, S, 2 kw + vw]``
+    outside a kernel (the plain path's conv writes one)."""
+    cfg = dataclasses.replace(PRESETS["hybrid-debug"], remat_policy="attn", gdn_head_dim=128)
+    params, tokens = init_params(cfg, jax.random.PRNGKey(0)), jnp.zeros((1, 64), jnp.int32)
+    step = jax.grad(lambda p: loss_fn(p, {"tokens": tokens}, cfg, chunk_tokens=64))
+    wide = (2 * cfg.gdn_key_heads + cfg.gdn_value_heads) * 128
+    monkeypatch.setattr(gdn, "_in_vmem", lambda c, rows: True)
+    text = str(jax.make_jaxpr(step)(params))
+    jax.clear_caches()       # the scanned, remat-ed block's trace is cached by its function
+    for kernel in ("gdn_conv", "gdn_norm"):
+        assert text.count(f"name={kernel}_fwd") == 6 and text.count(f"name={kernel}_bwd") == 3
+    assert text.count("name=gdn_wy_fwd") == 6 and text.count("name=gdn_fwd") == 3
+    # ``gdn_qkv`` itself is there in the model's type, and never in float32: a
+    # kernel's own body holds its tiles, not the whole array
+    assert f"bf16[1,64,{wide}]" in text and f"f32[1,64,{wide}]" not in text
+
+
 def test_flops_are_the_kinds_own_and_dense_configs_count_as_before():
     c = PRESETS["llama3-1b"]
     n = c.n_layers * (c.hidden * c.head_dim * (2 * c.n_heads + 2 * c.n_kv_heads)
