@@ -1,0 +1,184 @@
+"""Grouped-query softmax attention as two token mixers whose widths a spec
+owns, as ``models/mla.py`` is two of latent attention: ``gqa`` (every causal
+key) and ``gqa_win`` (a causal window), each described by a
+``GroupedQueryAttention`` on the config (``gqa``, ``gqa_window``). One model
+may so hold two head counts, two ropes and a window side by side
+(Laguna-S-2.1: 48 query heads under YaRN on half of a head in its full
+layers, 72 under plain rope and a 512-key window in the others, 8 kv heads
+of 128 in both).
+
+The ``attn`` kind of ``models/llama.py`` reads the config's own top-level
+widths and is NOT built from this spec. It is the one kind with what no
+other model of grouped queries here has: two q/k norms (over a head, over
+the whole projection), Qwen3-Next's element-wise gate cut from a q
+projection twice as wide, and three ways to run the scores (ring and
+Ulysses over ``sp``, a plain reference). A spec that carried all of that
+would be ``LlamaConfig`` again under another name, and the four accepted
+cells that run ``attn`` are held to the byte of their lowered step. What
+the two share is shared: the per-shard kernel call under a mesh
+(``kinds.flash_per_shard``), the head-wise gate and ``kept_keys``
+(``models/kinds.py``), rope (``ops/rope.py``).
+
+For the normed input ``x`` of a position, H query heads and KV key/value
+heads of D features (head h reads kv head ``h // (H / KV)``):
+
+    q_h = x W_q[h];  k_j = x W_k[j];  v_j = x W_v[j];  rope on q and k
+    o_h = softmax over the allowed keys of (q_h . k_j D^-1/2) v_j
+    y   = concat_h(sigmoid(x W_g)_h o_h) W_o          (``gate`` "headwise")
+
+Allowed keys of query t: s <= t, and with ``window`` t - s < window. Rope
+turns the first ``rotary_dim`` features of a head (0: all) by ``rope_theta``'s
+frequencies, or with ``yarn`` by YaRN's (``ops/rope.py``), cos and sin times
+its ``attention_factor``.
+
+The mixer counts beside its output, where it has a window, ``window_share``:
+the (query, key) pairs it attends over the causal pairs, from the positions
+it was given.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..observability.tracing import device_scope
+from ..ops import apply_rope
+from ..ops.rope import yarn_frequencies
+from ..parallel.sharding import shard_constraint
+from .kinds import LayerKind, flash_per_shard, headwise_gate, kept_keys
+
+SAVE_NAMES = ("q", "k", "v", "attn_out", "attn_lse", "attn_gate")
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's published parameters (a ``rope_parameters`` group of rope_type
+    ``yarn``)."""
+
+    factor: float
+    original_length: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedQueryAttention:
+    """The widths of one kind of grouped-query attention layer."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope_theta: float
+    rotary_dim: int = 0       # 0: rope on every feature of a head
+    yarn: Yarn | None = None
+    window: int = 0           # 0: none. Else query t sees keys t - window + 1 .. t
+    gate: str = "none"        # "none" | "headwise"
+
+    def __post_init__(self):
+        if self.gate not in ("none", "headwise"):
+            raise ValueError(f"gate is {self.gate!r}: 'none' or 'headwise'")
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads over {self.kv_heads} kv heads")
+
+
+def _axes(a: GroupedQueryAttention) -> dict:
+    axes = {
+        "wq": ("embed", "heads", "head_dim"),
+        "wk": ("embed", "kv_heads", "head_dim"),
+        "wv": ("embed", "kv_heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+    if a.gate == "headwise":
+        axes["w_attn_gate"] = ("embed", "heads")
+    return axes
+
+
+def _init(a: GroupedQueryAttention, c, keys, lead, normal) -> dict:
+    e, h, kh, d = c.hidden, a.heads, a.kv_heads, a.head_dim
+    params = {
+        "wq": normal(keys[0], lead + (e, h, d), e),
+        "wk": normal(keys[1], lead + (e, kh, d), e),
+        "wv": normal(keys[2], lead + (e, kh, d), e),
+        "wo": normal(keys[3], lead + (h, d, e), h * d),
+    }
+    if a.gate == "headwise":
+        params["w_attn_gate"] = normal(jax.random.fold_in(keys[0], 1), lead + (e, h), e)
+    return params
+
+
+def _rope(t, positions, a: GroupedQueryAttention):
+    rotary = a.rotary_dim or a.head_dim
+    if a.yarn is None:
+        return apply_rope(t, positions, theta=a.rope_theta, rotary_dim=rotary)
+    y = a.yarn
+    inv_freq = yarn_frequencies(rotary, theta=a.rope_theta, factor=y.factor,
+                                original_length=y.original_length,
+                                beta_fast=y.beta_fast, beta_slow=y.beta_slow)
+    return apply_rope(t, positions, rotary_dim=rotary, inv_freq=inv_freq,
+                      factor=y.attention_factor)
+
+
+def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=None):
+    """h [B, S, E] (normed) -> (y [B, S, E], aux). ``aux`` is ``{}`` for a
+    full layer and ``window_share`` for a window layer. Under a ``mesh`` the
+    heads shard over tp and the rows over the data axes, as the ``attn``
+    kind's do; the sequence is not split (no ``sp`` path)."""
+    s = h.shape[1]
+    if mesh is not None and mesh.shape["sp"] > 1:
+        raise NotImplementedError("grouped-query attention by spec does not split the "
+                                  "sequence: the mesh's sp is > 1")
+    with device_scope("gqa_win" if a.window else "gqa_full"):
+        q = jnp.einsum("bse,ehd->bhsd", h, layer["wq"])
+        k = jnp.einsum("bse,ehd->bhsd", h, layer["wk"])
+        v = jnp.einsum("bse,ehd->bhsd", h, layer["wv"])
+        q = _rope(q, positions, a)
+        if mesh is not None:
+            q = shard_constraint(q, mesh, ("batch", "heads", "seq", "head_dim"))
+        q = checkpoint_name(q, "q")
+        k = checkpoint_name(_rope(k, positions, a), "k")
+        v = checkpoint_name(v, "v")
+        aux = {}
+        if a.window:
+            # blocks of the window's size: a query block's band is two key blocks
+            attn = flash_per_shard(q, k, v, mesh, causal=True, window=a.window,
+                                   block_q=512, block_k=512)
+            kept = jnp.sum(jnp.minimum(positions.astype(jnp.float32) + 1.0, a.window))
+            aux["window_share"] = kept / (positions.size / s) / (s * (s + 1) / 2)
+        else:
+            attn = flash_per_shard(q, k, v, mesh, causal=True)
+        if a.gate == "headwise":
+            with device_scope("attn_gate"):
+                attn = headwise_gate(h, layer["w_attn_gate"], attn)
+        return jnp.einsum("bhsd,hde->bse", attn, layer["wo"]), aux
+
+
+def _matmul_params(a: GroupedQueryAttention, c) -> float:
+    gate = a.heads if a.gate == "headwise" else 0
+    return c.hidden * (a.head_dim * (2 * a.heads + 2 * a.kv_heads) + gate)
+
+
+def _mixing_flops(a: GroupedQueryAttention, c, seq: int) -> float:
+    """Scores and values, forward, over the keys a query keeps: the causal
+    triangle's, or the band's under a window."""
+    return 2.0 * a.heads * 2 * a.head_dim * kept_keys(seq, a.window or seq)
+
+
+def _kind(field: str) -> LayerKind:
+    spec = lambda c: getattr(c, field)  # noqa: E731
+    return LayerKind(
+        axes=lambda c: _axes(spec(c)),
+        init=lambda c, keys, lead, normal: _init(spec(c), c, keys, lead, normal),
+        apply=lambda h, layer, **kw: gqa_mixer(h, layer, spec(kw["config"]), **kw),
+        matmul_params=lambda c: _matmul_params(spec(c), c),
+        mixing_flops=lambda c, seq: _mixing_flops(spec(c), c, seq),
+        save_names=SAVE_NAMES)
+
+
+GQA = _kind("gqa")
+GQA_WINDOW = _kind("gqa_window")
+
+__all__ = ["GroupedQueryAttention", "Yarn", "GQA", "GQA_WINDOW", "SAVE_NAMES", "gqa_mixer"]

@@ -11,7 +11,15 @@ in the stack.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Callable
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ..ops import flash_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,3 +41,44 @@ class LayerKind:
     mixing_flops: Callable[[Any, int], float] = lambda c, seq: 0.0
     # checkpoint names the ``attn`` remat policy saves for this kind
     save_names: tuple[str, ...] = ()
+
+
+def headwise_gate(h, w_gate, attn):
+    """attn [B, H, S, D] times ``sigmoid(h W_g)``, one number a head and
+    position (the head-wise form of arXiv 2505.06708), in float32; the gate
+    goes under the checkpoint name ``attn_gate``. h [B, S, E] is the mixer's
+    normed input, ``w_gate`` [E, H]."""
+    gate = checkpoint_name(jax.nn.sigmoid(jnp.einsum(
+        "bse,eh->bhs", h, w_gate, preferred_element_type=jnp.float32)), "attn_gate")
+    return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+
+
+def kept_keys(seq: int, width: int) -> float:
+    """Mean keys a query attends when it keeps at most ``width`` of its
+    causal keys."""
+    width = min(width, seq)
+    return (width * (width + 1) / 2 + (seq - width) * width) / seq
+
+
+def flash_per_shard(q, k, v, mesh, **kw):
+    """``flash_attention(q, k, v, **kw)`` on [B, H, S, D]. The compiler cannot
+    partition a Mosaic kernel by itself ("wrap the call in a shard_map"), so
+    under a mesh of more than one device it runs per shard, batch rows over
+    the data axes and kv-head groups over tp: each is independent in
+    attention, so nothing is exchanged. Where the batch or a head count does
+    not divide, the call is left whole."""
+    batch_axes = ("dcn", "dp", "fsdp")
+    if mesh is not None and mesh.size > 1:
+        n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+        tp = mesh.shape["tp"]
+        if not (q.shape[0] % n_batch or q.shape[1] % tp or k.shape[1] % tp):
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            spec = P(batch_axes, "tp", None, None)
+            return shard_map(
+                functools.partial(flash_attention, **kw),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False,
+            )(q, k, v)
+    return flash_attention(q, k, v, **kw)

@@ -3,7 +3,9 @@
 A block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack knows no
 more than that: a token mixer (``attn``: softmax attention, below;
 ``gdn``: Gated DeltaNet, ``models/gdn.py``; ``mla`` / ``mla_win``: latent
-attention under a learned selection of keys or a window, ``models/mla.py``)
+attention under a learned selection of keys or a window, ``models/mla.py``;
+``gqa`` / ``gqa_win``: grouped-query attention whose widths a spec owns,
+whole or under a window, ``models/gqa.py``)
 and an MLP (``dense``, below; ``moe``: the routed experts,
 ``models/moe.py``) are ``LayerKind``s (``models/kinds.py``) that own their
 leaves, logical axes, init, FLOPs, counters and the names a remat policy
@@ -12,7 +14,9 @@ may save. ``layer_pattern`` lists the mixers of one PERIOD of the stack,
 the scan, whose MLP is a dense one of its own width. Llama-3, InternLM2,
 Mistral, Mixtral and OLMoE are a period of one attention block; Qwen3-Next
 is three DeltaNet blocks and one of gated attention; dots3-note-prev is a
-leading dense layer, then an indexed and three window layers.
+leading dense layer, then an indexed and three window layers; Laguna-S-2.1
+a leading dense layer under full attention, then three window layers of 72
+heads and a full one of 48.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -34,7 +38,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any
 
 import jax
@@ -44,11 +47,12 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ..observability.tracing import device_scope
-from ..ops import (flash_attention, mha_reference, ring_attention, rms_norm,
-                   apply_rope, ulysses_attention)
+from ..ops import (mha_reference, ring_attention, rms_norm, apply_rope,
+                   ulysses_attention)
 from ..parallel.sharding import shard_constraint
 from .gdn import GDN
-from .kinds import LayerKind
+from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention, Yarn
+from .kinds import LayerKind, flash_per_shard
 from .mla import MLA, MLA_WINDOW, LatentAttention
 from .moe import MOE, bias_step
 
@@ -97,8 +101,13 @@ class LlamaConfig:
     # Facts of an architecture, as above. Every RMSNorm multiplies by
     # ``1 + w`` and starts ``w`` at zero; q and k are normalised per head,
     # over head_dim, before rope; rope turns only the first ``rotary_dim``
-    # features of a head (0: all of them); the q projection also makes a
-    # per-head gate, and attention's output is multiplied by its sigmoid.
+    # features of a head (0: all of them), by ``rope_theta``'s plain
+    # frequencies; ``attn_out_gate`` is Qwen3-Next's ELEMENT-wise gate: the q
+    # projection is twice as wide, a head's second half is its gate, and
+    # attention's output is multiplied by its sigmoid feature by feature. All
+    # four are the ``attn`` kind's alone: a head-wise gate (one number a
+    # head), YaRN, a window or a second head count belong to a spec
+    # (``gqa`` / ``gqa_window``, ``mla`` / ``mla_window`` below).
     norm_plus_one: bool = False
     head_qk_norm: bool = False
     rotary_dim: int = 0
@@ -122,13 +131,21 @@ class LlamaConfig:
     # leaf no gradient moves and ``update_buffers`` steps by ``moe_bias_rate``
     # from a step's own counts; whether the shared expert is scaled by a
     # sigmoid of its own (Qwen's is; DeepSeek's is not).
+    # ``moe_routed_scale`` multiplies the (normalised) gates of the routed
+    # experts, so their sum's weight against the shared expert's
+    # (``moe_routed_scaling_factor``; Laguna-S-2.1: 2.5).
     moe_score: str = "softmax"
     moe_bias_rate: float = 0.0
     moe_shared_gate: bool = True
+    moe_routed_scale: float = 1.0
     # Latent attention (models/mla.py): the widths of the mixer kinds "mla"
     # (keys chosen by an indexer) and "mla_win" (a causal window).
     mla: LatentAttention | None = None
     mla_window: LatentAttention | None = None
+    # Grouped-query attention by spec (models/gqa.py): the widths of the
+    # mixer kinds "gqa" (every causal key) and "gqa_win" (a causal window).
+    gqa: GroupedQueryAttention | None = None
+    gqa_window: GroupedQueryAttention | None = None
     # Leading layers before the periods, outside the scan: their mixers, in
     # order, and the width of their dense MLP (DeepSeek's
     # ``first_k_dense_replace``). ``n_layers`` counts them.
@@ -198,6 +215,26 @@ PRESETS: dict[str, LlamaConfig] = {
         moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
         moe_score="sigmoid", moe_bias_rate=0.001, moe_shared_gate=False,
         moe_aux_weight=0.0001),
+    # grouped-query attention by spec at test size: a leading dense layer
+    # under a full mixer, then a period of three window layers and a full one;
+    # 6 query heads in the window layers and 4 in the full ones over 2 kv
+    # heads; a window of 5, which drops keys at 16+ positions; YaRN on half of
+    # a head whose factor slows pairs 2-3 at the tests' lengths; a head-wise
+    # gate; softmax routing over 8 experts, top-3 scaled by 2.5, 2 held, a
+    # plain shared expert
+    "window-moe-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=5, n_heads=4, n_kv_heads=2, intermediate=32,
+        head_dim=16, norm_eps=1e-6,
+        layer_pattern=("gqa_win", "gqa_win", "gqa_win", "gqa"),
+        lead_pattern=("gqa",), lead_intermediate=96,
+        gqa=GroupedQueryAttention(
+            heads=4, kv_heads=2, head_dim=16, rope_theta=100.0, rotary_dim=8,
+            yarn=Yarn(factor=8.0, original_length=16, beta_fast=2.0, beta_slow=0.5,
+                      attention_factor=1.2), gate="headwise"),
+        gqa_window=GroupedQueryAttention(
+            heads=6, kv_heads=2, head_dim=16, rope_theta=1e3, window=5, gate="headwise"),
+        moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
+        moe_shared_gate=False, moe_routed_scale=2.5, moe_aux_weight=0.001),
 }
 
 
@@ -349,25 +386,7 @@ def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
         g = q.shape[1] // k.shape[1]
         o = (q.reshape(q.shape[0], k.shape[1], g, *q.shape[2:]) * v[:, :, None]).reshape(q.shape)
         return checkpoint_name(o, "attn_out")
-    batch_axes = ("dcn", "dp", "fsdp")
-    if mesh is not None and mesh.size > 1:
-        n_batch = math.prod(mesh.shape[a] for a in batch_axes)
-        tp = mesh.shape["tp"]
-        if not (q.shape[0] % n_batch or q.shape[1] % tp or k.shape[1] % tp):
-            # The compiler cannot partition a Mosaic kernel by itself
-            # ("wrap the call in a shard_map"): run it per shard, batch
-            # rows over the data axes and kv-head groups over tp — each
-            # is independent in attention, so nothing is exchanged.
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            spec = P(batch_axes, "tp", None, None)
-            return shard_map(
-                functools.partial(flash_attention, causal=True),
-                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_vma=False,
-            )(q, k, v)
-    return flash_attention(q, k, v, causal=True)
+    return flash_per_shard(q, k, v, mesh, causal=True)
 
 
 def _norm_over_heads(t, weight, eps):
@@ -447,7 +466,8 @@ DENSE = LayerKind(
 LEAD_DENSE = LayerKind(
     axes=_dense_axes, init=functools.partial(_dense_init, width="lead_intermediate"),
     apply=_dense_mlp, matmul_params=lambda c: 3.0 * c.hidden * c.lead_intermediate)
-MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW}
+MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW,
+                                "gqa": GQA, "gqa_win": GQA_WINDOW}
 
 
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
@@ -536,7 +556,9 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     holds a share of the experts (``moe_held``), ``rows_per_held_expert``
     [L, count] and ``held_share`` [L] (their sum over all rows). ``{}`` for
     dense configs and on the pipelined path, which does not thread it
-    through the schedule yet. An indexed mixer (``mla``) adds ``index_loss``
+    through the schedule yet. A window mixer that counts its pairs
+    (``gqa_win``) adds ``attn_window_share``, the mean over those layers. An
+    indexed mixer (``mla``) adds ``index_loss``
     and ``attn_selected_share``, each the mean over the indexed layers, and
     with ``return_selection`` the key sets themselves, ``selection``
     [indexed layers, B, S, S] int8 in layer order (for a comparison; a
@@ -647,17 +669,19 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
                        held_share=per_layer["held_share"])
     # what the mixers counted: a leading layer's is a scalar, a period
     # position's [periods]; the mean over every layer that counted it
-    indexed = [m for m in mixed_auxes + mixed if m]
-    if indexed:
-        both = lambda name: jnp.mean(jnp.concatenate(  # noqa: E731
-            [jnp.ravel(m[name]) for m in indexed]))
+    counted = mixed_auxes + mixed
+    both = lambda name: jnp.mean(jnp.concatenate(  # noqa: E731
+        [jnp.ravel(m[name]) for m in counted if name in m]))
+    if any("window_share" in m for m in counted):
+        aux["attn_window_share"] = both("window_share")
+    if any("index_loss" in m for m in counted):
         aux.update(index_loss=both("index_loss"),
                    attn_selected_share=both("selected_share"))
         if return_selection:
             # a leading layer's [B, S, S]; a period position's [periods, B, S, S],
             # its layers ``len(blocks)`` apart
-            sets = [m["selection"][None] for m in mixed_auxes if m]
-            scanned_sets = [m["selection"] for m in mixed if m]
+            sets = [m["selection"][None] for m in mixed_auxes if "selection" in m]
+            scanned_sets = [m["selection"] for m in mixed if "selection" in m]
             if scanned_sets:
                 sets.append(jnp.stack(scanned_sets, axis=1).reshape(
                     (-1,) + scanned_sets[0].shape[1:]))

@@ -49,7 +49,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope, flash_attention, rms_norm
 from ..ops.sparse_index import index_kl, index_scores, select_top_k
-from .kinds import LayerKind
+from .kinds import LayerKind, headwise_gate, kept_keys
 
 SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask",
               "dsa_kl_z", "dsa_kl_lse")
@@ -201,10 +201,7 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
                                block_q=512, block_k=512)
     if a.gate:
         with device_scope("attn_gate"):
-            gate = checkpoint_name(jax.nn.sigmoid(jnp.einsum(
-                "bse,eh->bhs", h, layer["w_attn_gate"],
-                preferred_element_type=jnp.float32)), "attn_gate")
-            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+            attn = headwise_gate(h, layer["w_attn_gate"], attn)
     with device_scope("mla_out"):
         return jnp.einsum("bhsd,hde->bse", attn, layer["wo"]), aux
 
@@ -221,13 +218,6 @@ def _matmul_params(a: LatentAttention, c) -> float:
     index = (a.q_rank * a.index_heads * a.index_dim + e * a.index_dim
              + e * a.index_heads) if a.index_heads else 0
     return main + index * 2.0 / 3.0
-
-
-def kept_keys(seq: int, width: int) -> float:
-    """Mean keys a query attends when it keeps at most ``width`` of its
-    causal keys."""
-    width = min(width, seq)
-    return (width * (width + 1) / 2 + (seq - width) * width) / seq
 
 
 def _mixing_flops(a: LatentAttention, c, seq: int) -> float:

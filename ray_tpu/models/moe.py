@@ -8,6 +8,7 @@ state ``h`` after the block's second RMSNorm, X experts, k chosen:
     (g, e) = top_k(p)                                   k gates, k expert ids
     g      = g / sum(g)          only if ``norm_topk``  (Mixtral: yes; OLMoE:
                                                          ``norm_topk_prob`` false)
+    g      = s g                 ``routed_scale`` s     (Laguna-S-2.1: 2.5; else 1)
     y      = sum_j g_j * W_down[e_j] (silu(W_gate[e_j] h) * W_up[e_j] h)
 
 and, per layer, over the N tokens of the batch:
@@ -181,12 +182,12 @@ _permute.defvjp(lambda values, perm, inv_perm: (values[perm], (inv_perm,)),
 
 
 def route(tokens, router, *, top_k: int, norm_topk: bool, score: str = "softmax",
-          bias=None, n_seqs: int = 1):
+          bias=None, n_seqs: int = 1, scale: float = 1.0):
     """Routing in float32: tokens [N, E] -> a dict of the gates [N, k], the
     sort ``order`` of the N*k rows by expert with its inverse, the rows per
     expert ``sizes`` [X] and the two auxiliary terms. ``score`` "sigmoid"
     with the selection ``bias`` [X] and the tokens' ``n_seqs`` sequences:
-    the module's text."""
+    the module's text. ``scale`` multiplies the gates, after ``norm_topk``."""
     n, n_experts = tokens.shape[0], router.shape[1]
     # float32 in earnest: on a TPU a float32 product runs as one bf16 pass
     # unless asked otherwise, and a router logit off by 2^-8 reorders the
@@ -204,6 +205,8 @@ def route(tokens, router, *, top_k: int, norm_topk: bool, score: str = "softmax"
         gates, expert_idx = jax.lax.top_k(probs, top_k)
     if norm_topk:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gates = gates * scale
     gates = checkpoint_name(gates, "moe_gates")
     flat = expert_idx.reshape(n * top_k)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
@@ -361,7 +364,7 @@ _held_or_all_rows.defvjp(_held_or_all_fwd, _held_or_all_bwd)
 
 def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
               ep_axis: str | None = None, held: tuple[int, int] | None = None,
-              score: str = "softmax"):
+              score: str = "softmax", routed_scale: float = 1.0):
     """x: [B, S, E] -> ([B, S, E], aux). Routing in f32; experts in x.dtype
     with f32 accumulation.
 
@@ -375,14 +378,15 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     X/ep consecutive experts and the whole router. ``held`` = (first,
     count), static, on the plain path: ``params`` hold those experts and
     the whole router, and the result is their part of the routed sum. See
-    the module's text for both.
+    the module's text for both. ``routed_scale``: the routed experts' sum
+    times that much (on their gates), the shared expert plain.
     """
     b, s, e = x.shape
     n = b * s
     tokens = x.reshape(n, e)
     with device_scope("moe_route"):
         r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk, score=score,
-                  bias=params.get("router_bias"), n_seqs=b)
+                  bias=params.get("router_bias"), n_seqs=b, scale=routed_scale)
     sizes, offset = r["sizes"], None
     if ep_axis is not None or held is not None:
         local = params["w_gate"].shape[0]
@@ -432,7 +436,8 @@ def _moe_init(c, keys, lead, normal) -> dict:
 def _moe_apply(h, layer, *, config, mesh=None, ep_axis=None):
     c = config
     return moe_block(h, layer, top_k=c.moe_top_k, norm_topk=c.moe_norm_topk,
-                     ep_axis=ep_axis, held=c.moe_held, score=c.moe_score)
+                     ep_axis=ep_axis, held=c.moe_held, score=c.moe_score,
+                     routed_scale=c.moe_routed_scale)
 
 
 def _moe_matmul_params(c) -> float:

@@ -58,6 +58,23 @@ def _flash(chip, b, hq, hkv, s, d, backward):
     return jax.jit(fn).lower(q, kv, kv)
 
 
+def _grouped_window(chip, backward, b=1, hq=72, hkv=8, s=16384, d=128, window=512):
+    """The window kernels as ``models/gqa.py`` calls them: grouped queries,
+    512-blocks, a band of two key blocks a query block."""
+    q = jax.ShapeDtypeStruct((b, hq, s, d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, hkv, s, d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window, block_q=512, block_k=512,
+                               interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    return jax.jit(fn).lower(q, kv, kv)
+
+
 def _paged_staging(chip, layers, kh, g, d, page, slots=8, max_len=2560, k_steps=32,
                    live_pages=8):
     """The engine's decode call: layer-stacked pool, staging rows of the
@@ -241,6 +258,13 @@ CASES = {
     "dsa-index-bwd-8k": lambda c: _indexer(c, "bwd"),
     "dsa-probs-8k": lambda c: _indexer(c, "probs"),
     "dsa-probs-bwd-8k": lambda c: _indexer(c, "probs_bwd"),
+    # Laguna-S-2.1 at one row of 16,384: the plain kernels at 48 query heads
+    # over 8 kv heads, the window kernels at 72 over 8 (nine a kv head) under
+    # a 512-key band
+    "flash-fwd-48to8-16k": lambda c: _flash(c, 1, 48, 8, 16384, 128, backward=False),
+    "flash-bwd-48to8-16k": lambda c: _flash(c, 1, 48, 8, 16384, 128, backward=True),
+    "attn-win-fwd-72to8-16k": lambda c: _grouped_window(c, backward=False),
+    "attn-win-bwd-72to8-16k": lambda c: _grouped_window(c, backward=True),
 }
 
 
