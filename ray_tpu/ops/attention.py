@@ -1,10 +1,15 @@
 """Flash attention as a Pallas TPU kernel.
 
 Online-softmax tiled attention (Dao et al.) laid out for the MXU: the grid
-iterates (batch, head, q_block, k_block) with the k_block axis innermost —
-TPU grids execute the trailing axis sequentially on-core, so f32
-accumulators live in VMEM scratch across k steps. Inputs stay bf16 for the
-MXU; softmax statistics and the output accumulator are f32.
+iterates (batch, head, tile), and TPU grids execute the trailing axis
+sequentially on-core, so f32 accumulators live in VMEM scratch across a
+row's tiles. The tile axis walks a TABLE (``_tile_walk``, scalar-prefetched
+into SMEM) of the (q block, k block) tiles that hold a pair a query may
+see: the causal triangle, a window's band, or the whole rectangle, query
+block by query block with the key blocks inner (key-major for dK/dV). A
+tile above the diagonal is no grid step, so none is taken and none fetches
+K, V or key sets. Inputs stay bf16 for the MXU; softmax statistics and the
+output accumulator are f32.
 
 The reference has no attention kernel of its own (it delegates all model
 compute to torch/vLLM); this is the TPU-native equivalent of the kernels
@@ -18,6 +23,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -58,15 +64,50 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(probs.dtype)).astype(q.dtype)
 
 
-def _band_first(i, block_i, block_j, back: int):
-    """First j block that rows of i block ``i`` reach ``back`` positions behind."""
-    return jnp.maximum(i * block_i - back, 0) // block_j
+def _tile_walk(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
+               window: int | None, key_major: bool = False):
+    """The tiles a kernel's last grid axis walks: int32 tables (q block, k
+    block, first of its row, last of its row), a row being the accumulator's
+    block: a query block with its key blocks ascending, or for dK/dV
+    (``key_major``) a key block with its query blocks ascending. With them
+    ``(grid_steps, live_steps)``: the tables' length, and how many of the
+    tiles hold a (query, key) pair the in-tile masks allow (q >= k when
+    causal, q - k < window); equal but for a row that no tile serves (lengths
+    that differ), which still takes one step, on its last tile, all masked
+    there: its block is written."""
+    qi, ki = np.indices((n_q, n_k))
+    most = qi * block_q + block_q - 1 - ki * block_k        # a tile's largest q - k
+    least = qi * block_q - (ki * block_k + block_k - 1)     # and its smallest
+    live = np.ones((n_q, n_k), bool)
+    if causal:
+        live = most >= 0
+        if window is not None:
+            live &= least < window
+    walked = live.T.copy() if key_major else live.copy()
+    walked[~walked.any(axis=1), -1] = True
+    rows, cols = np.nonzero(walked)                         # row by row, ascending
+    edge = rows[1:] != rows[:-1]
+    first, last = np.append(True, edge), np.append(edge, True)
+    q_blocks, k_blocks = (cols, rows) if key_major else (rows, cols)
+    return (tuple(t.astype(np.int32) for t in (q_blocks, k_blocks, first, last)),
+            (len(rows), int(live.sum())))
 
 
-def _band_steps(n_i: int, block_i: int, block_j: int, back: int, ahead: int, n_j: int) -> int:
-    """Most j blocks any i block's band [first - back, last + ahead] meets."""
-    return max(min((i * block_i + block_i - 1 + ahead) // block_j, n_j - 1)
-               - max(i * block_i - back, 0) // block_j + 1 for i in range(n_i))
+def _tile_index_maps(rep: int):
+    """Index maps of a q-shaped operand [B, Hq, Sq, *] and of K / V [B, Hkv,
+    Sk, *], whose block a step takes from the walk's tables (scalar-prefetched
+    refs trail the grid's indices). GQA: a query head maps to its kv head in
+    the index map, no repeated K/V materialization in HBM."""
+    return (lambda bi, hi, t, qs, ks, *_: (bi, hi, qs[t], 0),
+            lambda bi, hi, t, qs, ks, *_: (bi, hi // rep, ks[t], 0))
+
+
+def _step(walk):
+    """This grid step's (q block, k block, first of its row, last of its row),
+    read from the walk's tables in SMEM."""
+    t = pl.program_id(2)
+    q_blocks, k_blocks, first, last = walk
+    return q_blocks[t], k_blocks[t], first[t] == 1, last[t] == 1
 
 
 def _allowed(s, q_start, k_start, causal, window, mask, q_axis: int):
@@ -85,63 +126,50 @@ def _allowed(s, q_start, k_start, causal, window, mask, q_axis: int):
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale, causal, block_q, block_k, n_k, window=None, n_steps=None
+    walk, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, sm_scale, causal, block_q, block_k, window=None
 ):
-    ki = pl.program_id(3)
-    qi = pl.program_id(2)
+    qi, ki, first, last = _step(walk)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q_start = qi * block_q
-    if window is None:
-        k_start = ki * block_k
-        # causal: skip blocks strictly above the diagonal
-        needed = jnp.logical_or(
-            jnp.logical_not(causal), k_start <= q_start + block_q - 1
-        )
+    k_start = ki * block_k
+
+    # Keep q/k/v in bf16 for the MXU (f32 inputs would run the MXU at a
+    # fraction of peak); accumulate in f32 via preferred_element_type.
+    q = q_ref[0, 0]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    s = s * sm_scale
+    if causal and window is None and mask_ref is None:
+        q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_ids >= k_ids, s, NEG_INF)
     else:
-        # the grid's last axis walks only the band's k blocks
-        kb = _band_first(qi, block_q, block_k, window - 1) + ki
-        k_start = kb * block_k
-        needed = k_start <= q_start + block_q - 1
+        s = _allowed(s, q_start, k_start, causal, window,
+                     None if mask_ref is None else mask_ref[0], 0)
+    m_prev = m_ref[:]
+    m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1] -> broadcast over lanes
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+    p = jnp.exp(s - m_new[:, :1])
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(
+        jnp.sum(p, axis=1, keepdims=True), l_ref.shape
+    )
+    acc_ref[:] = acc_ref[:] * alpha[:, :1] + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32
+    )
+    m_ref[:] = m_new
 
-    @pl.when(needed)
-    def _compute():
-        # Keep q/k/v in bf16 for the MXU (f32 inputs would run the MXU at a
-        # fraction of peak); accumulate in f32 via preferred_element_type.
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * sm_scale
-        if causal and window is None and mask_ref is None:
-            q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids >= k_ids, s, NEG_INF)
-        else:
-            s = _allowed(s, q_start, k_start, causal, window,
-                         None if mask_ref is None else mask_ref[0], 0)
-        m_prev = m_ref[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1] -> broadcast over lanes
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        p = jnp.exp(s - m_new[:, :1])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_ref.shape
-        )
-        acc_ref[:] = acc_ref[:] * alpha[:, :1] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_ref[:] = m_new
-
-    @pl.when(ki == (n_k if n_steps is None else n_steps) - 1)
+    @pl.when(last)
     def _final():
         o_ref[0, 0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
         if lse_ref is not None:
@@ -210,51 +238,37 @@ def _flash_forward(
         o = mha_reference(q, k, v, causal=causal, sm_scale=scale, window=window, mask=mask)
         return (o, None) if save_residuals else o
     note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
+    walk, steps = _tile_walk(sq // block_q, sk // block_k, block_q, block_k, causal, window)
     if variant is None and dv == d:
-        note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals)
+        note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals, steps=steps)
     else:
         note_attention_cost("fwd", variant, q, k, v,
                             _kept_pairs(sq, sk, causal, window, top_k),
-                            residuals=save_residuals, masked=mask is not None)
-    n_q, n_k = sq // block_q, sk // block_k
-
-    n_steps = None if window is None else _band_steps(
-        n_q, block_q, block_k, window - 1, 0, n_k)
-    grid = (b, hq, n_q, n_k if window is None else n_steps)
+                            residuals=save_residuals, masked=mask is not None, steps=steps)
     inner = functools.partial(
         _flash_kernel,
         sm_scale=scale,
         causal=causal,
         block_q=block_q,
         block_k=block_k,
-        n_k=n_k,
         window=window,
-        n_steps=n_steps,
     )
 
-    def kernel(q_ref, k_ref, v_ref, *refs):
+    def kernel(*refs):
+        walk, (q_ref, k_ref, v_ref), refs = refs[:4], refs[4:7], refs[7:]
         mask_ref, refs = (refs[0], refs[1:]) if mask is not None else (None, refs)
         o_ref, lse_ref, refs = ((refs[0], refs[1], refs[2:]) if save_residuals
                                 else (refs[0], None, refs[1:]))
-        inner(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *refs)
+        inner(walk, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *refs)
 
-    if window is None:
-        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
-    else:
-        def kv_index(bi, hi, qi, ki):
-            kb = _band_first(qi, block_q, block_k, window - 1) + ki
-            return (bi, hi // rep, jnp.minimum(kb, n_k - 1), 0)
-
-    out_specs = [pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0))]
+    q_index, kv_index = _tile_index_maps(rep)
+    out_specs = [pl.BlockSpec((1, 1, block_q, dv), q_index)]
     out_shape = [jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)]
     if save_residuals:
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q, 128), lambda bi, hi, qi, ki: (bi, hi, qi, 0)))
+        out_specs.append(pl.BlockSpec((1, 1, block_q, 128), q_index))
         out_shape.append(jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32))
     in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        # GQA: map query head to its kv head in the index_map — no
-        # repeated K/V materialization in HBM
+        pl.BlockSpec((1, 1, block_q, d), q_index),
         pl.BlockSpec((1, 1, block_k, d), kv_index),
         pl.BlockSpec((1, 1, block_k, dv), kv_index),
     ]
@@ -262,22 +276,25 @@ def _flash_forward(
     if mask is not None:
         # one key set a query row, shared by the heads of a batch row
         in_specs.append(pl.BlockSpec((1, block_q, block_k),
-                                     lambda bi, hi, qi, ki: (bi, qi, ki)))
+                                     lambda bi, hi, t, qs, ks, *_: (bi, qs[t], ks[t])))
         operands += (mask,)
     result = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs if save_residuals else out_specs[0],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, hq, steps[0]),
+            in_specs=in_specs,
+            out_specs=out_specs if save_residuals else out_specs[0],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
+        ),
         out_shape=out_shape if save_residuals else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_fwd" if variant is None else f"attn_{variant}_fwd",
-    )(*operands)
+    )(*walk, *operands)
     return result
 
 
@@ -305,82 +322,55 @@ def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start,
     return p_t, ds_t
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref, dq_ref,
-                   acc_ref, *, sm_scale, causal, block_q, block_k, n_k,
-                   window=None, n_steps=None):
-    """dQ: for one q block, accumulate dS @ K over all k blocks (k axis
-    innermost → sequential on-core, acc lives in VMEM)."""
-    ki = pl.program_id(3)
-    qi = pl.program_id(2)
+def _bwd_dq_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref, dq_ref,
+                   acc_ref, *, sm_scale, causal, block_q, block_k, window=None):
+    """dQ: for one q block, accumulate dS @ K over its k blocks (the walk is
+    query-major: a row's tiles run in sequence on-core, acc lives in VMEM)."""
+    qi, ki, first, last = _step(walk)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q_start = qi * block_q
-    if window is None:
-        k_start = ki * block_k
-        needed = jnp.logical_or(jnp.logical_not(causal), k_start <= q_start + block_q - 1)
-    else:
-        k_start = (_band_first(qi, block_q, block_k, window - 1) + ki) * block_k
-        needed = k_start <= q_start + block_q - 1
+    k = k_ref[0, 0]
+    _, ds_t = _bwd_probs_t(
+        q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0], lse_ref[0, 0, 0],
+        delta_ref[0, 0, 0], sm_scale=sm_scale, causal=causal,
+        q_start=qi * block_q, k_start=ki * block_k, window=window,
+        mask_t=None if mask_ref is None else mask_ref[0])
+    acc_ref[:] += jax.lax.dot_general(
+        ds_t, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )                                                  # [bq, d]
 
-    @pl.when(needed)
-    def _compute():
-        k = k_ref[0, 0]
-        _, ds_t = _bwd_probs_t(
-            q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0], lse_ref[0, 0, 0],
-            delta_ref[0, 0, 0], sm_scale=sm_scale, causal=causal,
-            q_start=q_start, k_start=k_start, window=window,
-            mask_t=None if mask_ref is None else mask_ref[0])
-        acc_ref[:] += jax.lax.dot_general(
-            ds_t, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                  # [bq, d]
-
-    @pl.when(ki == (n_k if n_steps is None else n_steps) - 1)
+    @pl.when(last)
     def _final():
         dq_ref[0, 0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
+def _bwd_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, sm_scale, causal, block_q, block_k, n_q,
-                     window=None, n_steps=None):
-    """dK/dV: for one k block, accumulate over all q blocks (q axis
-    innermost). P^T and dS^T come out k-major, so both products are
+                     *, sm_scale, causal, block_q, block_k, window=None):
+    """dK/dV: for one k block, accumulate over its q blocks (the walk is
+    key-major). P^T and dS^T come out k-major, so both products are
     plain [bk, bq] @ [bq, d] — no transposes materialize."""
-    qi = pl.program_id(3)
-    ki = pl.program_id(2)
+    qi, ki, first, last = _step(walk)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if window is None:
-        q_start = qi * block_q
-        k_start = ki * block_k
-        needed = jnp.logical_or(jnp.logical_not(causal), q_start + block_q - 1 >= k_start)
-    else:
-        # the grid's last axis walks only the q blocks whose band meets this k block
-        k_start = ki * block_k
-        q_start = (_band_first(ki, block_k, block_q, 0) + qi) * block_q
-        needed = q_start <= jnp.minimum(k_start + block_k - 1 + window - 1,
-                                        (n_q - 1) * block_q)
+    q = q_ref[0, 0]
+    g = g_ref[0, 0]
+    p_t, ds_t = _bwd_probs_t(
+        q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
+        sm_scale=sm_scale, causal=causal, q_start=qi * block_q, k_start=ki * block_k,
+        window=window, mask_t=None if mask_ref is None else mask_ref[0])
+    dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g,
+                             preferred_element_type=jnp.float32)  # [bk, dv]
+    dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0]
-        g = g_ref[0, 0]
-        p_t, ds_t = _bwd_probs_t(
-            q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
-            sm_scale=sm_scale, causal=causal, q_start=q_start, k_start=k_start,
-            window=window, mask_t=None if mask_ref is None else mask_ref[0])
-        dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g,
-                                 preferred_element_type=jnp.float32)  # [bk, dv]
-        dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
-
-    @pl.when(qi == (n_q if n_steps is None else n_steps) - 1)
+    @pl.when(last)
     def _final():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -407,97 +397,74 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
     lse = lse.reshape(b, hq, n_q, 1, block_q)
     delta = delta.reshape(b, hq, n_q, 1, block_q)
 
+    # the same tiles in two orders: dQ's accumulator is a query block's,
+    # dK/dV's a key block's
+    dq_walk, dq_steps = _tile_walk(n_q, n_k, block_q, block_k, causal, window)
+    dkdv_walk, dkdv_steps = _tile_walk(n_q, n_k, block_q, block_k, causal, window,
+                                       key_major=True)
     if variant is None and dv_width == d:
-        note_flash_cost("flash_bwd_dq", q, k, causal=causal)
-        note_flash_cost("flash_bwd_dkdv", q, k, causal=causal)
+        note_flash_cost("flash_bwd_dq", q, k, causal=causal, steps=dq_steps)
+        note_flash_cost("flash_bwd_dkdv", q, k, causal=causal, steps=dkdv_steps)
     else:
         pairs = _kept_pairs(sq, sk, causal, window, top_k)
-        note_attention_cost("bwd_dq", variant, q, k, v, pairs, masked=mask is not None)
-        note_attention_cost("bwd_dkdv", variant, q, k, v, pairs, masked=mask is not None)
+        note_attention_cost("bwd_dq", variant, q, k, v, pairs, masked=mask is not None,
+                            steps=dq_steps)
+        note_attention_cost("bwd_dkdv", variant, q, k, v, pairs, masked=mask is not None,
+                            steps=dkdv_steps)
     prefix = "flash" if variant is None else f"attn_{variant}"
 
+    # one set of specs for both kernels: each reads its own walk's tables
+    q_index, kv_index = _tile_index_maps(rep)
+    # dK / dV leave at the query heads' count
+    k_index = lambda bi, hi, t, qs, ks, *_: (bi, hi, ks[t], 0)  # noqa: E731
+    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
+                            lambda bi, hi, t, qs, ks, *_: (bi, hi, qs[t], 0, 0))
+    in_specs = [
+        pl.BlockSpec((1, 1, block_q, d), q_index),
+        pl.BlockSpec((1, 1, block_k, d), kv_index),
+        pl.BlockSpec((1, 1, block_k, dv_width), kv_index),
+        pl.BlockSpec((1, 1, block_q, dv_width), q_index),
+        row_spec, row_spec,
+    ]
     operands = (q, k, v, g, lse, delta)
-    mask_spec_qk, mask_spec_kq = [], []
     if mask is not None:
         # k-major, as the tiles are: the transpose is an XLA pass over int8
         operands += (jnp.swapaxes(mask, 1, 2),)
-        mask_spec_qk = [pl.BlockSpec((1, block_k, block_q),
-                                     lambda bi, hi, qi, ki: (bi, ki, qi))]
-        mask_spec_kq = [pl.BlockSpec((1, block_k, block_q),
-                                     lambda bi, hi, ki, qi: (bi, ki, qi))]
+        in_specs.append(pl.BlockSpec((1, block_k, block_q),
+                                     lambda bi, hi, t, qs, ks, *_: (bi, ks[t], qs[t])))
 
-    def with_mask(kernel, n_in=6):
-        if mask is not None:
-            return kernel
-        return lambda *refs: kernel(*refs[:n_in], None, *refs[n_in:])
+    def call(kernel, walk, out_specs, out_shape, scratch_shapes, name):
+        def body(*refs):
+            ins, rest = refs[4:10], refs[10:]
+            mask_ref, rest = (rest[0], rest[1:]) if mask is not None else (None, rest)
+            kernel(refs[:4], *ins, mask_ref, *rest, sm_scale=scale, causal=causal,
+                   block_q=block_q, block_k=block_k, window=window)
 
-    if window is None:
-        dq_steps = dkdv_steps = None
-        kv_index = lambda bi, hi, qi, ki: (bi, hi // rep, ki, 0)  # noqa: E731
-    else:
-        dq_steps = _band_steps(n_q, block_q, block_k, window - 1, 0, n_k)
-        dkdv_steps = _band_steps(n_k, block_k, block_q, 0, window - 1, n_q)
+        return pl.pallas_call(
+            body,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(b, hq, len(walk[0])), in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=scratch_shapes),
+            out_shape=out_shape,
+            interpret=interpret,
+            name=name,
+        )(*walk, *operands)
 
-        def kv_index(bi, hi, qi, ki):
-            kb = _band_first(qi, block_q, block_k, window - 1) + ki
-            return (bi, hi // rep, jnp.minimum(kb, n_k - 1), 0)
-
-    def q_of(ki, qi):
-        if window is None:
-            return qi
-        return jnp.minimum(_band_first(ki, block_k, block_q, 0) + qi, n_q - 1)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0))
-    g_spec = pl.BlockSpec((1, 1, block_q, dv_width),
-                          lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
-    v_spec = pl.BlockSpec((1, 1, block_k, dv_width),
-                          lambda bi, hi, ki, qi: (bi, hi // rep, ki, 0))
-    row_spec = pl.BlockSpec((1, 1, 1, 1, block_q),
-                            lambda bi, hi, ki, qi: (bi, hi, q_of(ki, qi), 0, 0))
-
-    dq = pl.pallas_call(
-        with_mask(functools.partial(
-            _bwd_dq_kernel, sm_scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, n_k=n_k, window=window, n_steps=dq_steps)),
-        grid=(b, hq, n_q, n_k if window is None else dq_steps),  # k innermost
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, dv_width), kv_index),
-            pl.BlockSpec((1, 1, block_q, dv_width), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
-        ] + mask_spec_qk,
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name=f"{prefix}_bwd_dq",
-    )(*operands)
-
-    dk, dv = pl.pallas_call(
-        with_mask(functools.partial(
-            _bwd_dkdv_kernel, sm_scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, n_q=n_q, window=window,
-            n_steps=dkdv_steps)),
-        grid=(b, hq, n_k, n_q if window is None else dkdv_steps),  # q innermost
-        in_specs=[q_spec, k_spec, v_spec, g_spec, row_spec, row_spec] + mask_spec_kq,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, dv_width), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hq, sk, dv_width), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv_width), jnp.float32),
-        ],
-        interpret=interpret,
-        name=f"{prefix}_bwd_dkdv",
-    )(*operands)
+    dq = call(
+        _bwd_dq_kernel, dq_walk,
+        pl.BlockSpec((1, 1, block_q, d), q_index),
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((block_q, d), jnp.float32)],
+        f"{prefix}_bwd_dq")
+    dk, dv = call(
+        _bwd_dkdv_kernel, dkdv_walk,
+        [pl.BlockSpec((1, 1, block_k, d), k_index),
+         pl.BlockSpec((1, 1, block_k, dv_width), k_index)],
+        [jax.ShapeDtypeStruct((b, hq, sk, d), k.dtype),
+         jax.ShapeDtypeStruct((b, hq, sk, dv_width), v.dtype)],
+        [pltpu.VMEM((block_k, d), jnp.float32),
+         pltpu.VMEM((block_k, dv_width), jnp.float32)],
+        f"{prefix}_bwd_dkdv")
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(b, hkv, rep, sk, dv_width).sum(axis=2).astype(v.dtype)
@@ -662,9 +629,9 @@ def flash_attention(
     Three static facts give other kernels, told apart on the op line by
     their names; with none of them the kernels are the plain ones:
     ``window`` (causal only): query t sees keys t - window + 1 .. t, and the
-    grid walks only the blocks the band meets (``attn_win_*``); ``mask``
+    grid walks only the tiles the band meets (``attn_win_*``); ``mask``
     [B, Sq, Sk] int8: one key set a query row, shared by the heads of a
-    batch row, under which the whole causal triangle is walked
+    batch row, under which the whole causal triangle's tiles are walked
     (``attn_sel_*``; ``top_k``, the most keys a set holds, only sizes the
     useful work that ``kernel_costs()`` records). ``return_lse`` also
     returns each query's logsumexp over its keys, [B, Hq, S] float32.

@@ -57,18 +57,24 @@ def kernel_traces() -> dict[str, int]:
         return dict(_counts)
 
 
-def note_kernel_cost(kernel: str, flops: float, nbytes: float) -> None:
+def note_kernel_cost(kernel: str, flops: float, nbytes: float,
+                     steps: tuple[int, int] | None = None) -> None:
     """Record what ONE call of the Pallas kernel named ``kernel`` costs at
-    the shapes it was just traced with (the last trace wins)."""
+    the shapes it was just traced with (the last trace wins). ``steps``, for
+    a kernel whose last grid axis walks tiles: how many steps a (batch, head)
+    takes, and how many of them compute (``grid_steps``, ``live_steps``)."""
     with _lock:
         traced = _costs.get(kernel, {}).get("traced", 0) + 1
         _costs[kernel] = {"traced": traced, "flops": float(flops),
                           "bytes": float(nbytes)}
+        if steps is not None:
+            _costs[kernel].update(grid_steps=int(steps[0]), live_steps=int(steps[1]))
 
 
 def kernel_costs() -> dict[str, dict]:
     """``{"<kernel name>": {"traced": n, "flops": ..., "bytes": ...}}``:
-    per call, at the shapes of the kernel's latest trace in this process."""
+    per call, at the shapes of the kernel's latest trace in this process; the
+    attention kernels' entries also hold ``grid_steps`` and ``live_steps``."""
     with _lock:
         return {k: dict(v) for k, v in _costs.items()}
 
@@ -77,7 +83,7 @@ _FLASH_MATMULS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkdv": 4}
 
 
 def note_flash_cost(kernel: str, q, k, *, causal: bool,
-                    residuals: bool = True) -> None:
+                    residuals: bool = True, steps=None) -> None:
     """Record one call of a flash kernel on q [B,Hq,Sq,D], k/v [B,Hkv,Sk,D].
     Each [Sq,Sk] x D matmul is 2*B*Hq*Sq*Sk*D FLOPs: the forward has two
     (QK^T, PV), dQ three (QK^T, dO V^T, dS K), dK/dV four (QK^T, P^T dO,
@@ -99,14 +105,14 @@ def note_flash_cost(kernel: str, q, k, *, causal: bool,
         "flash_bwd_dkdv": 2 * q_b + kv_b + 2 * stats
         + 2 * b * hq * sk * d * k.dtype.itemsize,
     }[kernel]
-    note_kernel_cost(kernel, flops, nbytes)
+    note_kernel_cost(kernel, flops, nbytes, steps)
 
 
 _ATTENTION_WIDTHS = {"fwd": (1, 1), "bwd_dq": (2, 1), "bwd_dkdv": (2, 2)}
 
 
 def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *,
-                        residuals: bool = True, masked: bool = False) -> None:
+                        residuals: bool = True, masked: bool = False, steps=None) -> None:
     """Record one call of an attention kernel variant (``attn_win_*``: a
     window; ``attn_sel_*``: a key set a query row; a plain kernel whose value
     head is narrower than its key head keeps its ``flash_*`` name). FLOPs are
@@ -131,4 +137,4 @@ def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *
         + b * hq * sk * (d + dv) * k.dtype.itemsize,
     }[part] + (b * sq * sk if masked else 0)
     name = f"flash_{part}" if variant is None else f"attn_{variant}_{part}"
-    note_kernel_cost(name, flops, nbytes)
+    note_kernel_cost(name, flops, nbytes, steps)

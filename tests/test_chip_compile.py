@@ -323,16 +323,18 @@ def _without_locations(lowered_text: str) -> str:
     return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
 
 
-PARENT_FLASH_KERNELS = "0eff5c1ee0ab1f2c71c166e8b7910b0b129da9fb2a87bdf78d310850dfab8be6"
+PLAIN_FLASH_KERNELS = "c344d17d2ea9862d6718e08c33b46ddcb50b010c808173cd60234679b8e19a23"
 
 
 def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(chip):
     """internlm2-1.8b's attention at the benchmark's 2 x 4096, forward and both
     backward kernels: the lowered program, its three Mosaic modules printed
-    without source locations, is what the commit before the window, the key
-    set and the narrower value head was (PR 33's tree, 2376b56, lowered here
-    the same way: the three modules' text, 74,932 characters, by its SHA-256). A kernel variant must leave the plain
-    path's traced instructions, their order included, as they were."""
+    without source locations, is what PR 40's tree lowered here the same way
+    (by its SHA-256). PR 40 moved the anchor: the grid's last axis walks a
+    table of live tiles, so the modules read their tile from SMEM and hold
+    no ``needed`` branch (until then the anchor was PR 33's tree, 2376b56,
+    0eff5c1e...). A kernel variant must leave the plain path's traced
+    instructions, their order included, as they were."""
     import hashlib
 
     q = jax.ShapeDtypeStruct((2, 16, 4096, 128), jnp.bfloat16, sharding=chip)
@@ -347,7 +349,7 @@ def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(ch
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).as_text(debug_info=False))
     kernels = re.findall(r"BODY<<.*?>>BODY", text, flags=re.S)
     assert len(kernels) == 3
-    assert hashlib.sha256("".join(kernels).encode()).hexdigest() == PARENT_FLASH_KERNELS, (
+    assert hashlib.sha256("".join(kernels).encode()).hexdigest() == PLAIN_FLASH_KERNELS, (
         [len(k) for k in kernels])
 
 

@@ -13,6 +13,8 @@ import numpy as np
 import optax
 import pytest
 
+import jaxpr_walk
+
 from benchmark.reference import latent_sparse_decoder as ref
 from ray_tpu.models import PRESETS, init_params, loss_fn, update_buffers
 from ray_tpu.models.llama import MIXERS, forward, train_flops_per_token
@@ -84,12 +86,18 @@ def test_attention_kernels_under_masks_match_mha_reference(kw, dims):
 
 
 def test_window_kernels_walk_only_the_band():
-    from ray_tpu.ops.attention import _band_steps
+    from ray_tpu.ops.attention import _tile_walk
 
     # 8k rows in 512-blocks under a 513-wide window: two key blocks a query
-    # block, two query blocks a key block, of sixteen
-    assert _band_steps(16, 512, 512, 512, 0, 16) == 2
-    assert _band_steps(16, 512, 512, 0, 512, 16) == 2
+    # block, two query blocks a key block, of sixteen (one in the first and
+    # the last row: 31 tiles of 256, every one of them live)
+    for key_major in (False, True):
+        (q_blocks, k_blocks, first, last), steps = _tile_walk(
+            16, 16, 512, 512, True, 513, key_major=key_major)
+        rows = k_blocks if key_major else q_blocks
+        assert np.bincount(rows).max() == 2 and (len(rows), len(rows)) == steps == (31, 31)
+        assert (q_blocks - k_blocks).tolist() == [0] + [1, 0] * 15
+        assert first.sum() == last.sum() == 16
 
 
 def test_index_scores_and_selection_match_plain_jnp(monkeypatch):
@@ -169,20 +177,6 @@ def test_index_kl_and_its_gradient_match_the_plain_form(case):
     assert not any(np.asarray(x).any() for x in others)
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold, a
-    Pallas kernel's own body left out."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name == "pallas_call":
-            continue
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _equations(sub)
-
-
 def test_the_indexers_loss_is_one_kernel_each_way_and_none_again_under_remat(params):
     """The differentiated, remat-ed stack (a leading full layer and a scanned
     one): the forward kernel once a full layer, the backward kernel once, no
@@ -193,7 +187,7 @@ def test_the_indexers_loss_is_one_kernel_each_way_and_none_again_under_remat(par
     tokens = jnp.zeros((1, 48), jnp.int32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16)))(params)
-    equations = list(_equations(jaxpr.jaxpr))
+    equations = list(jaxpr_walk.equations(jaxpr.jaxpr))
     kernels = [str(e.params["name"]) for e in equations if e.primitive.name == "pallas_call"]
     assert kernels.count("dsa_probs") == 2 and kernels.count("dsa_probs_bwd") == 2
     # what else an indexed layer runs, for scale: the scores again under remat
