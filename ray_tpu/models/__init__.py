@@ -4,16 +4,17 @@ params with f32 statistics, logical-axis shardings from ``ray_tpu.parallel``.
 Token mixers: softmax attention through the Pallas flash kernels or ring
 attention (with its variants: q/k norms, partial rope, an output gate), and
 Gated DeltaNet (``gdn.py``: a chunked delta-rule scan as Pallas kernels),
-latent attention (``mla.py``: low-rank q and kv, keys chosen by a learned
-indexer or a causal window, a head-wise gate), grouped-query attention by
-spec (``gqa.py``: a head count, a rope, plain or YaRN, and a window of a
-kind's own, a head-wise gate). MLPs: dense SwiGLU, or with
+latent attention (``mla.py``: low-rank q and kv; three choices of keys: a
+learned indexer's top-k, a causal window, every causal key; a head-wise gate;
+YaRN with its factor on the softmax scale), grouped-query attention by
+spec (``gqa.py``: a head count, a rope, plain or YaRN with its factor on cos
+and sin, and a window of a kind's own, a head-wise gate). MLPs: dense SwiGLU, or with
 ``moe_experts > 0`` a routed expert layer (``moe.py``: dropless, the
 (token, expert) rows sorted by expert over a Pallas grouped matmul, a softmax
 or a sigmoid router with its selection bias, a routed scale, a shared expert,
 a chip's share of the experts); leading layers may have an MLP kind of their own.
-Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next, dots3-note-prev and
-Laguna-S-2.1 are configurations."""
+Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next, dots3-note-prev,
+Laguna-S-2.1 and Kimi-K2 are configurations."""
 
 from .llama import (
     LlamaConfig,

@@ -46,23 +46,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.tracing import device_scope
 from ..ops import apply_rope
-from ..ops.rope import yarn_frequencies
 from ..parallel.sharding import shard_constraint
-from .kinds import LayerKind, flash_per_shard, headwise_gate, kept_keys
+from .kinds import LayerKind, Yarn, flash_per_shard, headwise_gate, kept_keys, rope_keywords
 
 SAVE_NAMES = ("q", "k", "v", "attn_out", "attn_lse", "attn_gate")
-
-
-@dataclasses.dataclass(frozen=True)
-class Yarn:
-    """YaRN's published parameters (a ``rope_parameters`` group of rope_type
-    ``yarn``)."""
-
-    factor: float
-    original_length: int
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,14 +99,8 @@ def _init(a: GroupedQueryAttention, c, keys, lead, normal) -> dict:
 
 def _rope(t, positions, a: GroupedQueryAttention):
     rotary = a.rotary_dim or a.head_dim
-    if a.yarn is None:
-        return apply_rope(t, positions, theta=a.rope_theta, rotary_dim=rotary)
-    y = a.yarn
-    inv_freq = yarn_frequencies(rotary, theta=a.rope_theta, factor=y.factor,
-                                original_length=y.original_length,
-                                beta_fast=y.beta_fast, beta_slow=y.beta_slow)
-    return apply_rope(t, positions, rotary_dim=rotary, inv_freq=inv_freq,
-                      factor=y.attention_factor)
+    return apply_rope(t, positions, rotary_dim=rotary,
+                      **rope_keywords(rotary, a.rope_theta, a.yarn))
 
 
 def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=None):

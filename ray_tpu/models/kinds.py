@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import flash_attention
+from ..ops.rope import yarn_frequencies
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +42,29 @@ class LayerKind:
     mixing_flops: Callable[[Any, int], float] = lambda c, seq: 0.0
     # checkpoint names the ``attn`` remat policy saves for this kind
     save_names: tuple[str, ...] = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's published parameters (a ``rope_parameters`` / ``rope_scaling``
+    group of type ``yarn``)."""
+
+    factor: float
+    original_length: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def rope_keywords(rotated: int, theta: float, yarn: Yarn | None) -> dict:
+    """``ops.apply_rope``'s keywords for the frequencies a kind's spec states
+    over its ``rotated`` features: ``theta``'s plain ones, or under ``yarn``
+    YaRN's blended ones with its ``attention_factor`` on cos and sin."""
+    if yarn is None:
+        return {"theta": theta}
+    return {"inv_freq": yarn_frequencies(
+        rotated, theta=theta, factor=yarn.factor, original_length=yarn.original_length,
+        beta_fast=yarn.beta_fast, beta_slow=yarn.beta_slow), "factor": yarn.attention_factor}
 
 
 def headwise_gate(h, w_gate, attn):

@@ -2,8 +2,9 @@
 
 A block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack knows no
 more than that: a token mixer (``attn``: softmax attention, below;
-``gdn``: Gated DeltaNet, ``models/gdn.py``; ``mla`` / ``mla_win``: latent
-attention under a learned selection of keys or a window, ``models/mla.py``;
+``gdn``: Gated DeltaNet, ``models/gdn.py``; ``mla`` / ``mla_win`` / ``mla_full``:
+latent attention under a learned selection of keys, a window or over every
+causal key, ``models/mla.py``;
 ``gqa`` / ``gqa_win``: grouped-query attention whose widths a spec owns,
 whole or under a window, ``models/gqa.py``)
 and an MLP (``dense``, below; ``moe``: the routed experts,
@@ -16,7 +17,8 @@ Mistral, Mixtral and OLMoE are a period of one attention block; Qwen3-Next
 is three DeltaNet blocks and one of gated attention; dots3-note-prev is a
 leading dense layer, then an indexed and three window layers; Laguna-S-2.1
 a leading dense layer under full attention, then three window layers of 72
-heads and a full one of 48.
+heads and a full one of 48; Kimi-K2 a leading dense layer and a period of
+one expert layer, all under latent attention over every causal key.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -38,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -51,9 +54,9 @@ from ..ops import (mha_reference, ring_attention, rms_norm, apply_rope,
                    ulysses_attention)
 from ..parallel.sharding import shard_constraint
 from .gdn import GDN
-from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention, Yarn
-from .kinds import LayerKind, flash_per_shard
-from .mla import MLA, MLA_WINDOW, LatentAttention
+from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention
+from .kinds import LayerKind, Yarn, flash_per_shard
+from .mla import MLA, MLA_FULL, MLA_WINDOW, LatentAttention, LatentAttentionYarn
 from .moe import MOE, bias_step
 
 
@@ -139,9 +142,11 @@ class LlamaConfig:
     moe_shared_gate: bool = True
     moe_routed_scale: float = 1.0
     # Latent attention (models/mla.py): the widths of the mixer kinds "mla"
-    # (keys chosen by an indexer) and "mla_win" (a causal window).
+    # (keys chosen by an indexer), "mla_win" (a causal window) and "mla_full"
+    # (every causal key).
     mla: LatentAttention | None = None
     mla_window: LatentAttention | None = None
+    mla_full: LatentAttention | None = None
     # Grouped-query attention by spec (models/gqa.py): the widths of the
     # mixer kinds "gqa" (every causal key) and "gqa_win" (a causal window).
     gqa: GroupedQueryAttention | None = None
@@ -235,6 +240,23 @@ PRESETS: dict[str, LlamaConfig] = {
             heads=6, kv_heads=2, head_dim=16, rope_theta=1e3, window=5, gate="headwise"),
         moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
         moe_shared_gate=False, moe_routed_scale=2.5, moe_aux_weight=0.001),
+    # latent attention over every causal key at test size: a leading dense
+    # layer, then a period of one expert layer; 4 heads of 8 + 4 query and key
+    # features and 6 value features; YaRN in latent attention's form (factor 8
+    # over 16 positions slows the second of the two pairs 8-fold, cos and sin
+    # plain, the softmax scale times (0.1 ln 8 + 1)^2); a sigmoid router with
+    # its bias over 12 experts, top-3 renormalised then x 2.827, 2 held
+    "latent-full-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=3, n_heads=4, n_kv_heads=4, intermediate=32,
+        head_dim=6, norm_eps=1e-6, layer_pattern=("mla_full",), lead_pattern=("mla_full",),
+        lead_intermediate=96,
+        mla_full=LatentAttentionYarn(
+            heads=4, q_rank=32, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=6, rope_theta=100.0,
+            yarn=Yarn(factor=8.0, original_length=16, beta_fast=1.0, beta_slow=1.0),
+            softmax_factor=(0.1 * math.log(8.0) + 1.0) ** 2),
+        moe_experts=12, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
+        moe_score="sigmoid", moe_bias_rate=0.001, moe_shared_gate=False,
+        moe_routed_scale=2.827, moe_aux_weight=0.0001),
 }
 
 
@@ -467,7 +489,7 @@ LEAD_DENSE = LayerKind(
     axes=_dense_axes, init=functools.partial(_dense_init, width="lead_intermediate"),
     apply=_dense_mlp, matmul_params=lambda c: 3.0 * c.hidden * c.lead_intermediate)
 MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW,
-                                "gqa": GQA, "gqa_win": GQA_WINDOW}
+                                "mla_full": MLA_FULL, "gqa": GQA, "gqa_win": GQA_WINDOW}
 
 
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,

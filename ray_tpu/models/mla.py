@@ -1,8 +1,10 @@
-"""Latent attention (MLA; DeepSeek-V2, arXiv 2405.04434) as two token
-mixers, as ``models/gdn.py`` is one: ``mla``, whose keys a learned indexer
-chooses (DeepSeek-V3.2's sparse attention), and ``mla_win``, a causal window.
-Both are described by a ``LatentAttention`` on the config (``mla``,
-``mla_window``) and share every line but the choice of keys.
+"""Latent attention (MLA; DeepSeek-V2, arXiv 2405.04434) as three token
+mixers, as ``models/gdn.py`` is one, told apart by their choice of keys:
+``mla``, whose keys a learned indexer chooses (DeepSeek-V3.2's sparse
+attention), ``mla_win``, a causal window, and ``mla_full``, every causal key
+(DeepSeek-V3, Kimi-K2). Each is described by a ``LatentAttention`` on the
+config (``mla``, ``mla_window``, ``mla_full``) and they share every line but
+that choice.
 
 For the normed input ``x`` of a position, ranks r_q and r_kv, H heads of d_n
 (no position) + d_r (rope) query and key features and d_v value features:
@@ -11,13 +13,22 @@ For the normed input ``x`` of a position, ranks r_q and r_kv, H heads of d_n
     q_h          = c_q W_uq[h],  rope on its last d_r       [d_n + d_r]
     [c_kv | k_r] = x W_dkv;  c_kv = s_kv rmsnorm(c_kv);  k_r = rope(k_r)
     [k_h^n | v_h] = c_kv W_ukv[h];  k_h = [k_h^n | k_r]     one k_r for all heads
-    o_h          = softmax over the allowed keys (q_h . k_h (d_n + d_r)^-1/2) v_h
+    o_h          = softmax over the allowed keys (f q_h . k_h (d_n + d_r)^-1/2) v_h
     y            = concat_h(sigmoid(x W_g)_h o_h) W_o       (``gate``)
 
 ``s_q = (hidden / r_q)^1/2``, ``s_kv = (hidden / r_kv)^1/2`` with ``rescale``,
-else 1. Allowed keys of query t: ``mla_win``: s in [t - window + 1, t];
-``mla``: the ``index_top_k`` keys the indexer scores highest
-(``ops/sparse_index.py``), from
+else 1. Rope turns by ``rope_theta``'s plain frequencies, or with ``yarn`` (a
+``LatentAttentionYarn``) by YaRN's blended ones (``ops/rope.py``). YaRN has
+two published forms, and such a spec can state either: ``models/gqa.py``'s
+puts its factor on cos and sin (``yarn.attention_factor``); latent attention's
+leaves cos and sin alone (their factor is ``mscale(F, mscale) / mscale(F,
+mscale_all_dim)``, 1 where the two keys are equal) and multiplies the SOFTMAX
+SCALE by ``f = softmax_factor = mscale(F, mscale_all_dim)^2``, ``mscale(F, m)
+= 0.1 m ln F + 1``; where the configuration is built computes both from the
+published keys.
+Allowed keys of query t: ``mla_full``: every s <= t; ``mla_win``: s in
+[t - window + 1, t]; ``mla``: the ``index_top_k`` keys the indexer scores
+highest (``ops/sparse_index.py``), from
 
     q^I_j = c_q W_iq[j]  (rope on its first d_r),  k^I = rope(layernorm(x W_ik)),
     w^I   = x W_iw J^-1/2 Di^-1/2
@@ -49,7 +60,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope, flash_attention, rms_norm
 from ..ops.sparse_index import index_kl, index_scores, select_top_k
-from .kinds import LayerKind, headwise_gate, kept_keys
+from .kinds import LayerKind, Yarn, headwise_gate, kept_keys, rope_keywords
 
 SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask",
               "dsa_kl_z", "dsa_kl_lse")
@@ -68,15 +79,29 @@ class LatentAttention:
     v_dim: int
     rope_theta: float
     window: int = 0          # 0: none. Else query t sees keys t - window + 1 .. t
-    index_heads: int = 0     # 0: no indexer
+    index_heads: int = 0     # 0: no indexer. With no window either: every causal key
     index_dim: int = 0
     index_top_k: int = 0
     rescale: bool = False
     gate: bool = False
+    # what a spec under YaRN states (``LatentAttentionYarn``'s fields); here
+    # facts of the class: plain frequencies, the plain softmax scale
+    yarn = None
+    softmax_factor = 1.0
 
     @property
     def qk_dim(self) -> int:
         return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionYarn(LatentAttention):
+    """The widths of a latent-attention layer under YaRN in the form latent
+    attention publishes (a spec of its own: the benchmark's accepted tests
+    hold ``LatentAttention`` to its thirteen fields)."""
+
+    yarn: Yarn | None = None        # YaRN's frequencies, its factor on cos and sin
+    softmax_factor: float = 1.0     # times the softmax scale (YaRN's mscale^2)
 
 
 def _axes(a: LatentAttention) -> dict:
@@ -129,11 +154,19 @@ def _layer_norm(x, weight, bias, eps):
     return (f * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope_tail(t, positions, theta, rope_dim):
+def _rope(t, positions, a: LatentAttention, rotary_dim: int | None = None):
+    """Rope on t [B, H, S, D], whole or on its first ``rotary_dim`` features:
+    ``rope_theta``'s frequencies, or YaRN's over the ``rope_dim`` rotated."""
+    kw = rope_keywords(a.rope_dim, a.rope_theta, a.yarn)
+    if rotary_dim is not None:
+        kw["rotary_dim"] = rotary_dim
+    return apply_rope(t, positions, **kw)
+
+
+def _rope_tail(t, positions, a: LatentAttention):
     """Rope on the LAST ``rope_dim`` features of t [B, H, S, D]."""
-    split = t.shape[-1] - rope_dim
-    return jnp.concatenate(
-        [t[..., :split], apply_rope(t[..., split:], positions, theta=theta)], axis=-1)
+    split = t.shape[-1] - a.rope_dim
+    return jnp.concatenate([t[..., :split], _rope(t[..., split:], positions, a)], axis=-1)
 
 
 def index_inputs(h, c_q, layer, a: LatentAttention, positions):
@@ -141,10 +174,10 @@ def index_inputs(h, c_q, layer, a: LatentAttention, positions):
     [B, J, S, Di], k^I [B, S, Di], w^I [B, S, J] float32."""
     h, c_q = jax.lax.stop_gradient(h), jax.lax.stop_gradient(c_q)
     q_i = jnp.einsum("bsr,rjd->bjsd", c_q, layer["w_iq"])
-    q_i = apply_rope(q_i, positions, theta=a.rope_theta, rotary_dim=a.rope_dim)
+    q_i = _rope(q_i, positions, a, a.rope_dim)
     k_i = _layer_norm(jnp.einsum("bse,ed->bsd", h, layer["w_ik"]),
                       layer["ik_norm"], layer["ik_bias"], INDEX_NORM_EPS)
-    k_i = apply_rope(k_i[:, None], positions, theta=a.rope_theta, rotary_dim=a.rope_dim)[:, 0]
+    k_i = _rope(k_i[:, None], positions, a, a.rope_dim)[:, 0]
     w_i = jnp.einsum("bse,ej->bsj", h, layer["w_iw"], preferred_element_type=jnp.float32)
     return q_i, k_i, w_i * (a.index_heads ** -0.5 * a.index_dim ** -0.5)
 
@@ -152,7 +185,7 @@ def index_inputs(h, c_q, layer, a: LatentAttention, positions):
 def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
               return_selection: bool = False):
     """h [B, S, E] (normed) -> (y [B, S, E], aux). ``aux`` is ``{}`` for a
-    window layer and ``index_loss``, ``selected_share`` for an indexed one;
+    window or a full layer and ``index_loss``, ``selected_share`` for an indexed one;
     ``return_selection`` adds the key sets [B, S, S] int8 (comparisons)."""
     c = config
     b, s, e = h.shape
@@ -167,19 +200,17 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
         c_q = checkpoint_name(
             latent(jnp.einsum("bse,er->bsr", h, layer["w_dq"]), layer["q_a_norm"], s_q),
             "mla_cq")
-        q = _rope_tail(jnp.einsum("bsr,rhd->bhsd", c_q, layer["w_uq"]), positions,
-                       a.rope_theta, a.rope_dim)
+        q = _rope_tail(jnp.einsum("bsr,rhd->bhsd", c_q, layer["w_uq"]), positions, a)
     with device_scope("mla_kv"):
         down = jnp.einsum("bse,er->bsr", h, layer["w_dkv"])
         c_kv = checkpoint_name(latent(down[..., :a.kv_rank], layer["kv_a_norm"], s_kv),
                                "mla_ckv")
-        k_r = checkpoint_name(apply_rope(down[:, None, :, a.kv_rank:], positions,
-                                         theta=a.rope_theta), "mla_kr")
+        k_r = checkpoint_name(_rope(down[:, None, :, a.kv_rank:], positions, a), "mla_kr")
         kv = jnp.einsum("bsr,rhd->bhsd", c_kv, layer["w_ukv"])
         k = jnp.concatenate(
             [kv[..., :a.nope_dim], jnp.broadcast_to(k_r, (b, a.heads, s, a.rope_dim))], axis=-1)
         v = kv[..., a.nope_dim:]
-    sm_scale = a.qk_dim ** -0.5
+    sm_scale = a.qk_dim ** -0.5 * a.softmax_factor
     aux = {}
     if a.index_heads:
         with device_scope("dsa_index"):
@@ -195,10 +226,14 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
                                      / (b * s * (s + 1) / 2))
         if return_selection:
             aux["selection"] = mask
-    else:
+    elif a.window:
         # blocks of the window's size: a query block's band is two key blocks
-        attn = flash_attention(q, k, v, sm_scale=sm_scale, window=a.window or None,
+        attn = flash_attention(q, k, v, sm_scale=sm_scale, window=a.window,
                                block_q=512, block_k=512)
+    else:
+        # every causal key: the plain kernels at their own blocks
+        with device_scope("mla_full"):
+            attn = flash_attention(q, k, v, sm_scale=sm_scale)
     if a.gate:
         with device_scope("attn_gate"):
             attn = headwise_gate(h, layer["w_attn_gate"], attn)
@@ -246,6 +281,8 @@ def _kind(field: str) -> LayerKind:
 
 MLA = _kind("mla")
 MLA_WINDOW = _kind("mla_window")
+MLA_FULL = _kind("mla_full")
 
-__all__ = ["LatentAttention", "MLA", "MLA_WINDOW", "SAVE_NAMES", "mla_mixer",
+__all__ = ["LatentAttention", "LatentAttentionYarn", "MLA", "MLA_WINDOW", "MLA_FULL",
+           "SAVE_NAMES", "mla_mixer",
            "index_inputs", "kept_keys"]

@@ -57,8 +57,8 @@ from its first, ``cap`` a static ``HELD_CAPACITY`` times the range's even
 share, the results added into their tokens (a scatter-add of ``cap`` rows
 where the whole permutation would gather N*k). No row is dropped whatever
 the skew: a step whose range is longer than ``cap`` takes, under a
-``lax.cond``, the path that gathers all N*k rows and computes the range
-by ``row_offset``.
+``lax.cond``, the path that walks the range expert by expert, a chunk of
+rows at a time, for as many turns as it is long (``_held_by_expert``).
 
 Expert parallelism inside a ``shard_map`` (``ep_axis``): routing is
 global (the router is replicated), the sort is the same on every device,
@@ -328,38 +328,108 @@ def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
         return jnp.zeros((n, e), jnp.float32).at[token].add(ys).astype(tokens.dtype)
 
 
+def _swiglu_rows(xs, weights, row_gates):
+    """ONE expert (``weights``: its three matrices) on rows xs [M, E], the
+    gate applied to the activation in float32, as ``_experts``: [M, E]."""
+    act = (jax.nn.silu(jnp.dot(xs, weights["w_gate"]).astype(jnp.float32))
+           * jnp.dot(xs, weights["w_up"]).astype(jnp.float32)
+           * row_gates[:, None]).astype(xs.dtype)
+    return jnp.dot(act, weights["w_down"])
+
+
+def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=None):
+    """The held range when it is longer than ``cap``: expert by expert, an
+    expert's share of ``cap`` sorted rows at a time, each chunk a plain SwiGLU
+    on its gathered tokens added into them, for as many chunks as the expert
+    has rows (loops whose counts are device values). Nothing here is as long as the N*k rows, which
+    ``_all_rows`` gathers whole (0.94 GB a [N*k, E] tensor at 8,192 tokens x 8
+    of width 7,168: as this branch of the ``cond``, which an even router never
+    takes, it cost a step 4.5 GB of scratch by the chip compiler's count).
+    With a cotangent ``g`` [N, E] it returns the gradients of (tokens,
+    weights, gates) and not the result: a loop of unknown length has no
+    reverse pass of its own, so each chunk's is taken where it is made again."""
+    n = tokens.shape[0]
+    flat_gates = gates.reshape(n * top_k)
+    ends = jnp.cumsum(sizes)
+    f32 = lambda tree: jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), tree)  # noqa: E731
+    # rows a chunk: an expert's even share of ``cap`` (in sublane tiles), so
+    # that a range of 2-3 x ``cap`` costs 2-3 chunks an expert and not
+    # ``cap`` rows for each of them
+    chunk_rows = -(-cap // (sizes.shape[0] * 16)) * 16
+
+    def expert(e, total):
+        w = jax.tree.map(lambda a: a[e], weights)
+        n_chunks = (sizes[e] + chunk_rows - 1) // chunk_rows
+
+        def chunk(j):
+            """The j-th chunk of this expert's sorted rows: (token * k + choice,
+            which of them are its rows, their tokens, their gates)."""
+            rows = j * chunk_rows + jnp.arange(chunk_rows, dtype=jnp.int32)
+            valid = rows < sizes[e]
+            pair = order[jnp.minimum(offset + ends[e] - sizes[e] + rows, n * top_k - 1)]
+            return pair, valid, tokens[pair // top_k], jnp.where(valid, flat_gates[pair], 0.0)
+
+        def forward(j, out):
+            pair, valid, rows, row_gates = chunk(j)
+            ys = jnp.where(valid[:, None], _swiglu_rows(rows, w, row_gates), 0)
+            return out.at[pair // top_k].add(ys.astype(jnp.float32))
+
+        def backward(j, carry):
+            d_tokens, d_w, d_gates = carry
+            pair, valid, rows, row_gates = chunk(j)
+            pull = jax.vjp(_swiglu_rows, rows, w, row_gates)[1]
+            d_rows, d, d_row_gates = pull(jnp.where(valid[:, None], g[pair // top_k], 0))
+            return (d_tokens.at[pair // top_k].add(d_rows.astype(jnp.float32)),
+                    jax.tree.map(lambda a, x: a + x.astype(jnp.float32), d_w, d),
+                    d_gates.at[pair].add(jnp.where(valid, d_row_gates, 0.0)))
+
+        if g is None:
+            return jax.lax.fori_loop(0, n_chunks, forward, total)
+        d_tokens, d_weights, d_gates = total
+        d_tokens, d_w, d_gates = jax.lax.fori_loop(0, n_chunks, backward,
+                                                   (d_tokens, f32(w), d_gates))
+        # an expert's slice written where it lies
+        return (d_tokens, jax.tree.map(lambda a, d: jax.lax.dynamic_update_index_in_dim(
+            a, d.astype(a.dtype), e, 0), d_weights, d_w), d_gates)
+
+    with device_scope("moe_experts"):
+        count = sizes.shape[0]
+        if g is None:
+            return jax.lax.fori_loop(0, count, expert, f32(tokens)).astype(tokens.dtype)
+        d_tokens, d_weights, d_gates = jax.lax.fori_loop(0, count, expert, (
+            f32(tokens), jax.tree.map(jnp.zeros_like, weights), f32(flat_gates)))
+        return d_tokens.astype(tokens.dtype), d_weights, d_gates.reshape(gates.shape)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_or_all_rows(top_k, cap, tokens, weights, gates, order, inv, sizes, offset):
+def _held_range(top_k, cap, tokens, weights, gates, order, sizes, offset):
     """The held range by the compact path, or, in a step whose range is
-    longer than ``cap``, by the path that gathers every row: dropless
-    whatever the skew. One ``lax.cond`` forward and one backward, each
-    running the branch taken: differentiating a plain ``cond`` keeps BOTH
-    branches' residuals (4.3 GB more at 16k tokens x 10, by the chip
-    compiler's count), so the backward rule runs its branch again instead."""
+    longer than ``cap``, expert by expert in as many turns as it takes:
+    dropless whatever the skew. One ``lax.cond`` forward and one backward,
+    each running the branch taken: differentiating a plain ``cond`` keeps
+    BOTH branches' residuals, so the backward rule runs its branch again
+    instead."""
     return jax.lax.cond(
         jnp.sum(sizes) <= cap,
         lambda: _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset),
-        lambda: _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset))
+        lambda: _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset))
 
 
-def _held_or_all_fwd(top_k, cap, *args):
-    return _held_or_all_rows(top_k, cap, *args), args
+def _held_range_fwd(top_k, cap, *args):
+    return _held_range(top_k, cap, *args), args
 
 
-def _held_or_all_bwd(top_k, cap, args, g):
-    tokens, weights, gates, order, inv, sizes, offset = args
-
-    def back(path):
-        return lambda: jax.vjp(path, tokens, weights, gates)[1](g)
-
+def _held_range_bwd(top_k, cap, args, g):
+    tokens, weights, gates, order, sizes, offset = args
     d = jax.lax.cond(
         jnp.sum(sizes) <= cap,
-        back(lambda t, w, gt: _held_rows(top_k, cap, t, w, gt, order, sizes, offset)),
-        back(lambda t, w, gt: _all_rows(top_k, t, w, gt, order, inv, sizes, offset)))
-    return (*d, None, None, None, None)
+        lambda: jax.vjp(lambda t, w, gt: _held_rows(top_k, cap, t, w, gt, order, sizes, offset),
+                        tokens, weights, gates)[1](g),
+        lambda: _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g))
+    return (*d, None, None, None)
 
 
-_held_or_all_rows.defvjp(_held_or_all_fwd, _held_or_all_bwd)
+_held_range.defvjp(_held_range_fwd, _held_range_bwd)
 
 
 def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
@@ -404,8 +474,7 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
         out = _all_rows(top_k, tokens, weights, r["gates"], r["order"], r["inv"],
                         sizes, offset)
     else:
-        out = _held_or_all_rows(top_k, cap, tokens, weights, r["gates"], r["order"],
-                                r["inv"], sizes, offset)
+        out = _held_range(top_k, cap, tokens, weights, r["gates"], r["order"], sizes, offset)
     if ep_axis is not None:
         with device_scope("moe_combine"):
             out = jax.lax.psum(out, ep_axis)

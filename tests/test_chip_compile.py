@@ -193,6 +193,18 @@ def _latent(chip, kind, backward, b=2, t=8192):
     return jax.jit(fn).lower(*args)
 
 
+def _latent_full(chip, backward, b=1, h=64, t=8192):
+    """Kimi-K2's attention at one row of 8,192 (and of the 4,096 its cell runs):
+    the PLAIN kernels at 64 query = 64 key heads, a 192-wide key head (one and
+    a half lane tiles) beside a 128-wide value head, under YaRN's softmax scale."""
+    sd = lambda d: jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16, sharding=chip)  # noqa: E731
+    fwd = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, sm_scale=1.81326 * 192 ** -0.5, interpret=False)
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+                  ) if backward else fwd
+    return jax.jit(fn).lower(sd(192), sd(192), sd(128))
+
+
 def _indexer(chip, what, b=2, t=8192):
     """Its indexer's kernels there: 64 index heads of 128 summed in VMEM
     (forward; the loss's gradient: two kernels), and the loss itself: the KL
@@ -265,6 +277,11 @@ CASES = {
     "flash-bwd-48to8-16k": lambda c: _flash(c, 1, 48, 8, 16384, 128, backward=True),
     "attn-win-fwd-72to8-16k": lambda c: _grouped_window(c, backward=False),
     "attn-win-bwd-72to8-16k": lambda c: _grouped_window(c, backward=True),
+    # Kimi-K2: the plain kernels at 1 x 64 x 8192 x 192 / 128, and at the 4,096
+    # positions its cell runs
+    "flash-fwd-192v128-8k": lambda c: _latent_full(c, backward=False),
+    "flash-bwd-192v128-8k": lambda c: _latent_full(c, backward=True),
+    "flash-bwd-192v128-4k": lambda c: _latent_full(c, backward=True, t=4096),
 }
 
 
@@ -381,7 +398,10 @@ def _tiny_step(chip, preset):
     # the ``gdn-conv-*`` / ``gdn-norm-*`` cases above)
     ("hybrid-debug", {"stack/attn", "stack/attn/gdn_scan", "stack/mlp/moe_experts"}),
     ("latent-sparse-debug", {"stack/attn", "stack/attn/dsa_index", "stack/attn/dsa_select",
-                             "stack/attn/dsa_loss", "stack/mlp/moe_experts"})])
+                             "stack/attn/dsa_loss", "stack/mlp/moe_experts"}),
+    # a layer that attends every causal key calls the plain kernels under a
+    # scope of its own
+    ("latent-full-debug", {"stack/attn/mla_full", "stack/mlp/moe_experts"})])
 def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_without(
         chip, monkeypatch, preset, scopes):
     """The hybrid and the sparse step, every kernel module steered to the chip
