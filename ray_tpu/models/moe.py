@@ -54,11 +54,14 @@ cost no grouped-matmul tile and add nothing; nothing is exchanged and
 nothing stands in for the exchange. The held rows are a contiguous range
 of the sorted rows, and the dispatch gathers that range alone: ``cap`` rows
 from its first, ``cap`` a static ``HELD_CAPACITY`` times the range's even
-share, the results added into their tokens (a scatter-add of ``cap`` rows
-where the whole permutation would gather N*k). No row is dropped whatever
-the skew: a step whose range is longer than ``cap`` takes, under a
-``lax.cond``, the path that walks the range expert by expert, a chunk of
-rows at a time, for as many turns as it is long (``_held_by_expert``).
+share, the results added into their tokens by ``ops/moe_rows.py``'s
+``sum_rows`` (the kernel ``moe_rows``: a token's rows fetched by id and
+summed in float32 in VMEM, where XLA's scatter-add went one row at a time
+through a float32 ``[N, E]``; the gather is XLA's, and its gradient is the
+same kernel). No row is dropped whatever the skew: a step whose range is
+longer than ``cap`` takes, under a ``lax.cond``, the path that walks the
+range expert by expert, a chunk of rows at a time, for as many turns as it
+is long (``_held_by_expert``).
 
 Expert parallelism inside a ``shard_map`` (``ep_axis``): routing is
 global (the router is replicated), the sort is the same on every device,
@@ -77,6 +80,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.tracing import device_scope
 from ..ops import grouped_matmul
+from ..ops.moe_rows import sum_rows
 from .kinds import LayerKind
 
 # what a remat policy may save of the routing: a few MB a layer against a
@@ -179,6 +183,33 @@ def _permute(values, perm, inv_perm):
 
 _permute.defvjp(lambda values, perm, inv_perm: (values[perm], (inv_perm,)),
                 lambda res, g: (g[res[0]], None, None))
+
+
+@jax.custom_vjp
+def _take_rows(values, ids):
+    """``values[ids]`` for a held range's ``cap`` rows: values [N, E], ids
+    [cap] a row's token, -1 past the range's end. Such a row reads token 0:
+    the grouped matmul computes no row outside its groups and hands none a
+    gradient, so nothing masks it here (a select fused into XLA's gather cost
+    it 0.29 -> 0.77 ms at 40,960 rows of 4 KB; PERF.md, PR 42). The gradient
+    adds each token's rows back with ``_add_rows``, where the gather's own
+    transpose would be a scatter-add of rows."""
+    return values[jnp.maximum(ids, 0)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _add_rows(rows, ids, n):
+    """``out[t] = sum of the rows whose id is t``, float32 sums, [n, E]:
+    ``ops/moe_rows.py::sum_rows``, the kernel ``moe_rows``. Its gradient is
+    ``_take_rows``: each is the other's transpose on the rows that name a
+    token."""
+    return sum_rows(rows, ids, n)
+
+
+_take_rows.defvjp(lambda values, ids: (_take_rows(values, ids), (ids, values.shape[0])),
+                  lambda res, g: (_add_rows(g, *res), None))
+_add_rows.defvjp(lambda rows, ids, n: (_add_rows(rows, ids, n), ids),
+                 lambda n, ids, g: (_take_rows(g, ids), None))
 
 
 def route(tokens, router, *, top_k: int, norm_topk: bool, score: str = "softmax",
@@ -309,23 +340,25 @@ def _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset):
 
 def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
     """Only the held experts' rows: the ``cap`` sorted rows from the held
-    range's first. Rows past the range's end belong to no group, come back
-    zero and are added nowhere."""
-    n, e = tokens.shape
+    range's first. ``_take_rows`` (XLA's gather) brings their tokens in,
+    ``_add_rows`` (the kernel ``moe_rows``) adds each token's rows back in
+    float32, and each is the other's gradient. Rows past the range's end
+    name no token: they belong to no group, come back zero and are added
+    nowhere."""
+    n = tokens.shape[0]
     rows = jnp.arange(cap, dtype=jnp.int32)
     valid = rows < jnp.sum(sizes)
     pair = order[jnp.minimum(offset + rows, n * top_k - 1)]  # token * k + choice
-    token = pair // top_k
+    token = jnp.where(valid, pair // top_k, -1)
     gmm = functools.partial(grouped_matmul, group_sizes=sizes,
                             row_offset=jnp.zeros((), jnp.int32))
     with device_scope("moe_dispatch"):
-        xs = checkpoint_name(tokens[token], "moe_xs")
+        xs = checkpoint_name(_take_rows(tokens, token), "moe_xs")
         row_gates = jnp.where(valid, gates.reshape(n * top_k)[pair], 0.0)
     with device_scope("moe_experts"):
         ys = _experts(xs, row_gates, weights, gmm)
     with device_scope("moe_combine"):
-        ys = jnp.where(valid[:, None], ys, 0).astype(jnp.float32)
-        return jnp.zeros((n, e), jnp.float32).at[token].add(ys).astype(tokens.dtype)
+        return _add_rows(ys, token, n)
 
 
 def _swiglu_rows(xs, weights, row_gates):

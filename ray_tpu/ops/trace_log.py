@@ -22,7 +22,10 @@ attention kernels under a window or a key set ``attn_win_*`` / ``attn_sel_*``,
 traced as ``flash_attention`` like the plain ones; the indexer's
 ``dsa_index_fwd`` / ``dsa_index_bwd_dq`` / ``dsa_index_bwd_dk``, traced as
 ``dsa_index``, and the loss's ``dsa_probs`` / ``dsa_probs_bwd``, traced as
-``dsa_probs``).
+``dsa_probs``; a held range's adds ``moe_rows``, 0 FLOPs and the bytes of the
+rows it may fetch and of the rows it writes, traced as ``moe_rows``:
+``pallas`` / ``interpret``, or ``xla`` where a width off the lane tiling took
+XLA's scatter-add).
 """
 
 from __future__ import annotations
@@ -41,8 +44,8 @@ _costs: dict[str, dict] = {}
 
 def note_kernel_trace(kernel: str, path: str) -> None:
     """Count one trace of ``kernel`` down ``path`` (``"pallas"``,
-    ``"interpret"``, ``"mha_reference"``, ``"ragged_dot"`` or ``"jnp"``); log
-    the first of each."""
+    ``"interpret"``, ``"mha_reference"``, ``"ragged_dot"``, ``"jnp"`` or
+    ``"xla"``); log the first of each."""
     key = f"{kernel}:{path}"
     with _lock:
         _counts[key] += 1

@@ -149,7 +149,8 @@ def leased_devices() -> list:
 
 def device_report() -> dict:
     """What this process's JAX holds, which attention paths it traced and
-    what one call of each Pallas kernel costs at its traced shapes: what a
+    what one call of each Pallas kernel costs at its traced shapes (the
+    held range's ``moe_rows`` among them: its bytes, no FLOPs): what a
     worker sends back so its caller can tell a chip run from a quiet CPU
     one. Platform, kind and count are as JAX reports them."""
     from .ops.trace_log import kernel_costs, kernel_traces

@@ -22,6 +22,7 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.moe_rows import sum_rows
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
@@ -114,6 +115,14 @@ def _grouped(chip, k, n, backward, rows=131072, groups=64):
 
     fn = jax.grad(loss, argnums=(0, 1)) if backward else fwd
     return jax.jit(fn).lower(lhs, rhs, sizes)
+
+
+def _rows(chip, n, cap, e):
+    """A held range's adds: ``cap`` rows of width ``e`` summed into ``n``
+    tokens (``moe_rows``; the gather's gradient is the same call)."""
+    rows = jax.ShapeDtypeStruct((cap, e), jnp.bfloat16, sharding=chip)
+    ids = jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=chip)
+    return jax.jit(lambda r, i: sum_rows(r, i, n, interpret=False)).lower(rows, ids)
 
 
 def _gdn(chip, backward, b=2, h=32, t=8192, d=128):
@@ -282,6 +291,12 @@ CASES = {
     "flash-fwd-192v128-8k": lambda c: _latent_full(c, backward=False),
     "flash-bwd-192v128-8k": lambda c: _latent_full(c, backward=True),
     "flash-bwd-192v128-4k": lambda c: _latent_full(c, backward=True, t=4096),
+    # the held range's adds at the four cells' shapes: rows of 40 and 56 lane
+    # tiles (dots3, Kimi-K2), and 40,960 ids in scalar memory (Laguna, Qwen3-Next)
+    "moe-rows-5120": lambda c: _rows(c, 16384, 8192, 5120),
+    "moe-rows-7168": lambda c: _rows(c, 4096, 1536, 7168),
+    "moe-rows-3072": lambda c: _rows(c, 16384, 40960, 3072),
+    "moe-rows-2048": lambda c: _rows(c, 16384, 40960, 2048),
 }
 
 
@@ -373,7 +388,7 @@ def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(ch
 # ------------------------------------------ the scopes, by the chip's compiler
 KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul",
                   "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta",
-                  "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn")
+                  "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn", "ray_tpu.ops.moe_rows")
 
 
 def _tiny_step(chip, preset):

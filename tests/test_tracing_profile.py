@@ -438,10 +438,10 @@ KINDS = {
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = .*?\s([a-z][\w\-]*)\(")
 
 
-def _lower_loss(preset="debug-128"):
+def _lower_loss(preset="debug-128", **changes):
     from ray_tpu.models.llama import PRESETS, init_params, loss_fn
 
-    cfg = dataclasses.replace(PRESETS[preset], dtype=jnp.float32, remat_policy="attn")
+    cfg = dataclasses.replace(PRESETS[preset], dtype=jnp.float32, remat_policy="attn", **changes)
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     batch = {"tokens": jax.ShapeDtypeStruct((2, 64), jnp.int32)}
     return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=32))
@@ -543,6 +543,14 @@ def test_the_head_loss_and_the_held_range_keep_their_scope_in_the_backward_rule(
     # the forward rule's own pass (the loss and its gradients in one scan)
     assert any('rt_scope="lm_head_loss"' in line and " while(" in line
                for line in text.splitlines())
+    # the held range's adds at a width of one lane tile (``moe_rows``, interpreted
+    # here, a custom call on a TPU): the forward one under ``moe_combine``, the
+    # gather's gradient under ``moe_dispatch`` inside the backward rule, so the
+    # two scope readers keep seeing them
+    wide = _lower_loss(KINDS["hybrid"][0], hidden=128)
+    rows = [line for line in wide.splitlines() if "/moe_rows/" in line and 'rt_scope="' in line]
+    assert {(re.search(r'rt_scope="([^"]*)"', line).group(1), "transpose(jvp" in line)
+            for line in rows} == {("stack/mlp/moe_combine", False), ("stack/mlp/moe_dispatch", True)}
 
 
 @pytest.mark.parametrize("kind", list(KINDS) + ["serving"])
