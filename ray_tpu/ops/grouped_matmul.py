@@ -24,6 +24,18 @@ group's rows. ``moe_gmm`` backs ``grouped_matmul`` and its gradient for
 ``lhs`` (the same product against ``rhs[g]^T``); ``moe_tgmm`` is the
 gradient for ``rhs``.
 
+Every visit multiplies all ``tm`` rows of its tile, so the tiles follow the
+rows a group holds: ``gmm_tiles`` / ``tgmm_tiles`` choose (tm, tk, tn) when
+a call is traced, from its static shapes alone (rows over groups, K, N, the
+item size). Under 2,048 rows a group: 128 rows, the whole contraction in
+one block (a group's matrix is then fetched once a group, not once a
+visit) and the widest column block that fits VMEM; at 2,048 and more the
+512 x 2048 x 1024 measured at OLMoE's shapes. Measured on a v5e at the
+five routed cells' shapes, every product of a layer alone (PERF.md, PR
+43). ``tile_visits`` counts visits and rows multiplied for any routing
+without a chip; ``trace_log.kernel_costs()`` holds what a traced call
+chose (``tiles``, ``work_items``, ``rhs_resident``).
+
 Off the TPU both kernels run in the Pallas interpreter, so the CPU tests
 exercise the same tiling. A row count no tile divides takes
 ``jax.lax.ragged_dot``; ``trace_log.kernel_traces()`` says which path
@@ -42,13 +54,14 @@ from jax.experimental.pallas import tpu as pltpu
 from ..tpu import on_tpu
 from .trace_log import note_kernel_cost, note_kernel_trace
 
-# (tm, tk, tn): rows, contraction and output columns of one tile. Chosen on
-# a v5e at OLMoE's shapes (131,072 rows, 64 groups, 2048 x 1024 and 1024 x
-# 2048; PERF.md, PR 26). ``tk`` spans the whole contraction there, so a
-# group's matrix stays in VMEM while its row tiles stream past.
-GMM_TILES = (512, 2048, 1024)
-TGMM_TILES = (512, 2048, 1024)
+# (tm, tk, tn): rows, contraction and output columns of one tile (``moe_tgmm``:
+# rows, and the two sides of a group's output block). The rule's largest
+# tiles, which it gives at 2,048 rows a group and more: chosen on a v5e at
+# OLMoE's shapes (131,072 rows, 64 groups, 2048 x 1024 and 1024 x 2048;
+# PERF.md, PR 26).
+_CEILING = (512, 2048, 1024)
 _VMEM_LIMIT = 96 * 1024 * 1024  # a v5e core has 128 MiB; the default scope is 16
+_VMEM_BLOCKS = 72 * 1024 * 1024  # of it for the rule's blocks; the rest is Mosaic's own
 
 
 def _fit(requested: int, dim: int, align: int) -> int | None:
@@ -62,6 +75,104 @@ def _fit(requested: int, dim: int, align: int) -> int | None:
     return t if t >= align else None
 
 
+def _fit_tiles(tiles, m: int, k: int, n: int, dtype) -> tuple:
+    """``tiles`` cut to divisors of the three dimensions, rows to the dtype's
+    sublane packing and the others to lanes; None where nothing divides."""
+    align = 16 if jnp.dtype(dtype).itemsize == 2 else 8
+    return _fit(tiles[0], m, align), _fit(tiles[1], k, 128), _fit(tiles[2], n, 128)
+
+
+def _gmm_vmem(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """Bytes of ``moe_gmm``'s blocks: the lhs tile, the matrix block and the
+    output tile, each buffered twice, and the float32 accumulator."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _tgmm_vmem(tm: int, tk: int, tn: int, itemsize: int, out_itemsize: int) -> int:
+    """Bytes of ``moe_tgmm``'s blocks: the two row tiles and the group's
+    output block, each buffered twice, and the float32 accumulator."""
+    return 2 * itemsize * (tm * tk + tm * tn) + (2 * out_itemsize + 4) * tk * tn
+
+
+def _widest(dim: int, fits) -> int | None:
+    """The widest block of ``dim`` (all of it, or a lane-aligned divisor)
+    that ``fits``; None where not even the narrowest does."""
+    blocks = [dim] + [t for t in range((dim - 1) // 128 * 128, 0, -128) if dim % t == 0]
+    return next((t for t in blocks if fits(t)), None)
+
+
+def _under_the_ceiling(m: int, groups: int, k: int, n: int, vmem) -> tuple | None:
+    """The tiles of a call whose groups would hold under 2,048 rows if ``m``
+    fell evenly on them (None at 2,048 and more, or where nothing fits):
+    128 rows, all of ``k``, and the widest ``tn`` whose blocks, counted by
+    ``vmem(tm, tk, tn)``, fit ``_VMEM_BLOCKS``.
+
+    A tile that straddles a boundary is visited once a group it touches and
+    every visit multiplies all its rows, so the row tile is the MXU's own
+    side: at 192, 640, 1,024 and 1,280 rows a group, of which a held range
+    fills about half, 128 read within 1% of the best of 64-512 in all six
+    products of a layer, and the widest ``tn`` was the best at every shape
+    (PERF.md, PR 43)."""
+    if m // groups >= 2048:
+        return None
+    tn = _widest(n, lambda tn: vmem(128, k, tn) <= _VMEM_BLOCKS)
+    return tn and (128, k, tn)
+
+
+def gmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2,
+              transpose_rhs: bool = False) -> tuple[int, int, int]:
+    """``moe_gmm``'s (tm, tk, tn) for lhs [m, k] against ``groups`` matrices
+    [k, n] (``transpose_rhs``: stored [n, k]), before ``_fit_tiles``.
+
+    Under the ceiling's rows the WHOLE contraction is one block: a group's
+    matrix block then keeps its index over the group's consecutive visits
+    and is fetched once a group and column block, which 128 rows of
+    products would not hide; the wider ``tn``, the fewer times the rows are
+    read again (at 7168 x 2048 the whole matrix, 29 MB twice buffered: the
+    call then runs at the matrices' bytes). At the ceiling's rows the
+    ceiling itself, ``tk`` and ``tn`` swapped for the transposed form as
+    the backward rule always asked: the routed cell's program is the one
+    PR 26 measured."""
+    ceiling = (_CEILING[0], _CEILING[2], _CEILING[1]) if transpose_rhs else _CEILING
+    return _under_the_ceiling(
+        m, groups, k, n, lambda *tiles: _gmm_vmem(*tiles, itemsize)) or ceiling
+
+
+def tgmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2,
+               out_itemsize: int = 2) -> tuple[int, int, int]:
+    """``moe_tgmm``'s (tm, tk, tn) for lhs [m, k] and dout [m, n] into
+    ``groups`` blocks [k, n], before ``_fit_tiles``. The contraction is over
+    ROWS; ``tk`` and ``tn`` cut a group's output block, and the rows are read
+    once a block of the other side: under the ceiling's rows ``tk`` is all of
+    ``k`` (the float32 accumulator and the twice-buffered block are 8 bytes
+    an element of the block); at them, the ceiling."""
+    return _under_the_ceiling(
+        m, groups, k, n,
+        lambda *tiles: _tgmm_vmem(*tiles, itemsize, out_itemsize)) or _CEILING
+
+
+def _group_tiles(group_sizes, row_offset, m: int, tm: int):
+    """Per group: absolute first row and end, the first row tile it touches
+    and how many it touches (0 for an empty group)."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(group_sizes) + (0 if row_offset is None else row_offset)
+    starts = ends - group_sizes
+    first = jnp.minimum(starts // tm, m // tm - 1)
+    n_tiles = jnp.where(group_sizes > 0, (ends + tm - 1) // tm - first, 0)
+    return starts, ends, first, n_tiles
+
+
+def tile_visits(group_sizes, m: int, tm: int, row_offset=None) -> tuple[int, int]:
+    """(visits, rows multiplied) of either kernel over ``m`` rows in tiles of
+    ``tm`` under this routing: a tile is visited once per group it holds
+    rows of, and every visit multiplies all ``tm`` rows. Rows multiplied
+    over ``sum(group_sizes)`` is what the tiling costs; the kernels' own
+    ``n_work`` is the same count (``_work_items``)."""
+    n_tiles = _group_tiles(jnp.asarray(group_sizes), row_offset, m, tm)[3]
+    visits = int(jnp.sum(n_tiles))
+    return visits, visits * tm
+
+
 def _work_items(group_sizes, row_offset, m: int, tm: int):
     """The (group, row tile) pairs the kernels visit, in row order.
 
@@ -70,11 +181,7 @@ def _work_items(group_sizes, row_offset, m: int, tm: int):
     W = M/tm + G - 1 is static. Items past ``n_work`` repeat the last real
     one and the kernels skip them, so no block index changes for them."""
     g = group_sizes.shape[0]
-    group_sizes = group_sizes.astype(jnp.int32)
-    ends = jnp.cumsum(group_sizes) + (0 if row_offset is None else row_offset)
-    starts = ends - group_sizes
-    first = jnp.minimum(starts // tm, m // tm - 1)
-    n_tiles = jnp.where(group_sizes > 0, (ends + tm - 1) // tm - first, 0)
+    starts, ends, first, n_tiles = _group_tiles(group_sizes, row_offset, m, tm)
     work_ends = jnp.cumsum(n_tiles)
     n_work = work_ends[-1]
     w = jnp.minimum(jnp.arange(m // tm + g - 1, dtype=jnp.int32),
@@ -162,25 +269,24 @@ def _tgmm_kernel(offs_ref, gid_ref, tile_ref, nwork_ref, lhs_ref, dout_ref,
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _align(dtype) -> int:
-    return 16 if jnp.dtype(dtype).itemsize == 2 else 8
-
-
 def _gmm(lhs, rhs, group_sizes, row_offset, *, transpose_rhs, tiles, interpret):
     """lhs [M, K] @ rhs[g] ([G, K, N], or [G, N, K] transposed) -> [M, N]."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm = _fit(tiles[0], m, _align(lhs.dtype))
-    tk, tn = _fit(tiles[1], k, 128), _fit(tiles[2], n, 128)
+    if tiles is None:
+        tiles = gmm_tiles(m, rhs.shape[0], k, n, lhs.dtype.itemsize, transpose_rhs)
+    tm, tk, tn = _fit_tiles(tiles, m, k, n, lhs.dtype)
     if None in (tm, tk, tn):
         note_kernel_trace("moe_gmm", "ragged_dot")
         return _ragged_reference(lhs, jnp.swapaxes(rhs, 1, 2) if transpose_rhs else rhs,
                                  group_sizes, row_offset)
     note_kernel_trace("moe_gmm", "interpret" if interpret else "pallas")
-    note_kernel_cost("moe_gmm", 2.0 * m * k * n,
-                     (m * k + m * n) * lhs.dtype.itemsize + rhs.size * rhs.dtype.itemsize)
     scalars = _work_items(group_sizes, row_offset, m, tm)
     n_k = k // tk
+    note_kernel_cost("moe_gmm", 2.0 * m * k * n,
+                     (m * k + m * n) * lhs.dtype.itemsize + rhs.size * rhs.dtype.itemsize,
+                     tiles=[tm, tk, tn], work_items=scalars[1].shape[0],
+                     rhs_resident=n_k == 1)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec((1, tn, tk), lambda ni, w, ki, o, g, t, c: (g[w], ni, ki))
     else:
@@ -215,17 +321,21 @@ def _tgmm(lhs, dout, group_sizes, row_offset, *, out_dtype, tiles, interpret):
     """out[g] = lhs[rows of g]^T @ dout[rows of g]: [G, K, N]."""
     (m, k), n = lhs.shape, dout.shape[1]
     g = group_sizes.shape[0]
-    tm = _fit(tiles[0], m, _align(lhs.dtype))
-    tk, tn = _fit(tiles[1], k, 128), _fit(tiles[2], n, 128)
+    if tiles is None:
+        tiles = tgmm_tiles(m, g, k, n, lhs.dtype.itemsize, jnp.dtype(out_dtype).itemsize)
+    tm, tk, tn = _fit_tiles(tiles, m, k, n, lhs.dtype)
     if None in (tm, tk, tn):
         note_kernel_trace("moe_tgmm", "ragged_dot")
         return _ragged_transposed_reference(lhs, dout, group_sizes, row_offset, out_dtype)
     note_kernel_trace("moe_tgmm", "interpret" if interpret else "pallas")
-    note_kernel_cost("moe_tgmm", 2.0 * m * k * n,
-                     (m * k + m * n) * lhs.dtype.itemsize
-                     + g * k * n * jnp.dtype(out_dtype).itemsize)
     scalars = _work_items(group_sizes, row_offset, m, tm)
     n_w = scalars[1].shape[0]
+    # ``rhs_resident`` here: ``tk`` spans lhs's width, so ``dout``'s row tiles
+    # are read once (the group's output block is resident in either case)
+    note_kernel_cost("moe_tgmm", 2.0 * m * k * n,
+                     (m * k + m * n) * lhs.dtype.itemsize
+                     + g * k * n * jnp.dtype(out_dtype).itemsize,
+                     tiles=[tm, tk, tn], work_items=n_w, rhs_resident=tk == k)
     out = pl.pallas_call(
         functools.partial(_tgmm_kernel, tm=tm, n_w=n_w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -292,9 +402,9 @@ def _make(gmm_tiles, tgmm_tiles, interpret, offset_given):
     def bwd(res, g):
         lhs, rhs, group_sizes, row_offset = res
         offset = row_offset if offset_given else None
-        # swap tk and tn: the contraction is now over rhs's last axis
-        d_lhs = _gmm(g, rhs, group_sizes, offset, transpose_rhs=True,
-                     tiles=(gmm_tiles[0], gmm_tiles[2], gmm_tiles[1]), **kw)
+        # an override's tk and tn swap: the contraction is now over rhs's last axis
+        swapped = gmm_tiles and (gmm_tiles[0], gmm_tiles[2], gmm_tiles[1])
+        d_lhs = _gmm(g, rhs, group_sizes, offset, transpose_rhs=True, tiles=swapped, **kw)
         d_rhs = _tgmm(lhs, g, group_sizes, offset, out_dtype=rhs.dtype,
                       tiles=tgmm_tiles, **kw)
         return d_lhs, d_rhs, None, None
@@ -304,7 +414,7 @@ def _make(gmm_tiles, tgmm_tiles, interpret, offset_given):
 
 
 def grouped_matmul(lhs, rhs, group_sizes, *, row_offset=None,
-                   gmm_tiles=GMM_TILES, tgmm_tiles=TGMM_TILES,
+                   gmm_tiles=None, tgmm_tiles=None,
                    interpret: bool | None = None):
     """``out[i] = lhs[i] @ rhs[group of row i]``: lhs [M, K], rhs [G, K, N],
     group_sizes [G] int32 (device values) -> [M, N] in lhs's dtype, float32
@@ -315,9 +425,12 @@ def grouped_matmul(lhs, rhs, group_sizes, *, row_offset=None,
     device: expert parallelism, where this device holds a contiguous range
     of the experts) the groups start at that row, and rows outside them
     come back zero, as do their gradients.
+
+    The tiles follow the shapes (``gmm_tiles`` / ``tgmm_tiles`` the
+    functions, above); the arguments of those names override them (tests).
     """
     if interpret is None:
         interpret = not on_tpu()
     offset = jnp.zeros((), jnp.int32) if row_offset is None else row_offset
-    return _make(tuple(gmm_tiles), tuple(tgmm_tiles), interpret,
+    return _make(gmm_tiles and tuple(gmm_tiles), tgmm_tiles and tuple(tgmm_tiles), interpret,
                  row_offset is not None)(lhs, rhs, group_sizes, offset)

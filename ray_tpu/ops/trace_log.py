@@ -61,15 +61,17 @@ def kernel_traces() -> dict[str, int]:
 
 
 def note_kernel_cost(kernel: str, flops: float, nbytes: float,
-                     steps: tuple[int, int] | None = None) -> None:
+                     steps: tuple[int, int] | None = None, **geometry) -> None:
     """Record what ONE call of the Pallas kernel named ``kernel`` costs at
     the shapes it was just traced with (the last trace wins). ``steps``, for
     a kernel whose last grid axis walks tiles: how many steps a (batch, head)
-    takes, and how many of them compute (``grid_steps``, ``live_steps``)."""
+    takes, and how many of them compute (``grid_steps``, ``live_steps``).
+    ``geometry``: what else the call chose from its shapes (the grouped
+    matmuls' ``tiles``, ``work_items``, ``rhs_resident``), kept as given."""
     with _lock:
         traced = _costs.get(kernel, {}).get("traced", 0) + 1
         _costs[kernel] = {"traced": traced, "flops": float(flops),
-                          "bytes": float(nbytes)}
+                          "bytes": float(nbytes), **geometry}
         if steps is not None:
             _costs[kernel].update(grid_steps=int(steps[0]), live_steps=int(steps[1]))
 
@@ -77,7 +79,11 @@ def note_kernel_cost(kernel: str, flops: float, nbytes: float,
 def kernel_costs() -> dict[str, dict]:
     """``{"<kernel name>": {"traced": n, "flops": ..., "bytes": ...}}``:
     per call, at the shapes of the kernel's latest trace in this process; the
-    attention kernels' entries also hold ``grid_steps`` and ``live_steps``."""
+    attention kernels' entries also hold ``grid_steps`` and ``live_steps``,
+    the grouped matmuls' ``tiles`` [tm, tk, tn], ``work_items`` (the static
+    bound on row-tile visits, M / tm + G - 1) and ``rhs_resident`` (``moe_gmm``:
+    ``tk`` spans the contraction, so a group's matrix block is fetched once a
+    group and column block; ``moe_tgmm``: ``tk`` spans lhs's width)."""
     with _lock:
         return {k: dict(v) for k, v in _costs.items()}
 

@@ -99,16 +99,19 @@ def _paged_staging(chip, layers, kh, g, d, page, slots=8, max_len=2560, k_steps=
     return jax.jit(fn).lower(q, pool, pool, tables, pos, stage, stage, scalar, scalar)
 
 
-def _grouped(chip, k, n, backward, rows=131072, groups=64):
+def _grouped(chip, k, n, backward, rows=131072, groups=64, held=False):
     """OLMoE's expert products at 4 x 4096 tokens x 8 experts a token: the
     sorted rows against 64 matrices; backward adds the same product against
-    the transposed matrices and the transposed product (``moe_tgmm``)."""
+    the transposed matrices and the transposed product (``moe_tgmm``).
+    ``held``: a held range's call (``rows`` its ``cap``, a row offset given),
+    whose tiles the rule chooses from ``rows / groups``."""
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=chip)
     rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16, sharding=chip)
     sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=chip)
 
     def fwd(lhs, rhs, sizes):
-        return grouped_matmul(lhs, rhs, sizes, interpret=False)
+        offset = jnp.zeros((), jnp.int32) if held else None
+        return grouped_matmul(lhs, rhs, sizes, row_offset=offset, interpret=False)
 
     def loss(lhs, rhs, sizes):
         return (fwd(lhs, rhs, sizes).astype(jnp.float32) ** 2).sum()
@@ -297,6 +300,18 @@ CASES = {
     "moe-rows-7168": lambda c: _rows(c, 4096, 1536, 7168),
     "moe-rows-3072": lambda c: _rows(c, 16384, 40960, 3072),
     "moe-rows-2048": lambda c: _rows(c, 16384, 40960, 2048),
+    # the held ranges' grouped matmuls at their ``cap`` rows, gate/up and down
+    # with both gradients: 192 (Kimi-K2), 640 (Qwen3-Next), 1,280 (Laguna) and
+    # 1,024 (dots3) rows a group choose other tiles than OLMoE's 2,048, and
+    # keep the whole contraction (up to 7168 wide) in VMEM
+    "moe-gmm-kimi-up-grad": lambda c: _grouped(c, 7168, 2048, True, 1536, 8, held=True),
+    "moe-gmm-kimi-down-grad": lambda c: _grouped(c, 2048, 7168, True, 1536, 8, held=True),
+    "moe-gmm-hybrid-up-grad": lambda c: _grouped(c, 2048, 512, True, 40960, 64, held=True),
+    "moe-gmm-hybrid-down-grad": lambda c: _grouped(c, 512, 2048, True, 40960, 64, held=True),
+    "moe-gmm-laguna-up-grad": lambda c: _grouped(c, 3072, 1024, True, 40960, 32, held=True),
+    "moe-gmm-laguna-down-grad": lambda c: _grouped(c, 1024, 3072, True, 40960, 32, held=True),
+    "moe-gmm-dots3-up-grad": lambda c: _grouped(c, 5120, 1536, True, 8192, 8, held=True),
+    "moe-gmm-dots3-down-grad": lambda c: _grouped(c, 1536, 5120, True, 8192, 8, held=True),
 }
 
 
@@ -308,6 +323,8 @@ def test_kernel_compiles_for_the_chip(chip, case):
         assert program.count('custom_call_target="tpu_custom_call"') == 1 + ("bwd" in case)
     if case.startswith(("gdn-conv", "gdn-norm")):  # one kernel each way
         assert program.count('custom_call_target="tpu_custom_call"') == 1
+    if case.startswith("moe-gmm") and case.endswith("-grad"):  # forward, d_lhs, d_rhs
+        assert program.count('custom_call_target="tpu_custom_call"') == 3
 
 
 @pytest.mark.parametrize("names,kernels", [
