@@ -58,8 +58,8 @@ _HIGHER_BETTER_SUFFIX = ("_per_s", "_per_sec", "_mb_s", "_tok_s",
 # AND compared in POINTS like _pct — a hit rate sliding 0.90 -> 0.45 is
 # a 45-point collapse; 0.02 -> 0.01 is noise, not a 50% regression.
 # "_accept_rate": the speculative drafter's 0-1 accept fraction.
-# "_frac" covers train_ckpt_overlap_frac (round 15) alongside the
-# serve goodput/suffix fractions. "_parity": greedy byte-parity cells
+# "_frac" covers the serve goodput/suffix fractions. "_parity": greedy
+# byte-parity cells
 # (spec_parity, serve_overload_parity, tenant_mixed_batch_parity) — a
 # 1.0-or-broken invariant, so pointwise; any slip below 1.0 is the
 # regression. Round-16 shadow audit: the new tenancy cells end in

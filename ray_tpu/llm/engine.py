@@ -127,6 +127,23 @@ class QueueFullError(RuntimeError):
         self.retry_after = max(1, int(retry_after))
 
 
+class AdmissionFailed(RuntimeError):
+    """The engine settled the request at admission without running it:
+    its adapter failed to load, it named a model on an engine without an
+    adapter manager, or its pages could not be had. The cause is in the
+    replica's log; the serve proxy answers with this status."""
+
+    http_status = "400 Bad Request"
+    reason = "admission_failed"
+    retry_after = None  # not a shed: retrying the same request fails again
+
+    def __init__(self, request: "Request"):
+        super().__init__(
+            f"request {request.request_id} was refused at admission"
+            + (f": model {request.model!r} is unknown to this engine or its "
+               f"adapter failed to load" if request.model else ""))
+
+
 class PageAllocator:
     """Page pool bookkeeping: free list, per-page refcounts, and a prefix
     TRIE keyed on token-block chain hashes (pages are immutable once
@@ -543,6 +560,7 @@ class InferenceEngine:
                         "deadline_expired_running": 0,
                         "queue_rejects": 0,
                         "admission_rejects": 0,
+                        "admission_failed": 0,
                         # Tenancy: admissions deferred because every
                         # HBM-resident adapter was pinned by an in-flight
                         # request (the request waits, it is not failed).
@@ -843,10 +861,11 @@ class InferenceEngine:
             # Belt-and-braces for a demote racing admission: no dispatch
             # ever runs against executor.params=None.
             self._ensure_weights_resident()
-        expired = self._expire_deadlines()
-        if expired:
-            return expired + self._step_scheduled()
-        return self._step_scheduled()
+        # settled without a dispatch: deadlines that passed, and what
+        # admission refused ("admission_failed")
+        settled = self._expire_deadlines() + self._admit()
+        events = self._step_scheduled()
+        return settled + events if settled else events
 
     def _expire_deadlines(self) -> list[dict]:
         """Overload protection: sweep expired request deadlines at the
@@ -912,7 +931,6 @@ class InferenceEngine:
         return events
 
     def _step_scheduled(self) -> list[dict]:
-        self._admit()
         mix = self.metrics["engine_step_mix"]
         with self._lock:
             r = self._prefilling[0] if self._prefilling else None
@@ -952,18 +970,26 @@ class InferenceEngine:
             return self._decode_all()
         return []
 
-    def _admit(self) -> None:
+    def _admit(self) -> list[dict]:
+        """Admit what fits; returns the terminal events of the requests
+        that admission settled instead (``"admission_failed"``)."""
         with annotate("engine.admit") as span:
-            admitted = self._admit_waiting()
+            admitted, failed = self._admit_waiting()
             span.set_metadata(admitted=len(admitted))
         now = time.monotonic()
         for r in admitted:
             r.admitted_at = now
             self.metrics["queue_wait_ms_sum"] += (now - r.arrived_at) * 1e3
             self.metrics["queue_wait_count"] += 1
+        self.metrics["admission_failed"] += len(failed)
+        return [{"request_id": r.request_id, "token": -1, "done": True,
+                 "finish_reason": r.finish_reason} for r in failed]
 
-    def _admit_waiting(self) -> list[Request]:
+    def _admit_waiting(self) -> tuple[list[Request], list[Request]]:
+        """Move waiting requests into slots: ``(admitted, failed)``. One that
+        cannot ever run is marked done and failed, its pages released."""
         admitted: list[Request] = []
+        failed: list[Request] = []
         with self._lock:
             while self._waiting and self._free_slots:
                 r = self._waiting[0]
@@ -1006,6 +1032,7 @@ class InferenceEngine:
                 if fresh is None:  # race-free under lock, but be safe
                     self._unpin_hits_locked(hits, partial)
                     r.done, r.finish_reason = True, "admission_failed"
+                    failed.append(r)
                     continue
                 if partial is not None:
                     # Shared partial tail block maps read-only at the
@@ -1049,11 +1076,13 @@ class InferenceEngine:
                             self.metrics["adapter_defers"] += 1
                             break
                         r.done, r.finish_reason = True, "admission_failed"
+                        failed.append(r)
                         logger.warning("adapter %r load failed: %s", r.model, e)
                         continue
                 elif r.model and self.lora_manager is None:
                     self._release_admission_locked(r)
                     r.done, r.finish_reason = True, "admission_failed"
+                    failed.append(r)
                     continue
                 r.slot = self._free_slots.pop()
                 self._lora_idx[r.slot] = r.lora_slot
@@ -1065,7 +1094,7 @@ class InferenceEngine:
                 r.timeline.add(loop_recorder.EV_PREFIX_HIT,
                                r.cached_prefix_tokens)
             self._record_prefix_match_span(r)
-        return admitted
+        return admitted, failed
 
     def _release_admission_locked(self, r: Request) -> None:
         """Undo a half-admitted request's page state (shared refs, fresh

@@ -12,6 +12,7 @@ scales vLLM engine replicas.
 
 from __future__ import annotations
 
+import itertools
 import json
 import queue
 import threading
@@ -20,7 +21,7 @@ import uuid
 from collections import OrderedDict
 
 from ..observability.tracing import annotate
-from .engine import InferenceEngine, Request
+from .engine import AdmissionFailed, InferenceEngine, Request
 from .tokenizer import ByteTokenizer
 
 # Request-level serving metrics (lazily created so importing llm doesn't
@@ -433,6 +434,8 @@ class LLMDeployment:
         # long-poll) so future estimates converge on reality.
         self.tenancy.note_actual(tenant, len(ids) + max_new_tokens,
                                  len(ids) + len(req.generated))
+        if finish == "admission_failed":
+            raise AdmissionFailed(req)  # the proxy answers its status
         self._note_residency(self._group_of(prompt, session_id), req)
         return {
             "request_id": rid,
@@ -814,14 +817,23 @@ class LLMDeployment:
             # quota-exhausted 429, or an invalid prompt surfaces on a
             # clean error status instead of a truncated 200 stream.
             q = self._admit_streaming(req, tenant)
+            # The engine admits on its own thread: take its first event
+            # before the head too, so a request it refuses (an adapter
+            # that fails to load) is an error status and not a 200.
+            events = self._stream_tokens(req, group, q=q, tenant=tenant)
+            first = next(events, None)
+            if first is not None and \
+                    first.get("finish_reason") == "admission_failed":
+                events.close()
+                raise AdmissionFailed(req)
             yield {"__serve_response__": True, "content_type": "text/event-stream"}
             if chat:
                 head = {"id": cid, "object": obj, "created": created, "model": model,
                         "choices": [{"index": 0, "delta": {"role": "assistant"},
                                      "finish_reason": None}]}
                 yield f"data: {json.dumps(head)}\n\n"
-            for event in self._stream_tokens(req, group, q=q,
-                                             tenant=tenant):
+            for event in itertools.chain(
+                    [] if first is None else [first], events):
                 # Terminal-only events (deadline expiry) carry token -1:
                 # no text, just the finish_reason.
                 text = (self.tokenizer.decode([event["token"]])

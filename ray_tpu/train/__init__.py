@@ -15,7 +15,6 @@ from .config import (
     ScalingConfig,
 )
 from .controller import ElasticScalingPolicy, FixedScalingPolicy
-from .loop import TrainLoopConfig, TrainLoopRunner
 from .session import get_checkpoint, get_context, get_dataset_shard, report
 from .trainer import DataParallelTrainer, JaxTrainer
 
@@ -27,8 +26,6 @@ __all__ = [
     "FixedScalingPolicy",
     "FailureConfig",
     "JaxTrainer",
-    "TrainLoopConfig",
-    "TrainLoopRunner",
     "Result",
     "RunConfig",
     "ScalingConfig",

@@ -97,11 +97,6 @@ class Result:
     # failure and of the first resumed report, plus the resume path — the
     # time to resume is the difference of the two stamps.
     recovery_events: list[dict] = dataclasses.field(default_factory=list)
-    # Compiled-loop mode only (train/loop.py): per-run drive statistics —
-    # mode, per-step wall, checkpoint-commit windows and
-    # `train_ckpt_overlap_frac` (fraction of checkpoint commit time that
-    # overlapped step compute).
-    loop_stats: dict | None = None
 
     @property
     def best_checkpoints(self) -> list:

@@ -16,8 +16,7 @@ import time
 from ..chaos import clock as chaos_clock
 from .checkpoint import Checkpoint, CheckpointManager
 from .config import Result, RunConfig, ScalingConfig
-from .loop import TrainLoopConfig, TrainLoopRunner
-from .worker_group import LoopWorkerGroup, WorkerGroup
+from .worker_group import WorkerGroup
 
 logger = logging.getLogger(__name__)
 
@@ -137,16 +136,8 @@ class TrainController:
         poll_interval_s: float = 0.2,
         datasets: dict | None = None,
         scaling_policy=None,
-        use_compiled_loop: bool | None = None,
     ):
         self._train_fn = train_fn
-        # Structured-step mode (round 15): a TrainLoopConfig instead of a
-        # closure routes the attempt through the stage-actor pipeline —
-        # eager per-step dispatch or the persistent compiled loop.
-        self._loop_spec = train_fn if isinstance(train_fn, TrainLoopConfig) \
-            else None
-        self._use_compiled_loop = use_compiled_loop
-        self.loop_stats: dict | None = None
         self._config = train_loop_config or {}
         self._datasets = datasets or {}
         self._scaling = scaling_config
@@ -189,21 +180,6 @@ class TrainController:
                 # Group creation can fail too (e.g. the placement group is
                 # unschedulable because a node died and the size is stale):
                 # route it through the same failure/re-size path.
-                if self._loop_spec is not None:
-                    # Structured-step mode: the group is the 3 resident
-                    # stage actors; the step stage loads the resume
-                    # checkpoint at construction.
-                    resume = self._resolve_resume()
-                    try:
-                        group = LoopWorkerGroup.create(
-                            self._scaling, name, run_dir, self._loop_spec,
-                            self._config,
-                            resume.path if resume else None)
-                    except Exception as e:
-                        raise WorkerGroupError(
-                            f"train-loop stage creation failed: {e}") from e
-                    self._run_attempt_loop(group)
-                    break
                 try:
                     group = WorkerGroup.create(
                         self._scaling, name, run_dir, num_workers=size)
@@ -243,12 +219,11 @@ class TrainController:
                     continue
                 return Result(
                     metrics=self._metrics_history[-1] if self._metrics_history else None,
-                    checkpoint=self._final_checkpoint(),
+                    checkpoint=self._ckpt_manager.best,
                     path=run_dir,
                     error=last_error,
                     metrics_history=self._metrics_history,
                     recovery_events=self.recovery_events,
-                    loop_stats=self.loop_stats,
                 )
             finally:
                 if group is not None:
@@ -256,12 +231,11 @@ class TrainController:
 
         return Result(
             metrics=self._metrics_history[-1] if self._metrics_history else None,
-            checkpoint=self._final_checkpoint(),
+            checkpoint=self._ckpt_manager.best,
             path=run_dir,
             error=None,
             metrics_history=self._metrics_history,
             recovery_events=self.recovery_events,
-            loop_stats=self.loop_stats,
         )
 
     # ------------------------------------------------------------------
@@ -271,10 +245,7 @@ class TrainController:
         through the control plane, so a dead worker node cannot hide it;
         the report()-registered manager is the sync-mode fallback."""
         ckpt_cfg = self._run_config.checkpoint_config
-        loop_snapshots = (self._loop_spec is not None
-                          and self._loop_spec.snapshot_every > 0)
-        if (getattr(ckpt_cfg, "async_save", False) or loop_snapshots) \
-                and self._experiment_name:
+        if getattr(ckpt_cfg, "async_save", False) and self._experiment_name:
             try:
                 from ..resilience import latest_registered
 
@@ -284,42 +255,6 @@ class TrainController:
             if entry is not None:
                 return Checkpoint(entry["path"])
         return self._ckpt_manager.latest or self._resume
-
-    def _run_attempt_loop(self, group: LoopWorkerGroup) -> None:
-        """One attempt of the structured-step pipeline. Entries flow
-        through the SAME ingest as closure-mode reports (identical
-        ``metrics_history``/recovery-stamp shape); any stage failure —
-        creation, mid-loop death, channel teardown — maps onto
-        ``WorkerGroupError`` so the controller's failure policy and
-        checkpoint-resume path apply unchanged."""
-        runner = TrainLoopRunner(group, self._loop_spec,
-                                 use_compiled_loop=self._use_compiled_loop)
-
-        def on_report(entry: dict) -> None:
-            report = {"rank": 0,
-                      "metrics": dict(entry.get("metrics") or {})}
-            if "ckpt_save_block_ms" in entry:
-                report["ckpt_save_block_ms"] = entry["ckpt_save_block_ms"]
-            self._ingest([{"reports": [report]}])
-
-        try:
-            self.loop_stats = runner.run(on_report)
-        except Exception as e:
-            raise WorkerGroupError(f"train loop attempt failed: {e}") from e
-
-    def _final_checkpoint(self):
-        """Result.checkpoint: loop-mode runs resolve the latest
-        GCS-registered async commit; closure mode keeps the manager."""
-        if self._loop_spec is not None and self._loop_spec.snapshot_every:
-            try:
-                from ..resilience import latest_registered
-
-                entry = latest_registered(self._experiment_name)
-                if entry is not None:
-                    return Checkpoint(entry["path"])
-            except Exception:
-                pass
-        return self._ckpt_manager.best
 
     def _run_attempt(self, group: WorkerGroup, size: int) -> None:
         resume = self._resolve_resume()

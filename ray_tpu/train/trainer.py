@@ -18,19 +18,8 @@ from .controller import TrainController
 class DataParallelTrainer:
     """Generic function trainer: N SPMD workers run ``train_loop_per_worker``.
 
-    ``train_loop_per_worker`` is either the classic closure (eager,
-    ``train.report()``-driven — the default path, unchanged) or a
-    :class:`~ray_tpu.train.TrainLoopConfig` structured step spec (round
-    15): data-loader → train-step → checkpoint-snapshot stage actors,
-    driven eagerly (one dispatch chain per step) or — with
-    ``use_compiled_loop=True`` — parked once on a persistent compiled
-    loop (``dag/loop.py``) so steady-state steps are a channel
-    write+read with zero per-step RPC/lease traffic and the async
-    checkpoint commit overlaps the next step's compute. Both drives are
-    byte-identical at a fixed seed.
-
-    ``use_compiled_loop``: ``None`` (default) defers to
-    ``TrainLoopConfig.use_compiled_loop``; ignored for closure specs.
+    ``train_loop_per_worker`` is a closure that drives its own steps and
+    calls ``train.report()``; ``train_loop_config`` is its plain dict.
     """
 
     def __init__(
@@ -43,7 +32,6 @@ class DataParallelTrainer:
         resume_from_checkpoint: Checkpoint | None = None,
         datasets: dict | None = None,
         scaling_policy=None,
-        use_compiled_loop: bool | None = None,
     ):
         self._train_fn = train_loop_per_worker
         self._train_loop_config = train_loop_config
@@ -52,7 +40,6 @@ class DataParallelTrainer:
         self._resume = resume_from_checkpoint
         self._datasets = datasets or {}
         self._scaling_policy = scaling_policy
-        self._use_compiled_loop = use_compiled_loop
 
     def fit(self) -> Result:
         controller = TrainController(
@@ -63,7 +50,6 @@ class DataParallelTrainer:
             resume_from_checkpoint=self._resume,
             datasets=self._datasets,
             scaling_policy=self._scaling_policy,
-            use_compiled_loop=self._use_compiled_loop,
         )
         return controller.run()
 

@@ -121,81 +121,6 @@ class TrainWorker:
         return True
 
 
-class LoopWorkerGroup:
-    """Compiled-loop mode (round 15): instead of N SPMD closure-driven
-    workers, the group is the THREE resident stage actors of
-    ``train/loop.py`` — data-loader, train-step, checkpoint-snapshot —
-    placed as one atomic unit so the controller's slice-atomic
-    failure/restart discipline applies unchanged: any stage death tears
-    the whole pipeline down and the next attempt resumes from the
-    latest GCS-registered async checkpoint."""
-
-    STAGE_NAMES = ("data", "step", "ckpt")
-
-    def __init__(self, data, step, ckpt, pg):
-        self.data = data
-        self.step = step
-        self.ckpt = ckpt
-        self._pg = pg
-
-    @classmethod
-    def create(cls, scaling_config, experiment_name: str, storage_path: str,
-               spec, config: dict, resume_path: str | None
-               ) -> "LoopWorkerGroup":
-        from .loop import CkptStage, DataLoaderStage, TrainStepStage
-
-        # The step stage owns the devices (the trainer's worker
-        # resources); loader + committer are host-side helpers.
-        bundles = [{"CPU": 0.5}, dict(scaling_config.worker_resources()),
-                   {"CPU": 0.5}]
-        pg = placement_group(bundles,
-                             strategy=scaling_config.placement_strategy)
-        if not pg.wait(timeout_seconds=60.0):
-            remove_placement_group(pg)
-            raise TimeoutError(
-                "placement group for the 3 train-loop stages not ready "
-                "within 60s")
-
-        def make(cls_, idx, name, *args):
-            return ray.remote(cls_).options(
-                resources=dict(bundles[idx]),
-                scheduling_strategy=PlacementGroupSchedulingStrategy(
-                    placement_group=pg, placement_group_bundle_index=idx),
-                name=f"train_loop_{experiment_name}_{name}",
-                runtime_env=scaling_config.worker_runtime_env,
-            ).remote(*args)
-
-        data = make(DataLoaderStage, 0, "data", spec, config)
-        step = make(TrainStepStage, 1, "step", spec, config, resume_path)
-        ckpt = make(CkptStage, 2, "ckpt", spec, config, storage_path,
-                    experiment_name)
-        group = cls(data, step, ckpt, pg)
-        try:
-            # Readiness probe: constructor errors (bad init_fn, corrupt
-            # resume checkpoint) surface HERE, as a group-creation
-            # failure, not mid-loop.
-            ray.get(step.start_step.remote(), timeout=120)
-        except Exception:
-            group.shutdown()
-            raise
-        return group
-
-    @property
-    def actors(self) -> list:
-        return [self.data, self.step, self.ckpt]
-
-    def shutdown(self) -> None:
-        for a in self.actors:
-            try:
-                ray.kill(a)
-            except Exception:
-                pass
-        try:
-            remove_placement_group(self._pg)
-        except Exception:
-            pass
-
-
 class WorkerGroup:
     """Creates, polls and tears down the worker actors as one unit."""
 

@@ -153,7 +153,7 @@ def test_concurrency_groups(ray_cluster):
     t0 = time.monotonic()
     refs = [w.io_call.remote() for _ in range(4)]
     refs += [w.default_call.remote() for _ in range(2)]
-    out = ray_tpu.get(refs, timeout=120)
+    out = ray_tpu.get(refs, timeout=60)
     wall = time.monotonic() - t0
     assert out == ["io"] * 4 + ["d"] * 2
     peaks = ray_tpu.get(w.peaks.remote(), timeout=60)

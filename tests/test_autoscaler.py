@@ -104,7 +104,7 @@ def test_scale_up_runs_infeasible_tasks_then_scales_down(scaling_cluster):
         def heavy(i):
             return i * 10
 
-        results = ray_tpu.get([heavy.remote(i) for i in range(3)], timeout=120)
+        results = ray_tpu.get([heavy.remote(i) for i in range(3)], timeout=60)
         assert sorted(results) == [0, 10, 20]
         assert provider.non_terminated_nodes(), "autoscaler never launched a node"
 

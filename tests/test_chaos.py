@@ -207,7 +207,7 @@ def test_run_plan_rpc_drop_task_retry_succeeds(chaos_cluster):
 def test_run_plan_worker_kill_lease_retry(chaos_cluster):
     """Bundled `worker-kill`: the first lease's worker is SIGKILLed at
     grant; the owner retries on a fresh worker and the run verifies."""
-    report = chaos.run_plan("worker-kill", seed=0, verify_timeout_s=90)
+    report = chaos.run_plan("worker-kill", seed=0, verify_timeout_s=60)
     assert report["verify"]["ok"], report["verify"]["violations"]
     assert report["workload"]["failures"] == 0, report["workload"]
     assert report["injections"].get("kill_worker:kill_worker", 0) >= 1
@@ -226,10 +226,10 @@ def test_lease_reply_drop_orphan_reclaim(chaos_cluster):
 
     def workload():
         refs = [probe.remote(i) for i in range(8)]
-        return {"results": ray_tpu.get(refs, timeout=120)}
+        return {"results": ray_tpu.get(refs, timeout=60)}
 
     report = chaos.run_plan("lease-reply-drop", seed=3, workload=workload,
-                            verify_timeout_s=90)
+                            verify_timeout_s=60)
     assert report["verify"]["ok"], report["verify"]["violations"]
     assert report["workload"]["results"] == [i * i for i in range(8)]
     if report["injections"].get("rpc_response_drop:RequestWorkerLease"):
@@ -285,11 +285,11 @@ def test_gcs_blackout_client_reconnects(chaos_cluster):
     def workload():
         t0 = time.monotonic()
         refs = [ping.remote(i) for i in range(4)]
-        results = ray_tpu.get(refs, timeout=120)
+        results = ray_tpu.get(refs, timeout=60)
         return {"results": results, "elapsed_s": time.monotonic() - t0}
 
     report = chaos.run_plan("gcs-blackout", seed=0, workload=workload,
-                            verify_timeout_s=90)
+                            verify_timeout_s=60)
     assert report["verify"]["ok"], report["verify"]["violations"]
     assert report["workload"]["results"] == [1, 2, 3, 4]
     assert any(k.startswith("gcs_blackout") for k in report["injections"]), \
@@ -415,7 +415,7 @@ def test_serve_replica_kill_request_retried(chaos_cluster):
         os.kill(pid, signal.SIGKILL)
         # the request that lands on the corpse is retried on the
         # controller's replacement replica
-        assert handle.hello.remote("b").result(timeout=90) == "hello b"
+        assert handle.hello.remote("b").result(timeout=60) == "hello b"
     finally:
         try:
             serve.delete("chaosapp")
@@ -467,7 +467,7 @@ def test_affinity_map_survives_replica_death(chaos_cluster):
         os.kill(pid, signal.SIGKILL)
         # retried on the controller's replacement; the router must have
         # purged the corpse's group before re-routing
-        second = session.ask.remote("b").result(timeout=90)
+        second = session.ask.remote("b").result(timeout=60)
         assert second["answer"] == "ok b"
         assert second["instance"] != first["instance"]  # state died: cold
         assert second["seen"] == 1
@@ -557,7 +557,7 @@ def test_roadmap_1c_cascade_repro_under_virtual_clock(chaos_cluster):
         while time.monotonic() < deadline:
             leaked.extend(ray_tpu.put(np.zeros(256)) for _ in range(8))
             time.sleep(0.1)
-        results = ray_tpu.get(refs, timeout=120)
+        results = ray_tpu.get(refs, timeout=60)
         return {"results": results}
 
     report = chaos.run_plan(plan, seed=2, workload=workload,
@@ -592,7 +592,7 @@ def test_randomized_seed_sweep(chaos_cluster):
     re-running with the printed seed)."""
     for seed in range(4):
         report = chaos.run_plan("mixed-seeded", seed=seed,
-                                verify_timeout_s=120)
+                                verify_timeout_s=60)
         assert report["verify"]["ok"], (
             f"seed {seed}: {report['verify']['violations']}")
         assert report["workload"]["failures"] == 0, (
@@ -607,6 +607,6 @@ def test_bundled_plans_all_verify_green(chaos_cluster):
     for name in chaos.BUILTIN_PLANS:
         if name in ("spill-disk-error",):  # exercised by its own test
             continue
-        report = chaos.run_plan(name, seed=1, verify_timeout_s=120)
+        report = chaos.run_plan(name, seed=1, verify_timeout_s=60)
         assert report["verify"]["ok"], (
             f"{name}: {report['verify']['violations']}")

@@ -400,66 +400,3 @@ def test_the_plain_causal_kernels_are_the_parents_instruction_for_instruction(ch
     assert len(kernels) == 3
     assert hashlib.sha256("".join(kernels).encode()).hexdigest() == PLAIN_FLASH_KERNELS, (
         [len(k) for k in kernels])
-
-
-# ------------------------------------------ the scopes, by the chip's compiler
-KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul",
-                  "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta",
-                  "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn", "ray_tpu.ops.moe_rows")
-
-
-def _tiny_step(chip, preset):
-    """A debug preset's whole step (loss and gradient under remat ``attn``),
-    compiled for the described chip: its optimized text."""
-    import dataclasses
-
-    from ray_tpu.models.llama import PRESETS, init_params, loss_fn
-
-    cfg = dataclasses.replace(PRESETS[preset], remat_policy="attn")
-    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)  # noqa: E731
-    params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=chip)}
-    return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=128))
-                   ).lower(params, batch).compile().as_text()
-
-
-@pytest.mark.parametrize("preset,scopes", [
-    # ``hybrid-debug``'s DeltaNet heads are 16 wide, no lane tile: its conv and
-    # gated norm take the plain functions by their shape, steered or not, so no
-    # kernel reads under ``gdn_conv`` or ``gdn_out`` here (the cell's widths:
-    # the ``gdn-conv-*`` / ``gdn-norm-*`` cases above)
-    ("hybrid-debug", {"stack/attn", "stack/attn/gdn_scan", "stack/mlp/moe_experts"}),
-    ("latent-sparse-debug", {"stack/attn", "stack/attn/dsa_index", "stack/attn/dsa_select",
-                             "stack/attn/dsa_loss", "stack/mlp/moe_experts"}),
-    # a layer that attends every causal key calls the plain kernels under a
-    # scope of its own
-    ("latent-full-debug", {"stack/attn/mla_full", "stack/mlp/moe_experts"})])
-def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_without(
-        chip, monkeypatch, preset, scopes):
-    """The hybrid and the sparse step, every kernel module steered to the chip
-    (this process's backend is the CPU): each Mosaic call's own text holds
-    ``rt_scope`` beside ``kernel_metadata``, as the op line prints it, and the
-    program has the kernels it has with ``device_scope`` switched off."""
-    import contextlib
-    import importlib
-    import re
-    import sys
-
-    for name in KERNEL_MODULES:
-        importlib.import_module(name)
-        monkeypatch.setattr(sys.modules[name], "on_tpu", lambda: True)
-    jax.clear_caches()
-    calls = [line for line in _tiny_step(chip, preset).splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    paths = [re.search(r'frontend_attributes=\{kernel_metadata=\{\},rt_scope="([^"]*)"\}', line)
-             for line in calls]
-    assert calls and all(paths)
-    assert {m.group(1) for m in paths} == scopes
-    for module in ("llama", "moe", "mla", "gdn"):
-        monkeypatch.setattr(sys.modules[f"ray_tpu.models.{module}"], "device_scope",
-                            lambda name: contextlib.nullcontext())
-    jax.clear_caches()
-    without = _tiny_step(chip, preset)
-    assert "rt_scope" not in without
-    assert without.count('custom_call_target="tpu_custom_call"') == len(calls)
-    jax.clear_caches()

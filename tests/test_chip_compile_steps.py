@@ -1,0 +1,80 @@
+"""A debug preset's whole step, compiled by the chip's own compiler for a
+described ``v5e:2x2`` with every kernel module steered to it: which scope each
+Mosaic call carries, and that switching the scopes off changes no call. The
+kernels one at a time at the cells' shapes, and the described chip itself
+(``chip``): ``tests/test_chip_compile.py``. Two files so that ``--dist
+loadfile`` can give them to two workers; each worker loads the TPU compiler's
+library, which the driver's command allows (``ALLOW_MULTIPLE_LIBTPU_LOAD=1``;
+without it the second to load skips its tests)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from test_chip_compile import chip  # noqa: F401 - the fixture; sets TPU_LOG_DIR too
+
+KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul",
+                  "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta",
+                  "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn", "ray_tpu.ops.moe_rows")
+
+
+def _tiny_step(chip, preset):
+    """A debug preset's whole step (loss and gradient under remat ``attn``),
+    lowered for the described chip: ``.as_text()`` is what was traced,
+    ``.compile().as_text()`` the chip's optimized program."""
+    import dataclasses
+
+    from ray_tpu.models.llama import PRESETS, init_params, loss_fn
+
+    cfg = dataclasses.replace(PRESETS[preset], remat_policy="attn")
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)  # noqa: E731
+    params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=chip)}
+    return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=128))
+                   ).lower(params, batch)
+
+
+@pytest.mark.parametrize("preset,scopes", [
+    # ``hybrid-debug``'s DeltaNet heads are 16 wide, no lane tile: its conv and
+    # gated norm take the plain functions by their shape, steered or not, so no
+    # kernel reads under ``gdn_conv`` or ``gdn_out`` here (the cell's widths:
+    # ``tests/test_chip_compile.py``'s ``gdn-conv-*`` / ``gdn-norm-*``)
+    ("hybrid-debug", {"stack/attn", "stack/attn/gdn_scan", "stack/mlp/moe_experts"}),
+    ("latent-sparse-debug", {"stack/attn", "stack/attn/dsa_index", "stack/attn/dsa_select",
+                             "stack/attn/dsa_loss", "stack/mlp/moe_experts"}),
+    # a layer that attends every causal key calls the plain kernels under a
+    # scope of its own
+    ("latent-full-debug", {"stack/attn/mla_full", "stack/mlp/moe_experts"})])
+def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_without(
+        chip, monkeypatch, preset, scopes):
+    """The hybrid and the sparse step, every kernel module steered to the chip
+    (this process's backend is the CPU): each Mosaic call's own text holds
+    ``rt_scope`` beside ``kernel_metadata``, as the op line prints it, the
+    chip's compiler keeps every call that was traced, and as many are traced
+    with ``device_scope`` switched off (that form is lowered for the chip and
+    not compiled a second time: the compiler dropped no call of the first)."""
+    import contextlib
+    import importlib
+    import re
+    import sys
+
+    for name in KERNEL_MODULES:
+        importlib.import_module(name)
+        monkeypatch.setattr(sys.modules[name], "on_tpu", lambda: True)
+    jax.clear_caches()
+    lowered = _tiny_step(chip, preset)
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert lowered.as_text().count("@tpu_custom_call") == len(calls)
+    paths = [re.search(r'frontend_attributes=\{kernel_metadata=\{\},rt_scope="([^"]*)"\}', line)
+             for line in calls]
+    assert calls and all(paths)
+    assert {m.group(1) for m in paths} == scopes
+    for module in ("llama", "moe", "mla", "gdn"):
+        monkeypatch.setattr(sys.modules[f"ray_tpu.models.{module}"], "device_scope",
+                            lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    without = _tiny_step(chip, preset).as_text()
+    assert "rt_scope" not in without
+    assert without.count("@tpu_custom_call") == len(calls)
+    jax.clear_caches()

@@ -104,7 +104,7 @@ def test_leases_get_disjoint_chips_and_a_host_lease_gets_all(monkeypatch):
         assert ray_tpu.cluster_resources()["TPU"] == 4.0
         cls = ray_tpu.remote(resources={"TPU": 1}, num_cpus=0)(_Seen)
         a, b = cls.remote(), cls.remote()   # both hold their lease at once
-        env_a, env_b = ray_tpu.get([a.env.remote(), b.env.remote()], timeout=120)
+        env_a, env_b = ray_tpu.get([a.env.remote(), b.env.remote()], timeout=60)
         assert {env_a["TPU_VISIBLE_CHIPS"], env_b["TPU_VISIBLE_CHIPS"]} == {"0", "1"}
         for env in (env_a, env_b):
             assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
@@ -120,7 +120,7 @@ def test_leases_get_disjoint_chips_and_a_host_lease_gets_all(monkeypatch):
             assert time.monotonic() < deadline, "chips not returned"
             time.sleep(0.2)
         whole = ray_tpu.remote(resources={"TPU": 4}, num_cpus=0)(_Seen).remote()
-        env = ray_tpu.get(whole.env.remote(), timeout=120)
+        env = ray_tpu.get(whole.env.remote(), timeout=60)
         assert env["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
         assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,2,1"
     finally:

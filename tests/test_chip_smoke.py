@@ -57,7 +57,7 @@ def test_a_phase_that_ran_on_the_wrong_platform_fails():
 
 def test_command_line_refuses_a_host_without_a_chip():
     out = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
-                         capture_output=True, text=True, timeout=240, cwd=ROOT)
+                         capture_output=True, text=True, timeout=120, cwd=ROOT)
     assert out.returncode != 0
     assert '"ok"' not in out.stdout
     assert "0 TPU chip(s)" in out.stderr

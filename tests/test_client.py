@@ -46,7 +46,7 @@ def test_client_mode_end_to_end():
             # tasks + ref args
             a = double.remote(21)
             b = double.remote(a)
-            assert ray_tpu.get(b, timeout=120) == 84
+            assert ray_tpu.get(b, timeout=60) == 84
 
             # put/get + wait
             ref = ray_tpu.put({{"k": [1, 2, 3]}})
@@ -63,7 +63,7 @@ def test_client_mode_end_to_end():
                     self.n += k
                     return self.n
             c = Counter.remote()
-            assert ray_tpu.get(c.inc.remote(5), timeout=120) == 5
+            assert ray_tpu.get(c.inc.remote(5), timeout=60) == 5
             assert ray_tpu.get(c.inc.remote(2), timeout=60) == 7
 
             # named actor created by the CLUSTER driver
@@ -84,7 +84,7 @@ def test_client_mode_end_to_end():
             print("CLIENT_OK")
         """)
         proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                              text=True, timeout=300, cwd="/root/repo")
+                              text=True, timeout=60, cwd="/root/repo")
         assert "CLIENT_OK" in proc.stdout, proc.stderr[-2000:]
 
         # cluster-side state mutated by the client is visible here
@@ -141,7 +141,7 @@ def test_client_streaming_generator():
             print("STREAM_OK")
         """)
         proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                              text=True, timeout=300, cwd="/root/repo")
+                              text=True, timeout=60, cwd="/root/repo")
         assert "STREAM_OK" in proc.stdout, proc.stderr[-2000:]
     finally:
         server.stop()
@@ -168,7 +168,7 @@ def test_client_actor_method_concurrency_group():
                     return "io-ok"
 
             g = Grouped.remote()
-            assert ray_tpu.get(g.plain.remote(), timeout=120) == "ok"
+            assert ray_tpu.get(g.plain.remote(), timeout=60) == "ok"
             assert ray_tpu.get(
                 g.fetch.options(concurrency_group="io").remote(),
                 timeout=60) == "io-ok"
@@ -176,7 +176,7 @@ def test_client_actor_method_concurrency_group():
             print("CG_OK")
         """)
         proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                              text=True, timeout=300, cwd="/root/repo")
+                              text=True, timeout=60, cwd="/root/repo")
         assert "CG_OK" in proc.stdout, proc.stderr[-2000:]
     finally:
         server.stop()
@@ -207,12 +207,12 @@ def test_client_crash_reaps_session():
                     return "alive"
 
             h = Held.remote()
-            assert ray_tpu.get(h.ping.remote(), timeout=120) == "alive"
+            assert ray_tpu.get(h.ping.remote(), timeout=60) == "alive"
             print("ACTOR_UP")
             os._exit(1)  # crash: no disconnect, no more pings
         """)
         proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                              text=True, timeout=300, cwd="/root/repo")
+                              text=True, timeout=60, cwd="/root/repo")
         assert "ACTOR_UP" in proc.stdout, proc.stderr[-2000:]
 
         # the per-client job was registered
@@ -268,7 +268,7 @@ def test_client_session_expiry_fails_fast():
             print("EXPIRED_OK")
         """)
         proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                              text=True, timeout=300, cwd="/root/repo")
+                              text=True, timeout=60, cwd="/root/repo")
         assert "EXPIRED_OK" in proc.stdout, proc.stderr[-2000:]
     finally:
         cfg.client_session_timeout_s = old_t

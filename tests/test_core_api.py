@@ -217,7 +217,7 @@ def test_cancel_queued_running_and_force(ray_cluster):
     blockers = [blocker.remote(gate) for _ in range(n_cpus)]
     # generous: worker cold-start under full-suite load on 1 core
     deadline = time.time() + 120
-    while ray_tpu.get(gate.count.remote(), timeout=120) < n_cpus:
+    while ray_tpu.get(gate.count.remote(), timeout=60) < n_cpus:
         assert time.time() < deadline, "blockers never started"
         time.sleep(0.05)
     queued = never.remote()   # no CPU free: must queue

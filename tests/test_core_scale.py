@@ -83,7 +83,7 @@ def test_actor_storm_chaos_green(_fresh_cluster_slot):
         def warm():
             return None
 
-        ray_tpu.get([warm.remote() for _ in range(16)], timeout=120)
+        ray_tpu.get([warm.remote() for _ in range(16)], timeout=60)
         time.sleep(1.0)
         baseline_pools = _pool_sizes(cluster)
 
@@ -92,7 +92,7 @@ def test_actor_storm_chaos_green(_fresh_cluster_slot):
             ok = failures = 0
             for a in actors:
                 try:
-                    ray_tpu.get(a.ping.remote(1), timeout=120)
+                    ray_tpu.get(a.ping.remote(1), timeout=60)
                     ok += 1
                 except Exception:
                     failures += 1
@@ -105,7 +105,7 @@ def test_actor_storm_chaos_green(_fresh_cluster_slot):
             return {"ok": ok, "failures": failures}
 
         report = chaos.run_plan("actor-storm", seed=14, workload=workload,
-                                verify_timeout_s=120)
+                                verify_timeout_s=60)
         assert report["verify"]["ok"], report["verify"]["violations"]
         # the plan actually fired: worker kills and (4 nodes exist) the
         # mid-storm preemption notice

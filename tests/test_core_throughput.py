@@ -278,7 +278,7 @@ def test_multiplexed_lease_grants_equivalent_results(ray_cluster, _knobs):
     for batch in (1, 4):
         cfg.lease_grant_batch_size = batch
         assert ray_tpu.get([sq.remote(i) for i in range(40)],
-                           timeout=90) == [i * i for i in range(40)]
+                           timeout=60) == [i * i for i in range(40)]
 
 
 def test_raylet_extra_grants_lease_state(ray_cluster, _knobs):
@@ -356,7 +356,7 @@ def test_multiplexed_lease_recovers_from_dropped_reply(ray_cluster, _knobs):
         report = chaos.run_plan(
             plan, seed=7, verify=False,
             workload=lambda: ray_tpu.get(
-                [val.remote(i) for i in range(24)], timeout=120))
+                [val.remote(i) for i in range(24)], timeout=60))
         assert report["workload"] == list(range(24))
     finally:
         set_chaos(None)

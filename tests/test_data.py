@@ -294,7 +294,7 @@ def test_parquet_row_group_streaming_tasks(ray_cluster, tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=120)
+        t.join(timeout=60)
     assert sorted(seen) == list(builtins.range(n))
 
 

@@ -153,7 +153,7 @@ def test_broadcast_parity_two_concurrent_readers(small_model):
     for t in ts:
         t.start()
     for t in ts:
-        t.join(timeout=90)
+        t.join(timeout=60)
     src.join(timeout=10)
     for res in got:
         assert res is not None and res["complete"], res and res["status"]
@@ -243,7 +243,7 @@ def llm_replica():
 
     dep = LLMDeployment("debug-128", max_slots=2, max_len=64, page_size=8,
                         prefill_chunk_size=32, attention_impl="dense",
-                        use_compiled_loop=False)
+                        use_compiled_loop=False, request_timeout_s=60)
     yield dep
 
 
@@ -303,7 +303,7 @@ def test_promotion_survives_dead_address_and_lost_host_copy(llm_replica):
 
 
 # ----------------------------------------------------------- serve e2e
-def _get(addr, path, timeout=90.0):
+def _get(addr, path, timeout=60.0):
     try:
         with urllib.request.urlopen(addr + path, timeout=timeout) as r:
             return r.status, r.read()
@@ -317,7 +317,7 @@ def _dep_status(app="fleet"):
     return next(iter((serve.status().get(app) or {}).values()), None) or {}
 
 
-def _wait_for(pred, timeout=120.0, period=0.25):
+def _wait_for(pred, timeout=60.0, period=0.25):
     deadline = time.time() + timeout
     while time.time() < deadline:
         st = _dep_status()
@@ -341,7 +341,7 @@ def test_scale_to_zero_and_first_request_wake_e2e(ray_cluster):
             attention_impl="dense", use_compiled_loop=False,
             autoscaling_config={"min_replicas": 1, "max_replicas": 2,
                                 "scale_to_zero_idle_s": 2.0}),
-        name="fleet", route_prefix="/fleet", timeout_s=360.0)
+        name="fleet", route_prefix="/fleet", timeout_s=60.0)
     addr = serve.http_address()
     try:
         status, body = _get(addr, "/fleet?prompt=hi&max_new_tokens=4")
@@ -353,7 +353,7 @@ def test_scale_to_zero_and_first_request_wake_e2e(ray_cluster):
                        and s.get("running_replicas") == 0
                        and s.get("standby_replicas", 0) >= 1
                        and s.get("fleet", {}).get("host_resident", 0) >= 1,
-                       timeout=150.0)
+                       timeout=60.0)
         assert st is not None, _dep_status()
         assert st["healthy"]
 
@@ -386,7 +386,7 @@ def test_standby_pool_demotes_excess_e2e(ray_cluster):
             attention_impl="dense", use_compiled_loop=False,
             autoscaling_config={"min_replicas": 1, "max_replicas": 2,
                                 "standby_replicas": 1}),
-        name="fleet", route_prefix="/fleet", timeout_s=360.0)
+        name="fleet", route_prefix="/fleet", timeout_s=60.0)
     addr = serve.http_address()
     try:
         status, body = _get(addr, "/fleet?prompt=hi&max_new_tokens=4")
@@ -397,7 +397,7 @@ def test_standby_pool_demotes_excess_e2e(ray_cluster):
         st = _wait_for(lambda s: s.get("standby_replicas", 0) >= 1
                        and s.get("running_replicas", 0) >= 1
                        and (s.get("fleet") or {}).get("host_resident", 0) >= 1,
-                       timeout=150.0)
+                       timeout=60.0)
         assert st is not None, _dep_status()
         # Traffic still lands on the running replica only.
         status, _ = _get(addr, "/fleet?prompt=more&max_new_tokens=4")
@@ -417,7 +417,7 @@ def test_util_state_serve_fleet_surface(ray_cluster):
             "debug-128", max_slots=2, max_len=64, page_size=8,
             prefill_chunk_size=32, num_replicas=1, max_ongoing_requests=2,
             attention_impl="dense", use_compiled_loop=False),
-        name="fleet", route_prefix="/fleet", timeout_s=360.0)
+        name="fleet", route_prefix="/fleet", timeout_s=60.0)
     try:
         view = util_state.serve_fleet()
         row = next((v for k, v in view.items() if k.startswith("fleet#")),

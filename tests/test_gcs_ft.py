@@ -71,7 +71,7 @@ def test_what_was_acknowledged_survives_a_crash_at_once(ft_cluster):
     assert call("KvGet", {"key": "kept"})["value"] == b"v"
     assert not call("KvGet", {"key": "gone"})["found"]
     assert call("AddJob", {"driver_address": ""})["job_id"] == job_id + 1
-    assert ray_tpu.get(ray_tpu.get_actor("acked").ping.remote(), timeout=120) == "pong"
+    assert ray_tpu.get(ray_tpu.get_actor("acked").ping.remote(), timeout=60) == "pong"
 
 
 def test_concurrent_acknowledgements_share_a_snapshot(tmp_path):
@@ -142,7 +142,7 @@ def test_gcs_restart_recovers_cluster(ft_cluster):
     def after_restart():
         return "scheduled"
 
-    assert ray_tpu.get(after_restart.remote(), timeout=90) == "scheduled"
+    assert ray_tpu.get(after_restart.remote(), timeout=60) == "scheduled"
 
 
 def test_actor_death_during_gcs_outage_reported_after_restart(ft_cluster):
@@ -224,14 +224,14 @@ def test_gcs_crash_during_actor_creation(ft_cluster):
     ok, dead = 0, 0
     for a in actors:
         try:
-            assert ray_tpu.get(a.ping.remote(), timeout=120) == "pong"
+            assert ray_tpu.get(a.ping.remote(), timeout=60) == "pong"
             ok += 1
         except Exception:
             dead += 1
     # no hangs; the restored GCS must still be able to create NEW actors
     assert ok + dead == 6
     fresh = Slow.options(num_cpus=0.1).remote()
-    assert ray_tpu.get(fresh.ping.remote(), timeout=120) == "pong"
+    assert ray_tpu.get(fresh.ping.remote(), timeout=60) == "pong"
 
 
 def test_gcs_crash_during_pg_commit(ft_cluster):
@@ -275,7 +275,7 @@ def test_gcs_crash_during_long_poll(ft_cluster, capfd):
     ft_cluster.crash_gcs()
     time.sleep(0.3)
     ft_cluster.restart_gcs()
-    assert ray_tpu.get(speak.remote("after"), timeout=90) == "after"
+    assert ray_tpu.get(speak.remote("after"), timeout=60) == "after"
     # the driver's log-echo poller must deliver the post-restart line
     seen = ""
     deadline = time.time() + 30

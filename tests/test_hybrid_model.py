@@ -3,6 +3,7 @@ periods of layer kinds) against its plain reference
 (``benchmark/reference/hybrid_decoder.py``), at a small size on the CPU."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,15 +44,31 @@ def model():
     return params, tokens
 
 
+@pytest.fixture(scope="module")
+def compiled_step(model):
+    """The program's loss with everything counted beside it, and every
+    gradient, compiled once: its outputs are ``program_step``."""
+    params, tokens = model
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=64, return_aux=True),
+            has_aux=True)).lower(params).compile()
+
+
+@pytest.fixture(scope="module")
+def program_step(model, compiled_step):
+    return compiled_step(model[0])
+
+
 def pick(params, slot):
     return jax.tree.map(lambda a: a[0], params["layers"][slot])
 
 
-def test_logits_and_loss_match_the_reference(model):
+def test_logits_and_loss_match_the_reference(model, program_step):
     params, tokens = model
+    (loss, aux), _ = program_step
     with jax.default_matmul_precision("highest"):
-        got = forward(params, tokens, CFG)
-        loss, aux = loss_fn(params, {"tokens": tokens}, CFG, chunk_tokens=64, return_aux=True)
+        got = jax.jit(lambda p: forward(p, tokens, CFG))(params)
     for i in range(tokens.shape[0]):
         want, _ = ref.logits(params, tokens[i], **ARCH)
         assert float(ref.position_errors(got[i], want).max()) < 1e-4
@@ -65,11 +82,12 @@ def test_logits_and_loss_match_the_reference(model):
     np.testing.assert_allclose(aux["held_share"], aux["rows_per_held_expert"].sum(-1) / n_rows, rtol=1e-6)
 
 
-def test_every_gradient_leaf_matches_the_references(model):
+def test_every_gradient_leaf_matches_the_references(model, program_step):
     params, tokens = model
+    _, got = program_step
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=64))(params)
-        want = jax.grad(lambda p: ref.loss(p, tokens, aux_weight=CFG.moe_aux_weight, **ARCH))(params)
+        want = jax.jit(jax.grad(
+            lambda p: ref.loss(p, tokens, aux_weight=CFG.moe_aux_weight, **ARCH)))(params)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     assert len(flat_got) == len(flat_want) == 2 + 1 + 3 * 17 + 16
@@ -78,6 +96,32 @@ def test_every_gradient_leaf_matches_the_references(model):
         assert scale > 0, jax.tree_util.keystr(path)
         np.testing.assert_allclose(a, b, atol=2e-3 * scale, rtol=0,
                                    err_msg=jax.tree_util.keystr(path))
+
+
+def test_at_the_presets_own_width_the_held_ranges_adds_are_plain_ops_under_their_scopes(
+        compiled_step):
+    """A hidden width of 64 is no lane tile, so the held range's rows move by
+    a gather and a scatter-add and not by ``moe_rows`` (at one lane tile:
+    ``tests/test_device_scopes.py``): each of the four, the two of the
+    backward rule too, carries its scope's path, as every gather and scatter
+    of the step does."""
+    text = compiled_step.as_text()
+    assert "moe_rows" not in text
+    moved = set()
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = .*?\s(gather|scatter)\(", line)
+        if not op:
+            continue
+        scope = re.search(r'rt_scope="([^"]*)"', line)
+        assert scope, line
+        by = re.search(r"(transpose\(jvp\(|jvp\(|/)(moe_dispatch|moe_combine)\)*/",
+                       re.search(r'op_name="([^"]*)"', line).group(1))
+        if by:
+            moved.add((op.group(1), scope.group(1), by.group(1)))
+    assert moved >= {("gather", "stack/mlp/moe_dispatch", "jvp("),
+                     ("scatter", "stack/mlp/moe_dispatch", "transpose(jvp("),
+                     ("scatter", "stack/mlp/moe_combine", "/"),
+                     ("gather", "stack/mlp/moe_combine", "transpose(jvp(")}, moved
 
 
 @pytest.mark.parametrize("impl", ["kernels", "jnp"])

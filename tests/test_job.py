@@ -73,7 +73,7 @@ def test_job_driver_connects_to_cluster(ray_cluster, tmp_path):
     jid = client.submit_job(
         entrypoint="python driver.py", runtime_env={"working_dir": str(tmp_path)}
     )
-    status = client.wait_until_terminal(jid, timeout=120)
+    status = client.wait_until_terminal(jid, timeout=60)
     logs = client.get_job_logs(jid)
     assert status == JobStatus.SUCCEEDED, logs
     assert "task-ran-on-cluster" in logs
@@ -100,5 +100,5 @@ def test_task_runtime_env_working_dir(ray_cluster, tmp_path):
 
         return task_helper.ping() + ":" + os.path.basename(os.getcwd())
 
-    out = ray_tpu.get(uses_helper.remote(), timeout=120)
+    out = ray_tpu.get(uses_helper.remote(), timeout=60)
     assert out == f"imported:{os.path.basename(tmp_path)}"

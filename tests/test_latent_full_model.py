@@ -7,6 +7,7 @@ the CPU with the Pallas kernels interpreted, at a size where YaRN's factor
 moves a frequency and the held experts are a sixth of the router's."""
 
 import dataclasses
+import zlib
 import math
 
 import jax
@@ -48,7 +49,9 @@ def params():
 
     def move(path, leaf):  # norms off 1 and biases off 0: one left out must show
         name = str(getattr(path[-1], "key", ""))
-        key = jax.random.fold_in(jax.random.PRNGKey(1), hash(jax.tree_util.keystr(path)) % 2**31)
+        # crc32 and not ``hash``, which differs from one process to the next
+        key = jax.random.fold_in(jax.random.PRNGKey(1),
+                                 zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
         if name.endswith("norm"):
             return leaf + jax.random.uniform(key, leaf.shape, minval=-0.5, maxval=0.5)
         if name == "router_bias":

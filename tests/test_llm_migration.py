@@ -370,7 +370,7 @@ def test_disaggregated_serve_end_to_end(ray_cluster):
             req = urllib.request.Request(
                 addr + path, data=data,
                 headers={"Content-Type": "application/json"})
-            return urllib.request.urlopen(req, timeout=120)
+            return urllib.request.urlopen(req, timeout=60)
 
         ref = json.loads(post("/uni/v1/completions", body).read())
         ref_text = ref["choices"][0]["text"]
@@ -430,14 +430,14 @@ def test_spill_migration_end_to_end(ray_cluster):
             method_name="completions", prefix_group="grp-mig")
         prompt = "You are a helpful assistant. " * 4 + " tail"
         body = {"prompt": prompt, "max_tokens": 6}
-        out1 = h.remote(body).result(timeout=120)
+        out1 = h.remote(body).result(timeout=60)
         router = h._get_router()
         affine = router._group_affinity["grp-mig"]
         bump = cfg.serve_affinity_spill_margin + 1
         with router._cond:
             router._inflight[affine] += bump
         try:
-            out2 = h.remote(body).result(timeout=120)
+            out2 = h.remote(body).result(timeout=60)
         finally:
             with router._cond:
                 router._inflight[affine] -= bump
@@ -468,7 +468,7 @@ def test_prefill_replica_death_mid_migration_retries_cold(ray_cluster):
     cfg = get_config()
     saved_chunk = cfg.kv_migration_chunk_pages
     cfg.kv_migration_chunk_pages = 1  # widen the mid-migration window
-    verifier = RecoveryVerifier(timeout_s=90)
+    verifier = RecoveryVerifier(timeout_s=60)
     baseline = verifier.snapshot_baseline()
     try:
         serve.run(build_llm_app("debug-128", max_slots=4, max_len=256,
@@ -481,7 +481,7 @@ def test_prefill_replica_death_mid_migration_retries_cold(ray_cluster):
         body = json.dumps({"prompt": prompt, "max_tokens": 6,
                            "stream": True}).encode()
 
-        def run_once(timeout=120.0):
+        def run_once(timeout=60.0):
             req = urllib.request.Request(
                 addr + "/chaos/v1/completions", data=body,
                 headers={"Content-Type": "application/json"})
@@ -514,7 +514,7 @@ def test_prefill_replica_death_mid_migration_retries_cold(ray_cluster):
         t.start()
         time.sleep(0.15)  # let admission + the migration stream begin
         ray_tpu.kill(prefill_actor)
-        t.join(timeout=150)
+        t.join(timeout=60)
         assert not t.is_alive()
 
         # Retry until the controller's replacement replica serves it.

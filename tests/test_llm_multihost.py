@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 import ray_tpu
+from llm_cases import within
 from ray_tpu.llm import InferenceEngine, create_sharded_executor
 from ray_tpu.llm.serving import LLMDeployment
 from ray_tpu.models.llama import PRESETS
@@ -40,7 +41,8 @@ def test_multihost_engine_token_parity(ray_cluster, small_cfg):
     ref = InferenceEngine(small_cfg, max_slots=2, max_len=64, page_size=8, seed=0)
     expected = [ref.generate(list(p), max_new_tokens=6) for p in prompts]
 
-    executor = create_sharded_executor(
+    executor = within(
+        60, create_sharded_executor,
         small_cfg, 2,
         max_slots=2,
         num_pages=InferenceEngine.total_pages(2, 64, 8),
@@ -72,7 +74,8 @@ def test_multihost_compiled_loop_token_parity(ray_cluster, small_cfg):
     ref = InferenceEngine(small_cfg, max_slots=2, max_len=64, page_size=8, seed=0)
     expected = [ref.generate(list(p), max_new_tokens=6) for p in prompts]
 
-    executor = create_sharded_executor(
+    executor = within(
+        60, create_sharded_executor,
         small_cfg, 2,
         max_slots=2,
         num_pages=InferenceEngine.total_pages(2, 64, 8),
@@ -106,7 +109,8 @@ def test_multihost_pp_token_parity(ray_cluster, small_cfg):
     ref = InferenceEngine(small_cfg, max_slots=2, max_len=64, page_size=8, seed=0)
     expected = [ref.generate(list(p), max_new_tokens=6) for p in prompts]
 
-    executor = create_sharded_executor(
+    executor = within(
+        60, create_sharded_executor,
         small_cfg, 2,
         max_slots=2,
         num_pages=InferenceEngine.total_pages(2, 64, 8),
@@ -129,11 +133,12 @@ def test_multihost_deployment_generates(ray_cluster):
     behind one replica-facing engine; requests flow scheduler -> shards."""
     cfg = dataclasses.replace(
         PRESETS["debug-128"], dtype=jnp.float32, attn_impl="reference")
-    dep = LLMDeployment(
+    dep = within(
+        60, LLMDeployment,
         cfg, max_slots=2, max_len=64, page_size=8,
         prefill_chunk_size=16, decode_steps_per_dispatch=4,
         num_hosts=2, shard_resources={"CPU": 0.5},
-        shard_runtime_env=SHARD_ENV,
+        shard_runtime_env=SHARD_ENV, request_timeout_s=60,
     )
     try:
         out = dep.generate("ab", max_new_tokens=4)

@@ -287,7 +287,7 @@ def test_profile_capture_and_listing(capsys):
     the artifact lands on disk and registers under list_profiles()."""
     reply = _poll(
         lambda: (r := state.capture_profile(duration=0.3)).get("path") and r,
-        timeout=90.0, interval=1.0)
+        timeout=60.0, interval=1.0)
     assert reply, f"profile capture never succeeded: {state.capture_profile(duration=0.3)}"
     assert os.path.isdir(reply["path"])
     # jax writes plugins/profile/<ts>/*.xplane.pb under the trace dir

@@ -46,7 +46,7 @@ def test_spread_across_nodes(cluster):
     seen = set(
         ray_tpu.get(
             [node_of_task.options(scheduling_strategy={"type": "spread"}).remote() for _ in range(8)],
-            timeout=90,
+            timeout=60,
         )
     )
     assert len(seen) == 2, f"spread used only {seen}"
@@ -102,7 +102,7 @@ def test_cross_node_object_fetch(cluster):
     def big():
         return np.arange(500_000, dtype=np.float32)
 
-    out = ray_tpu.get(big.remote(), timeout=90)
+    out = ray_tpu.get(big.remote(), timeout=60)
     np.testing.assert_array_equal(out, np.arange(500_000, dtype=np.float32))
 
 
@@ -115,7 +115,7 @@ def test_cross_node_large_arg(cluster):
     def total(x):
         return float(x.sum())
 
-    assert ray_tpu.get(total.remote(ref), timeout=90) == 400_000.0
+    assert ray_tpu.get(total.remote(ref), timeout=60) == 400_000.0
 
 
 def test_node_death_detected(cluster):
@@ -136,13 +136,13 @@ def test_lineage_reconstruction_after_node_death(cluster):
         return np.full(300_000, 7.0, dtype=np.float32)
 
     ref = big_on_side.remote()
-    first = ray_tpu.get(ref, timeout=90)
+    first = ray_tpu.get(ref, timeout=60)
     assert first[0] == 7.0
     cluster.remove_node(n2)
     cluster.wait_for_node_death(n2, timeout=30)
     # give the head resources to host the reconstruction
     cluster.add_node(num_cpus=1, resources={"side": 1.0})
-    out = ray_tpu.get(ref, timeout=120)
+    out = ray_tpu.get(ref, timeout=60)
     assert out.shape == (300_000,) and out[0] == 7.0
 
 
@@ -162,7 +162,7 @@ def test_actor_restart_after_node_death(cluster):
             return ray_tpu.get_runtime_context().node_id
 
     a = Stateful.remote()
-    assert ray_tpu.get(a.bump.remote(), timeout=90) == 1
+    assert ray_tpu.get(a.bump.remote(), timeout=60) == 1
     assert ray_tpu.get(a.where.remote(), timeout=60) == n2.node_id.hex()
     n3 = cluster.add_node(num_cpus=1, resources={"side": 1.0})
     cluster.remove_node(n2)
@@ -195,7 +195,7 @@ def test_actor_restart_after_worker_kill(cluster):
             os._exit(1)
 
     a = Phoenix.remote()
-    pid1 = ray_tpu.get(a.pid.remote(), timeout=90)
+    pid1 = ray_tpu.get(a.pid.remote(), timeout=60)
     a.die.remote()
     deadline = time.monotonic() + 90
     while True:
@@ -226,7 +226,7 @@ def test_pg_strict_spread_two_nodes(cluster):
                     placement_group=pg, placement_group_bundle_index=i
                 )
             ).remote(),
-            timeout=90,
+            timeout=60,
         )
         for i in range(2)
     ]
@@ -252,7 +252,7 @@ def test_pg_task_spills_to_bundle_node(cluster):
                 placement_group=pg, placement_group_bundle_index=0
             )
         ).remote(),
-        timeout=90,
+        timeout=60,
     )
     assert where == n2.node_id.hex()
     remove_placement_group(pg)
@@ -273,7 +273,7 @@ def test_task_retry_after_node_death(cluster):
     time.sleep(1.0)  # let it start on n2
     cluster.remove_node(n2)
     cluster.add_node(num_cpus=1, resources={"side": 1.0})
-    out = ray_tpu.get(ref, timeout=120)
+    out = ray_tpu.get(ref, timeout=60)
     assert out != n2.node_id.hex()
 
 
@@ -284,7 +284,7 @@ def test_rpc_chaos_cluster_still_works(cluster):
 
     set_chaos(RpcChaos("Heartbeat=0.3,0.3"))
     try:
-        vals = ray_tpu.get([node_of_task.remote() for _ in range(6)], timeout=120)
+        vals = ray_tpu.get([node_of_task.remote() for _ in range(6)], timeout=60)
         assert len(vals) == 6
     finally:
         set_chaos(RpcChaos(""))
@@ -302,7 +302,7 @@ def test_shuffle_exchange_multinode(cluster):
     ds = rd.range(n, parallelism=16).random_shuffle(seed=11)
     refs = list(ds.iter_internal_ref_bundles())
     assert len(refs) > 1  # partitioned output, not one consolidation block
-    blocks = [ray_tpu.get(r, timeout=120) for r in refs]
+    blocks = [ray_tpu.get(r, timeout=60) for r in refs]
     rows = [v for b in blocks for v in b.column("id").to_pylist()]
     assert sorted(rows) == list(range(n))
     assert rows != sorted(rows)
@@ -376,7 +376,7 @@ def test_broadcast_push_fans_out(cluster):
     for i in range(3):
         out = ray_tpu.get(
             consume.options(resources={f"slot{i}": 0.5}).remote(ref),
-            timeout=120)
+            timeout=60)
         assert out == expected
 
     from ray_tpu.core.worker import global_worker

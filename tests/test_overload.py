@@ -548,7 +548,7 @@ def test_llm_engine_queue_shed_e2e_503(serve_instance):
     addr = serve.http_address()
     # warm the compile caches so the storm is about queueing, not XLA
     _post(addr, "/shed/v1/completions", {"prompt": "warm" * 10,
-                                         "max_tokens": 2}, timeout=120)
+                                         "max_tokens": 2}, timeout=60)
     results = []
     lock = threading.Lock()
 
@@ -557,7 +557,7 @@ def test_llm_engine_queue_shed_e2e_503(serve_instance):
             addr, "/shed/v1/completions",
             {"prompt": f"storm {i}: " + "abcd" * 12, "max_tokens": 24,
              "stream": True},
-            timeout=120)
+            timeout=60)
         with lock:
             results.append((status, headers.get("Retry-After")))
 
@@ -566,7 +566,7 @@ def test_llm_engine_queue_shed_e2e_503(serve_instance):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=150)
+        t.join(timeout=60)
     statuses = [s for s, _ra in results]
     assert statuses.count(200) >= 1, results
     sheds = [(s, ra) for s, ra in results if s == 503]
@@ -596,7 +596,7 @@ def test_llm_deadline_e2e_504_and_mid_decode(serve_instance):
               name="dl-llm", route_prefix="/dlm")
     addr = serve.http_address()
     _post(addr, "/dlm/v1/completions", {"prompt": "warm" * 10,
-                                        "max_tokens": 2}, timeout=120)
+                                        "max_tokens": 2}, timeout=60)
     # Tiny budget + long generation: the deadline expires mid-decode and
     # the stream ends with finish_reason "deadline" — or the request
     # fails fast before admission (504 from the router queue / 503 if
@@ -642,7 +642,7 @@ def test_overload_storm_chaos_recovers_green(ray_cluster):
     from ray_tpu.chaos.verifier import RecoveryVerifier
     from ray_tpu.llm import build_llm_app
 
-    verifier = RecoveryVerifier(timeout_s=90)
+    verifier = RecoveryVerifier(timeout_s=60)
     baseline = verifier.snapshot_baseline()
     serve.run(build_llm_app("debug-128", num_replicas=2, max_slots=2,
                             max_len=128, page_size=16,
@@ -652,7 +652,7 @@ def test_overload_storm_chaos_recovers_green(ray_cluster):
               name="overload", route_prefix="/ovl")
     addr = serve.http_address()
 
-    def one(i, deadline_ms=None, max_tokens=24, timeout=120.0):
+    def one(i, deadline_ms=None, max_tokens=24, timeout=60.0):
         headers = {"Content-Type": "application/json"}
         if deadline_ms:
             headers["x-raytpu-deadline-ms"] = str(deadline_ms)
@@ -678,7 +678,7 @@ def test_overload_storm_chaos_recovers_green(ray_cluster):
     for t in warm:
         t.start()
     for t in warm:
-        t.join(timeout=150)
+        t.join(timeout=60)
 
     # Install the plan in the driver AND inside every replica process —
     # the replica_delay fault fires where the handles execute.

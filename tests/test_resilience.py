@@ -351,7 +351,7 @@ def test_preempt_slice_mid_train_resumes_from_async_ckpt(tmp_path):
         # the replacement slice (in production: the autoscaler's
         # preempt_replaced launch; see test_autoscaler_v2)
         c.add_node(num_cpus=2, resources={"spot_slice": 1.0})
-        t.join(timeout=240)
+        t.join(timeout=60)
         assert not t.is_alive(), "fit() did not finish after the preemption"
         result = box["result"]
         assert result.error is None, result.error
@@ -423,7 +423,7 @@ def test_preempt_mid_serve_proactive_reroute(tmp_path):
         assert _wait_for(
             lambda: (serve.status().get("resilapp", {}).get("Echo", {})
                      .get("running_replicas") == 2),
-            timeout=120), serve.status()
+            timeout=60), serve.status()
         # preempt a replica-hosting node that is NOT the controller's
         ctrl_node = next((a.get("node_id") for a in state.list_actors()
                           if a.get("name") == "SERVE_CONTROLLER"), "")

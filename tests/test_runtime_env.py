@@ -36,7 +36,7 @@ def test_py_modules_staged_on_worker_path(tmp_path):
 
     assert ray_tpu.get(
         use_module.options(runtime_env={"py_modules": [pkg]}).remote(),
-        timeout=120) == 42
+        timeout=60) == 42
 
 
 def test_py_modules_content_hash_invalidates(tmp_path):
@@ -52,12 +52,12 @@ def test_py_modules_content_hash_invalidates(tmp_path):
 
     assert ray_tpu.get(
         read_value.options(runtime_env={"py_modules": [pkg]}).remote(),
-        timeout=120) == 1
+        timeout=60) == 1
     with open(os.path.join(pkg, "__init__.py"), "w") as f:
         f.write("VALUE = 2\n")
     assert ray_tpu.get(
         read_value.options(runtime_env={"py_modules": [pkg]}).remote(),
-        timeout=120) == 2
+        timeout=60) == 2
 
 
 def test_pip_local_package_installed_once(tmp_path):
@@ -87,9 +87,9 @@ def test_pip_local_package_installed_once(tmp_path):
         return renv_pipmod.VALUE
 
     renv = {"pip": [pip_pkg]}
-    assert ray_tpu.get(use_pip.options(runtime_env=renv).remote(), timeout=300) == "installed"
+    assert ray_tpu.get(use_pip.options(runtime_env=renv).remote(), timeout=60) == "installed"
     before = set(glob.glob("/tmp/ray_tpu/runtime_env/pip/*"))
-    assert ray_tpu.get(use_pip.options(runtime_env=renv).remote(), timeout=300) == "installed"
+    assert ray_tpu.get(use_pip.options(runtime_env=renv).remote(), timeout=60) == "installed"
     after = set(glob.glob("/tmp/ray_tpu/runtime_env/pip/*"))
     assert before == after  # cached URI reused, no reinstall
 
@@ -112,9 +112,9 @@ def test_mismatched_envs_never_share_a_worker(tmp_path):
 
     assert ray_tpu.get(
         has_module.options(runtime_env={"py_modules": [pkg_a]}).remote("renv_only_a"),
-        timeout=120) is True
+        timeout=60) is True
     # plain-env task right after: must NOT land on the py_modules worker
-    assert ray_tpu.get(has_module.remote("renv_only_a"), timeout=120) is False
+    assert ray_tpu.get(has_module.remote("renv_only_a"), timeout=60) is False
 
 
 def test_py_executable_plugin(ray_cluster):
@@ -131,7 +131,7 @@ def test_py_executable_plugin(ray_cluster):
 
         return s.executable
 
-    out = ray_tpu.get(which_python.remote(), timeout=120)
+    out = ray_tpu.get(which_python.remote(), timeout=60)
     assert out == sys.executable
 
 
@@ -161,7 +161,7 @@ def test_conda_and_container_gated_errors(ray_cluster):
         return 1
 
     with _pytest.raises(Exception, match="conda"):
-        ray_tpu.get(f.remote(), timeout=120)
+        ray_tpu.get(f.remote(), timeout=60)
 
 
 def test_conda_plugin_resolves_existing_env(tmp_path, monkeypatch):

@@ -36,13 +36,13 @@ def test_actor_creation_under_task_flood():
 
     t0 = time.monotonic()
     actors = [A.remote() for _ in range(3)]
-    out = [ray_tpu.get(a.ping.remote(), timeout=90) for a in actors]
+    out = [ray_tpu.get(a.ping.remote(), timeout=60) for a in actors]
     creation_s = time.monotonic() - t0
     assert out == ["pong"] * 3
     # Actor creation goes to the head of the admission queue: it must beat
     # the ~10s+ task backlog by a wide margin.
     assert creation_s < 45.0, f"actor creation took {creation_s:.1f}s under task flood"
-    assert ray_tpu.get(refs, timeout=180) == list(range(120)) * 2
+    assert ray_tpu.get(refs, timeout=60) == list(range(120)) * 2
 
 
 def test_dag_compiles_under_task_flood():
@@ -70,4 +70,4 @@ def test_dag_compiles_under_task_flood():
         assert compiled.execute(21) == 42
     finally:
         compiled.teardown()
-    ray_tpu.get(refs, timeout=120)
+    ray_tpu.get(refs, timeout=60)

@@ -64,7 +64,7 @@ def test_concurrent_http_traffic(serve_instance):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=90)
+        t.join(timeout=60)
     assert not errors
     assert sorted(r["echo"] for r in results) == sorted(str(i) for i in range(16))
 
@@ -418,7 +418,7 @@ def test_serve_batch_decorator(serve_instance):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=90)
+        t.join(timeout=60)
     assert results == {i: i * 2 for i in range(8)}
     sizes = handle.options(method_name="sizes").remote(None).result(timeout=60)
     # 8 calls with max_batch_size=4 must have been grouped (not 8x size-1).
@@ -796,9 +796,9 @@ def test_llm_serve_prefix_affinity_end_to_end(serve_instance):
         data=json.dumps(body).encode(),
         headers={"Content-Type": "application/json"})
     try:
-        with urllib.request.urlopen(req, timeout=120) as r:
+        with urllib.request.urlopen(req, timeout=60) as r:
             first = json.loads(r.read())
-        with urllib.request.urlopen(req, timeout=120) as r:
+        with urllib.request.urlopen(req, timeout=60) as r:
             second = json.loads(r.read())
         # greedy byte-parity across the cached re-send
         assert first["choices"][0]["text"] == second["choices"][0]["text"]

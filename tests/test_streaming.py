@@ -157,7 +157,7 @@ def test_streaming_retry_mid_items():
             yield i
 
     try:
-        out = [ray_tpu.get(r, timeout=120) for r in fragile.remote(marker)]
+        out = [ray_tpu.get(r, timeout=60) for r in fragile.remote(marker)]
     finally:
         if os.path.exists(marker):
             os.unlink(marker)

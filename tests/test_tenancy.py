@@ -488,17 +488,20 @@ def test_quota_429_and_tenant_rows_e2e(serve_instance):
         "debug-128", max_slots=4, max_len=128, page_size=16,
         prefill_chunk_size=64, num_replicas=1, max_ongoing_requests=8,
         tenancy_config={"tenants": {
-            "metered": {"tokens_per_s": 1.0, "burst_tokens": 40.0},
+            # one request's worth of burst and a refill that covers a
+            # second only after 72 s: however long a loaded host takes
+            # over the first request, the next one is refused
+            "metered": {"tokens_per_s": 0.25, "burst_tokens": 24.0},
             "free": {"weight": 2.0},
         }})
-    serve.run(app, name="quota", route_prefix="/quota", timeout_s=240.0)
+    serve.run(app, name="quota", route_prefix="/quota", timeout_s=60.0)
     addr = serve.http_address()
     body = {"prompt": "hello quota world", "max_tokens": 4}
     status, raw, _h = _post(addr, "/quota/v1/completions", body,
                             headers={"x-raytpu-model": "metered"},
-                            timeout=180.0)
+                            timeout=60.0)
     assert status == 200, raw[:200]
-    # burst exhausted (cost ≈ 17 prompt + 4 gen ≈ 21 of the 40-token
+    # burst exhausted (cost ≈ 17 prompt + 4 gen ≈ 21 of the 24-token
     # burst): the second/third request cannot be covered
     saw_429 = None
     for _ in range(3):
@@ -510,12 +513,12 @@ def test_quota_429_and_tenant_rows_e2e(serve_instance):
             break
     assert saw_429 is not None, "quota never produced a 429"
     retry = int(saw_429.get("Retry-After", "0"))
-    # honest: ~20-token deficit at 1 tok/s, never the constant 1
+    # honest: a deficit of up to 18 tokens at 0.25 tok/s, never the constant 1
     assert 2 <= retry <= 60, retry
     # the quiet tenant is untouched by the metered tenant's quota
     status, _raw, _h = _post(addr, "/quota/v1/completions", body,
                              headers={"x-raytpu-model": "free"},
-                             timeout=120.0)
+                             timeout=60.0)
     assert status == 200
     # per-tenant rows reach serve.status() through the probe fold
     deadline = time.monotonic() + 45
@@ -574,9 +577,9 @@ def test_tenant_aware_shed_quiet_tenant_clean_e2e(serve_instance):
         quiet = threading.Thread(target=client, args=("quiet", 3),
                                  daemon=True)
         quiet.start()
-        quiet.join(timeout=90)
+        quiet.join(timeout=60)
         for t in noisy:
-            t.join(timeout=90)
+            t.join(timeout=60)
         assert results["quiet"] == [200, 200, 200], results["quiet"]
         assert any(s == 503 for s in results["noisy"]), results["noisy"]
     finally:
@@ -747,10 +750,10 @@ def test_live_wfq_reweight_midrun_e2e(serve_instance):
         prefill_chunk_size=32, num_replicas=1, max_ongoing_requests=4,
         tenancy_config={"tenants": {"gold": {"weight": 3.0},
                                     "free": {"weight": 1.0}}})
-    serve.run(app, name="wfq", route_prefix="/wfq", timeout_s=240.0)
+    serve.run(app, name="wfq", route_prefix="/wfq", timeout_s=60.0)
     addr = serve.http_address()
     body = {"prompt": "hello weights", "max_tokens": 4}
-    status, raw, _h = _post(addr, "/wfq/v1/completions", body, timeout=180.0)
+    status, raw, _h = _post(addr, "/wfq/v1/completions", body, timeout=60.0)
     assert status == 200, raw[:200]
 
     router = Router("wfq", "LLMDeployment")  # live, like the proxy's

@@ -46,7 +46,7 @@ def test_tpu_lease_pipeline_reuses_the_holder_process(tpu_cluster):
     def f():
         return os.getpid()
 
-    pids = {ray_tpu.get(f.remote(), timeout=120) for _ in range(3)}
+    pids = {ray_tpu.get(f.remote(), timeout=60) for _ in range(3)}
     assert len(pids) == 1, f"TPU tasks in one pipeline should share a process, got {pids}"
 
 
@@ -59,7 +59,7 @@ def test_tpu_handoff_waits_for_holder_death(tpu_cluster):
     def hold():
         return os.getpid()
 
-    pid1 = ray_tpu.get(hold.remote(), timeout=120)
+    pid1 = ray_tpu.get(hold.remote(), timeout=60)
 
     @ray_tpu.remote(resources={"TPU": 1.0}, num_cpus=1)
     def second(prev_pid):
@@ -70,7 +70,7 @@ def test_tpu_handoff_waits_for_holder_death(tpu_cluster):
             prev_alive = False
         return os.getpid(), prev_alive
 
-    pid2, prev_alive = ray_tpu.get(second.remote(pid1), timeout=120)
+    pid2, prev_alive = ray_tpu.get(second.remote(pid1), timeout=60)
     assert pid2 != pid1
     assert not prev_alive, "previous TPU holder was still alive at grant time"
 
@@ -85,11 +85,11 @@ def test_tpu_handoff_after_actor_kill(tpu_cluster):
             return os.getpid()
 
     a = Holder.remote()
-    pid1 = ray_tpu.get(a.pid.remote(), timeout=120)
+    pid1 = ray_tpu.get(a.pid.remote(), timeout=60)
     ray_tpu.kill(a)
 
     b = Holder.remote()
-    pid2 = ray_tpu.get(b.pid.remote(), timeout=120)
+    pid2 = ray_tpu.get(b.pid.remote(), timeout=60)
     assert pid2 != pid1
     assert not _alive(pid1), "killed TPU actor still alive after next grant"
     ray_tpu.kill(b)
@@ -102,7 +102,7 @@ def test_non_tpu_workers_still_pooled(tpu_cluster):
     def f():
         return os.getpid()
 
-    pids = {ray_tpu.get(f.remote(), timeout=120) for _ in range(3)}
+    pids = {ray_tpu.get(f.remote(), timeout=60) for _ in range(3)}
     assert len(pids) == 1, f"CPU workers should be pooled, got {pids}"
 
 
@@ -129,7 +129,7 @@ def test_tpu_fence_survives_pg_teardown(tpu_cluster):
         scheduling_strategy=PlacementGroupSchedulingStrategy(
             placement_group=pg, placement_group_bundle_index=0),
     ).remote()
-    pid1 = ray_tpu.get(a.pid.remote(), timeout=120)
+    pid1 = ray_tpu.get(a.pid.remote(), timeout=60)
     ray_tpu.kill(a)
     remove_placement_group(pg)  # immediately, as multi-host teardown does
 
@@ -141,7 +141,7 @@ def test_tpu_fence_survives_pg_teardown(tpu_cluster):
         except OSError:
             return os.getpid(), False
 
-    pid2, prev_alive = ray_tpu.get(next_lease.remote(pid1), timeout=120)
+    pid2, prev_alive = ray_tpu.get(next_lease.remote(pid1), timeout=60)
     assert pid2 != pid1
     assert not prev_alive, "PG teardown re-granted the chip before holder death"
 
@@ -182,7 +182,7 @@ def test_tpu_grant_fence_waits_for_external_lock_holder(tmp_path, monkeypatch):
 
         t = threading.Thread(target=release_later)
         t.start()
-        ran_at = ray_tpu.get(probe.remote(), timeout=120)
+        ran_at = ray_tpu.get(probe.remote(), timeout=60)
         t.join()
         assert released_at[0] is not None
         assert ran_at >= released_at[0], (

@@ -1,16 +1,12 @@
 """One clock: the program's host spans (``tracing.annotate``) on the
 profiler's trace, the capture's own window, the reduction of a capture
-(``observability/profile.py``), what a kernel costs, the engine's
-always-on step counters, and the ``tracing.device_scope``s: a path in each
-op's own text, the program otherwise as it was, a capture's table by scope."""
+(``observability/profile.py``) with its table by scope, what a kernel costs,
+and the engine's always-on step counters. What ``tracing.device_scope`` puts
+into an op's own text: ``tests/test_device_scopes.py``."""
 
-import collections
 import contextlib
-import dataclasses
-import functools
 import glob
 import os
-import re
 import subprocess
 import sys
 import threading
@@ -28,8 +24,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _captured(work, tmp_path) -> str:
-    """Run ``work()`` inside a capture of this process; the trace's path."""
-    jax.profiler.start_trace(str(tmp_path))
+    """Run ``work()`` inside a capture of this process, taken as
+    ``profile.capture`` takes one (no Python frames: hooking every thread of
+    a process that holds a cluster costs the export tens of seconds); the
+    trace's path."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         with jax.profiler.TraceAnnotation(profile.WINDOW, wall_s=time.time(),
                                           mono_s=time.monotonic()):
@@ -82,7 +83,7 @@ def test_annotate_is_a_noop_that_imports_nothing_without_jax():
             "assert 'jax' not in sys.modules\n"
             "print('ok')\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True,
-                         capture_output=True, timeout=120)
+                         capture_output=True, timeout=60)
     assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr[-2000:]
 
 
@@ -175,7 +176,7 @@ def test_train_spans_nest_and_carry_what_was_processed(train_capture):
 def serve_capture(tmp_path_factory):
     from ray_tpu.llm.serving import LLMDeployment
 
-    server = LLMDeployment("debug-128", max_slots=4, max_len=128)
+    server = LLMDeployment("debug-128", max_slots=4, max_len=128, request_timeout_s=60)
     out = {}
     try:
         server.generate("warm up the programs", max_new_tokens=4)
@@ -398,252 +399,6 @@ def test_paged_decode_cost_and_the_device_report():
     assert "kernel_traces" in device_report()
 
 
-# ------------------------------------------- the scopes are text in the op
-def _stripped(text: str) -> str:
-    """Compiled HLO text without names, metadata and ``rt_scope``: what is
-    left is the program."""
-    text = re.sub(r", metadata=\{[^}]*\}", "", text)
-    text = re.sub(r', frontend_attributes=\{rt_scope="[^"]*"\}', "", text)
-    text = re.sub(r'rt_scope="[^"]*",|,rt_scope="[^"]*"', "", text)  # beside another attribute
-    # the tables of files, functions and stack frames that metadata points into
-    text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames)$|^\d+ .*$", "", text)
-    text = re.sub(r"%[\w.\-]+", "%", text)
-    text = re.sub(r"[A-Za-z_][\w.\-]*: ", "", text)  # a computation's parameters
-    text = re.sub(r"(HloModule|ENTRY|calls=|to_apply=|body=|condition=)\s*\S+", r"\1", text)
-    return "\n".join(line for line in text.splitlines() if line.strip())
-
-
-# every scope a model opens, outermost first: an instruction's ``op_name``
-# holds the open ones among jax's own (``jvp(stack)/while/body/.../attn/mul``)
-SCOPES = ("stack", "embed", "lm_head_loss", "index_loss", "attn", "mlp", "moe_route",
-          "moe_dispatch", "moe_experts", "moe_combine", "moe_shared", "gdn_proj", "gdn_conv",
-          "gdn_scan", "gdn_out", "mla_q", "mla_kv", "dsa_index", "dsa_select", "dsa_loss",
-          "attn_gate", "mla_out", "prefill_chunk", "decode_step")
-# model kind -> (its debug preset, paths its step must hold). The held range's
-# backward runs its branch again inside a ``custom_vjp`` rule: its dispatch,
-# experts and combine are under ``stack/mlp`` there too
-KINDS = {
-    "dense": ("debug-128", {"embed", "lm_head_loss", "stack", "stack/attn", "stack/mlp"}),
-    "routed": ("llama-moe-debug", {"stack/mlp/moe_route", "stack/mlp/moe_dispatch",
-                                   "stack/mlp/moe_experts", "stack/mlp/moe_combine"}),
-    "hybrid": ("hybrid-debug", {"stack/attn/gdn_proj", "stack/attn/gdn_conv",
-                                "stack/attn/gdn_scan", "stack/attn/gdn_out",
-                                "stack/attn/attn_gate", "stack/mlp/moe_shared",
-                                "stack/mlp/moe_experts"}),
-    "sparse": ("latent-sparse-debug", {"stack/attn/mla_q", "stack/attn/mla_kv",
-                                       "stack/attn/mla_out", "stack/attn/dsa_index",
-                                       "stack/attn/dsa_select", "stack/attn/dsa_loss",
-                                       "stack/attn/attn_gate", "stack/mlp/moe_shared"}),
-}
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = .*?\s([a-z][\w\-]*)\(")
-
-
-def _lower_loss(preset="debug-128", **changes):
-    from ray_tpu.models.llama import PRESETS, init_params, loss_fn
-
-    cfg = dataclasses.replace(PRESETS[preset], dtype=jnp.float32, remat_policy="attn", **changes)
-    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 64), jnp.int32)}
-    return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=32))
-                   ).lower(params, batch).compile().as_text()
-
-
-def _lower_mixed():
-    from ray_tpu.llm import model as llm_model
-    from ray_tpu.models.llama import PRESETS, init_params
-
-    cfg = PRESETS["debug-128"]
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    page, slots, max_pages = 16, 2, 4
-    pages = llm_model.init_pages(cfg, slots + slots * max_pages, page)
-    op = (jnp.zeros(max_pages, jnp.int32), jnp.zeros(16, jnp.int32), jnp.int32(0))
-    z = lambda dt=jnp.int32: jnp.zeros(slots, dt)  # noqa: E731
-    return llm_model.mixed_dispatch.lower(
-        params, pages, (op,), jnp.zeros((slots, max_pages), jnp.int32), z(), z(),
-        z(jnp.float32), z() - 1, z() + 4, jax.random.PRNGKey(0), config=cfg,
-        page_size=page, n_steps=2, live_pages=2, prefill_live_pages=(1,)
-    ).compile().as_text()
-
-
-def _without_scopes(monkeypatch):
-    """The helper switched off at every module that opens a scope."""
-    import ray_tpu.llm.model
-    import ray_tpu.models.gdn
-    import ray_tpu.models.llama
-    import ray_tpu.models.mla
-    import ray_tpu.models.moe
-
-    for module in (ray_tpu.models.llama, ray_tpu.models.moe, ray_tpu.models.mla,
-                   ray_tpu.models.gdn, ray_tpu.llm.model):
-        monkeypatch.setattr(module, "device_scope", lambda name: contextlib.nullcontext())
-    jax.clear_caches()
-
-
-@pytest.fixture(scope="module")
-def step_texts():
-    """kind -> the compiled text of its tiny step, compiled once a module."""
-    texts = {}
-
-    def get(kind):
-        if kind not in texts:
-            texts[kind] = _lower_loss(KINDS[kind][0])
-        return texts[kind]
-
-    return get
-
-
-def _scoped_instructions(text):
-    """(name, opcode, rt_scope or None, the model scopes in op_name in order)
-    of the instructions that compute."""
-    for line in text.splitlines():
-        m = _INSTRUCTION.match(line)
-        if not m or m.group(2) in ("parameter", "constant", "get-tuple-element", "tuple",
-                                   "bitcast"):
-            continue
-        scope = re.search(r'rt_scope="([^"]*)"', line)
-        op_name = re.search(r'op_name="([^"]*)"', line)
-        named = [part for part in re.split(r"[/()]", op_name.group(1)) if part in SCOPES] \
-            if op_name else []
-        yield m.group(1), m.group(2), scope and scope.group(1), named
-
-
-@pytest.mark.parametrize("kind", list(KINDS))
-def test_every_op_a_model_scope_issued_carries_its_path(step_texts, kind):
-    rows = list(_scoped_instructions(step_texts(kind)))
-    paths = {scope for _, _, scope, _ in rows if scope}
-    assert KINDS[kind][1] <= paths, KINDS[kind][1] - paths
-    assert all(set(path.split("/")) <= set(SCOPES) for path in paths), paths
-    # products, loops and gathers that op_name puts in a scope: all carry it
-    # (a kernel too: ``tests/test_chip_compile.py``, where one is a custom call)
-    heavy = [r for r in rows if r[1] in ("dot", "convolution", "while", "dynamic-update-slice",
-                                         "gather", "scatter", "sort") and r[3]]
-    assert heavy and not [r for r in heavy if not r[2]]
-    # a fusion carries the path of the instruction it was made around (its root
-    # then), so one made around a bitcast, a broadcast or a copy of the
-    # compiler's is bare whatever it holds: most are not
-    fusions = [r for r in rows if r[1] == "fusion" and r[3]]
-    assert len([r for r in fusions if r[2]]) >= 0.7 * len(fusions)
-    # and the path agrees with op_name, under scan, checkpoint and each
-    # custom_vjp rule alike: the same innermost scope, no scope op_name lacks
-    # but the scan's own (op_name may repeat one or lose ``stack``; the path
-    # holds each as opened)
-    for name, opcode, scope, named in rows:
-        if scope and named and opcode in ("dot", "convolution"):  # never the compiler's
-            parts = scope.split("/")
-            assert parts[-1] == named[-1] and set(named) <= set(parts) <= {"stack", *named}, (
-                name, scope, named)
-
-
-def test_the_head_loss_and_the_held_range_keep_their_scope_in_the_backward_rule(step_texts):
-    text = step_texts("hybrid")
-    back = [line for line in text.splitlines() if "transpose(jvp" in line]
-    for path in ("lm_head_loss", "stack/mlp/moe_dispatch", "stack/mlp/moe_experts",
-                 "stack/mlp/moe_combine"):
-        assert any(f'rt_scope="{path}"' in line for line in back), path
-    # the forward rule's own pass (the loss and its gradients in one scan)
-    assert any('rt_scope="lm_head_loss"' in line and " while(" in line
-               for line in text.splitlines())
-    # the held range's adds at a width of one lane tile (``moe_rows``, interpreted
-    # here, a custom call on a TPU): the forward one under ``moe_combine``, the
-    # gather's gradient under ``moe_dispatch`` inside the backward rule, so the
-    # two scope readers keep seeing them
-    wide = _lower_loss(KINDS["hybrid"][0], hidden=128)
-    rows = [line for line in wide.splitlines() if "/moe_rows/" in line and 'rt_scope="' in line]
-    assert {(re.search(r'rt_scope="([^"]*)"', line).group(1), "transpose(jvp" in line)
-            for line in rows} == {("stack/mlp/moe_combine", False), ("stack/mlp/moe_dispatch", True)}
-
-
-@pytest.mark.parametrize("kind", list(KINDS) + ["serving"])
-def test_scopes_change_names_metadata_and_the_attribute_only(monkeypatch, step_texts, kind):
-    lower = _lower_mixed if kind == "serving" else functools.partial(_lower_loss, KINDS[kind][0])
-    with_scopes = _lower_mixed() if kind == "serving" else step_texts(kind)
-    for scope in (("prefill_chunk", "decode_step") if kind == "serving"
-                  else ("attn", "mlp", "embed", "lm_head_loss")):
-        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', with_scopes), scope
-        assert re.search(rf'rt_scope="([^"]*/)?{scope}(/[^"]*)?"', with_scopes), scope
-    _without_scopes(monkeypatch)
-    without = lower()
-    assert "rt_scope" not in without
-    assert not re.search(r'op_name="[^"]*[/(](attn|mlp|decode_step)[/)]', without)
-    assert _stripped(with_scopes) == _stripped(without)
-    assert with_scopes.count("custom-call") == without.count("custom-call")
-
-
-def _paths(fn, *args) -> list:
-    """rt_scope of every op of ``fn``'s lowering that has one, in order."""
-    return re.findall(r'rt_scope = "([^"]*)"', jax.jit(fn).lower(*args).as_text())
-
-
-def test_nested_scopes_give_paths_and_leave_none_behind():
-    def fn(x):
-        with tracing.device_scope("outer") as outer:
-            x = jnp.sin(x)
-            with tracing.device_scope("inner") as inner:
-                x = jnp.cos(x)
-            x = jnp.tan(x)
-        assert (outer, inner) == ("outer", "outer/inner")
-        return jnp.exp(x)  # after the block: no attribute
-
-    text = jax.jit(fn).lower(jnp.ones(4)).as_text()
-    assert re.findall(r'rt_scope = "([^"]*)"', text) == ["outer", "outer/inner", "outer"]
-    assert "rt_scope" not in next(l for l in text.splitlines() if "exponential" in l)
-    assert re.search(r'stablehlo\.cosine.*rt_scope = "outer/inner"', text)
-
-
-def test_a_scope_follows_scan_checkpoint_and_a_custom_vjp_rule():
-    @jax.custom_vjp
-    def f(x, w):
-        return jnp.tanh(x @ w)
-
-    def f_bwd(res, g):
-        x, w = res
-        with tracing.device_scope("rule"):  # nests under the CALL's path
-            gg = g * (1 - jnp.tanh(x @ w) ** 2)
-        return gg @ w.T, x.T @ gg  # bare in the rule: the call's path
-
-    f.defvjp(lambda x, w: (f(x, w), (x, w)), f_bwd)
-
-    def loss(w, x):
-        def body(c, wi):
-            with tracing.device_scope("mlp"):
-                c = jax.checkpoint(lambda c, wi: f(c, wi) + jnp.sin(c))(c, wi)
-            return c, None
-
-        with tracing.device_scope("stack"):
-            c, _ = jax.lax.scan(body, x, w)
-        return jnp.sum(c ** 2)
-
-    text = jax.jit(jax.grad(loss)).lower(jnp.ones((3, 8, 8)), jnp.ones((4, 8))
-                                         ).compile().as_text()
-    by_path = collections.Counter(
-        (m.group(2), scope.group(1)) for line in text.splitlines()
-        if (m := _INSTRUCTION.match(line)) and (scope := re.search(r'rt_scope="([^"]*)"', line)))
-    assert by_path[("dot", "stack/mlp/rule")] == 1   # the rule's own product
-    assert by_path[("dot", "stack/mlp")] == 3        # forward, and the rule's two bare ones
-    assert by_path[("cosine", "stack/mlp")] == 1     # sin's derivative, from the remat
-    assert by_path[("while", "stack")] == 2          # the scan, forward and transposed
-    assert not [k for k in by_path if not k[1].startswith("stack")]
-
-
-def test_a_thread_or_a_jit_traced_inside_a_scope_leaks_none():
-    double = jax.jit(lambda x: x * 2)
-    seen = {}
-
-    def elsewhere():
-        seen["thread"] = _paths(lambda x: jnp.sin(x), jnp.ones(4))
-
-    with tracing.device_scope("outer"):
-        # traced first inside the scope, as a call of an outer program
-        inside = _paths(lambda x: double(x) + 1, jnp.ones(4))
-        t = threading.Thread(target=elsewhere)
-        t.start()
-        t.join()
-    assert inside and set(inside) == {"outer"}
-    assert seen["thread"] == []
-    assert _paths(lambda x: double(x) + 1, jnp.ones(4)) == []
-    assert "rt_scope" not in double.lower(jnp.ones(4)).as_text()
-
-
 def test_summarize_tables_the_step_by_scope(tmp_path):
     """Self time by ``rt_scope`` path on device 0, rolled up to a path's
     first and last component; an op without one is under ""."""
@@ -709,7 +464,7 @@ def stepper(train_capture):
             return n
 
     actor = Stepper.options(name="stepper-for-profile").remote()
-    assert ray_tpu.get(actor.run.remote(0.05), timeout=120) > 0  # jax is imported there now
+    assert ray_tpu.get(actor.run.remote(0.05), timeout=60) > 0  # jax is imported there now
     yield actor
     ray_tpu.kill(actor)
 

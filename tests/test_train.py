@@ -268,7 +268,7 @@ def test_elastic_scaling_upscale(tmp_path):
         t.start()
         time.sleep(3.0)  # let the 1-worker attempt make progress
         c.add_node(num_cpus=2)  # capacity for 2 more workers
-        t.join(timeout=180)
+        t.join(timeout=60)
         assert not t.is_alive(), "elastic fit() did not finish"
         result = result_box["result"]
         assert result.error is None, result.error
@@ -373,7 +373,7 @@ def test_elastic_scaling_downscale_on_node_death(tmp_path):
         else:
             raise AssertionError("3-worker attempt never checkpointed")
         c.remove_node(n2)  # kill 2 of 3 workers' node
-        t.join(timeout=240)
+        t.join(timeout=60)
         assert not t.is_alive(), "fit() did not finish after node loss"
         result = box["result"]
         assert result.error is None, result.error

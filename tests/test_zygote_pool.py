@@ -101,7 +101,7 @@ def test_pooled_worker_never_handed_to_mismatched_lease(ray_cluster,
     # Workers of each env exist and are keyed correctly end to end: the
     # env var actually differs inside the processes.
     assert ray_tpu.get([probe_a.remote(), probe_b.remote()],
-                       timeout=120) == ["a", "b"]
+                       timeout=60) == ["a", "b"]
     by_hash = {}
     for w in raylet._workers.values():
         if w.state in ("idle", "leased"):
@@ -144,7 +144,7 @@ def test_pool_eviction_on_env_mismatch(ray_cluster, _pool_knobs):
 
     # Touch three env keys in order via the lease path.
     for i, env in enumerate(envs):
-        ray_tpu.get(mk.options(runtime_env=env).remote(i), timeout=120)
+        ray_tpu.get(mk.options(runtime_env=env).remote(i), timeout=60)
     deadline = time.monotonic() + 10
     while time.monotonic() < deadline and hashes[0] in raylet._pool_keys:
         time.sleep(0.1)
@@ -178,7 +178,7 @@ def test_idle_pool_shrinks_to_target(ray_cluster, _pool_knobs):
         time.sleep(0.05)
         return i
 
-    ray_tpu.get([burst.remote(i) for i in range(12)], timeout=120)
+    ray_tpu.get([burst.remote(i) for i in range(12)], timeout=60)
     target = max(cfg.num_prestart_workers, cfg.zygote_pool_size)
     deadline = time.monotonic() + 15
     while time.monotonic() < deadline:
@@ -204,7 +204,7 @@ def test_spawn_histogram_records_pooled_and_cold(ray_cluster, _pool_knobs):
     def touch():
         return 1
 
-    ray_tpu.get([touch.remote() for _ in range(8)], timeout=120)
+    ray_tpu.get([touch.remote() for _ in range(8)], timeout=60)
     deadline = time.monotonic() + 20
     while time.monotonic() < deadline and not raylet._spawn_stats.get("pooled"):
         ray_tpu.get(touch.remote(), timeout=60)
