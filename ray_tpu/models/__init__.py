@@ -8,13 +8,17 @@ latent attention (``mla.py``: low-rank q and kv; three choices of keys: a
 learned indexer's top-k, a causal window, every causal key; a head-wise gate;
 YaRN with its factor on the softmax scale), grouped-query attention by
 spec (``gqa.py``: a head count, a rope, plain or YaRN with its factor on cos
-and sin, and a window of a kind's own, a head-wise gate). MLPs: dense SwiGLU, or with
+and sin, or no rope at all, and a window of a kind's own, a head-wise gate).
+MLPs: dense SwiGLU, or with
 ``moe_experts > 0`` a routed expert layer (``moe.py``: dropless, the
 (token, expert) rows sorted by expert over a Pallas grouped matmul, a softmax
 or a sigmoid router with its selection bias, a routed scale, a shared expert,
-a chip's share of the experts); leading layers may have an MLP kind of their own.
+a chip's share of the experts, SwiGLU or ReGLU experts); leading layers may
+have an MLP kind of their own. A block hands its MLP kind the block's input
+before the mixer runs, for a router that reads the residual stream there and
+not the MLP's own normed input (``kinds.py``: ``early``).
 Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next, dots3-note-prev,
-Laguna-S-2.1 and Kimi-K2 are configurations."""
+Laguna-S-2.1, Kimi-K2 and SmallThinker are configurations."""
 
 from .llama import (
     LlamaConfig,

@@ -5,7 +5,9 @@ key) and ``gqa_win`` (a causal window), each described by a
 may so hold two head counts, two ropes and a window side by side
 (Laguna-S-2.1: 48 query heads under YaRN on half of a head in its full
 layers, 72 under plain rope and a 512-key window in the others, 8 kv heads
-of 128 in both).
+of 128 in both; SmallThinker: full layers with NO rope, whose only position
+signal is the causal mask, beside roped layers under a 4,096-key window, 28
+query heads over 4 kv heads in both).
 
 The ``attn`` kind of ``models/llama.py`` reads the config's own top-level
 widths and is NOT built from this spec. It is the one kind with what no
@@ -23,13 +25,16 @@ For the normed input ``x`` of a position, H query heads and KV key/value
 heads of D features (head h reads kv head ``h // (H / KV)``):
 
     q_h = x W_q[h];  k_j = x W_k[j];  v_j = x W_v[j];  rope on q and k
+                                              (``rope_theta`` 0: none, q and
+                                               k go to the scores as projected)
     o_h = softmax over the allowed keys of (q_h . k_j D^-1/2) v_j
     y   = concat_h(sigmoid(x W_g)_h o_h) W_o          (``gate`` "headwise")
 
 Allowed keys of query t: s <= t, and with ``window`` t - s < window. Rope
 turns the first ``rotary_dim`` features of a head (0: all) by ``rope_theta``'s
 frequencies, or with ``yarn`` by YaRN's (``ops/rope.py``), cos and sin times
-its ``attention_factor``.
+its ``attention_factor``. A window layer's kernels run in blocks that follow
+the window (``window_blocks``).
 
 The mixer counts beside its output, where it has a window, ``window_share``:
 the (query, key) pairs it attends over the causal pairs, from the positions
@@ -59,7 +64,7 @@ class GroupedQueryAttention:
     heads: int
     kv_heads: int
     head_dim: int
-    rope_theta: float
+    rope_theta: float         # 0: no rope at all (the causal mask alone)
     rotary_dim: int = 0       # 0: rope on every feature of a head
     yarn: Yarn | None = None
     window: int = 0           # 0: none. Else query t sees keys t - window + 1 .. t
@@ -98,9 +103,28 @@ def _init(a: GroupedQueryAttention, c, keys, lead, normal) -> dict:
 
 
 def _rope(t, positions, a: GroupedQueryAttention):
+    if not a.rope_theta:
+        return t
     rotary = a.rotary_dim or a.head_dim
     return apply_rope(t, positions, rotary_dim=rotary,
                       **rope_keywords(rotary, a.rope_theta, a.yarn))
+
+
+# Blocks of a window longer than 512 keys. At `1 x 28 x 16384 x 128` over 4 kv
+# heads under a 4,096-key window, the kernels alone on a v5e (my chip run, PR
+# 45: timed calls, q x k blocks, forward / forward and backward, ms): 512 x 512
+# 13.0 / 37.7 (a band of 9 tiles, 8 whole), 1024 x 1024 8.0 / 29.8 (5 tiles, 4
+# whole), 512 x 1024 8.8 / 32.1, 1024 x 512 15.5 / 38.7; 2048 rows either way
+# do not fit VMEM. By whole traced steps of the 12-layer cell (9 window
+# layers): 1,223.3 ms a step at 512 x 512, 1,153.5 at 1024 x 1024.
+WIDE_WINDOW_BLOCK = 1024
+
+
+def window_blocks(window: int) -> int:
+    """Query and key rows a tile of the ``attn_win_*`` kernels, by the window:
+    a window of at most 512 keys takes 512-blocks (blocks of its own size: a
+    query block's band is two key blocks), a longer one WIDE_WINDOW_BLOCK."""
+    return 512 if window <= 512 else WIDE_WINDOW_BLOCK
 
 
 def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=None):
@@ -124,9 +148,9 @@ def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=Non
         v = checkpoint_name(v, "v")
         aux = {}
         if a.window:
-            # blocks of the window's size: a query block's band is two key blocks
+            block = window_blocks(a.window)
             attn = flash_per_shard(q, k, v, mesh, causal=True, window=a.window,
-                                   block_q=512, block_k=512)
+                                   block_q=block, block_k=block)
             kept = jnp.sum(jnp.minimum(positions.astype(jnp.float32) + 1.0, a.window))
             aux["window_share"] = kept / (positions.size / s) / (s * (s + 1) / 2)
         else:
@@ -162,4 +186,5 @@ def _kind(field: str) -> LayerKind:
 GQA = _kind("gqa")
 GQA_WINDOW = _kind("gqa_window")
 
-__all__ = ["GroupedQueryAttention", "Yarn", "GQA", "GQA_WINDOW", "SAVE_NAMES", "gqa_mixer"]
+__all__ = ["GroupedQueryAttention", "Yarn", "GQA", "GQA_WINDOW", "SAVE_NAMES", "gqa_mixer",
+           "window_blocks"]

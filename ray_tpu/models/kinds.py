@@ -1,6 +1,9 @@
 """What a kind of layer part owns.
 
-A decoder block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack
+A decoder block is ``x += mixer(norm(x)); x += mlp(norm(x))``, and an MLP
+kind may besides compute something from the block's input ``x`` itself,
+before the mixer runs (``early``: a router that reads the residual stream as
+it enters the block; most kinds have none). The stack
 (``models/llama.py``) knows that much and no more: which leaves a mixer or
 an MLP has, how they shard, how they start, what they cost, what of their
 forward pass a remat policy may save and what they count beside the loss
@@ -33,7 +36,8 @@ class LayerKind:
     # stack's truncated-normal draw in the model's dtype
     init: Callable[[Any, Any, tuple, Callable], dict]
     # mixer: (h, layer, config=, positions=, mesh=) -> y
-    # mlp:   (h, layer, config=, mesh=, ep_axis=) -> (y, aux)
+    # mlp:   (h, layer, config=, mesh=, ep_axis=) -> (y, aux), and with
+    #        ``early=`` what its ``early`` returned, where that is not None
     apply: Callable
     # config -> matmul parameters one token passes through in one layer
     matmul_params: Callable[[Any], float]
@@ -42,6 +46,10 @@ class LayerKind:
     mixing_flops: Callable[[Any, int], float] = lambda c, seq: 0.0
     # checkpoint names the ``attn`` remat policy saves for this kind
     save_names: tuple[str, ...] = ()
+    # mlp only: (x, layer, config=) -> what the kind computes from the
+    # block's input x [B, S, E] (before the attention norm and the mixer), or
+    # None; the block hands it to ``apply`` as ``early=``
+    early: Callable | None = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,6 +1,9 @@
 """A pre-norm decoder described by layer kinds, TPU-first.
 
-A block is ``x += mixer(norm(x)); x += mlp(norm(x))``. The stack knows no
+A block is ``x += mixer(norm(x)); x += mlp(norm(x))``, the MLP kind first
+given the block's input ``x`` itself for what it computes from that alone (a
+router that reads the residual stream before the attention norm; the dense
+kinds take nothing from it). The stack knows no
 more than that: a token mixer (``attn``: softmax attention, below;
 ``gdn``: Gated DeltaNet, ``models/gdn.py``; ``mla`` / ``mla_win`` / ``mla_full``:
 latent attention under a learned selection of keys, a window or over every
@@ -18,7 +21,10 @@ is three DeltaNet blocks and one of gated attention; dots3-note-prev is a
 leading dense layer, then an indexed and three window layers; Laguna-S-2.1
 a leading dense layer under full attention, then three window layers of 72
 heads and a full one of 48; Kimi-K2 a leading dense layer and a period of
-one expert layer, all under latent attention over every causal key.
+one expert layer, all under latent attention over every causal key;
+SmallThinker a period of one un-roped full layer and three roped 4,096-key
+window layers, no leading layer, ReGLU experts whose router reads the block's
+input.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -141,6 +147,13 @@ class LlamaConfig:
     moe_bias_rate: float = 0.0
     moe_shared_gate: bool = True
     moe_routed_scale: float = 1.0
+    # Which tensor of a token the router reads: "mlp_norm", the expert
+    # layer's own normed input (every model above), or "block", the residual
+    # stream as it enters the block, before the attention norm
+    # (SmallThinker's: the routing then waits for nothing the mixer computes).
+    # And the experts' gate: "silu" (SwiGLU) or "relu" (ReGLU, SmallThinker's).
+    moe_router_input: str = "mlp_norm"
+    moe_activation: str = "silu"
     # Latent attention (models/mla.py): the widths of the mixer kinds "mla"
     # (keys chosen by an indexer), "mla_win" (a causal window) and "mla_full"
     # (every causal key).
@@ -158,6 +171,13 @@ class LlamaConfig:
     lead_intermediate: int = 0
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
+
+    def __post_init__(self):
+        if self.moe_router_input not in ("mlp_norm", "block"):
+            raise ValueError(f"moe_router_input is {self.moe_router_input!r}: "
+                             "'mlp_norm' or 'block'")
+        if self.moe_activation not in ("silu", "relu"):
+            raise ValueError(f"moe_activation is {self.moe_activation!r}: 'silu' or 'relu'")
 
     @property
     def norm_offset(self) -> float:
@@ -257,6 +277,19 @@ PRESETS: dict[str, LlamaConfig] = {
         moe_experts=12, moe_top_k=3, moe_norm_topk=True, moe_shared=32, moe_held=(0, 2),
         moe_score="sigmoid", moe_bias_rate=0.001, moe_shared_gate=False,
         moe_routed_scale=2.827, moe_aux_weight=0.0001),
+    # a router ahead of attention at test size: two periods of one full layer
+    # with NO rope and one roped window layer (a window of 5, which drops keys
+    # at 16+ positions), 6 query heads over 2 kv heads (a group of three), no
+    # leading layer, no shared expert; the router reads the block's input and
+    # takes top-3 of 8 renormalised, 2 held, ReGLU experts
+    "prerouted-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=6, n_kv_heads=2, intermediate=32,
+        head_dim=16, norm_eps=1e-6, layer_pattern=("gqa", "gqa_win"),
+        gqa=GroupedQueryAttention(heads=6, kv_heads=2, head_dim=16, rope_theta=0.0),
+        gqa_window=GroupedQueryAttention(heads=6, kv_heads=2, head_dim=16, rope_theta=1e3,
+                                         window=5),
+        moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_held=(0, 2),
+        moe_router_input="block", moe_activation="relu", moe_aux_weight=0.001),
 }
 
 
@@ -511,6 +544,13 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
     # name on the profiler's op line (``tracing.device_scope``; ``op_name``
     # in the compiled text's metadata too), and leave the compiled program
     # as it was
+    mlp = _mlp_kind(c, lead)
+    early = None
+    if mlp.early is not None:
+        # what the MLP takes from the block's input alone: under its own
+        # scopes, here, where that input is ready and the mixer has not run
+        with device_scope("mlp"):
+            early = mlp.early(x, layer, config=c)
     with device_scope("attn"):
         h = rms_norm(x, layer["attn_norm"], eps=c.norm_eps, offset=c.norm_offset)
         mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh,
@@ -520,7 +560,8 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
 
     with device_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
-        down, aux = _mlp_kind(c, lead).apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis)
+        down, aux = mlp.apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis,
+                              **({} if early is None else {"early": early}))
         x = x + sc(down, ("batch", "seq", "embed_act"))
     return x, aux, mixed_aux
 
@@ -574,9 +615,11 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     ``return_aux=True`` additionally returns what the routed layers
     counted in the same pass: ``load_balance`` and ``z`` (the auxiliary
     terms, each the mean over layers), ``rows_per_expert`` [L, X] int32,
-    ``rows_dropped`` (0: the dispatch is dropless) and, where the program
+    ``rows_dropped`` (0: the dispatch is dropless), where the program
     holds a share of the experts (``moe_held``), ``rows_per_held_expert``
-    [L, count] and ``held_share`` [L] (their sum over all rows). ``{}`` for
+    [L, count] and ``held_share`` [L] (their sum over all rows), and under
+    ReGLU experts ``act_zero`` [L] (the share of the computed rows' gate
+    products that the activation zeroed). ``{}`` for
     dense configs and on the pipelined path, which does not thread it
     through the schedule yet. A window mixer that counts its pairs
     (``gqa_win``) adds ``attn_window_share``, the mean over those layers. An
@@ -689,6 +732,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
         if "rows_held" in per_layer:
             aux.update(rows_per_held_expert=per_layer["rows_held"],
                        held_share=per_layer["held_share"])
+        if "act_zero" in per_layer:
+            aux["act_zero"] = per_layer["act_zero"]
     # what the mixers counted: a leading layer's is a scalar, a period
     # position's [periods]; the mean over every layer that counted it
     counted = mixed_auxes + mixed

@@ -2,19 +2,24 @@
 
 The layer, from the published descriptions (OLMoE, arXiv 2409.02060, and
 its ``config.json``; Mixtral differs in one line), for one token's hidden
-state ``h`` after the block's second RMSNorm, X experts, k chosen:
+state ``h`` after the block's second RMSNorm, X experts, k chosen, ``r`` what
+the router reads (``h``; or, ``router_input``, another tensor of the token:
+SmallThinker's router reads the residual stream as it ENTERS the block,
+before the attention norm, so its routing depends on nothing the mixer
+computes) and ``act`` the gate's activation (``silu``: SwiGLU; ``relu``:
+ReGLU, SmallThinker's):
 
-    p      = softmax_f32(h W_router)                    over all X experts
+    p      = softmax_f32(r W_router)                    over all X experts
     (g, e) = top_k(p)                                   k gates, k expert ids
     g      = g / sum(g)          only if ``norm_topk``  (Mixtral: yes; OLMoE:
                                                          ``norm_topk_prob`` false)
     g      = s g                 ``routed_scale`` s     (Laguna-S-2.1: 2.5; else 1)
-    y      = sum_j g_j * W_down[e_j] (silu(W_gate[e_j] h) * W_up[e_j] h)
+    y      = sum_j g_j * W_down[e_j] (act(W_gate[e_j] h) * W_up[e_j] h)
 
 and, per layer, over the N tokens of the batch:
 
     load_balance = X * sum_x (rows_x / (N k)) * mean_n p[n, x]      (Switch)
-    z            = mean_n logsumexp(h W_router)^2                   (ST-MoE)
+    z            = mean_n logsumexp(r W_router)^2                   (ST-MoE)
 
 Every (token, expert) pair is computed whatever the routing's skew: there
 is no capacity, no dropped token and no padding that grows with the
@@ -24,12 +29,17 @@ order, ``ops.grouped_matmul`` multiplies each contiguous group of rows by
 its own expert's matrices (group sizes are device values, a bincount of
 the expert ids),
 and the rows are brought back to token order and summed. The gate ``g_j``
-is applied to ``silu(gate) * up`` in float32 before the down projection,
+is applied to ``act(gate) * up`` (``gate_act``, the one place the activation
+is written) in float32 before the down projection,
 which is linear, so the result equals the equation's; the backward pass
-then needs no output of the down projection.
+then needs no output of the down projection. Under ``relu`` the layer counts
+``act_zero``: the share of the computed rows' gate products that the
+activation sets to zero (rows of padding left out), which is what a down
+product that skipped those columns would be sized by.
 
 A shared expert (Qwen3-Next, DeepSeek: leaves ``w_shared_*`` present) is a
-SwiGLU every token passes through, scaled by ``sigmoid(h w_shared_scale)``
+gated unit of the same activation every token passes through, scaled by
+``sigmoid(h w_shared_scale)``
 where that leaf is present (Qwen's; DeepSeek's has none and is plain), and
 added to the routed sum; it has its own scope, ``moe_shared``.
 
@@ -278,20 +288,41 @@ def bias_step(bias, rows, rate: float):
     return bias + rate * jnp.sign(jnp.mean(rows, axis=-1, keepdims=True) - rows)
 
 
-def shared_expert(tokens, params):
-    """The always-on expert on tokens [N, E]: a SwiGLU scaled, token by
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def gate_act(gate, activation: str = "silu"):
+    """``act(gate)`` in float32, the gate of a gated unit ``act(gate) * up``
+    (``silu``: SwiGLU; ``relu``: ReGLU): the one place that says which."""
+    return ACTIVATIONS[activation](gate.astype(jnp.float32))
+
+
+def _zeroed(gate, counted, activation: str) -> dict:
+    """A dispatch path's counts: ``{"zeroed": how many elements of the gate
+    product [M, F] the activation sets to zero}`` over the rows ``counted``
+    [M] (None: all), float32; ``{}`` for an activation that zeroes none (an
+    empty tree adds nothing to such a layer's program)."""
+    if activation != "relu":
+        return {}
+    cut = gate <= 0
+    if counted is not None:
+        cut &= counted[:, None]
+    return {"zeroed": jnp.sum(cut, dtype=jnp.float32)}
+
+
+def shared_expert(tokens, params, activation: str = "silu"):
+    """The always-on expert on tokens [N, E]: a gated unit scaled, token by
     token, by ``sigmoid(h . w_shared_scale)`` where it has that leaf."""
     if "w_shared_scale" not in params:
         gate = jnp.einsum("ne,em->nm", tokens, params["w_shared_gate"])
         up = jnp.einsum("ne,em->nm", tokens, params["w_shared_up"])
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(tokens.dtype)
+        act = (gate_act(gate, activation) * up.astype(jnp.float32)).astype(tokens.dtype)
         return jnp.einsum("nm,me->ne", act, params["w_shared_down"])
     scale = jax.nn.sigmoid(jnp.einsum(
         "ne,e->n", tokens, params["w_shared_scale"], preferred_element_type=jnp.float32))
     gate = jnp.einsum("ne,em->nm", tokens, params["w_shared_gate"])
     up = jnp.einsum("ne,em->nm", tokens, params["w_shared_up"])
-    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    act = (gate_act(gate, activation) * up.astype(jnp.float32)
            * scale[:, None]).astype(tokens.dtype)
     return jnp.einsum("nm,me->ne", act, params["w_shared_down"])
 
@@ -313,32 +344,45 @@ def _held_capacity(n_rows: int, held, n_experts: int) -> int | None:
     return cap if 2 * cap <= n_rows else None
 
 
-def _experts(xs, row_gates, weights, gmm):
+def _experts(xs, row_gates, weights, gmm, activation, counted=None):
     """The three grouped products on sorted rows xs [M, E], the gate applied
-    to the activation in float32: [M, E]."""
+    to the activation in float32: ([M, E], ``_zeroed``'s count over the rows
+    ``counted``)."""
     gate = checkpoint_name(gmm(xs, weights["w_gate"]), "moe_gate")
     up = checkpoint_name(gmm(xs, weights["w_up"]), "moe_up")
-    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    act = (gate_act(gate, activation) * up.astype(jnp.float32)
            * row_gates[:, None]).astype(xs.dtype)
-    return gmm(act, weights["w_down"])
+    return gmm(act, weights["w_down"]), _zeroed(gate, counted, activation)
 
 
-def _all_rows(top_k, tokens, weights, gates, order, inv, sizes, offset):
+def _all_rows_counted(top_k, tokens, weights, gates, order, inv, sizes, offset,
+                      activation="silu"):
     """Every one of the N*k sorted rows is gathered; the grouped matmul
-    computes the groups' range of them (all, when ``offset`` is None)."""
+    computes the groups' range of them (all, when ``offset`` is None):
+    ([N, E], ``_zeroed``'s counts)."""
     n, e = tokens.shape
+    counted = None
+    if offset is not None and activation == "relu":  # the rows the groups hold
+        rows = jnp.arange(n * top_k, dtype=jnp.int32)
+        counted = (rows >= offset) & (rows < offset + jnp.sum(sizes))
     gmm = functools.partial(grouped_matmul, group_sizes=sizes, row_offset=offset)
     with device_scope("moe_dispatch"):
         xs = checkpoint_name(_dispatch(tokens, order, inv, top_k), "moe_xs")
         row_gates = _permute(gates.reshape(n * top_k), order, inv)
     with device_scope("moe_experts"):
-        ys = _experts(xs, row_gates, weights, gmm)
+        ys, counts = _experts(xs, row_gates, weights, gmm, activation, counted)
     with device_scope("moe_combine"):
         out = _permute(ys, inv, order).reshape(n, top_k, e)
-        return out.astype(jnp.float32).sum(axis=1).astype(tokens.dtype)
+        return out.astype(jnp.float32).sum(axis=1).astype(tokens.dtype), counts
 
 
-def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
+def _all_rows(*args, **kwargs):
+    """``_all_rows_counted``'s rows alone, for ``tests/test_moe_rows.py``, which
+    holds this path's jaxpr to an earlier commit's."""
+    return _all_rows_counted(*args, **kwargs)[0]
+
+
+def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset, activation="silu"):
     """Only the held experts' rows: the ``cap`` sorted rows from the held
     range's first. ``_take_rows`` (XLA's gather) brings their tokens in,
     ``_add_rows`` (the kernel ``moe_rows``) adds each token's rows back in
@@ -356,30 +400,33 @@ def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset):
         xs = checkpoint_name(_take_rows(tokens, token), "moe_xs")
         row_gates = jnp.where(valid, gates.reshape(n * top_k)[pair], 0.0)
     with device_scope("moe_experts"):
-        ys = _experts(xs, row_gates, weights, gmm)
+        ys, counts = _experts(xs, row_gates, weights, gmm, activation, valid)
     with device_scope("moe_combine"):
-        return _add_rows(ys, token, n)
+        return _add_rows(ys, token, n), counts
 
 
-def _swiglu_rows(xs, weights, row_gates):
+def _glu_rows(xs, weights, row_gates, activation, counted=None):
     """ONE expert (``weights``: its three matrices) on rows xs [M, E], the
-    gate applied to the activation in float32, as ``_experts``: [M, E]."""
-    act = (jax.nn.silu(jnp.dot(xs, weights["w_gate"]).astype(jnp.float32))
-           * jnp.dot(xs, weights["w_up"]).astype(jnp.float32)
+    gate applied to the activation in float32, as ``_experts``: ([M, E], its
+    count)."""
+    gate = jnp.dot(xs, weights["w_gate"])
+    act = (gate_act(gate, activation) * jnp.dot(xs, weights["w_up"]).astype(jnp.float32)
            * row_gates[:, None]).astype(xs.dtype)
-    return jnp.dot(act, weights["w_down"])
+    return jnp.dot(act, weights["w_down"]), _zeroed(gate, counted, activation)
 
 
-def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=None):
+def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=None,
+                    activation="silu"):
     """The held range when it is longer than ``cap``: expert by expert, an
-    expert's share of ``cap`` sorted rows at a time, each chunk a plain SwiGLU
+    expert's share of ``cap`` sorted rows at a time, each chunk a plain gated unit
     on its gathered tokens added into them, for as many chunks as the expert
     has rows (loops whose counts are device values). Nothing here is as long as the N*k rows, which
     ``_all_rows`` gathers whole (0.94 GB a [N*k, E] tensor at 8,192 tokens x 8
     of width 7,168: as this branch of the ``cond``, which an even router never
     takes, it cost a step 4.5 GB of scratch by the chip compiler's count).
-    With a cotangent ``g`` [N, E] it returns the gradients of (tokens,
-    weights, gates) and not the result: a loop of unknown length has no
+    It returns (result, ``_zeroed``'s count); with a cotangent ``g`` [N, E]
+    the gradients of (tokens, weights, gates) instead: a loop of unknown
+    length has no
     reverse pass of its own, so each chunk's is taken where it is made again."""
     n = tokens.shape[0]
     flat_gates = gates.reshape(n * top_k)
@@ -402,15 +449,19 @@ def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=
             pair = order[jnp.minimum(offset + ends[e] - sizes[e] + rows, n * top_k - 1)]
             return pair, valid, tokens[pair // top_k], jnp.where(valid, flat_gates[pair], 0.0)
 
-        def forward(j, out):
+        def forward(j, carry):
+            out, counts = carry
             pair, valid, rows, row_gates = chunk(j)
-            ys = jnp.where(valid[:, None], _swiglu_rows(rows, w, row_gates), 0)
-            return out.at[pair // top_k].add(ys.astype(jnp.float32))
+            keep = valid[:, None]
+            ys, zeroed = _glu_rows(rows, w, row_gates, activation, valid)
+            ys = jnp.where(keep, ys, 0)
+            return (out.at[pair // top_k].add(ys.astype(jnp.float32)),
+                    jax.tree.map(jnp.add, counts, zeroed))
 
         def backward(j, carry):
             d_tokens, d_w, d_gates = carry
             pair, valid, rows, row_gates = chunk(j)
-            pull = jax.vjp(_swiglu_rows, rows, w, row_gates)[1]
+            pull = jax.vjp(lambda *a: _glu_rows(*a, activation)[0], rows, w, row_gates)[1]
             d_rows, d, d_row_gates = pull(jnp.where(valid[:, None], g[pair // top_k], 0))
             return (d_tokens.at[pair // top_k].add(d_rows.astype(jnp.float32)),
                     jax.tree.map(lambda a, x: a + x.astype(jnp.float32), d_w, d),
@@ -428,54 +479,85 @@ def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=
     with device_scope("moe_experts"):
         count = sizes.shape[0]
         if g is None:
-            return jax.lax.fori_loop(0, count, expert, f32(tokens)).astype(tokens.dtype)
+            # the counts start as those of no row: 0, or nothing to count
+            out, counts = jax.lax.fori_loop(
+                0, count, expert,
+                (f32(tokens), _zeroed(jnp.zeros((0, 1)), None, activation)))
+            return out.astype(tokens.dtype), counts
         d_tokens, d_weights, d_gates = jax.lax.fori_loop(0, count, expert, (
             f32(tokens), jax.tree.map(jnp.zeros_like, weights), f32(flat_gates)))
         return d_tokens.astype(tokens.dtype), d_weights, d_gates.reshape(gates.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_range(top_k, cap, tokens, weights, gates, order, sizes, offset):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 8))
+def _held_range_counted(top_k, cap, tokens, weights, gates, order, sizes, offset,
+                        activation="silu"):
     """The held range by the compact path, or, in a step whose range is
     longer than ``cap``, expert by expert in as many turns as it takes:
     dropless whatever the skew. One ``lax.cond`` forward and one backward,
     each running the branch taken: differentiating a plain ``cond`` keeps
     BOTH branches' residuals, so the backward rule runs its branch again
-    instead."""
+    instead. Returns ([N, E], ``_zeroed``'s counts); the counts take no
+    gradient."""
+    args = (top_k, cap, tokens, weights, gates, order, sizes, offset)
     return jax.lax.cond(
         jnp.sum(sizes) <= cap,
-        lambda: _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset),
-        lambda: _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset))
+        lambda: _held_rows(*args, activation=activation),
+        lambda: _held_by_expert(*args, activation=activation))
 
 
 def _held_range_fwd(top_k, cap, *args):
-    return _held_range(top_k, cap, *args), args
+    return _held_range_counted(top_k, cap, *args), args[:6]
 
 
-def _held_range_bwd(top_k, cap, args, g):
+def _held_range_bwd(top_k, cap, activation, args, g):
     tokens, weights, gates, order, sizes, offset = args
+    g, _ = g
     d = jax.lax.cond(
         jnp.sum(sizes) <= cap,
-        lambda: jax.vjp(lambda t, w, gt: _held_rows(top_k, cap, t, w, gt, order, sizes, offset),
+        lambda: jax.vjp(lambda t, w, gt: _held_rows(top_k, cap, t, w, gt, order, sizes, offset,
+                                                    activation)[0],
                         tokens, weights, gates)[1](g),
-        lambda: _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g))
+        lambda: _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g,
+                                activation))
     return (*d, None, None, None)
 
 
-_held_range.defvjp(_held_range_fwd, _held_range_bwd)
+_held_range_counted.defvjp(_held_range_fwd, _held_range_bwd)
+
+
+def _held_range(*args, **kwargs):
+    """``_held_range_counted``'s rows alone, as ``tests/test_moe_rows.py``
+    differentiates the path."""
+    return _held_range_counted(*args, **kwargs)[0]
+
+
+def route_block(tokens, params, n_seqs: int, *, top_k: int = 2, norm_topk: bool = True,
+                score: str = "softmax", routed_scale: float = 1.0):
+    """``route`` of tokens [N, E] (``n_seqs`` sequences) by ``params``' router,
+    under the scope ``moe_route``: what ``moe_block`` does first, callable
+    apart from it where the router reads another tensor than the experts."""
+    with device_scope("moe_route"):
+        return route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk, score=score,
+                     bias=params.get("router_bias"), n_seqs=n_seqs, scale=routed_scale)
 
 
 def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
               ep_axis: str | None = None, held: tuple[int, int] | None = None,
-              score: str = "softmax", routed_scale: float = 1.0):
+              score: str = "softmax", routed_scale: float = 1.0,
+              activation: str = "silu", routed: dict | None = None):
     """x: [B, S, E] -> ([B, S, E], aux). Routing in f32; experts in x.dtype
-    with f32 accumulation.
+    with f32 accumulation. ``routed``: the routing, where it was made from
+    another tensor of the same tokens than ``x`` (``route_block`` with the
+    same keywords; None: made here, from ``x``). ``activation``: the
+    experts' gate, "silu" | "relu".
 
     ``aux``: ``load_balance`` and ``z`` (scalars, this layer's auxiliary
     terms), ``rows`` [X] int32 (rows routed to each expert the router
-    scores), ``dropped`` (N*k less their sum: 0 by construction) and,
+    scores), ``dropped`` (N*k less their sum: 0 by construction),
     with ``held``, ``rows_held`` [count] int32 (rows computed by each
-    expert held here) and ``held_share`` (their sum over N*k).
+    expert held here) and ``held_share`` (their sum over N*k), and under
+    "relu" ``act_zero`` (the module's text).
 
     ``ep_axis`` (inside a ``shard_map``): ``params`` hold this device's
     X/ep consecutive experts and the whole router. ``held`` = (first,
@@ -487,9 +569,9 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     b, s, e = x.shape
     n = b * s
     tokens = x.reshape(n, e)
-    with device_scope("moe_route"):
-        r = route(tokens, params["router"], top_k=top_k, norm_topk=norm_topk, score=score,
-                  bias=params.get("router_bias"), n_seqs=b, scale=routed_scale)
+    r = routed if routed is not None else route_block(
+        tokens, params, b, top_k=top_k, norm_topk=norm_topk, score=score,
+        routed_scale=routed_scale)
     sizes, offset = r["sizes"], None
     if ep_axis is not None or held is not None:
         local = params["w_gate"].shape[0]
@@ -504,21 +586,25 @@ def moe_block(x, params, *, top_k: int = 2, norm_topk: bool = True,
     weights = {k: params[k] for k in ("w_gate", "w_up", "w_down")}
     cap = _held_capacity(n * top_k, held, r["sizes"].shape[0])
     if cap is None:
-        out = _all_rows(top_k, tokens, weights, r["gates"], r["order"], r["inv"],
-                        sizes, offset)
+        out, counts = _all_rows_counted(top_k, tokens, weights, r["gates"], r["order"],
+                                        r["inv"], sizes, offset, activation)
     else:
-        out = _held_range(top_k, cap, tokens, weights, r["gates"], r["order"], sizes, offset)
+        out, counts = _held_range_counted(top_k, cap, tokens, weights, r["gates"], r["order"],
+                                          sizes, offset, activation)
     if ep_axis is not None:
         with device_scope("moe_combine"):
             out = jax.lax.psum(out, ep_axis)
     if "w_shared_gate" in params:
         with device_scope("moe_shared"):
-            out = out + shared_expert(tokens, params)
+            out = out + shared_expert(tokens, params, activation)
     aux = {"load_balance": r["load_balance"], "z": r["z"], "rows": r["sizes"],
            "dropped": n * top_k - jnp.sum(r["sizes"])}
     if held is not None:
         aux["rows_held"] = sizes
         aux["held_share"] = jnp.sum(sizes).astype(jnp.float32) / (n * top_k)
+    if counts:
+        computed = jnp.sum(sizes).astype(jnp.float32) * params["w_gate"].shape[-1]
+        aux["act_zero"] = jax.lax.stop_gradient(counts["zeroed"]) / jnp.maximum(computed, 1.0)
     return out.reshape(b, s, e), aux
 
 
@@ -535,11 +621,22 @@ def _moe_init(c, keys, lead, normal) -> dict:
         shared_gate=c.moe_shared_gate, bias=c.moe_bias_rate > 0)
 
 
-def _moe_apply(h, layer, *, config, mesh=None, ep_axis=None):
+def _routing(c) -> dict:
+    return dict(top_k=c.moe_top_k, norm_topk=c.moe_norm_topk, score=c.moe_score,
+                routed_scale=c.moe_routed_scale)
+
+
+def _moe_early(x, layer, *, config):
+    """The routing, where the router reads the block's input ``x``."""
+    if config.moe_router_input != "block":
+        return None
+    return route_block(x.reshape(-1, x.shape[-1]), layer, x.shape[0], **_routing(config))
+
+
+def _moe_apply(h, layer, *, config, mesh=None, ep_axis=None, early=None):
     c = config
-    return moe_block(h, layer, top_k=c.moe_top_k, norm_topk=c.moe_norm_topk,
-                     ep_axis=ep_axis, held=c.moe_held, score=c.moe_score,
-                     routed_scale=c.moe_routed_scale)
+    return moe_block(h, layer, ep_axis=ep_axis, held=c.moe_held, activation=c.moe_activation,
+                     routed=early, **_routing(c))
 
 
 def _moe_matmul_params(c) -> float:
@@ -552,5 +649,5 @@ def _moe_matmul_params(c) -> float:
             + c.moe_top_k * share * 3 * c.hidden * c.intermediate)
 
 
-MOE = LayerKind(axes=_moe_axes, init=_moe_init, apply=_moe_apply,
+MOE = LayerKind(axes=_moe_axes, init=_moe_init, apply=_moe_apply, early=_moe_early,
                 matmul_params=_moe_matmul_params, save_names=ROUTE_NAMES)

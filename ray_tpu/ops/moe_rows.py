@@ -30,7 +30,11 @@ an HBM array only along untiled dimensions (a ``[1, E]`` slice of a
 shaped is one contiguous block in HBM and whole vector registers in VMEM,
 so adding it costs a handful of vector operations. The reshape before the
 call is a pass over the source at bandwidth; the result leaves the kernel
-as ``[n, E]``, laid out a lane tile at a time from the accumulator.
+as ``[n, E]``, laid out a lane tile at a time from the accumulator. The
+``E / 128`` lane tiles of a row are padded to a multiple of 8 (a width of
+2,560 is 20: 24 are fetched, which is what the array's tiled layout holds in
+HBM anyway, and 20 laid out): the chip's compiler slices that dimension only
+by whole sublane tiles too ("aligned to tiling (8), but is 20"; PR 45).
 
 Off the TPU the kernel runs in the Pallas interpreter. A width that is no
 multiple of 128 lanes takes XLA's scatter-add;
@@ -121,7 +125,7 @@ def _rows_kernel(ids_ref, starts_ref, src_ref, out_ref, pairs_ref, next_ref, acc
         out_ref[:, pl.ds(lane, 128)] = acc_ref[:, c, :].astype(out_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, acc_ref.shape[1], lay, None)
+    jax.lax.fori_loop(0, out_ref.shape[1] // 128, lay, None)
 
 
 def _tile(n: int) -> int:
@@ -149,13 +153,13 @@ def sum_rows(src, ids, n: int, *, interpret: bool | None = None):
         return out.astype(src.dtype)
     note_kernel_trace("moe_rows", "interpret" if interpret else "pallas")
     note_kernel_cost("moe_rows", 0, (m + n) * e * src.dtype.itemsize)  # every row named, at most
-    tm, lanes = _tile(n), e // 128
+    tm, lanes = _tile(n), -(-e // 1024) * 8  # a row's lane tiles, in whole sublane tiles
     ids = jnp.where(ids < n, ids, -1)  # the kernel's scalar memory has no bounds check
     # rows a tile, as a compare-and-sum (``models/moe.py::route`` says why)
     tile = jnp.where(ids >= 0, ids // tm, -1)
     per_tile = jnp.sum(tile[:, None] == jnp.arange(n // tm)[None, :], axis=0, dtype=jnp.int32)
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(per_tile)])
-    return pl.pallas_call(
+    kernel = pl.pallas_call(
         functools.partial(_rows_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -173,4 +177,8 @@ def sum_rows(src, ids, n: int, *, interpret: bool | None = None):
                                              vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="moe_rows",
-    )(ids, starts, src.reshape(m, lanes, 128))
+    )
+    rows = src.reshape(m, e // 128, 128)
+    if lanes != e // 128:
+        rows = jnp.pad(rows, ((0, 0), (0, lanes - e // 128), (0, 0)))
+    return kernel(ids, starts, rows)

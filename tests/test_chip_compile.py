@@ -22,6 +22,7 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.models.gqa import window_blocks
 from ray_tpu.ops.moe_rows import sum_rows
 from ray_tpu.ops.paged_attention import paged_decode_attention
 
@@ -61,13 +62,15 @@ def _flash(chip, b, hq, hkv, s, d, backward):
 
 def _grouped_window(chip, backward, b=1, hq=72, hkv=8, s=16384, d=128, window=512):
     """The window kernels as ``models/gqa.py`` calls them: grouped queries,
-    512-blocks, a band of two key blocks a query block."""
+    blocks that follow the window (512-blocks at 512 keys: a band of two key
+    blocks a query block)."""
+    block = window_blocks(window)
     q = jax.ShapeDtypeStruct((b, hq, s, d), jnp.bfloat16, sharding=chip)
     kv = jax.ShapeDtypeStruct((b, hkv, s, d), jnp.bfloat16, sharding=chip)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=window, block_q=512, block_k=512,
-                               interpret=False)
+        return flash_attention(q, k, v, causal=True, window=window, block_q=block,
+                               block_k=block, interpret=False)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
@@ -312,6 +315,20 @@ CASES = {
     "moe-gmm-laguna-down-grad": lambda c: _grouped(c, 1024, 3072, True, 40960, 32, held=True),
     "moe-gmm-dots3-up-grad": lambda c: _grouped(c, 5120, 1536, True, 8192, 8, held=True),
     "moe-gmm-dots3-down-grad": lambda c: _grouped(c, 1536, 5120, True, 8192, 8, held=True),
+    # SmallThinker-21BA3B at one row of 16,384: 28 query heads over 4 kv heads
+    # (seven a kv head), the plain kernels with q and k un-roped and the window
+    # kernels under a 4,096-key band at the blocks ``models/gqa.py`` gives them;
+    # the held range's adds at 20 lane tiles a row (no multiple of 8) and
+    # 49,152 ids; the held experts' grouped matmuls at 3,072 rows a group
+    "flash-fwd-28to4-16k": lambda c: _flash(c, 1, 28, 4, 16384, 128, backward=False),
+    "flash-bwd-28to4-16k": lambda c: _flash(c, 1, 28, 4, 16384, 128, backward=True),
+    "attn-win-fwd-28to4-16k-w4096": lambda c: _grouped_window(
+        c, backward=False, hq=28, hkv=4, window=4096),
+    "attn-win-bwd-28to4-16k-w4096": lambda c: _grouped_window(
+        c, backward=True, hq=28, hkv=4, window=4096),
+    "moe-rows-2560": lambda c: _rows(c, 16384, 49152, 2560),
+    "moe-gmm-prerouted-up-grad": lambda c: _grouped(c, 2560, 768, True, 49152, 16, held=True),
+    "moe-gmm-prerouted-down-grad": lambda c: _grouped(c, 768, 2560, True, 49152, 16, held=True),
 }
 
 
