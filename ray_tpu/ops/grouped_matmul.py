@@ -27,14 +27,16 @@ gradient for ``rhs``.
 Every visit multiplies all ``tm`` rows of its tile, so the tiles follow the
 rows a group holds: ``gmm_tiles`` / ``tgmm_tiles`` choose (tm, tk, tn) when
 a call is traced, from its static shapes alone (rows over groups, K, N, the
-item size). Under 2,048 rows a group: 128 rows, the whole contraction in
-one block (a group's matrix is then fetched once a group, not once a
-visit) and the widest column block that fits VMEM; at 2,048 and more the
-512 x 2048 x 1024 measured at OLMoE's shapes. Measured on a v5e at the
-five routed cells' shapes, every product of a layer alone (PERF.md, PR
-43). ``tile_visits`` counts visits and rows multiplied for any routing
-without a chip; ``trace_log.kernel_costs()`` holds what a traced call
-chose (``tiles``, ``work_items``, ``rhs_resident``).
+item size). At every row count the whole contraction is one block (a
+group's matrix is then fetched once a group, not once a visit) beside the
+widest column block that fits VMEM; the row tile is 128 rows under 2,048
+rows a group and 256 at and above. Measured on a v5e, every product of a
+layer alone: under 2,048 at four held-range cells' shapes (PERF.md, PR
+43), at and above at 49,152 rows over 16 groups of 2560 x 768 and 131,072
+over 64 of 2048 x 1024 (PERF.md, PR 46). ``tile_visits``
+counts visits and rows multiplied for any routing without a chip;
+``trace_log.kernel_costs()`` holds what a traced call chose (``tiles``,
+``work_items``, ``rhs_resident``).
 
 Off the TPU both kernels run in the Pallas interpreter, so the CPU tests
 exercise the same tiling. A row count no tile divides takes
@@ -54,12 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..tpu import on_tpu
 from .trace_log import note_kernel_cost, note_kernel_trace
 
-# (tm, tk, tn): rows, contraction and output columns of one tile (``moe_tgmm``:
-# rows, and the two sides of a group's output block). The rule's largest
-# tiles, which it gives at 2,048 rows a group and more: chosen on a v5e at
-# OLMoE's shapes (131,072 rows, 64 groups, 2048 x 1024 and 1024 x 2048;
-# PERF.md, PR 26).
-_CEILING = (512, 2048, 1024)
+# (tm, tk, tn) everywhere below: rows, contraction and output columns of one
+# tile (``moe_tgmm``: rows, and the two sides of a group's output block).
 _VMEM_LIMIT = 96 * 1024 * 1024  # a v5e core has 128 MiB; the default scope is 16
 _VMEM_BLOCKS = 72 * 1024 * 1024  # of it for the rule's blocks; the rest is Mosaic's own
 
@@ -94,48 +92,48 @@ def _tgmm_vmem(tm: int, tk: int, tn: int, itemsize: int, out_itemsize: int) -> i
     return 2 * itemsize * (tm * tk + tm * tn) + (2 * out_itemsize + 4) * tk * tn
 
 
-def _widest(dim: int, fits) -> int | None:
+def _widest(dim: int, fits) -> int:
     """The widest block of ``dim`` (all of it, or a lane-aligned divisor)
-    that ``fits``; None where not even the narrowest does."""
+    that ``fits``, and the narrowest where none does: Mosaic then refuses the
+    call by its VMEM limit. That takes a contraction of ~36,000 columns
+    beside a 128-lane block, five times the widest any cell has."""
     blocks = [dim] + [t for t in range((dim - 1) // 128 * 128, 0, -128) if dim % t == 0]
-    return next((t for t in blocks if fits(t)), None)
+    return next((t for t in blocks if fits(t)), blocks[-1])
 
 
-def _under_the_ceiling(m: int, groups: int, k: int, n: int, vmem) -> tuple | None:
-    """The tiles of a call whose groups would hold under 2,048 rows if ``m``
-    fell evenly on them (None at 2,048 and more, or where nothing fits):
-    128 rows, all of ``k``, and the widest ``tn`` whose blocks, counted by
-    ``vmem(tm, tk, tn)``, fit ``_VMEM_BLOCKS``.
+def _whole_blocks(m: int, groups: int, k: int, n: int, vmem) -> tuple[int, int, int]:
+    """The rule: all of ``k``, the widest ``tn`` whose blocks, counted by
+    ``vmem(tm, tk, tn)``, fit ``_VMEM_BLOCKS``, and a row tile of 128 rows
+    where the groups would hold under 2,048 if ``m`` fell evenly on them,
+    256 at and above.
 
     A tile that straddles a boundary is visited once a group it touches and
-    every visit multiplies all its rows, so the row tile is the MXU's own
-    side: at 192, 640, 1,024 and 1,280 rows a group, of which a held range
-    fills about half, 128 read within 1% of the best of 64-512 in all six
-    products of a layer, and the widest ``tn`` was the best at every shape
-    (PERF.md, PR 43)."""
-    if m // groups >= 2048:
-        return None
-    tn = _widest(n, lambda tn: vmem(128, k, tn) <= _VMEM_BLOCKS)
-    return tn and (128, k, tn)
+    every visit multiplies all its rows, so the row tile is small: at 192,
+    640, 1,024 and 1,280 rows a group, of which a held range fills about
+    half, 128 (the MXU's own side) read within 1% of the best of 64-512 in
+    all six products of a layer (PERF.md, PR 43). At 3,072 rows a group of
+    which 1,533 are valid a layer's products read 4% less at 256 than at
+    128 or 512, and at 2,048 all valid 2% and 0.6% less (``moe_tgmm`` level
+    with 512 there): half of 128's grid steps, of which a held range's
+    ``cap`` leaves half dead, for 6-7% more rows multiplied. The widest
+    ``tn`` was the best at both, and the whole contraction at every row
+    tile (PERF.md, PR 46)."""
+    tm = 128 if m // groups < 2048 else 256
+    return tm, k, _widest(n, lambda tn: vmem(tm, k, tn) <= _VMEM_BLOCKS)
 
 
-def gmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2,
-              transpose_rhs: bool = False) -> tuple[int, int, int]:
+def gmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2) -> tuple[int, int, int]:
     """``moe_gmm``'s (tm, tk, tn) for lhs [m, k] against ``groups`` matrices
-    [k, n] (``transpose_rhs``: stored [n, k]), before ``_fit_tiles``.
+    [k, n] or, transposed, [n, k] (the blocks are the same bytes), before
+    ``_fit_tiles``.
 
-    Under the ceiling's rows the WHOLE contraction is one block: a group's
-    matrix block then keeps its index over the group's consecutive visits
-    and is fetched once a group and column block, which 128 rows of
-    products would not hide; the wider ``tn``, the fewer times the rows are
-    read again (at 7168 x 2048 the whole matrix, 29 MB twice buffered: the
-    call then runs at the matrices' bytes). At the ceiling's rows the
-    ceiling itself, ``tk`` and ``tn`` swapped for the transposed form as
-    the backward rule always asked: the routed cell's program is the one
-    PR 26 measured."""
-    ceiling = (_CEILING[0], _CEILING[2], _CEILING[1]) if transpose_rhs else _CEILING
-    return _under_the_ceiling(
-        m, groups, k, n, lambda *tiles: _gmm_vmem(*tiles, itemsize)) or ceiling
+    The WHOLE contraction is one block: a group's matrix block then keeps
+    its index over the group's consecutive visits and is fetched once a
+    group and column block, which a small row tile's products would not
+    hide; the wider ``tn``, the fewer times the rows are read again (at
+    7168 x 2048 the whole matrix, 29 MB twice buffered: the call then runs
+    at the matrices' bytes)."""
+    return _whole_blocks(m, groups, k, n, lambda *tiles: _gmm_vmem(*tiles, itemsize))
 
 
 def tgmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2,
@@ -143,12 +141,11 @@ def tgmm_tiles(m: int, groups: int, k: int, n: int, itemsize: int = 2,
     """``moe_tgmm``'s (tm, tk, tn) for lhs [m, k] and dout [m, n] into
     ``groups`` blocks [k, n], before ``_fit_tiles``. The contraction is over
     ROWS; ``tk`` and ``tn`` cut a group's output block, and the rows are read
-    once a block of the other side: under the ceiling's rows ``tk`` is all of
-    ``k`` (the float32 accumulator and the twice-buffered block are 8 bytes
-    an element of the block); at them, the ceiling."""
-    return _under_the_ceiling(
-        m, groups, k, n,
-        lambda *tiles: _tgmm_vmem(*tiles, itemsize, out_itemsize)) or _CEILING
+    once a block of the other side: ``tk`` is all of ``k`` (the float32
+    accumulator and the twice-buffered block are 8 bytes an element of the
+    block: 15.7 MB at 2560 x 768, 16.8 at 2048 x 1024)."""
+    return _whole_blocks(
+        m, groups, k, n, lambda *tiles: _tgmm_vmem(*tiles, itemsize, out_itemsize))
 
 
 def _group_tiles(group_sizes, row_offset, m: int, tm: int):
@@ -274,7 +271,7 @@ def _gmm(lhs, rhs, group_sizes, row_offset, *, transpose_rhs, tiles, interpret):
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     if tiles is None:
-        tiles = gmm_tiles(m, rhs.shape[0], k, n, lhs.dtype.itemsize, transpose_rhs)
+        tiles = gmm_tiles(m, rhs.shape[0], k, n, lhs.dtype.itemsize)
     tm, tk, tn = _fit_tiles(tiles, m, k, n, lhs.dtype)
     if None in (tm, tk, tn):
         note_kernel_trace("moe_gmm", "ragged_dot")

@@ -1,5 +1,5 @@
 """The grouped matmuls' tile rule (ops/grouped_matmul.py: ``gmm_tiles``,
-``tgmm_tiles``, ``tile_visits``): what it gives at the five routed cells'
+``tgmm_tiles``, ``tile_visits``): what it gives at the six routed cells'
 shapes, what those tiles cost in rows multiplied under seeded routing, and
 the kernels under the RULE's tiles (no override) against a loop over groups,
 interpreted on the CPU. No cluster is started here."""
@@ -18,13 +18,15 @@ G = importlib.import_module("ray_tpu.ops.grouped_matmul")
 grouped_matmul = G.grouped_matmul
 
 # cell: rows of the call (a held range's ``cap``), groups, hidden, expert
-# width, rows that are valid, the busiest group over the mean (ledger, PR 42)
+# width, rows that are valid, the busiest group over the mean (ledger, PR 42;
+# the last, PR 45)
 CELLS = {
     "kimi": (1536, 8, 7168, 2048, 700, 2.63),
     "hybrid": (40960, 64, 2048, 512, 20700, 2.37),
     "window-full": (40960, 32, 3072, 1024, 20500, 1.51),
     "sparse": (8192, 8, 5120, 1536, 4064, 1.70),
     "routed": (131072, 64, 2048, 1024, 131072, 3.36),
+    "prerouted": (49152, 16, 2560, 768, 24534, 1.07),
 }
 # product: kernel, transposed, (K, N) from (hidden, width)
 PRODUCTS = {
@@ -36,7 +38,8 @@ PRODUCTS = {
     "down-tgmm": ("tgmm", None, lambda h, w: (w, h)),
 }
 # what the rule gives, after the cut to divisors (PERF.md, PR 43: measured on a
-# v5e but the three the table marks); the routed cell's are PR 26's
+# v5e but the three the table marks; the last two rows, at 2,048 rows a group
+# and more, PR 46)
 EXPECTED = {
     "kimi": [(128, 7168, 2048), (128, 2048, 7168), (128, 2048, 7168), (128, 7168, 2048),
              (128, 7168, 1024), (128, 2048, 3584)],
@@ -46,8 +49,10 @@ EXPECTED = {
                     (128, 3072, 1024), (128, 3072, 1024), (128, 1024, 3072)],
     "sparse": [(128, 5120, 1536), (128, 1536, 5120), (128, 1536, 5120), (128, 5120, 1536),
                (128, 5120, 1536), (128, 1536, 5120)],
-    "routed": [(512, 2048, 1024), (512, 1024, 1024), (512, 1024, 2048), (512, 1024, 1024),
-               (512, 2048, 1024), (512, 1024, 1024)],
+    "routed": [(256, 2048, 1024), (256, 1024, 2048), (256, 1024, 2048), (256, 2048, 1024),
+               (256, 2048, 1024), (256, 1024, 2048)],
+    "prerouted": [(256, 2560, 768), (256, 768, 2560), (256, 768, 2560), (256, 2560, 768),
+                  (256, 2560, 768), (256, 768, 2560)],
 }
 
 
@@ -56,27 +61,45 @@ def _rule(cell, product):
     kernel, transposed, dims = PRODUCTS[product]
     k, n = dims(hidden, width)
     if kernel == "gmm":
-        tiles = G.gmm_tiles(m, groups, k, n, 2, transposed)
+        tiles = G.gmm_tiles(m, groups, k, n, 2)
     else:
         tiles = G.tgmm_tiles(m, groups, k, n, 2, 2)
     return kernel, (m, k, n), G._fit_tiles(tiles, m, k, n, jnp.bfloat16)
+
+
+def _traced(kernel, transposed, m, groups, k, n):
+    """What ``kernel_costs()`` holds after the product is traced (not run) at
+    these shapes with no override."""
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32)
+    if kernel == "gmm":
+        rhs = jax.ShapeDtypeStruct((groups, n, k) if transposed else (groups, k, n), jnp.bfloat16)
+        jax.eval_shape(lambda l, r, s: G._gmm(l, r, s, None, transpose_rhs=transposed,
+                                              tiles=None, interpret=True), lhs, rhs, sizes)
+    else:
+        dout = jax.ShapeDtypeStruct((m, n), jnp.bfloat16)
+        jax.eval_shape(lambda l, d, s: G._tgmm(l, d, s, None, out_dtype=jnp.bfloat16,
+                                               tiles=None, interpret=True), lhs, dout, sizes)
+    return trace_log.kernel_costs()["moe_" + kernel]
 
 
 @pytest.mark.parametrize("product", list(PRODUCTS))
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_the_rule_at_a_cells_shapes(cell, product):
     kernel, (m, k, n), (tm, tk, tn) = _rule(cell, product)
+    groups, transposed = CELLS[cell][1], PRODUCTS[product][1]
     assert (tm, tk, tn) == EXPECTED[cell][list(PRODUCTS).index(product)]
     assert m % tm == 0 and k % tk == 0 and n % tn == 0
     assert tm % 16 == 0 and tk % 128 == 0 and tn % 128 == 0  # bf16 sublanes, lanes
     vmem = (G._gmm_vmem(tm, tk, tn, 2) if kernel == "gmm"
             else G._tgmm_vmem(tm, tk, tn, 2, 2))
-    assert vmem <= G._VMEM_LIMIT
-    if cell == "routed":  # the parent's constants, cut as the parent cut them
-        asked = (512, 1024, 2048) if PRODUCTS[product][1] else (512, 2048, 1024)
-        assert (tm, tk, tn) == G._fit_tiles(asked, m, k, n, jnp.bfloat16)
-    else:  # the matrix block keeps its index over a group's visits
-        assert tm == 128 and tk == k
+    assert vmem <= G._VMEM_BLOCKS
+    # one step of the rows a group would hold; the matrix block keeps its index
+    # over a group's visits at every row count
+    assert tm == (128 if m // groups < 2048 else 256) and tk == k
+    cost = _traced(kernel, transposed, m, groups, k, n)
+    assert tuple(cost["tiles"]) == (tm, tk, tn) and cost["rhs_resident"] is True
+    assert cost["work_items"] == m // tm + groups - 1
 
 
 def _seeded_sizes(groups, valid, skew, seed):
@@ -97,7 +120,7 @@ def _seeded_sizes(groups, valid, skew, seed):
 # cell stays under on every seed, and what 512-row tiles cost on seed 43
 @pytest.mark.parametrize("cell,ceiling,at_512", [
     ("kimi", 3.0, 6.58), ("hybrid", 1.5, 2.57), ("window-full", 1.3, 1.80),
-    ("sparse", 1.4, 1.89), ("routed", 1.3, 1.25)])
+    ("sparse", 1.4, 1.89), ("routed", 1.15, 1.25), ("prerouted", 1.2, 1.31)])
 def test_rows_multiplied_over_rows_that_exist(cell, ceiling, at_512):
     m, groups, _, _, valid, skew = CELLS[cell]
     tm = _rule(cell, "up-fwd")[2][0]
@@ -122,26 +145,28 @@ def _reference(lhs, rhs, sizes, offset):
     return out
 
 
-# sizes, row offset (None: not given), K, N -> the rule's (tm, tk, tn) for the
-# forward product and whether the contraction is one block there
+# sizes, (row offset, rows of the call) (None: no offset given, the rows are the
+# sizes' sum), K, N -> the rule's (tm, tk, tn) for the forward product; the
+# contraction is one block in every one
 REGIMES = {
-    "many-groups-in-one-tile": ([10, 0, 30, 24, 5, 7, 20, 32], None, 64, 128,
-                                (128, 64, 128), True),
-    "a-group-smaller-than-a-tile": ([100, 0, 300, 112], None, 256, 256,
-                                    (128, 256, 256), True),
-    "all-in-the-last-group": ([0, 0, 0, 384], None, 128, 128, (128, 128, 128), True),
-    "row-offset-given": ([60, 0, 200, 100], 70, 128, 256, (128, 128, 256), True),
-    "a-wide-contraction-in-one-block": ([31, 97, 0, 128], None, 2304, 256,
-                                        (128, 2304, 256), True),
-    "the-ceiling-splits-the-contraction": ([1500, 2596], None, 2304, 128,
-                                           (512, 1152, 128), False),
+    "many-groups-in-one-tile": ([10, 0, 30, 24, 5, 7, 20, 32], None, 64, 128, (128, 64, 128)),
+    "a-group-smaller-than-a-tile": ([100, 0, 300, 112], None, 256, 256, (128, 256, 256)),
+    "all-in-the-last-group": ([0, 0, 0, 384], None, 128, 128, (128, 128, 128)),
+    "row-offset-given": ([60, 0, 200, 100], (70, 512), 128, 256, (128, 128, 256)),
+    "a-wide-contraction-in-one-block": ([31, 97, 0, 128], None, 2304, 256, (128, 2304, 256)),
+    # 2,048 rows a group and more: 256-row tiles, an empty group inside the tile
+    # that straddles, a contraction of three lane tiles
+    "whole-blocks-at-2048-rows-a-group": ([1500, 0, 4644], None, 384, 256, (256, 384, 256)),
+    # a held range: the valid rows fill a quarter of the call's, so most work
+    # items are past ``n_work``
+    "whole-blocks-in-a-held-range": ([700, 0, 833], (70, 6144), 384, 128, (256, 384, 128)),
 }
 
 
 @pytest.mark.parametrize("regime", list(REGIMES))
 def test_the_rules_tiles_against_a_loop_over_groups(regime):
-    sizes, offset, k, n, tiles, resident = REGIMES[regime]
-    m = 512 if offset is not None else sum(sizes)
+    sizes, held, k, n, tiles = REGIMES[regime]
+    offset, m = held or (None, sum(sizes))
     keys = jax.random.split(jax.random.PRNGKey(len(sizes) + k), 3)
     lhs = jax.random.normal(keys[0], (m, k)) / 8
     rhs = jax.random.normal(keys[1], (len(sizes), k, n)) / 8
@@ -158,7 +183,7 @@ def test_the_rules_tiles_against_a_loop_over_groups(regime):
 
     np.testing.assert_allclose(fn(lhs, rhs), ref(lhs, rhs), atol=2e-4)
     cost = trace_log.kernel_costs()["moe_gmm"]
-    assert (tuple(cost["tiles"]), cost["rhs_resident"]) == (tiles, resident)
+    assert (tuple(cost["tiles"]), cost["rhs_resident"]) == (tiles, True)
     assert cost["work_items"] == m // tiles[0] + len(sizes) - 1
     got = jax.grad(lambda l, r: (fn(l, r) * weight).sum(), argnums=(0, 1))(lhs, rhs)
     want = jax.grad(lambda l, r: (ref(l, r) * weight).sum(), argnums=(0, 1))(lhs, rhs)
@@ -168,7 +193,7 @@ def test_the_rules_tiles_against_a_loop_over_groups(regime):
     # the backward product contracts over N, the transposed one over rows
     assert tuple(costs["moe_gmm"]["tiles"]) == (tiles[0], n, tiles[1])
     assert tuple(costs["moe_tgmm"]["tiles"]) == (tiles[0], tiles[1], n)
-    assert costs["moe_tgmm"]["rhs_resident"] == resident
+    assert costs["moe_gmm"]["rhs_resident"] and costs["moe_tgmm"]["rhs_resident"]
     after = trace_log.kernel_traces()
     for kernel in ("moe_gmm", "moe_tgmm"):  # the kernels ran, not ``ragged_dot``
         assert after.get(f"{kernel}:interpret", 0) > before.get(f"{kernel}:interpret", 0)
