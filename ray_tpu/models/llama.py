@@ -58,6 +58,7 @@ from jax.sharding import Mesh
 from ..observability.tracing import device_scope
 from ..ops import (mha_reference, ring_attention, rms_norm, apply_rope,
                    ulysses_attention)
+from ..ops.moe_rows import take_rows
 from ..parallel.sharding import shard_constraint
 from .gdn import GDN
 from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention
@@ -632,7 +633,18 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
     with device_scope("embed"):
-        x = params["embed"][tokens].astype(c.dtype)
+        if mesh is None or mesh.size == 1:
+            # XLA's gather, whose gradient is the kernel ``moe_rows`` (a
+            # float32 sum in VMEM of the rows that name a token id) where the
+            # gather's own transpose is a scatter-add of rows into a float32
+            # [vocab, E] of zeros: 2-4 us a 10-14 KB row on a v5e. Under a
+            # mesh of more than one device the table may be sharded and keeps
+            # the plain lookup: the partitioner would gather the table for a
+            # ``pallas_call``.
+            x = take_rows(params["embed"], tokens.reshape(b * s)).reshape(b, s, -1)
+        else:
+            x = params["embed"][tokens]
+        x = x.astype(c.dtype)
     if mesh is not None:
         # Two-hop resharding. The gather's output inherits the table's
         # embed=fsdp sharding; jumping straight to batch=(dcn,dp,fsdp)

@@ -90,7 +90,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.tracing import device_scope
 from ..ops import grouped_matmul
-from ..ops.moe_rows import sum_rows
+from ..ops.moe_rows import add_rows, take_rows
 from .kinds import LayerKind
 
 # what a remat policy may save of the routing: a few MB a layer against a
@@ -193,33 +193,6 @@ def _permute(values, perm, inv_perm):
 
 _permute.defvjp(lambda values, perm, inv_perm: (values[perm], (inv_perm,)),
                 lambda res, g: (g[res[0]], None, None))
-
-
-@jax.custom_vjp
-def _take_rows(values, ids):
-    """``values[ids]`` for a held range's ``cap`` rows: values [N, E], ids
-    [cap] a row's token, -1 past the range's end. Such a row reads token 0:
-    the grouped matmul computes no row outside its groups and hands none a
-    gradient, so nothing masks it here (a select fused into XLA's gather cost
-    it 0.29 -> 0.77 ms at 40,960 rows of 4 KB; PERF.md, PR 42). The gradient
-    adds each token's rows back with ``_add_rows``, where the gather's own
-    transpose would be a scatter-add of rows."""
-    return values[jnp.maximum(ids, 0)]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _add_rows(rows, ids, n):
-    """``out[t] = sum of the rows whose id is t``, float32 sums, [n, E]:
-    ``ops/moe_rows.py::sum_rows``, the kernel ``moe_rows``. Its gradient is
-    ``_take_rows``: each is the other's transpose on the rows that name a
-    token."""
-    return sum_rows(rows, ids, n)
-
-
-_take_rows.defvjp(lambda values, ids: (_take_rows(values, ids), (ids, values.shape[0])),
-                  lambda res, g: (_add_rows(g, *res), None))
-_add_rows.defvjp(lambda rows, ids, n: (_add_rows(rows, ids, n), ids),
-                 lambda n, ids, g: (_take_rows(g, ids), None))
 
 
 def route(tokens, router, *, top_k: int, norm_topk: bool, score: str = "softmax",
@@ -384,8 +357,8 @@ def _all_rows(*args, **kwargs):
 
 def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset, activation="silu"):
     """Only the held experts' rows: the ``cap`` sorted rows from the held
-    range's first. ``_take_rows`` (XLA's gather) brings their tokens in,
-    ``_add_rows`` (the kernel ``moe_rows``) adds each token's rows back in
+    range's first. ``take_rows`` (XLA's gather) brings their tokens in,
+    ``add_rows`` (the kernel ``moe_rows``) adds each token's rows back in
     float32, and each is the other's gradient. Rows past the range's end
     name no token: they belong to no group, come back zero and are added
     nowhere."""
@@ -397,12 +370,12 @@ def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset, activat
     gmm = functools.partial(grouped_matmul, group_sizes=sizes,
                             row_offset=jnp.zeros((), jnp.int32))
     with device_scope("moe_dispatch"):
-        xs = checkpoint_name(_take_rows(tokens, token), "moe_xs")
+        xs = checkpoint_name(take_rows(tokens, token), "moe_xs")
         row_gates = jnp.where(valid, gates.reshape(n * top_k)[pair], 0.0)
     with device_scope("moe_experts"):
         ys, counts = _experts(xs, row_gates, weights, gmm, activation, valid)
     with device_scope("moe_combine"):
-        return _add_rows(ys, token, n), counts
+        return add_rows(ys, token, n), counts
 
 
 def _glu_rows(xs, weights, row_gates, activation, counted=None):

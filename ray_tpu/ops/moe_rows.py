@@ -1,11 +1,15 @@
 """Rows added into the rows their ids name, as a Pallas TPU kernel.
 
 ``sum_rows(src, ids, n)``   out[i] = sum of src[m] over the m with ids[m] == i
+``take_rows(values, ids)``  values[ids], whose gradient is ``add_rows``
+``add_rows(rows, ids, n)``  ``sum_rows``, whose gradient is ``take_rows``
 
 The transpose of a row gather ``table[ids]``, and so its gradient. It is
 how a held range of experts adds its rows back into their tokens
-(``models/moe.py::_held_rows``, which pairs it with XLA's gather: each is
-the other's gradient there).
+(``models/moe.py::_held_rows``) and how the embedding table gets its
+gradient (``models/llama.py::forward_hidden``): both go through the pair
+``take_rows`` / ``add_rows``, XLA's gather and this kernel, each the other's
+gradient.
 
 The gather itself stays XLA's: on a v5e it moves a 10 KB row in 0.08 us
 and a 4 KB row in 0.007 (PERF.md, PR 42). What XLA does one row at a time
@@ -15,7 +19,11 @@ converted. The sum is therefore the kernel ``moe_rows`` (the name on a
 TPU's op line): a float32 sum in VMEM, no float32 ``[n, E]`` and no
 scatter in HBM.
 
-It cuts the ``n`` destination rows into tiles of ``tm``. Before the first
+It cuts the ``n`` destination rows into tiles of ``tm``, the largest power
+of two up to ``ROW_TILE`` that divides ``n`` (16 rows, at a vocabulary of
+18,992, cost what 256 do: PERF.md, PR 47); where that is under the 8 rows of
+a sublane tile, the sum is made into the next multiple of ``ROW_TILE`` rows
+and cut. Before the first
 tile the scalar core places the rows by destination tile (a counting sort
 whose counts XLA made; a tile's rows keep their order), so each tile owns a
 run of (source row, destination row) pairs. Each pair's source row is
@@ -37,7 +45,9 @@ HBM anyway, and 20 laid out): the chip's compiler slices that dimension only
 by whole sublane tiles too ("aligned to tiling (8), but is 20"; PR 45).
 
 Off the TPU the kernel runs in the Pallas interpreter. A width that is no
-multiple of 128 lanes takes XLA's scatter-add;
+multiple of 128 lanes takes XLA's scatter-add, and so do more rows than the
+scalar memory holds ids for (98,304 less the destination tiles; an
+embedding's step of 131,072 tokens on one chip would not compile);
 ``trace_log.kernel_traces()`` says which (``moe_rows:pallas`` /
 ``:interpret`` / ``:xla``).
 """
@@ -57,6 +67,7 @@ from .trace_log import note_kernel_cost, note_kernel_trace
 ROW_TILE = 256  # destination rows a grid step; a float32 accumulator of 256 x 28 KB at width 7168
 IN_FLIGHT = 16  # row copies under way
 _VMEM_LIMIT = 64 * 1024 * 1024
+_SMEM_LIMIT = 768 * 1024
 
 
 def _rows_kernel(ids_ref, starts_ref, src_ref, out_ref, pairs_ref, next_ref, acc_ref, buf_ref,
@@ -137,23 +148,36 @@ def _tile(n: int) -> int:
     return tm
 
 
+def _ids_fit(m: int, tiles: int) -> bool:
+    """Whether the scalar memory holds the kernel's ids and pairs [m], starts
+    and next [tiles], int32: a v5e has 1 MiB, and its compiler takes 97,280
+    rows into 501 tiles and refuses 131,072."""
+    return 8 * (m + tiles) <= _SMEM_LIMIT
+
+
 def sum_rows(src, ids, n: int, *, interpret: bool | None = None):
     """``out[i] = sum of src[m] over the m with ids[m] == i``, summed in
     float32, zero for an ``i`` no row names; a row whose id is -1 is added
     nowhere: src [M, E], ids [M] int32 in [-1, n) (device values) -> [n, E]
     in src's dtype. Not differentiable by itself: its gradient for ``src``
-    is the gather ``g[ids]``, which its caller pairs it with."""
+    is the gather ``g[ids]``, which ``add_rows`` pairs it with."""
     if interpret is None:
         interpret = not on_tpu()
     m, e = src.shape
-    if e % 128:
+    tm = _tile(n)
+    if tm < 8 and not e % 128:
+        # a vocabulary of 50,257: the chip's compiler takes an output block of
+        # whole sublane tiles only
+        whole = -(-n // ROW_TILE) * ROW_TILE
+        return sum_rows(src, jnp.where(ids < n, ids, -1), whole, interpret=interpret)[:n]
+    if e % 128 or not _ids_fit(m, n // tm):
         note_kernel_trace("moe_rows", "xla")
         out = jnp.zeros((n, e), jnp.float32).at[jnp.where(ids >= 0, ids, n)].add(
             src.astype(jnp.float32), mode="drop")
         return out.astype(src.dtype)
     note_kernel_trace("moe_rows", "interpret" if interpret else "pallas")
     note_kernel_cost("moe_rows", 0, (m + n) * e * src.dtype.itemsize)  # every row named, at most
-    tm, lanes = _tile(n), -(-e // 1024) * 8  # a row's lane tiles, in whole sublane tiles
+    lanes = -(-e // 1024) * 8  # a row's lane tiles, in whole sublane tiles
     ids = jnp.where(ids < n, ids, -1)  # the kernel's scalar memory has no bounds check
     # rows a tile, as a compare-and-sum (``models/moe.py::route`` says why)
     tile = jnp.where(ids >= 0, ids // tm, -1)
@@ -182,3 +206,32 @@ def sum_rows(src, ids, n: int, *, interpret: bool | None = None):
     if lanes != e // 128:
         rows = jnp.pad(rows, ((0, 0), (0, lanes - e // 128), (0, 0)))
     return kernel(ids, starts, rows)
+
+
+@jax.custom_vjp
+def take_rows(values, ids):
+    """``values[ids]``: values [N, E], ids [M] int32 in [0, N) -> [M, E]. The
+    gradient adds each row back into the row it was read from with
+    ``add_rows``, where the gather's own transpose would be a scatter-add of
+    rows. Out of range: an id of -1 (a held range's rows past its end) reads
+    row 0, where plain indexing would wrap to the last row, and an id of N or
+    more reads row N - 1 as plain indexing does; the gradient adds such a
+    row nowhere. The grouped matmul computes no row outside its groups and
+    hands none a gradient, so nothing masks a -1 row here (a select fused
+    into XLA's gather cost it 0.29 -> 0.77 ms at 40,960 rows of 4 KB;
+    PERF.md, PR 42)."""
+    return values[jnp.maximum(ids, 0)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def add_rows(rows, ids, n):
+    """``out[t] = sum of the rows whose id is t``, float32 sums, [n, E]:
+    ``sum_rows``, the kernel ``moe_rows``. Its gradient is ``take_rows``:
+    each is the other's transpose on the rows that name a row in [0, n)."""
+    return sum_rows(rows, ids, n)
+
+
+take_rows.defvjp(lambda values, ids: (take_rows(values, ids), (ids, values.shape[0])),
+                 lambda res, g: (add_rows(g, *res), None))
+add_rows.defvjp(lambda rows, ids, n: (add_rows(rows, ids, n), ids),
+                lambda n, ids, g: (take_rows(g, ids), None))

@@ -125,7 +125,8 @@ def _grouped(chip, k, n, backward, rows=131072, groups=64, held=False):
 
 def _rows(chip, n, cap, e):
     """A held range's adds: ``cap`` rows of width ``e`` summed into ``n``
-    tokens (``moe_rows``; the gather's gradient is the same call)."""
+    tokens (``moe_rows``; the gather's gradient is the same call, and so is
+    the embedding table's: ``cap`` tokens' rows into ``n`` = the vocabulary)."""
     rows = jax.ShapeDtypeStruct((cap, e), jnp.bfloat16, sharding=chip)
     ids = jax.ShapeDtypeStruct((cap,), jnp.int32, sharding=chip)
     return jax.jit(lambda r, i: sum_rows(r, i, n, interpret=False)).lower(rows, ids)
@@ -329,6 +330,16 @@ CASES = {
     "moe-rows-2560": lambda c: _rows(c, 16384, 49152, 2560),
     "moe-gmm-prerouted-up-grad": lambda c: _grouped(c, 2560, 768, True, 49152, 16, held=True),
     "moe-gmm-prerouted-down-grad": lambda c: _grouped(c, 768, 2560, True, 49152, 16, held=True),
+    # the embedding table's gradient (the same kernel, ``n`` the vocabulary,
+    # the rows a step's tokens) where the vocabulary's destination tile is
+    # awkward: Kimi-K2's 256 rows of 56 lane tiles, dots3's 64 of 40,
+    # SmallThinker's 32 of 20 and Qwen3-Next's 16 of 16 over 1,187 grid steps
+    "embed-rows-kimi": lambda c: _rows(c, 20480, 4096, 7168),
+    "embed-rows-dots3": lambda c: _rows(c, 19008, 16384, 5120),
+    "embed-rows-prerouted": lambda c: _rows(c, 37984, 16384, 2560),
+    "embed-rows-hybrid": lambda c: _rows(c, 18992, 16384, 2048),
+    # a vocabulary no 8 rows divide (GPT-2's): summed into 50,432 rows and cut
+    "embed-rows-odd-vocab": lambda c: _rows(c, 50257, 8192, 2048),
 }
 
 

@@ -35,6 +35,9 @@ def _tiny_step(chip, preset):
 
 
 @pytest.mark.parametrize("preset,scopes", [
+    # a width of one lane tile: the embedding table's gradient is the kernel
+    # ``moe_rows``, called inside the lookup's backward rule under ``embed``
+    ("debug-128", {"embed", "stack/attn"}),
     # ``hybrid-debug``'s DeltaNet heads are 16 wide, no lane tile: its conv and
     # gated norm take the plain functions by their shape, steered or not, so no
     # kernel reads under ``gdn_conv`` or ``gdn_out`` here (the cell's widths:
@@ -47,7 +50,7 @@ def _tiny_step(chip, preset):
     ("latent-full-debug", {"stack/attn/mla_full", "stack/mlp/moe_experts"})])
 def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_without(
         chip, monkeypatch, preset, scopes):
-    """The hybrid and the sparse step, every kernel module steered to the chip
+    """A dense, the hybrid and two latent steps, every kernel module steered to the chip
     (this process's backend is the CPU): each Mosaic call's own text holds
     ``rt_scope`` beside ``kernel_metadata``, as the op line prints it, the
     chip's compiler keeps every call that was traced, and as many are traced

@@ -169,10 +169,12 @@ def test_the_head_loss_and_the_held_range_keep_their_scope_in_the_backward_rule(
     # the held range's adds at a width of one lane tile (``moe_rows``, interpreted
     # here, a custom call on a TPU): the forward one under ``moe_combine``, the
     # gather's gradient under ``moe_dispatch`` inside the backward rule, so the
-    # two scope readers keep seeing them
+    # two scope readers keep seeing them; the embedding table's gradient is the
+    # same rule's call under ``embed``
     rows = [line for line in text.splitlines() if "/moe_rows/" in line and 'rt_scope="' in line]
     assert {(re.search(r'rt_scope="([^"]*)"', line).group(1), "transpose(jvp" in line)
-            for line in rows} == {("stack/mlp/moe_combine", False), ("stack/mlp/moe_dispatch", True)}
+            for line in rows} == {("stack/mlp/moe_combine", False), ("stack/mlp/moe_dispatch", True),
+                                  ("embed", True)}
 
 
 @pytest.mark.parametrize("kind", list(KINDS) + ["serving"])

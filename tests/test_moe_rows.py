@@ -1,12 +1,15 @@
-"""The held range's adds (``ops/moe_rows.py::sum_rows``, the kernel
-``moe_rows``) against plain ``jnp``, interpreted on the CPU; the pair
-``models/moe.py`` makes of it and XLA's gather, each the other's gradient;
-``_held_range`` through them against ``_all_rows``; and that the
-differentiated, rematerialised stacks hold the kernel and no scatter-add of
-rows."""
+"""The held range's adds and the embedding's gradient
+(``ops/moe_rows.py::sum_rows``, the kernel ``moe_rows``) against plain
+``jnp``, interpreted on the CPU; the pair ``take_rows`` / ``add_rows`` made
+of it and XLA's gather, each the other's gradient; ``_held_range`` through
+them against ``_all_rows``; the embedding's lookup through them against
+plain indexing; and that the differentiated, rematerialised stacks hold the
+kernel and no scatter-add of rows."""
 
 import dataclasses
 import hashlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +49,10 @@ def _ids(kind, m, n, rng):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("kind", ["one", "top_k", "holes", "none"])
-@pytest.mark.parametrize("n, m, e", [(96, 64, 5120), (48, 80, 7168), (1024, 96, 256)],
-                         ids=["40_lane_tiles", "56_lane_tiles", "four_tiles_of_256"])
+@pytest.mark.parametrize("n, m, e", [(96, 64, 5120), (48, 80, 7168), (1024, 96, 256),
+                                     (50, 64, 128)],
+                         ids=["40_lane_tiles", "56_lane_tiles", "four_tiles_of_256",
+                              "a_tile_under_8_rows_padded"])
 def test_sum_rows_matches_jnp(n, m, e, kind, dtype):
     rng = np.random.default_rng(n + m)
     m = min(m, n) if kind == "one" else m
@@ -66,7 +71,7 @@ def test_sum_rows_matches_jnp(n, m, e, kind, dtype):
 
 
 def test_the_gather_and_the_add_are_each_others_gradient():
-    """``models/moe.py``'s pair on the rows that name a token; a row whose id
+    """The pair on the rows that name a token; a row whose id
     is -1 reads token 0 and gets token 0's cotangent back, which the held
     range never looks at."""
     rng = np.random.default_rng(5)
@@ -78,26 +83,41 @@ def test_the_gather_and_the_add_are_each_others_gradient():
     rows = jnp.asarray(rng.standard_normal((m, e)), jnp.float32)
     g_rows = jnp.asarray(rng.standard_normal((m, e)), jnp.float32)
     g_table = jnp.asarray(rng.standard_normal((n, e)), jnp.float32)
-    taken, pull = jax.vjp(lambda t: moe._take_rows(t, ids), table)
+    taken, pull = jax.vjp(lambda t: moe_rows.take_rows(t, ids), table)
     np.testing.assert_array_equal(taken[named], table[ids[named]])
     np.testing.assert_array_equal(taken[~named], jnp.broadcast_to(table[0], taken[~named].shape))
     np.testing.assert_allclose(pull(g_rows)[0], _plain_sum(g_rows, ids, n), rtol=1e-6, atol=1e-6)
-    added, pull = jax.vjp(lambda r: moe._add_rows(r, ids, n), rows)
+    added, pull = jax.vjp(lambda r: moe_rows.add_rows(r, ids, n), rows)
     np.testing.assert_allclose(added, _plain_sum(rows, ids, n), rtol=1e-6, atol=1e-6)
     np.testing.assert_array_equal(pull(g_table)[0][named], g_table[ids[named]])
     # and the gather's gradient is the kernel, not a scatter-add
-    jaxpr = jax.make_jaxpr(jax.grad(lambda t: moe._take_rows(t, ids).sum()))(table)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda t: moe_rows.take_rows(t, ids).sum()))(table)
     names = [e.primitive.name for e in jaxpr_walk.equations(jaxpr.jaxpr)]
     assert "pallas_call" in names and "scatter-add" not in names and "scatter_add" not in names
 
 
-def test_a_width_off_the_lane_tiling_takes_xla_and_says_so():
+@pytest.mark.parametrize("width, smem", [(64, None), (128, 8 * 4)],
+                         ids=["width_64", "more_ids_than_scalar_memory"])
+def test_a_shape_the_kernel_cannot_take_goes_to_xla_and_says_so(monkeypatch, width, smem):
+    """A width off the lane tiling, or more rows than the scalar memory holds
+    ids for (the limit lowered to fewer than these 4 rows and 1 tile)."""
+    if smem is not None:
+        monkeypatch.setattr(moe_rows, "_SMEM_LIMIT", smem)
     before = trace_log.kernel_traces().get("moe_rows:xla", 0)
     ids = jnp.asarray([3, -1, 0, 3], jnp.int32)
-    src = jnp.arange(4 * 64, dtype=jnp.float32).reshape(4, 64)
+    src = jnp.arange(4 * width, dtype=jnp.float32).reshape(4, width)
     got = sum_rows(src, ids, 8)
     np.testing.assert_array_equal(got, _plain_sum(src, ids, 8))
     assert trace_log.kernel_traces()["moe_rows:xla"] == before + 1
+
+
+def test_the_scalar_memory_limit_admits_every_cells_calls():
+    """49,152 held rows into 16,384 tokens (the eighth cell) and 16,384 tokens
+    into a vocabulary of 1,187 tiles are far inside it; 131,072 rows, which the
+    chip's compiler refuses, are outside."""
+    fits = lambda m, n: moe_rows._ids_fit(m, n // moe_rows._tile(n))  # noqa: E731
+    assert fits(49152, 16384) and fits(16384, 18992) and fits(97280, 128256)
+    assert not fits(131072, 128256)
 
 
 def test_the_cost_entry_is_the_rows_bytes():
@@ -165,8 +185,9 @@ def test_the_differentiated_remat_stack_holds_the_kernel_and_no_scatter_add(pres
     calls: for each expert layer's place in the program (a scanned period's
     layers share theirs) one forward and two in the backward rule, the add
     of its own forward pass, which nothing reads and the compiler drops, and
-    the gather's gradient; none run again under remat, and no scatter-add is
-    left under the two scopes."""
+    the gather's gradient; none run again under remat; one more is the
+    embedding table's gradient; and no scatter-add of rows is left under the
+    two scopes or under ``embed``."""
     c = dataclasses.replace(PRESETS[preset], dtype=jnp.float32, remat_policy="attn", hidden=128,
                             moe_experts=16)
     params = jax.eval_shape(lambda key: init_params(c, key), jax.random.PRNGKey(0))
@@ -177,13 +198,136 @@ def test_the_differentiated_remat_stack_holds_the_kernel_and_no_scatter_add(pres
     assert trace_log.kernel_traces()["moe_rows:interpret"] > before
     equations = list(jaxpr_walk.equations(jaxpr.jaxpr))
     kernels = [str(e.params["name"]) for e in equations if e.primitive.name == "pallas_call"]
-    assert kernels.count("moe_rows") == 3 * len(c.layer_pattern), kernels.count("moe_rows")
+    assert kernels.count("moe_rows") == 3 * len(c.layer_pattern) + 1, kernels.count("moe_rows")
     # what is left of scatter-adds there moves scalars (the gates' gradient), no row
     for e in equations:
         if e.primitive.name in ("scatter-add", "scatter_add"):
             stack = str(e.source_info.name_stack)
             assert e.outvars[0].aval.ndim == 1 or not (
-                "moe_dispatch" in stack or "moe_combine" in stack), (stack, e.outvars[0].aval)
+                "moe_dispatch" in stack or "moe_combine" in stack
+                or "embed" in stack), (stack, e.outvars[0].aval)
+
+
+def _lookup(table, tokens):
+    """``forward_hidden``'s lookup where no mesh shards the table."""
+    b, s = tokens.shape
+    return moe_rows.take_rows(table, tokens.reshape(b * s)).reshape(b, s, -1)
+
+
+# a vocabulary's destination tile: 512 -> 256, 48 -> 16 (as the hybrid cell's
+# 18,992 = 16 x 1,187); every batch names the first and the last row of the
+# table and repeats ids, ``few`` so often that a row is the sum of a dozen
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("vocab, tile", [(512, 256), (48, 16)], ids=["tile_256", "tile_16"])
+@pytest.mark.parametrize("ids", ["spread", "few"])
+def test_the_lookups_gradient_is_plain_indexings(ids, vocab, tile, dtype, tol):
+    assert moe_rows._tile(vocab) == tile
+    rng = np.random.default_rng(vocab)
+    tokens = rng.integers(0, vocab if ids == "spread" else 5, size=(2, 32)).astype(np.int32)
+    tokens[0, :2], tokens[1, -2:] = (0, vocab - 1), (vocab - 1, 0)
+    tokens = jnp.asarray(tokens)
+    table = jnp.asarray(rng.standard_normal((vocab, 256)), dtype)
+    cot = jnp.asarray(rng.standard_normal((2, 32, 256)), dtype)
+
+    def grad(lookup, table, cot):
+        out, pull = jax.vjp(lambda t: lookup(t, tokens), table)
+        return out, pull(cot)[0]
+
+    # plain indexing's gradient at float32: off a TPU its bfloat16 scatter-add
+    # rounds after every row, where the kernel sums in float32 and rounds once
+    got_x, got = grad(_lookup, table, cot)
+    want_x, want = grad(lambda t, ids: t[ids], table.astype(jnp.float32), cot.astype(jnp.float32))
+    np.testing.assert_array_equal(got_x, want_x.astype(dtype))
+    assert got.dtype == dtype and got.shape == table.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol, atol=tol)
+    unnamed = np.setdiff1d(np.arange(vocab), np.asarray(tokens))
+    assert len(unnamed) and not np.asarray(got, np.float32)[unnamed].any()
+
+
+def test_ids_out_of_range_read_an_end_of_the_table_and_are_added_nowhere():
+    """What ``take_rows``'s docstring says of the two cases: -1 reads row 0
+    (plain indexing wraps to the last), an id past the table reads the last
+    row as plain indexing does, and the gradient gives neither's cotangent
+    to any row."""
+    table = jnp.arange(8 * 128, dtype=jnp.float32).reshape(8, 128)
+    ids = jnp.asarray([3, -1, 8, 11], jnp.int32)
+    out, pull = jax.vjp(lambda t: moe_rows.take_rows(t, ids), table)
+    np.testing.assert_array_equal(out, table[jnp.asarray([3, 0, 7, 7])])
+    np.testing.assert_array_equal(table[ids[2:]], out[2:])
+    grad = pull(jnp.ones_like(out))[0]
+    np.testing.assert_array_equal(grad, jnp.zeros_like(table).at[3].set(1.0))
+
+
+def _dense_step(preset, mesh=None):
+    c = dataclasses.replace(PRESETS[preset], dtype=jnp.float32, remat_policy="attn")
+    params = jax.eval_shape(lambda key: init_params(c, key), jax.random.PRNGKey(0))
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    return jax.grad(lambda p: loss_fn(p, {"tokens": tokens}, c, mesh=mesh, chunk_tokens=16)), params
+
+
+def _embed_lines(text):
+    """The lowered instructions under scope ``embed``, their value names
+    left out; a scatter is one line, from its name to the types after its
+    body."""
+    text = re.sub(r'("stablehlo\.scatter"[^\n]*)\n.*?\n\s*(\}\) [^\n]*)', r"\1 ... \2", text,
+                  flags=re.DOTALL)
+    return [re.sub(r"%[\w#]+", "%", line).strip() for line in text.splitlines()
+            if 'rt_scope = "embed"' in line]
+
+
+def _mesh(**axes):
+    from ray_tpu.parallel import MeshConfig, create_mesh
+
+    return create_mesh(MeshConfig(**axes), devices=jax.devices()[:math.prod(axes.values())])
+
+
+@pytest.mark.parametrize("preset, path, mesh", [
+    ("debug-128", "interpret", None), ("debug-128", "interpret", {"dp": 1}), ("debug", "xla", None)],
+    ids=["width_128", "width_128_on_a_mesh_of_one_device", "width_64"])
+def test_a_dense_steps_embedding_gradient_is_the_kernel_at_a_lane_tile_and_xlas_below(
+        preset, path, mesh):
+    """At a width of 128 the differentiated step holds one ``moe_rows`` call
+    and no scatter under ``embed`` (that the call reads under ``embed`` in the
+    backward rule: ``tests/test_device_scopes.py``, and compiled for the chip
+    ``tests/test_chip_compile_steps.py``), without a mesh and under the mesh
+    of one device that the benchmark's one-chip cells run under; at 64
+    ``sum_rows`` takes XLA's scatter-add into the float32 table under the
+    same scope and says so."""
+    before = trace_log.kernel_traces().get(f"moe_rows:{path}", 0)
+    step, params = _dense_step(preset, mesh=mesh and _mesh(**mesh))
+    equations = jaxpr_walk.equations(jax.make_jaxpr(step)(params).jaxpr)
+    assert trace_log.kernel_traces()[f"moe_rows:{path}"] == before + 1
+    kernels = [str(e.params["name"]) for e in equations if e.primitive.name == "pallas_call"]
+    scatters = [line for line in _embed_lines(jax.jit(step).lower(params).as_text())
+                if "stablehlo.scatter" in line]
+    if path == "interpret":
+        assert kernels.count("moe_rows") == 1 and not scatters
+    else:
+        v, e = params["embed"].shape
+        assert "moe_rows" not in kernels and len(scatters) == 1
+        assert scatters[0].endswith(f"-> tensor<{v}x{e}xf32>")
+
+
+def test_with_a_mesh_of_four_devices_the_lookup_is_the_parents():
+    """Under a mesh of more than one device the table may be sharded and
+    keeps plain indexing: no ``moe_rows`` in the step, and under ``embed`` the
+    text the parent commit lowered (its SHA-256, taken on the parent: a
+    gather, and its transpose, a scatter-add into float32 zeros)."""
+    def traced():
+        return {k: v for k, v in trace_log.kernel_traces().items() if k.startswith("moe_rows")}
+
+    before = traced()
+    step, params = _dense_step("debug-128", mesh=_mesh(fsdp=4))
+    text = jax.jit(step).lower(params).as_text()
+    assert traced() == before and "moe_rows" not in text
+    lines = _embed_lines(text)
+    assert any("stablehlo.gather" in line for line in lines)
+    assert any("stablehlo.scatter" in line for line in lines)
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == MESHED_EMBED_SHA256
+
+
+MESHED_EMBED_SHA256 = "96e2912fba797a5ff1f354792b6a9c615941bafcb904aa45e38638604823a21c"
 
 
 def test_all_rows_is_untouched():
