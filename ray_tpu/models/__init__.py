@@ -8,7 +8,13 @@ latent attention (``mla.py``: low-rank q and kv; three choices of keys: a
 learned indexer's top-k, a causal window, every causal key; a head-wise gate;
 YaRN with its factor on the softmax scale), grouped-query attention by
 spec (``gqa.py``: a head count, a rope, plain or YaRN with its factor on cos
-and sin, or no rope at all, and a window of a kind's own, a head-wise gate).
+and sin, or no rope at all, and a window of a kind's own, a head-wise gate),
+lightning attention (``lightning.py``: linear attention whose state decays by
+a constant of the head and the layer, two Pallas kernels over chunks, q/k
+norms, rope, an output norm and an element-wise gate) and block-selected
+attention (``block_sparse.py``: grouped queries over the 64 blocks of 64 keys
+that the layer chooses from its own q and k with no parameters, one choice a
+kv group, no rope, an element-wise gate).
 MLPs: dense SwiGLU, or with
 ``moe_experts > 0`` a routed expert layer (``moe.py``: dropless, the
 (token, expert) rows sorted by expert over a Pallas grouped matmul, a softmax
@@ -18,7 +24,9 @@ have an MLP kind of their own. A block hands its MLP kind the block's input
 before the mixer runs, for a router that reads the residual stream there and
 not the MLP's own normed input (``kinds.py``: ``early``).
 Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next, dots3-note-prev,
-Laguna-S-2.1, Kimi-K2 and SmallThinker are configurations."""
+Laguna-S-2.1, Kimi-K2, SmallThinker and MiniCPM-SALA (under fixed multipliers
+on the embedding, the residual branches and the head's input) are
+configurations."""
 
 from .llama import (
     LlamaConfig,

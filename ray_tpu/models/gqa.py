@@ -52,7 +52,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope
 from ..parallel.sharding import shard_constraint
-from .kinds import LayerKind, Yarn, flash_per_shard, headwise_gate, kept_keys, rope_keywords
+from .kinds import LayerKind, Yarn, flash_per_shard, sigmoid_gate, kept_keys, rope_keywords
 
 SAVE_NAMES = ("q", "k", "v", "attn_out", "attn_lse", "attn_gate")
 
@@ -157,7 +157,7 @@ def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=Non
             attn = flash_per_shard(q, k, v, mesh, causal=True)
         if a.gate == "headwise":
             with device_scope("attn_gate"):
-                attn = headwise_gate(h, layer["w_attn_gate"], attn)
+                attn = sigmoid_gate(h, layer["w_attn_gate"], attn)
         return jnp.einsum("bhsd,hde->bse", attn, layer["wo"]), aux
 
 

@@ -46,6 +46,11 @@ class LayerKind:
     mixing_flops: Callable[[Any, int], float] = lambda c, seq: 0.0
     # checkpoint names the ``attn`` remat policy saves for this kind
     save_names: tuple[str, ...] = ()
+    # (config, ids) -> {leaf: array}: the kind's leaves that no gradient moves
+    # and that depend on WHICH layers of the stack these are (``ids``: the
+    # stack's index of each of the leaf's stacked layers, or of the one
+    # leading layer); they have their entry in ``axes`` like any leaf
+    buffers: Callable | None = None
     # mlp only: (x, layer, config=) -> what the kind computes from the
     # block's input x [B, S, E] (before the attention norm and the mixer), or
     # None; the block hands it to ``apply`` as ``early=``
@@ -75,14 +80,32 @@ def rope_keywords(rotated: int, theta: float, yarn: Yarn | None) -> dict:
         beta_fast=yarn.beta_fast, beta_slow=yarn.beta_slow), "factor": yarn.attention_factor}
 
 
-def headwise_gate(h, w_gate, attn):
-    """attn [B, H, S, D] times ``sigmoid(h W_g)``, one number a head and
-    position (the head-wise form of arXiv 2505.06708), in float32; the gate
-    goes under the checkpoint name ``attn_gate``. h [B, S, E] is the mixer's
-    normed input, ``w_gate`` [E, H]."""
+def sigmoid_gate(h, w_gate, attn):
+    """attn [B, H, S, D] times ``sigmoid(h W_g)``, in float32, by the gate's
+    shape. ``w_gate`` [E, H]: one number a head and position (the head-wise
+    form of arXiv 2505.06708), the gate itself under the checkpoint name
+    ``attn_gate``. ``w_gate`` [E, H, D]: one a feature (the element-wise form);
+    there the projection goes under that name as the product leaves it, and
+    the sigmoid is made again from it. h [B, S, E] is the mixer's normed
+    input."""
+    if w_gate.ndim == 3:
+        gate = checkpoint_name(jnp.einsum("bse,ehd->bhsd", h, w_gate), "attn_gate")
+        return (attn.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
     gate = checkpoint_name(jax.nn.sigmoid(jnp.einsum(
         "bse,eh->bhs", h, w_gate, preferred_element_type=jnp.float32)), "attn_gate")
     return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+
+
+def norm_over_heads(t, weight, eps):
+    """RMSNorm of t [B, H, S, D] over all H*D features of a position (the
+    whole projection, as OLMoE normalises q and k), weight [H*D]; f32
+    statistics."""
+    _, h, _, d = t.shape
+    f = t.astype(jnp.float32)
+    var = jnp.mean(jnp.square(f), axis=(1, 3), keepdims=True)
+    w = weight.astype(jnp.float32).reshape(1, h, 1, d)
+    return (f * jax.lax.rsqrt(var + eps) * w).astype(t.dtype)
 
 
 def kept_keys(seq: int, width: int) -> float:

@@ -9,7 +9,9 @@ more than that: a token mixer (``attn``: softmax attention, below;
 latent attention under a learned selection of keys, a window or over every
 causal key, ``models/mla.py``;
 ``gqa`` / ``gqa_win``: grouped-query attention whose widths a spec owns,
-whole or under a window, ``models/gqa.py``)
+whole or under a window, ``models/gqa.py``; ``lightning``: linear attention
+under a constant decay, ``models/lightning.py``; ``block_sparse``: grouped
+queries over key blocks the layer selects, ``models/block_sparse.py``)
 and an MLP (``dense``, below; ``moe``: the routed experts,
 ``models/moe.py``) are ``LayerKind``s (``models/kinds.py``) that own their
 leaves, logical axes, init, FLOPs, counters and the names a remat policy
@@ -24,7 +26,9 @@ heads and a full one of 48; Kimi-K2 a leading dense layer and a period of
 one expert layer, all under latent attention over every causal key;
 SmallThinker a period of one un-roped full layer and three roped 4,096-key
 window layers, no leading layer, ReGLU experts whose router reads the block's
-input.
+input; MiniCPM-SALA a period of one block-selected layer and three of lightning
+attention, under fixed multipliers on the embedding, the residual branches and
+the head's input.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -60,9 +64,11 @@ from ..ops import (mha_reference, ring_attention, rms_norm, apply_rope,
                    ulysses_attention)
 from ..ops.moe_rows import take_rows
 from ..parallel.sharding import shard_constraint
+from .block_sparse import BLOCK_SPARSE, BlockSparseAttention
 from .gdn import GDN
 from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention
-from .kinds import LayerKind, Yarn, flash_per_shard
+from .kinds import LayerKind, Yarn, flash_per_shard, norm_over_heads
+from .lightning import LIGHTNING, LightningAttention
 from .mla import MLA, MLA_FULL, MLA_WINDOW, LatentAttention, LatentAttentionYarn
 from .moe import MOE, bias_step
 
@@ -170,6 +176,22 @@ class LlamaConfig:
     # ``first_k_dense_replace``). ``n_layers`` counts them.
     lead_pattern: tuple[str, ...] = ()
     lead_intermediate: int = 0
+    # Lightning attention (models/lightning.py) and block-selected attention
+    # (models/block_sparse.py): the widths of the mixer kinds "lightning" and
+    # "block_sparse". ``layer_ids``: for a cut of a published stack, the
+    # published index of each of this stack's layers, in order (empty: their
+    # own), for what a layer computes from its index (lightning's decay).
+    lightning: LightningAttention | None = None
+    block_sparse: BlockSparseAttention | None = None
+    layer_ids: tuple[int, ...] = ()
+    # Fixed multipliers (MiniCPM's muP scalings, facts of an architecture):
+    # on the embedding's rows (``scale_emb``), on each residual branch before
+    # it joins the stream (``scale_depth / sqrt(layers)``), and on the final
+    # norm's output before the head (``dim_model_base / hidden``). At 1 the
+    # program is what it is without them.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
 
@@ -179,6 +201,8 @@ class LlamaConfig:
                              "'mlp_norm' or 'block'")
         if self.moe_activation not in ("silu", "relu"):
             raise ValueError(f"moe_activation is {self.moe_activation!r}: 'silu' or 'relu'")
+        if self.layer_ids and len(self.layer_ids) != self.n_layers:
+            raise ValueError(f"{len(self.layer_ids)} layer ids for {self.n_layers} layers")
 
     @property
     def norm_offset(self) -> float:
@@ -291,6 +315,21 @@ PRESETS: dict[str, LlamaConfig] = {
                                          window=5),
         moe_experts=8, moe_top_k=3, moe_norm_topk=True, moe_held=(0, 2),
         moe_router_input="block", moe_activation="relu", moe_aux_weight=0.001),
+    # block-selected attention beside lightning attention at test size: a cut
+    # of a published stack of 6 (layers 0, 1 and 3, 4), one block-selected
+    # layer and one of lightning attention a period; 4 query heads over 2 kv
+    # heads, sets of top-4 blocks of 16 keys (the first and a 24-key window
+    # forced) from keys pooled 8 at stride 4, which drop blocks at 64+
+    # positions; the three multipliers all away from 1
+    "sparse-linear-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=4, n_heads=4, n_kv_heads=2, intermediate=128,
+        head_dim=16, norm_eps=1e-6, layer_pattern=("block_sparse", "lightning"),
+        block_sparse=BlockSparseAttention(heads=4, kv_heads=2, head_dim=16, kernel_size=8,
+                                          kernel_stride=4, block_size=16, init_blocks=1,
+                                          window_size=24, topk=4),
+        lightning=LightningAttention(heads=4, head_dim=16, rope_theta=1e4, depth=6),
+        layer_ids=(0, 1, 3, 4), embed_scale=12.0, residual_scale=1.4 / math.sqrt(6),
+        logit_scale=0.25),
 }
 
 
@@ -398,12 +437,20 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
         ks = keys if len(c.layer_pattern) == 1 and not leading else jax.random.split(
             jax.random.fold_in(key, i + (1000 if leading else 0)), 9)
         stack = () if leading else lead
-        return {
+        leaves = {
             "attn_norm": norm_fill(stack + (E,), c.dtype),
             **MIXERS[mixer].init(c, ks[1:5], stack, norm_init),
             "mlp_norm": norm_fill(stack + (E,), c.dtype),
             **_mlp_kind(c, leading).init(c, ks[5:8], stack, norm_init),
         }
+        if MIXERS[mixer].buffers is not None:
+            # what a layer holds by its place in the stack: position i of
+            # period p is layer len(lead_pattern) + p * len(layer_pattern) + i
+            ids = [i] if leading else [len(c.lead_pattern) + p * len(c.layer_pattern) + i
+                                       for p in range(c.n_periods)]
+            held = MIXERS[mixer].buffers(c, ids)
+            leaves.update({k: v[0] if leading else v for k, v in held.items()})
+        return leaves
 
     return {
         "embed": norm_init(keys[0], (c.vocab_size, E), E),
@@ -445,17 +492,6 @@ def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
     return flash_per_shard(q, k, v, mesh, causal=True)
 
 
-def _norm_over_heads(t, weight, eps):
-    """RMSNorm of t [B, H, S, D] over all H*D features of a position (the
-    whole projection, as OLMoE normalises q and k), weight [H*D]; f32
-    statistics."""
-    _, h, _, d = t.shape
-    f = t.astype(jnp.float32)
-    var = jnp.mean(jnp.square(f), axis=(1, 3), keepdims=True)
-    w = weight.astype(jnp.float32).reshape(1, h, 1, d)
-    return (f * lax.rsqrt(var + eps) * w).astype(t.dtype)
-
-
 def _gate_output(attn, gate):
     """attn * sigmoid(gate), element by element, in float32."""
     return (attn.astype(jnp.float32)
@@ -475,8 +511,8 @@ def _attn_mixer(h, layer, *, config: LlamaConfig, positions, mesh: Mesh | None):
     if c.attn_out_gate:
         q, gate = q[..., :c.head_dim], q[..., c.head_dim:]
     if c.qk_norm:
-        q = _norm_over_heads(q, layer["q_norm"], c.norm_eps)
-        k = _norm_over_heads(k, layer["k_norm"], c.norm_eps)
+        q = norm_over_heads(q, layer["q_norm"], c.norm_eps)
+        k = norm_over_heads(k, layer["k_norm"], c.norm_eps)
     if c.head_qk_norm:
         # per head, over its head_dim features, weight [D]
         q = rms_norm(q, layer["q_norm"], eps=c.norm_eps, offset=c.norm_offset)
@@ -523,7 +559,15 @@ LEAD_DENSE = LayerKind(
     axes=_dense_axes, init=functools.partial(_dense_init, width="lead_intermediate"),
     apply=_dense_mlp, matmul_params=lambda c: 3.0 * c.hidden * c.lead_intermediate)
 MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW,
-                                "mla_full": MLA_FULL, "gqa": GQA, "gqa_win": GQA_WINDOW}
+                                "mla_full": MLA_FULL, "gqa": GQA, "gqa_win": GQA_WINDOW,
+                                "lightning": LIGHTNING, "block_sparse": BLOCK_SPARSE}
+
+
+def _scaled(t, scale: float):
+    """t times a fixed multiplier, in float32, rounded once; t itself at 1."""
+    if scale == 1.0:
+        return t
+    return (t.astype(jnp.float32) * scale).astype(t.dtype)
 
 
 def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
@@ -557,13 +601,13 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
         mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh,
                                     **({"return_selection": True} if return_selection else {}))
         mixed, mixed_aux = mixed if isinstance(mixed, tuple) else (mixed, {})
-        x = x + sc(mixed, ("batch", "seq", "embed_act"))
+        x = x + sc(_scaled(mixed, c.residual_scale), ("batch", "seq", "embed_act"))
 
     with device_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
         down, aux = mlp.apply(h, layer, config=c, mesh=mesh, ep_axis=ep_axis,
                               **({} if early is None else {"early": early}))
-        x = x + sc(down, ("batch", "seq", "embed_act"))
+        x = x + sc(_scaled(down, c.residual_scale), ("batch", "seq", "embed_act"))
     return x, aux, mixed_aux
 
 
@@ -628,7 +672,10 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     and ``attn_selected_share``, each the mean over the indexed layers, and
     with ``return_selection`` the key sets themselves, ``selection``
     [indexed layers, B, S, S] int8 in layer order (for a comparison; a
-    training step does not ask)."""
+    training step does not ask). A block-selected mixer (``block_sparse``) adds
+    ``attn_block_kept_share``, ``attn_block_forced_share`` and
+    ``attn_block_tile_share``, each the mean over those layers, and with
+    ``return_selection`` its sets [those layers, B, KV, S, S / block]."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -644,7 +691,7 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
             x = take_rows(params["embed"], tokens.reshape(b * s)).reshape(b, s, -1)
         else:
             x = params["embed"][tokens]
-        x = x.astype(c.dtype)
+        x = _scaled(x, c.embed_scale).astype(c.dtype)
     if mesh is not None:
         # Two-hop resharding. The gather's output inherits the table's
         # embed=fsdp sharding; jumping straight to batch=(dcn,dp,fsdp)
@@ -691,7 +738,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
                 mesh=mesh, n_microbatches=c.pipeline_microbatches,
                 param_specs=param_specs,
             )
-        out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
+        out = _scaled(rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset),
+                      c.logit_scale)
         return (out, {}) if return_aux else out
 
     # leading layers: outside the scan, each its own block, their MLP the
@@ -732,7 +780,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     scanned = c.n_layers - len(c.lead_pattern)
     per_layer = auxes[0] if len(auxes) == 1 else jax.tree.map(
         lambda *a: jnp.stack(a, axis=1).reshape((scanned,) + a[0].shape[1:]), *auxes)
-    out = rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset)
+    out = _scaled(rms_norm(x, params["final_norm"], eps=c.norm_eps, offset=c.norm_offset),
+                  c.logit_scale)
     if not return_aux:
         return out
     aux = {}
@@ -756,15 +805,19 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     if any("index_loss" in m for m in counted):
         aux.update(index_loss=both("index_loss"),
                    attn_selected_share=both("selected_share"))
-        if return_selection:
-            # a leading layer's [B, S, S]; a period position's [periods, B, S, S],
-            # its layers ``len(blocks)`` apart
-            sets = [m["selection"][None] for m in mixed_auxes if "selection" in m]
-            scanned_sets = [m["selection"] for m in mixed if "selection" in m]
-            if scanned_sets:
-                sets.append(jnp.stack(scanned_sets, axis=1).reshape(
-                    (-1,) + scanned_sets[0].shape[1:]))
-            aux["selection"] = jnp.concatenate(sets)
+    if any("block_kept_share" in m for m in counted):
+        aux.update({f"attn_{name}": both(name) for name in (
+            "block_kept_share", "block_forced_share", "block_tile_share")})
+    if return_selection and any("selection" in m for m in counted):
+        # a leading layer's [B, S, S]; a period position's [periods, B, S, S],
+        # its layers ``len(blocks)`` apart (a block-selected layer's sets are
+        # [B, KV, S, S / block] in the same places)
+        sets = [m["selection"][None] for m in mixed_auxes if "selection" in m]
+        scanned_sets = [m["selection"] for m in mixed if "selection" in m]
+        if scanned_sets:
+            sets.append(jnp.stack(scanned_sets, axis=1).reshape(
+                (-1,) + scanned_sets[0].shape[1:]))
+        aux["selection"] = jnp.concatenate(sets)
     return out, aux
 
 

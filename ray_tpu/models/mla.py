@@ -60,7 +60,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope, flash_attention, rms_norm
 from ..ops.sparse_index import index_kl, index_scores, select_top_k
-from .kinds import LayerKind, Yarn, headwise_gate, kept_keys, rope_keywords
+from .kinds import LayerKind, Yarn, sigmoid_gate, kept_keys, rope_keywords
 
 SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask",
               "dsa_kl_z", "dsa_kl_lse")
@@ -236,7 +236,7 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
             attn = flash_attention(q, k, v, sm_scale=sm_scale)
     if a.gate:
         with device_scope("attn_gate"):
-            attn = headwise_gate(h, layer["w_attn_gate"], attn)
+            attn = sigmoid_gate(h, layer["w_attn_gate"], attn)
     with device_scope("mla_out"):
         return jnp.einsum("bhsd,hde->bse", attn, layer["wo"]), aux
 

@@ -29,18 +29,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .trace_log import note_attention_cost, note_flash_cost, note_kernel_trace
+from .trace_log import (note_attention_cost, note_block_set_cost, note_flash_cost,
+                        note_kernel_trace)
 
 NEG_INF = -1e30
 
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None,
-                  window: int | None = None, mask=None):
+                  window: int | None = None, mask=None, block_sets=None, set_block: int = 64):
     """Pure-jnp attention; ground truth for kernel tests and the CPU path.
 
     Shapes: q [B, Hq, S, D], k [B, Hkv, S, D], v [B, Hkv, S, Dv]; GQA when
     Hq > Hkv. ``window``: query t sees keys t - window + 1 .. t; ``mask``
-    [B, Sq, Sk] (non-zero: allowed), one key set a query row for all heads.
+    [B, Sq, Sk] (non-zero: allowed), one key set a query row for all heads;
+    ``block_sets`` [B, Hkv, Sq, Sk / set_block]: one set a query row and kv
+    head, by blocks of ``set_block`` keys.
     """
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
@@ -60,6 +63,9 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: float | None = None
         logits = jnp.where(t < window, logits, NEG_INF)
     if key_mask is not None:
         logits = jnp.where(key_mask[:, None] != 0, logits, NEG_INF)
+    if block_sets is not None:
+        keys = jnp.repeat(block_sets, set_block, axis=-1)[..., :k.shape[2]]
+        logits = jnp.where(jnp.repeat(keys, hq // hkv, axis=1) != 0, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(probs.dtype)).astype(q.dtype)
 
@@ -125,9 +131,44 @@ def _allowed(s, q_start, k_start, causal, window, mask, q_axis: int):
     return s
 
 
+def _set_slab(n_sets: int) -> int:
+    """How many of a row's ``n_sets`` block flags a grid step takes: all of
+    them, or where they are whole lane tiles the 128 that hold the tile's."""
+    return 128 if n_sets > 128 and n_sets % 128 == 0 else n_sets
+
+
+def _expand_sets(slab, ki, block_k: int, set_block: int, key_major: bool):
+    """A tile of the key sets from block flags: ``slab`` [block_q, W] int8 holds
+    a flag a query row and block of ``set_block`` keys, the tile's own
+    ``block_k / set_block`` of them from lane ``ki * that % W`` on. Each flag is
+    spread over its block's keys by a 0/1 product (exact), query-major
+    [block_q, block_k] or ``key_major`` its transpose; int32, non-zero: allowed."""
+    width = slab.shape[1]
+    first = (ki * (block_k // set_block)) % width
+    flags = slab.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+    shape = (block_k, width) if key_major else (width, block_k)
+    key = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if key_major else 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if key_major else 0)
+    spread = jnp.where(lane == first + key // set_block, 1.0, 0.0).astype(jnp.bfloat16)
+    dims = (((1,), (1,)), ((), ())) if key_major else (((1,), (0,)), ((), ()))
+    operands = (spread, flags) if key_major else (flags, spread)
+    return (jax.lax.dot_general(*operands, dims, preferred_element_type=jnp.float32)
+            > 0.5).astype(jnp.int32)
+
+
+def _tile_mask(mask_ref, ki, block_k, set_block, key_major):
+    """The tile of key sets a step masks by: none, the operand's own block (one
+    set a query row, [Sq, Sk]), or block flags spread out (``set_block``)."""
+    if mask_ref is None:
+        return None
+    if set_block is None:
+        return mask_ref[0]
+    return _expand_sets(mask_ref[0, 0], ki, block_k, set_block, key_major)
+
+
 def _flash_kernel(
     walk, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, sm_scale, causal, block_q, block_k, window=None
+    *, sm_scale, causal, block_q, block_k, window=None, set_block=None
 ):
     qi, ki, first, last = _step(walk)
 
@@ -155,7 +196,7 @@ def _flash_kernel(
         s = jnp.where(q_ids >= k_ids, s, NEG_INF)
     else:
         s = _allowed(s, q_start, k_start, causal, window,
-                     None if mask_ref is None else mask_ref[0], 0)
+                     _tile_mask(mask_ref, ki, block_k, set_block, False), 0)
     m_prev = m_ref[:]
     m_cur = jnp.max(s, axis=1, keepdims=True)  # [bq, 1] -> broadcast over lanes
     m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
@@ -178,21 +219,24 @@ def _flash_kernel(
             lse_ref[0, 0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
 
 
-def _fit_block(requested: int, seq: int) -> int:
+def _fit_block(requested: int, seq: int, multiple: int = 16) -> int:
     """Largest block <= requested that divides ``seq`` and is a multiple
     of the bf16 sublane tile (16) — so e.g. S=1536 stays on the Pallas
     kernel with 512-wide blocks instead of silently falling back to the
     unblocked reference when the default block does not divide it."""
     b = min(requested, seq)
-    while b >= 16 and (seq % b or b % 16):
-        b -= 16
-    return max(b, 16)
+    while b >= multiple and (seq % b or b % multiple):
+        b -= multiple
+    return max(b, multiple)
 
 
-def _variant(window, mask) -> str | None:
+def _variant(window, mask, set_block=None) -> str | None:
     """What tells a kernel variant's calls apart on the op line: None for the
     plain kernels (``flash_fwd`` ...), ``win`` for a window (``attn_win_fwd``
-    ...), ``sel`` for a key set a query row (``attn_sel_fwd`` ...)."""
+    ...), ``sel`` for a key set a query row (``attn_sel_fwd`` ...), ``blk`` for
+    a set a query row and kv head by blocks (``attn_blk_fwd`` ...)."""
+    if set_block is not None:
+        return "blk"
     return "sel" if mask is not None else "win" if window is not None else None
 
 
@@ -205,6 +249,20 @@ def _kept_pairs(sq: int, sk: int, causal: bool, window, top_k) -> float:
     if top_k is not None:
         width = min(width, top_k)
     return float(sum(min(t + 1 + sk - sq, width) for t in range(sq)))
+
+
+def _set_spec(n_sets: int, rep: int, block_q: int, block_k: int, set_block: int):
+    """The block flags [B, Hkv, Sq, Sk / set_block] int8 a step takes, for every
+    kernel and both walks: the query block's rows of the query head's kv head,
+    and of a row's flags the slab that holds the key block's (``_set_slab``;
+    it changes every ``slab x set_block`` keys, so a query block's steps
+    mostly keep the one they have)."""
+    slab, per_tile = _set_slab(n_sets), block_k // set_block
+    if slab % per_tile:
+        raise ValueError(f"a key block's {per_tile} flags straddle slabs of {slab}")
+    return pl.BlockSpec(
+        (1, 1, block_q, slab),
+        lambda bi, hi, t, qs, ks, *_: (bi, hi // rep, qs[t], ks[t] * per_tile // slab))
 
 
 def _flash_forward(
@@ -221,6 +279,7 @@ def _flash_forward(
     save_residuals: bool = False,
     window: int | None = None,
     top_k: int | None = None,
+    set_block: int | None = None,
 ):
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
@@ -229,18 +288,23 @@ def _flash_forward(
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     rep = hq // hkv
     block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, sk)
-    variant = _variant(window, mask)
+    block_k = _fit_block(block_k, sk, set_block or 16)
+    variant = _variant(window, mask, set_block)
     # fallback for shapes the TPU tiling can't take: ragged blocks or blocks
     # not multiple of the bf16 sublane tile (16)
     if sq % block_q or sk % block_k or block_q % 16 or block_k % 16:
         note_kernel_trace("flash_attention", "mha_reference")
-        o = mha_reference(q, k, v, causal=causal, sm_scale=scale, window=window, mask=mask)
+        sets = {"mask": mask} if set_block is None else {"block_sets": mask,
+                                                         "set_block": set_block}
+        o = mha_reference(q, k, v, causal=causal, sm_scale=scale, window=window, **sets)
         return (o, None) if save_residuals else o
     note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
     walk, steps = _tile_walk(sq // block_q, sk // block_k, block_q, block_k, causal, window)
     if variant is None and dv == d:
         note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals, steps=steps)
+    elif set_block is not None:
+        note_block_set_cost("fwd", q, k, v, (block_q, block_k), _set_slab(mask.shape[3]),
+                            residuals=save_residuals, steps=steps)
     else:
         note_attention_cost("fwd", variant, q, k, v,
                             _kept_pairs(sq, sk, causal, window, top_k),
@@ -252,6 +316,7 @@ def _flash_forward(
         block_q=block_q,
         block_k=block_k,
         window=window,
+        set_block=set_block,
     )
 
     def kernel(*refs):
@@ -273,7 +338,10 @@ def _flash_forward(
         pl.BlockSpec((1, 1, block_k, dv), kv_index),
     ]
     operands = (q, k, v)
-    if mask is not None:
+    if set_block is not None:
+        in_specs.append(_set_spec(mask.shape[3], rep, block_q, block_k, set_block))
+        operands += (mask,)
+    elif mask is not None:
         # one key set a query row, shared by the heads of a batch row
         in_specs.append(pl.BlockSpec((1, block_q, block_k),
                                      lambda bi, hi, t, qs, ks, *_: (bi, qs[t], ks[t])))
@@ -323,7 +391,8 @@ def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start,
 
 
 def _bwd_dq_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref, dq_ref,
-                   acc_ref, *, sm_scale, causal, block_q, block_k, window=None):
+                   acc_ref, *, sm_scale, causal, block_q, block_k, window=None,
+                   set_block=None):
     """dQ: for one q block, accumulate dS @ K over its k blocks (the walk is
     query-major: a row's tiles run in sequence on-core, acc lives in VMEM)."""
     qi, ki, first, last = _step(walk)
@@ -337,7 +406,7 @@ def _bwd_dq_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_re
         q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0], lse_ref[0, 0, 0],
         delta_ref[0, 0, 0], sm_scale=sm_scale, causal=causal,
         q_start=qi * block_q, k_start=ki * block_k, window=window,
-        mask_t=None if mask_ref is None else mask_ref[0])
+        mask_t=_tile_mask(mask_ref, ki, block_k, set_block, True))
     acc_ref[:] += jax.lax.dot_general(
         ds_t, k, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                                  # [bq, d]
@@ -349,7 +418,7 @@ def _bwd_dq_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_re
 
 def _bwd_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, sm_scale, causal, block_q, block_k, window=None):
+                     *, sm_scale, causal, block_q, block_k, window=None, set_block=None):
     """dK/dV: for one k block, accumulate over its q blocks (the walk is
     key-major). P^T and dS^T come out k-major, so both products are
     plain [bk, bq] @ [bq, d] — no transposes materialize."""
@@ -365,7 +434,7 @@ def _bwd_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_
     p_t, ds_t = _bwd_probs_t(
         q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
         sm_scale=sm_scale, causal=causal, q_start=qi * block_q, k_start=ki * block_k,
-        window=window, mask_t=None if mask_ref is None else mask_ref[0])
+        window=window, mask_t=_tile_mask(mask_ref, ki, block_k, set_block, True))
     dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g,
                              preferred_element_type=jnp.float32)  # [bk, dv]
     dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
@@ -377,7 +446,7 @@ def _bwd_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_
 
 
 def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q, block_k,
-                    interpret, window=None, top_k=None):
+                    interpret, window=None, top_k=None, set_block=None):
     """Pallas dq/dk/dv. ``lse`` is the compact f32 [B, Hq, S] residual.
     K/V stay at kv-head count (GQA via index maps); dk/dv come out at
     q-head count and are reduced by the caller."""
@@ -387,9 +456,9 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
     rep = hq // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, sk)
+    block_k = _fit_block(block_k, sk, set_block or 16)
     n_q, n_k = sq // block_q, sk // block_k
-    variant = _variant(window, mask)
+    variant = _variant(window, mask, set_block)
     # Per-query statistics as one [1, block_q] row per q block (a block
     # whose trailing dims are the array's own fits any block size): lse,
     # and delta = rowsum(dO * O).
@@ -405,6 +474,10 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
     if variant is None and dv_width == d:
         note_flash_cost("flash_bwd_dq", q, k, causal=causal, steps=dq_steps)
         note_flash_cost("flash_bwd_dkdv", q, k, causal=causal, steps=dkdv_steps)
+    elif set_block is not None:
+        tiles, slab = (block_q, block_k), _set_slab(mask.shape[3])
+        note_block_set_cost("bwd_dq", q, k, v, tiles, slab, steps=dq_steps)
+        note_block_set_cost("bwd_dkdv", q, k, v, tiles, slab, steps=dkdv_steps)
     else:
         pairs = _kept_pairs(sq, sk, causal, window, top_k)
         note_attention_cost("bwd_dq", variant, q, k, v, pairs, masked=mask is not None,
@@ -427,7 +500,11 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
         row_spec, row_spec,
     ]
     operands = (q, k, v, g, lse, delta)
-    if mask is not None:
+    if set_block is not None:
+        # query-major as they are: a step spreads its flags key-major itself
+        operands += (mask,)
+        in_specs.append(_set_spec(mask.shape[3], rep, block_q, block_k, set_block))
+    elif mask is not None:
         # k-major, as the tiles are: the transpose is an XLA pass over int8
         operands += (jnp.swapaxes(mask, 1, 2),)
         in_specs.append(pl.BlockSpec((1, block_k, block_q),
@@ -438,7 +515,7 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
             ins, rest = refs[4:10], refs[10:]
             mask_ref, rest = (rest[0], rest[1:]) if mask is not None else (None, rest)
             kernel(refs[:4], *ins, mask_ref, *rest, sm_scale=scale, causal=causal,
-                   block_q=block_q, block_k=block_k, window=window)
+                   block_q=block_q, block_k=block_k, window=window, set_block=set_block)
 
         return pl.pallas_call(
             body,
@@ -523,15 +600,15 @@ def _mha_backward_blocked(q, k, v, g, *, causal, sm_scale, block_q):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _blocks_fit(sq, sk, block_q, block_k) -> bool:
+def _blocks_fit(sq, sk, block_q, block_k, set_block=None) -> bool:
     block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, sk)
+    block_k = _fit_block(block_k, sk, set_block or 16)
     return not (sq % block_q or sk % block_k or block_q % 16 or block_k % 16)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_k=None,
-                masked=False, with_lse=False):
+                masked=False, with_lse=False, set_block=None):
     """custom_vjp wrapper: Pallas kernels for BOTH directions (forward
     saves the logsumexp residual; dq and dk/dv are dedicated kernels).
     Ragged shapes fall back to the jnp blocked paths.
@@ -546,10 +623,11 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_
     the backward pass, as before.
 
     ``masked``: the function takes a fourth operand, the key sets [B, Sq,
-    Sk] int8, which gets no cotangent. ``with_lse``: it returns ``(o, lse)``,
+    Sk] int8 (with ``set_block`` block flags [B, Hkv, Sq, Sk / set_block]),
+    which gets no cotangent. ``with_lse``: it returns ``(o, lse)``,
     the logsumexp [B, Hq, S] as a constant (its cotangent is dropped)."""
     static = dict(causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
-                  interpret=interpret, window=window, top_k=top_k)
+                  interpret=interpret, window=window, top_k=top_k, set_block=set_block)
 
     @jax.custom_vjp
     def f(q, k, v, *mask):
@@ -559,7 +637,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_
         return _flash_forward(q, k, v, *mask, **static)
 
     def fwd(q, k, v, *mask):
-        if not _blocks_fit(q.shape[2], k.shape[2], block_q, block_k):
+        if not _blocks_fit(q.shape[2], k.shape[2], block_q, block_k, set_block):
             if with_lse:
                 raise NotImplementedError("the logsumexp comes from blocks that fit")
             return checkpoint_name(f(q, k, v, *mask), "attn_out"), (q, k, v, None, None, mask)
@@ -616,6 +694,8 @@ def flash_attention(
     mask=None,
     top_k: int | None = None,
     return_lse: bool = False,
+    block_sets=None,
+    set_block: int = 64,
 ):
     """Tiled attention. q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv] (GQA
     folded by repeat; the value head may be narrower than the key head).
@@ -633,13 +713,22 @@ def flash_attention(
     [B, Sq, Sk] int8: one key set a query row, shared by the heads of a
     batch row, under which the whole causal triangle's tiles are walked
     (``attn_sel_*``; ``top_k``, the most keys a set holds, only sizes the
-    useful work that ``kernel_costs()`` records). ``return_lse`` also
-    returns each query's logsumexp over its keys, [B, Hq, S] float32.
+    useful work that ``kernel_costs()`` records); ``block_sets`` [B, Hkv, Sq,
+    Sk / set_block] int8: one key set a query row and kv head, a flag a block
+    of ``set_block`` keys, spread over the block's keys inside each of the
+    causal triangle's tiles, so that no [Sq, Sk] array exists (``attn_blk_*``).
+    ``return_lse`` also returns each query's logsumexp over its keys,
+    [B, Hq, S] float32.
     """
     if interpret is None:
         interpret = not on_tpu()
     if window is not None and not causal:
         raise ValueError("a window is a causal window")
+    if block_sets is not None:
+        if mask is not None or window is not None or return_lse or not causal:
+            raise ValueError("block sets are causal and come alone")
+        return _make_flash(causal, sm_scale, block_q, block_k, interpret, None, None, True,
+                           False, set_block)(q, k, v, jax.lax.stop_gradient(block_sets))
     if mask is None and not return_lse:
         if window is None:
             return _make_flash(causal, sm_scale, block_q, block_k, interpret)(q, k, v)

@@ -260,6 +260,18 @@ def _ordered_bits(x):
     return jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
+def kth_largest(keys, top_k: int):
+    """The ``top_k``-th largest of each row of ``keys`` [..., N] uint32 (0 where
+    a row holds fewer than ``top_k`` non-zero keys: nothing is then below it),
+    found bit by bit: 32 counts of the keys at or above a candidate, no sort."""
+    def refine(i, prefix):
+        candidate = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = jnp.sum(keys >= candidate[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(count >= top_k, candidate, prefix)
+
+    return jax.lax.fori_loop(0, 32, refine, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
 def select_top_k(scores, top_k: int):
     """Key sets [B, T, T] int8 from causal scores [B, T, T]: key s is in
     query t's set iff s <= t and I[t, s] is at least the ``top_k``-th largest
@@ -272,14 +284,7 @@ def select_top_k(scores, top_k: int):
         return jnp.broadcast_to(causal, scores.shape).astype(jnp.int8)
     # 0 is below every score's pattern: what a row may not see never counts
     keys = jnp.where(causal, _ordered_bits(scores), jnp.uint32(0))
-
-    def refine(i, prefix):
-        candidate = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        count = jnp.sum(keys >= candidate[..., None], axis=-1, dtype=jnp.int32)
-        return jnp.where(count >= top_k, candidate, prefix)
-
-    threshold = jax.lax.fori_loop(0, 32, refine, jnp.zeros(scores.shape[:2], jnp.uint32))
-    return (causal & (keys >= threshold[..., None])).astype(jnp.int8)
+    return (causal & (keys >= kth_largest(keys, top_k)[..., None])).astype(jnp.int8)
 
 
 _NEG = -1e30  # below every score; finite, so that exp(_NEG - _NEG) is 1 and no NaN

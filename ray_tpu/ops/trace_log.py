@@ -25,7 +25,10 @@ traced as ``flash_attention`` like the plain ones; the indexer's
 ``dsa_probs``; a held range's adds ``moe_rows``, 0 FLOPs and the bytes of the
 rows it may fetch and of the rows it writes, traced as ``moe_rows``:
 ``pallas`` / ``interpret``, or ``xla`` where a width off the lane tiling took
-XLA's scatter-add).
+XLA's scatter-add; lightning attention's ``lightning_fwd`` / ``lightning_bwd``,
+traced as ``lightning``; the attention kernels under block sets
+``attn_blk_*``, traced as ``flash_attention`` and costed as the tiles they
+compute, ``note_block_set_cost``).
 """
 
 from __future__ import annotations
@@ -120,6 +123,24 @@ def note_flash_cost(kernel: str, q, k, *, causal: bool,
 _ATTENTION_WIDTHS = {"fwd": (1, 1), "bwd_dq": (2, 1), "bwd_dkdv": (2, 2)}
 
 
+def _attention_bytes(part: str, q, k, v, residuals: bool) -> float:
+    """Operands and results of one attention kernel call once, as
+    ``note_flash_cost`` counts them, with v, o and dO at the value head's
+    width."""
+    b, hq, sq, d = q.shape
+    sk, dv = k.shape[2], v.shape[3]
+    q_b = b * hq * sq * d * q.dtype.itemsize
+    o_b = b * hq * sq * dv * q.dtype.itemsize
+    kv_b = (math.prod(k.shape) + math.prod(v.shape)) * k.dtype.itemsize
+    stats = b * hq * sq * 4
+    return {
+        "fwd": q_b + o_b + kv_b + (128 * stats if residuals else 0),
+        "bwd_dq": 2 * q_b + o_b + kv_b + 2 * stats,
+        "bwd_dkdv": q_b + o_b + kv_b + 2 * stats
+        + b * hq * sk * (d + dv) * k.dtype.itemsize,
+    }[part]
+
+
 def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *,
                         residuals: bool = True, masked: bool = False, steps=None) -> None:
     """Record one call of an attention kernel variant (``attn_win_*``: a
@@ -135,15 +156,23 @@ def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *
     sk, dv = k.shape[2], v.shape[3]
     n_d, n_dv = _ATTENTION_WIDTHS[part]
     flops = 2.0 * b * hq * pairs * (n_d * d + n_dv * dv)
-    q_b = b * hq * sq * d * q.dtype.itemsize
-    o_b = b * hq * sq * dv * q.dtype.itemsize
-    kv_b = (math.prod(k.shape) + math.prod(v.shape)) * k.dtype.itemsize
-    stats = b * hq * sq * 4
-    nbytes = {
-        "fwd": q_b + o_b + kv_b + (128 * stats if residuals else 0),
-        "bwd_dq": 2 * q_b + o_b + kv_b + 2 * stats,
-        "bwd_dkdv": q_b + o_b + kv_b + 2 * stats
-        + b * hq * sk * (d + dv) * k.dtype.itemsize,
-    }[part] + (b * sq * sk if masked else 0)
+    nbytes = _attention_bytes(part, q, k, v, residuals) + (b * sq * sk if masked else 0)
     name = f"flash_{part}" if variant is None else f"attn_{variant}_{part}"
     note_kernel_cost(name, flops, nbytes, steps)
+
+
+def note_block_set_cost(part: str, q, k, v, tiles: tuple[int, int], slab: int, *,
+                        residuals: bool = True, steps=None) -> None:
+    """Record one call of an attention kernel under block sets
+    (``attn_blk_*``) as the work it DOES: every live tile of the walk
+    (``steps[1]``) computes all of its ``block_q x block_k`` pairs, whatever
+    its sets keep, and spreads its flags by one more product over ``slab``
+    lanes. Bytes as ``note_attention_cost``, with the int8 flags [B, Hkv, Sq,
+    Sk / set_block] once a query head (each head's steps fetch their slab)."""
+    b, hq, sq, d = q.shape
+    n_d, n_dv = _ATTENTION_WIDTHS[part]
+    pairs = float(steps[1]) * tiles[0] * tiles[1]
+    flops = 2.0 * b * hq * pairs * (n_d * d + n_dv * v.shape[3] + slab)
+    nbytes = _attention_bytes(part, q, k, v, residuals) + b * hq * sq * slab
+    note_kernel_cost(f"attn_blk_{part}", flops, nbytes, steps, tiles=list(tiles), slab=slab)
+

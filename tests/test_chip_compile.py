@@ -22,6 +22,7 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.lightning_attention import lightning_attention
 from ray_tpu.models.gqa import window_blocks
 from ray_tpu.ops.moe_rows import sum_rows
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -77,6 +78,34 @@ def _grouped_window(chip, backward, b=1, hq=72, hkv=8, s=16384, d=128, window=51
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
     return jax.jit(fn).lower(q, kv, kv)
+
+
+def _lightning(chip, backward, b=1, h=32, t=16384, d=128):
+    """MiniCPM-SALA's lightning layers at one row of 16,384: 32 heads of 128,
+    bf16 operands, a decay a head; backward keeps 128 chunk states in VMEM."""
+    x = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16, sharding=chip)
+    decay = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=chip)
+
+    def fwd(q, k, v, log_decay):
+        return lightning_attention(q, k, v, log_decay, scale=d ** -0.5, interpret=False)
+
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+                  ) if backward else fwd
+    return jax.jit(fn).lower(x, x, x, decay)
+
+
+def _block_sets(chip, backward, b=1, hq=32, hkv=2, t=16384, d=128, block=64):
+    """MiniCPM-SALA's block-selected layers at one row of 16,384: 32 query heads
+    over 2 kv heads under int8 block flags [B, KV, T, T / 64], a slab of 128 of
+    a row's 256 flags a grid step."""
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    args = (sd((b, hq, t, d)), sd((b, hkv, t, d)), sd((b, hkv, t, d)),
+            sd((b, hkv, t, t // block), jnp.int8))
+    fwd = lambda q, k, v, sets: flash_attention(  # noqa: E731
+        q, k, v, block_sets=sets, set_block=block, interpret=False)
+    fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+                  ) if backward else fwd
+    return jax.jit(fn).lower(*args)
 
 
 def _paged_staging(chip, layers, kh, g, d, page, slots=8, max_len=2560, k_steps=32,
@@ -340,6 +369,14 @@ CASES = {
     "embed-rows-hybrid": lambda c: _rows(c, 18992, 16384, 2048),
     # a vocabulary no 8 rows divide (GPT-2's): summed into 50,432 rows and cut
     "embed-rows-odd-vocab": lambda c: _rows(c, 50257, 8192, 2048),
+    # MiniCPM-SALA at one row of 16,384: the lightning kernels at 32 heads of
+    # 128, the attention kernels under block sets at 32 query heads over 2 kv
+    # heads, and the embedding's gradient at 18,362 rows (no multiple of 128)
+    "lightning-fwd-32h-16k": lambda c: _lightning(c, backward=False),
+    "lightning-bwd-32h-16k": lambda c: _lightning(c, backward=True),
+    "attn-blk-fwd-32to2-16k": lambda c: _block_sets(c, backward=False),
+    "attn-blk-bwd-32to2-16k": lambda c: _block_sets(c, backward=True),
+    "embed-rows-sala": lambda c: _rows(c, 18362, 16384, 4096),
 }
 
 
