@@ -34,7 +34,8 @@ Allowed keys of query t: s <= t, and with ``window`` t - s < window. Rope
 turns the first ``rotary_dim`` features of a head (0: all) by ``rope_theta``'s
 frequencies, or with ``yarn`` by YaRN's (``ops/rope.py``), cos and sin times
 its ``attention_factor``. A window layer's kernels run in blocks that follow
-the window (``window_blocks``).
+the window and the group (``window_blocks``): with more than one query head a
+kv head, a tile's rows are the kv head's whole group x a short query block.
 
 The mixer counts beside its output, where it has a window, ``window_share``:
 the (query, key) pairs it attends over the causal pairs, from the positions
@@ -119,12 +120,53 @@ def _rope(t, positions, a: GroupedQueryAttention):
 # layers): 1,223.3 ms a step at 512 x 512, 1,153.5 at 1024 x 1024.
 WIDE_WINDOW_BLOCK = 1024
 
+# Blocks under grouped queries, where a tile's rows are a kv head's whole
+# group x the query block (``ops/attention.py``: the folded kernels). The
+# three kernels alone on a v5e, traced (my chip runs, PR 49; q x k blocks;
+# forward / dQ / dK-dV ms a call, then a whole forward-and-backward with the
+# XLA passes beside the kernels: delta, and unfolded the sum of dK / dV over a
+# group). `1 x 72 : 8 x 16384 x 128` under 512 keys (the band of a query block
+# as ONE tile unless "walk"):
+#   one head a tile, 512 x 512   9.16 / 6.75 / 7.95   26.84  (2.0 x the kept pairs)
+#   folded 512 x 512 walk        5.45 / 5.29 / 7.05   18.89  (2.0 x)
+#   folded 256 x 512 walk        5.64 / 5.34 / 6.66   18.70
+#   folded 256 x 256 walk        7.25 / 4.42 / 5.27   18.02  (1.5 x, three rescales)
+#   folded 128 x 128 walk       10.17 / 6.35 / 6.05   23.67  (1.25 x, five)
+#   folded 256 x 256, [2304, 768] 3.15 / 3.71 / 5.27  13.20  (1.5 x, no rescale)
+#   folded 128 x 128, [1152, 640] 2.63 / 3.31 / 6.06  13.08  (1.25 x)
+#   folded 128 x 512, [1152, 640] 2.63 / 3.31 / 6.95  13.96
+#   folded 128 x 256, [1152, 640] 2.63 / 3.31 / 5.74  12.74  <- the rule
+# `1 x 28 : 4 x 16384 x 128` under 4,096 keys (the band is walked: it fits no
+# tile):
+#   one head a tile, 1024 x 1024  7.88 / 8.99 / 11.79  30.17
+#   folded 1024 x 1024            7.46 / 8.56 / 12.02  28.68
+#   folded 512 x 1024             7.55 / 8.61 / 12.04  28.80
+#   folded 256 x 1024             7.73 / 8.59 / 11.24  28.20
+#   folded 128 x 1024             8.29 / 9.91 / 11.47  30.31
+#   folded 512 x 512              7.21 / 8.07 / 10.96  26.82
+#   folded 256 x 512              7.78 / 8.17 / 10.43  26.97  <- the rule (half the rows)
+#   folded 128 x 512              8.56 / 8.69 / 10.95  28.80
+#   folded 256 x 256             13.07 / 8.50 / 10.47  32.60
+#   folded 128 x 256             14.25 / 10.81 / 11.54 37.18
+# dK/dV wants a key block of 256 or 512 whatever the query block (its matrix
+# unit streams the key block's rows); the forward wants the whole band at once.
+FOLDED_NARROW_BLOCKS = (128, 256)
+FOLDED_WIDE_BLOCKS = (256, 512)
 
-def window_blocks(window: int) -> int:
-    """Query and key rows a tile of the ``attn_win_*`` kernels, by the window:
-    a window of at most 512 keys takes 512-blocks (blocks of its own size: a
-    query block's band is two key blocks), a longer one WIDE_WINDOW_BLOCK."""
-    return 512 if window <= 512 else WIDE_WINDOW_BLOCK
+
+def window_blocks(window: int, rep: int = 1) -> tuple[int, int]:
+    """(query rows, key rows) of the ``attn_win_*`` kernels' blocks, by the
+    window and by ``rep``, the query heads a kv head. One head a group: square
+    tiles, 512-blocks for a window of at most 512 keys (a query block's band
+    is two key blocks) and WIDE_WINDOW_BLOCK for a longer one. Grouped queries
+    (the kernels fold the group into a tile's rows): a SHORT query block, whose
+    band of ``window + 127`` keys is one tile at up to 512 keys (the key rows
+    are then dK/dV's block alone), and for a longer window 256 queries against
+    key blocks of 512."""
+    if rep == 1:
+        block = 512 if window <= 512 else WIDE_WINDOW_BLOCK
+        return block, block
+    return FOLDED_NARROW_BLOCKS if window <= 512 else FOLDED_WIDE_BLOCKS
 
 
 def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=None):
@@ -148,9 +190,9 @@ def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=Non
         v = checkpoint_name(v, "v")
         aux = {}
         if a.window:
-            block = window_blocks(a.window)
+            block_q, block_k = window_blocks(a.window, a.heads // a.kv_heads)
             attn = flash_per_shard(q, k, v, mesh, causal=True, window=a.window,
-                                   block_q=block, block_k=block)
+                                   block_q=block_q, block_k=block_k)
             kept = jnp.sum(jnp.minimum(positions.astype(jnp.float32) + 1.0, a.window))
             aux["window_share"] = kept / (positions.size / s) / (s * (s + 1) / 2)
         else:

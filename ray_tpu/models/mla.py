@@ -228,6 +228,7 @@ def mla_mixer(h, layer, a: LatentAttention, *, config, positions, mesh=None,
             aux["selection"] = mask
     elif a.window:
         # blocks of the window's size: a query block's band is two key blocks
+        # (as many key heads as query heads: nothing for the kernels to fold)
         attn = flash_attention(q, k, v, sm_scale=sm_scale, window=a.window,
                                block_q=512, block_k=512)
     else:

@@ -1,9 +1,10 @@
 """Flash attention as a Pallas TPU kernel.
 
 Online-softmax tiled attention (Dao et al.) laid out for the MXU: the grid
-iterates (batch, head, tile), and TPU grids execute the trailing axis
-sequentially on-core, so f32 accumulators live in VMEM scratch across a
-row's tiles. The tile axis walks a TABLE (``_tile_walk``, scalar-prefetched
+iterates (batch, head, tile; under a window and grouped queries the head is
+a KV head and a tile holds its whole group), and TPU grids execute the
+trailing axis sequentially on-core, so f32 accumulators live in VMEM scratch
+across a row's tiles. The tile axis walks a TABLE (``_tile_walk``, scalar-prefetched
 into SMEM) of the (q block, k block) tiles that hold a pair a query may
 see: the causal triangle, a window's band, or the whole rectangle, query
 block by query block with the key blocks inner (key-major for dK/dV). A
@@ -102,8 +103,11 @@ def _tile_walk(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
 def _tile_index_maps(rep: int):
     """Index maps of a q-shaped operand [B, Hq, Sq, *] and of K / V [B, Hkv,
     Sk, *], whose block a step takes from the walk's tables (scalar-prefetched
-    refs trail the grid's indices). GQA: a query head maps to its kv head in
-    the index map, no repeated K/V materialization in HBM."""
+    refs trail the grid's indices). GQA in the plain, ``sel`` and ``blk``
+    kernels and under a window with one head a group: the grid walks QUERY
+    heads and a query head maps to its kv head in the index map, no repeated
+    K/V materialization in HBM (a window's grouped queries walk KV heads:
+    ``_fold_specs``)."""
     return (lambda bi, hi, t, qs, ks, *_: (bi, hi, qs[t], 0),
             lambda bi, hi, t, qs, ks, *_: (bi, hi // rep, ks[t], 0))
 
@@ -129,6 +133,16 @@ def _allowed(s, q_start, k_start, causal, window, mask, q_axis: int):
     if mask is not None:
         s = jnp.where(mask.astype(jnp.int32) != 0, s, NEG_INF)
     return s
+
+
+def _band_seen(q_start, k_start, shape, window, q_axis: int):
+    """Which (query, key) pairs of a tile a causal window allows, bool of
+    ``shape`` ([queries, keys], or with ``q_axis`` 1 its transpose): positions
+    do not depend on the head, so the folded kernels make it once a step at one
+    head's shape and repeat it over the group."""
+    ahead = ((q_start - k_start) + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    return (ahead >= 0) & (ahead < window)
 
 
 def _set_slab(n_sets: int) -> int:
@@ -251,6 +265,16 @@ def _kept_pairs(sq: int, sk: int, causal: bool, window, top_k) -> float:
     return float(sum(min(t + 1 + sk - sq, width) for t in range(sq)))
 
 
+def _win_geometry(variant, rep: int, block_q: int, keys: int, steps) -> dict:
+    """What the ``attn_win_*`` entries of ``kernel_costs()`` say of a call's
+    tiling: the query heads a tile holds, a step's [rows, keys], and the pairs
+    a head's steps walk, beside the kept pairs its FLOPs count."""
+    if variant != "win":
+        return {}
+    return dict(heads_a_tile=rep, tiles=[rep * block_q, keys],
+                walked_pairs=float(steps[0] * block_q * keys))
+
+
 def _set_spec(n_sets: int, rep: int, block_q: int, block_k: int, set_block: int):
     """The block flags [B, Hkv, Sq, Sk / set_block] int8 a step takes, for every
     kernel and both walks: the query block's rows of the query head's kv head,
@@ -299,6 +323,10 @@ def _flash_forward(
         o = mha_reference(q, k, v, causal=causal, sm_scale=scale, window=window, **sets)
         return (o, None) if save_residuals else o
     note_kernel_trace("flash_attention", "interpret" if interpret else "pallas")
+    if _folds(variant, rep, block_q, interpret):
+        return _fold_forward(q, k, v, scale=scale, block_q=block_q, block_k=block_k,
+                             window=window, interpret=interpret,
+                             save_residuals=save_residuals)
     walk, steps = _tile_walk(sq // block_q, sk // block_k, block_q, block_k, causal, window)
     if variant is None and dv == d:
         note_flash_cost("flash_fwd", q, k, causal=causal, residuals=save_residuals, steps=steps)
@@ -308,7 +336,8 @@ def _flash_forward(
     else:
         note_attention_cost("fwd", variant, q, k, v,
                             _kept_pairs(sq, sk, causal, window, top_k),
-                            residuals=save_residuals, masked=mask is not None, steps=steps)
+                            residuals=save_residuals, masked=mask is not None, steps=steps,
+                            **_win_geometry(variant, 1, block_q, block_k, steps))
     inner = functools.partial(
         _flash_kernel,
         sm_scale=scale,
@@ -363,20 +392,30 @@ def _flash_forward(
         interpret=interpret,
         name="flash_fwd" if variant is None else f"attn_{variant}_fwd",
     )(*walk, *operands)
-    return result
+    # The kernel writes lse replicated over 128 lanes; one lane is the
+    # residual and what the backward kernels read (S minor: a trailing
+    # 1 would be padded back to 128 lanes in HBM).
+    return (result[0], result[1][..., 0]) if save_residuals else result
 
 
 def _bwd_probs_t(q, k, v, g, lse, delta, *, sm_scale, causal, q_start, k_start,
-                 window=None, mask_t=None):
+                 window=None, mask_t=None, fold=None):
     """One [block_k, block_q] tile of the backward pass, k-major: P^T and
     dS^T. Scores are taken transposed (K Q^T) so that the per-query
     statistics ``lse`` and ``delta`` are [1, block_q] ROWS, broadcast
     along sublanes: compact in HBM, where a column per query would be
-    padded to 128 lanes (67 MB a layer at 2 x 16 x 4096, not 0.5)."""
+    padded to 128 lanes (67 MB a layer at 2 x 16 x 4096, not 0.5).
+    ``fold`` (rep, block_q): the queries are a kv head's ``rep`` query heads
+    x one query block, head-major along the lanes, under a window; the
+    band's mask is made once at [block_k, block_q] and repeated a head."""
     s_t = jax.lax.dot_general(
         k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale
-    if window is not None or mask_t is not None:
+    if fold is not None:
+        rep, block_q = fold
+        seen = _band_seen(q_start, k_start, (k.shape[0], block_q), window, 1)
+        s_t = s_t + jnp.tile(jnp.where(seen, 0.0, NEG_INF), (1, rep))
+    elif window is not None or mask_t is not None:
         s_t = _allowed(s_t, q_start, k_start, causal, window, mask_t, 1)
     elif causal:
         k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
@@ -448,8 +487,10 @@ def _bwd_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, mask_
 def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q, block_k,
                     interpret, window=None, top_k=None, set_block=None):
     """Pallas dq/dk/dv. ``lse`` is the compact f32 [B, Hq, S] residual.
-    K/V stay at kv-head count (GQA via index maps); dk/dv come out at
-    q-head count and are reduced by the caller."""
+    K/V stay at kv-head count (GQA via index maps). The plain, ``sel`` and
+    ``blk`` kernels write dk/dv at the q-head count and the sum over a group
+    follows here; a window's grouped queries take ``_fold_backward``, whose
+    dk/dv leave the kernel at the kv-head count."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     dv_width = v.shape[3]
@@ -459,6 +500,9 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
     block_k = _fit_block(block_k, sk, set_block or 16)
     n_q, n_k = sq // block_q, sk // block_k
     variant = _variant(window, mask, set_block)
+    if _folds(variant, rep, block_q, interpret):
+        return _fold_backward(q, k, v, o, lse, g, scale=scale, block_q=block_q,
+                              block_k=block_k, window=window, interpret=interpret)
     # Per-query statistics as one [1, block_q] row per q block (a block
     # whose trailing dims are the array's own fits any block size): lse,
     # and delta = rowsum(dO * O).
@@ -480,10 +524,10 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
         note_block_set_cost("bwd_dkdv", q, k, v, tiles, slab, steps=dkdv_steps)
     else:
         pairs = _kept_pairs(sq, sk, causal, window, top_k)
-        note_attention_cost("bwd_dq", variant, q, k, v, pairs, masked=mask is not None,
-                            steps=dq_steps)
-        note_attention_cost("bwd_dkdv", variant, q, k, v, pairs, masked=mask is not None,
-                            steps=dkdv_steps)
+        for part, steps in (("bwd_dq", dq_steps), ("bwd_dkdv", dkdv_steps)):
+            note_attention_cost(part, variant, q, k, v, pairs, masked=mask is not None,
+                                steps=steps,
+                                **_win_geometry(variant, 1, block_q, block_k, steps))
     prefix = "flash" if variant is None else f"attn_{variant}"
 
     # one set of specs for both kernels: each reads its own walk's tables
@@ -545,6 +589,282 @@ def _flash_backward(q, k, v, o, lse, g, mask=None, *, causal, sm_scale, block_q,
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(b, hkv, rep, sk, dv_width).sum(axis=2).astype(v.dtype)
+    return dq, dk, dv
+
+
+# The window kernels under grouped queries (``window is not None and rep > 1``):
+# the grid's head axis walks KV heads, and a tile's rows are the ``rep`` query
+# heads of that kv head x one query block, [rep, block_q, D] -> [rep * block_q,
+# D] in VMEM, against keys fetched once a group. Where a query block's whole
+# band fits one [rows, keys] float32 tile of _BAND_TILE_BYTES it IS one tile
+# (``_band_tile``: a step a query block, softmax direct, no rescale) for the
+# forward and dQ; wider bands, and dK/dV always, walk key blocks.
+_BAND_TILE_BYTES = 8 * 1024 * 1024
+_FOLD_VMEM_LIMIT = 100 * 1024 * 1024  # a v5e core has 128 MiB; the default scope is 16
+
+
+def _folds(variant, rep: int, block_q: int, interpret: bool) -> bool:
+    """Whether a call takes the folded kernels: a window alone, grouped queries,
+    and on the chip a query block of whole lane tiles (a head's queries are 128
+    lanes or more of the backward tiles and of the logsumexp's row)."""
+    return variant == "win" and rep > 1 and (interpret or block_q % 128 == 0)
+
+
+def _band_tile(sq: int, sk: int, block_q: int, window: int, rep: int) -> int | None:
+    """Keys of the one tile that holds the whole band of a query block of the
+    folded window kernels (``block_q + window - 1`` keys, in whole query
+    blocks), or None where the band is walked in key blocks."""
+    band = min(-(-(block_q + window - 1) // block_q) * block_q, sk)
+    return band if sq == sk and rep * block_q * band * 4 <= _BAND_TILE_BYTES else None
+
+
+def _fold_walk(sq, sk, block_q, block_k, window, band):
+    """The query-major walk of the folded forward and dQ: ``_tile_walk``'s, or
+    where the band is one tile one step a query block, whose key column counts
+    QUERY blocks (K and V are fetched at an offset of so many, the band's
+    start, not by blocks of their own size; ``band``: ``_band_tile``'s). With
+    it ``(grid_steps, live_steps)``, the keys of a tile and the keys its column
+    counts by."""
+    if band is None:
+        return *_tile_walk(sq // block_q, sk // block_k, block_q, block_k, True,
+                           window), block_k, block_k
+    qs = np.arange(sq // block_q, dtype=np.int32)
+    starts = np.clip(qs + 1 - band // block_q, 0, (sk - band) // block_q).astype(np.int32)
+    return ((qs, starts, np.ones_like(qs), np.ones_like(qs)), (len(qs), len(qs)), band,
+            block_q)
+
+
+def _fold_specs(rep, block_q, d, dv, keys, unit):
+    """Block specs of the folded kernels' grid (batch, KV head, step): a
+    q-shaped operand viewed [B, Hkv, rep, S, *], K and V tiles of ``keys`` keys
+    from key ``unit`` x the walk's key column on, a row of per-query
+    statistics [B, Hkv, n_q, 1, rep * block_q]."""
+    group = lambda bi, hi, t, qs, ks, *_: (bi, hi, 0, qs[t], 0)  # noqa: E731
+    at = lambda bi, hi, t, qs, ks, *_: (bi, hi, ks[t] * unit, 0)  # noqa: E731
+    kv = lambda width: pl.BlockSpec(  # noqa: E731
+        (pl.Squeezed(), pl.Squeezed(), pl.Element(keys), pl.Element(width)), at)
+    return dict(
+        q=pl.BlockSpec((1, 1, rep, block_q, d), group),
+        o=pl.BlockSpec((1, 1, rep, block_q, dv), group),
+        k=kv(d), v=kv(dv),
+        row=pl.BlockSpec((1, 1, 1, 1, rep * block_q),
+                         lambda bi, hi, t, qs, ks, *_: (bi, hi, qs[t], 0, 0)))
+
+
+def _group_rows(ref):
+    """A folded q-shaped block [1, 1, rep, block_q, D] as the tile's rows
+    [rep * block_q, D]: free where block_q is whole sublane tiles."""
+    rep, block_q, d = ref.shape[2:]
+    return ref[0, 0].reshape(rep * block_q, d)
+
+
+def _to_group_rows(x, b, hkv, rep, n_q, block_q):
+    """Per-query statistics [B, Hq, S] as the folded kernels' rows [B, Hkv,
+    n_q, 1, rep * block_q] (head-major inside a query block)."""
+    x = x.reshape(b, hkv, rep, n_q, block_q).transpose(0, 1, 3, 2, 4)
+    return x.reshape(b, hkv, n_q, 1, rep * block_q)
+
+
+def _from_group_rows(x, b, hkv, rep, n_q, block_q):
+    x = x.reshape(b, hkv, n_q, rep, block_q).transpose(0, 1, 3, 2, 4)
+    return x.reshape(b, hkv * rep, n_q * block_q)
+
+
+def _fold_fwd_kernel(walk, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                     sm_scale, block_q, unit, window, rep, single):
+    qi, ki, first, last = _step(walk)
+    keys, rows = k_ref.shape[0], rep * block_q
+    v = v_ref[...]
+    s = jax.lax.dot_general(_group_rows(q_ref), k_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    seen = _band_seen(qi * block_q, ki * unit, (block_q, keys), window, 0)
+    s = jnp.where(seen[None], s.reshape(rep, block_q, keys), NEG_INF).reshape(rows, keys)
+
+    def write(o, lse):
+        o_ref[0, 0] = o.reshape(rep, block_q, v.shape[1]).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # a column a query -> the compact row the backward kernels read
+            lse_ref[0, 0, 0] = jnp.transpose(jnp.broadcast_to(lse, (rows, 128)))[:1]
+
+    m_cur = jnp.max(s, axis=1, keepdims=True)
+    if single:  # the whole band: softmax direct, no accumulator
+        p = jnp.exp(s - m_cur)
+        l_cur = jnp.sum(p, axis=1, keepdims=True)
+        o = jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        write(o / l_cur, m_cur + jnp.log(jnp.maximum(l_cur, 1e-30)))
+        return
+    acc_ref, m_ref, l_ref = scratch
+
+    @pl.when(first)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+    p = jnp.exp(s - m_new[:, :1])
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(jnp.sum(p, axis=1, keepdims=True),
+                                                   l_ref.shape)
+    acc_ref[:] = acc_ref[:] * alpha[:, :1] + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_ref[:] = m_new
+
+    @pl.when(last)
+    def _final():
+        write(acc_ref[:] / l_ref[:, :1],
+              m_ref[:, :1] + jnp.log(jnp.maximum(l_ref[:, :1], 1e-30)))
+
+
+def _fold_forward(q, k, v, *, scale, block_q, block_k, window, interpret, save_residuals):
+    """The window variant's forward under grouped queries. Returns ``o``, or
+    with ``save_residuals`` ``(o, lse)``, the logsumexp compact [B, Hq, S]."""
+    b, hq, sq, d = q.shape
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
+    rep, n_q = hq // hkv, sq // block_q
+    rows = rep * block_q
+    band = _band_tile(sq, sk, block_q, window, rep)
+    walk, steps, keys, unit = _fold_walk(sq, sk, block_q, block_k, window, band)
+    note_attention_cost("fwd", "win", q, k, v, _kept_pairs(sq, sk, True, window, None),
+                        residuals=save_residuals, steps=steps,
+                        **_win_geometry("win", rep, block_q, keys, steps))
+    specs = _fold_specs(rep, block_q, d, dv, keys, unit)
+    out_specs, out_shape = [specs["o"]], [jax.ShapeDtypeStruct((b, hkv, rep, sq, dv), q.dtype)]
+    if save_residuals:
+        out_specs.append(specs["row"])
+        out_shape.append(jax.ShapeDtypeStruct((b, hkv, n_q, 1, rows), jnp.float32))
+
+    def kernel(*refs):
+        lse_ref, scratch = (refs[8], refs[9:]) if save_residuals else (None, refs[8:])
+        _fold_fwd_kernel(refs[:4], *refs[4:8], lse_ref, *scratch, sm_scale=scale,
+                         block_q=block_q, unit=unit, window=window, rep=rep,
+                         single=band is not None)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, hkv, steps[0]),
+            in_specs=[specs["q"], specs["k"], specs["v"]], out_specs=out_specs,
+            scratch_shapes=[] if band else [pltpu.VMEM((rows, dv), jnp.float32),
+                                            pltpu.VMEM((rows, 128), jnp.float32),
+                                            pltpu.VMEM((rows, 128), jnp.float32)]),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FOLD_VMEM_LIMIT),
+        interpret=interpret,
+        name="attn_win_fwd",
+    )(*walk, q.reshape(b, hkv, rep, sq, d), k, v)
+    o = out[0].reshape(b, hq, sq, dv)
+    return (o, _from_group_rows(out[1], b, hkv, rep, n_q, block_q)) if save_residuals else o
+
+
+def _fold_dq_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref, *scratch,
+                    sm_scale, block_q, unit, window, rep, single):
+    """dQ of a group's query block: dS @ K over its band, one tile or a walk
+    of key blocks accumulated in VMEM."""
+    qi, ki, first, last = _step(walk)
+    k = k_ref[...]
+    _, ds_t = _bwd_probs_t(
+        _group_rows(q_ref), k, v_ref[...], _group_rows(g_ref), lse_ref[0, 0, 0],
+        delta_ref[0, 0, 0], sm_scale=sm_scale, causal=True, q_start=qi * block_q,
+        k_start=ki * unit, window=window, fold=(rep, block_q))
+    dq = jax.lax.dot_general(ds_t, k, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)      # [rows, d]
+    if single:
+        dq_ref[0, 0] = dq.reshape(dq_ref.shape[2:]).astype(dq_ref.dtype)
+        return
+    acc_ref, = scratch
+
+    @pl.when(first)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    acc_ref[:] += dq
+
+    @pl.when(last)
+    def _final():
+        dq_ref[0, 0] = acc_ref[:].reshape(dq_ref.shape[2:]).astype(dq_ref.dtype)
+
+
+def _fold_dkdv_kernel(walk, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                      dk_acc, dv_acc, *, sm_scale, block_q, block_k, window, rep):
+    """dK/dV of a key block: the contractions run over the group's heads and
+    the block's queries at once, so the sum over a group happens in the
+    float32 accumulators and dK / dV leave at the KV heads' count."""
+    qi, ki, first, last = _step(walk)
+
+    @pl.when(first)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q, g = _group_rows(q_ref), _group_rows(g_ref)
+    p_t, ds_t = _bwd_probs_t(
+        q, k_ref[0, 0], v_ref[0, 0], g, lse_ref[0, 0, 0], delta_ref[0, 0, 0],
+        sm_scale=sm_scale, causal=True, q_start=qi * block_q, k_start=ki * block_k,
+        window=window, fold=(rep, block_q))
+    dv_acc[:] += jax.lax.dot(p_t.astype(g.dtype), g, preferred_element_type=jnp.float32)
+    dk_acc[:] += jax.lax.dot(ds_t, q, preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _final():
+        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _fold_backward(q, k, v, o, lse, g, *, scale, block_q, block_k, window, interpret):
+    """The window variant's dq / dk / dv under grouped queries; dK and dV come
+    out at the KV heads' count, [B, Hkv, S, *]."""
+    b, hq, sq, d = q.shape
+    hkv, sk, dv_width = k.shape[1], k.shape[2], v.shape[3]
+    rep, n_q, n_k = hq // hkv, sq // block_q, sk // block_k
+    rows = rep * block_q
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    lse, delta = (_to_group_rows(x, b, hkv, rep, n_q, block_q) for x in (lse, delta))
+    band = _band_tile(sq, sk, block_q, window, rep)
+    dq_walk, dq_steps, keys, unit = _fold_walk(sq, sk, block_q, block_k, window, band)
+    dkdv_walk, dkdv_steps = _tile_walk(n_q, n_k, block_q, block_k, True, window,
+                                       key_major=True)
+    pairs = _kept_pairs(sq, sk, True, window, None)
+    for part, steps, tile_keys in (("bwd_dq", dq_steps, keys), ("bwd_dkdv", dkdv_steps, block_k)):
+        note_attention_cost(part, "win", q, k, v, pairs, steps=steps,
+                            **_win_geometry("win", rep, block_q, tile_keys, steps))
+    grouped = lambda x: x.reshape(b, hkv, rep, sq, x.shape[3])  # noqa: E731
+    operands = (grouped(q), k, v, grouped(g), lse, delta)
+    params = dict(compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FOLD_VMEM_LIMIT),
+                  interpret=interpret)
+
+    def body(kernel, **static):
+        return lambda *refs: kernel(refs[:4], *refs[4:], sm_scale=scale, block_q=block_q,
+                                    window=window, rep=rep, **static)
+
+    specs = _fold_specs(rep, block_q, d, dv_width, keys, unit)
+    dq = pl.pallas_call(
+        body(_fold_dq_kernel, single=band is not None, unit=unit),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, hkv, dq_steps[0]),
+            in_specs=[specs["q"], specs["k"], specs["v"], specs["o"], specs["row"],
+                      specs["row"]],
+            out_specs=specs["q"],
+            scratch_shapes=[] if band else [pltpu.VMEM((rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rep, sq, d), q.dtype),
+        name="attn_win_bwd_dq", **params,
+    )(*dq_walk, *operands).reshape(q.shape)
+    block = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, 1, block_k, width), lambda bi, hi, t, qs, ks, *_: (bi, hi, ks[t], 0))
+    dk, dv = pl.pallas_call(
+        body(_fold_dkdv_kernel, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, hkv, dkdv_steps[0]),
+            in_specs=[specs["q"], block(d), block(dv_width), specs["o"], specs["row"],
+                      specs["row"]],
+            out_specs=[block(d), block(dv_width)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, dv_width), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        name="attn_win_bwd_dkdv", **params,
+    )(*dkdv_walk, *operands)
     return dq, dk, dv
 
 
@@ -632,8 +952,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_
     @jax.custom_vjp
     def f(q, k, v, *mask):
         if with_lse:
-            o, lse = _flash_forward(q, k, v, *mask, save_residuals=True, **static)
-            return o, lse[..., 0]
+            return _flash_forward(q, k, v, *mask, save_residuals=True, **static)
         return _flash_forward(q, k, v, *mask, **static)
 
     def fwd(q, k, v, *mask):
@@ -643,10 +962,7 @@ def _make_flash(causal, sm_scale, block_q, block_k, interpret, window=None, top_
             return checkpoint_name(f(q, k, v, *mask), "attn_out"), (q, k, v, None, None, mask)
         o, lse = _flash_forward(q, k, v, *mask, save_residuals=True, **static)
         o = checkpoint_name(o, "attn_out")
-        # The kernel writes lse replicated over 128 lanes; one lane is the
-        # residual and what the backward kernels read (S minor: a trailing
-        # 1 would be padded back to 128 lanes in HBM).
-        lse = checkpoint_name(lse[..., 0], "attn_lse")
+        lse = checkpoint_name(lse, "attn_lse")
         return ((o, lse) if with_lse else o), (q, k, v, o, lse, mask)
 
     def bwd(res, g):
@@ -709,7 +1025,12 @@ def flash_attention(
     Three static facts give other kernels, told apart on the op line by
     their names; with none of them the kernels are the plain ones:
     ``window`` (causal only): query t sees keys t - window + 1 .. t, and the
-    grid walks only the tiles the band meets (``attn_win_*``); ``mask``
+    grid walks only the tiles the band meets (``attn_win_*``; with grouped
+    queries, Hq > Hkv, the grid's head axis walks KV heads and a tile's rows are
+    the group's query heads x ``block_q`` queries, the band's keys fetched once
+    a group, as one tile where it fits ``_BAND_TILE_BYTES`` and else in blocks
+    of ``block_k``, and dK / dV are summed over the group inside the kernel);
+    ``mask``
     [B, Sq, Sk] int8: one key set a query row, shared by the heads of a
     batch row, under which the whole causal triangle's tiles are walked
     (``attn_sel_*``; ``top_k``, the most keys a set holds, only sizes the

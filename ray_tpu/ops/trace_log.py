@@ -82,9 +82,11 @@ def note_kernel_cost(kernel: str, flops: float, nbytes: float,
 def kernel_costs() -> dict[str, dict]:
     """``{"<kernel name>": {"traced": n, "flops": ..., "bytes": ...}}``:
     per call, at the shapes of the kernel's latest trace in this process; the
-    attention kernels' entries also hold ``grid_steps`` and ``live_steps``,
-    the grouped matmuls' ``tiles`` [tm, tk, tn], ``work_items`` (the static
-    bound on row-tile visits, M / tm + G - 1) and ``rhs_resident`` (``moe_gmm``:
+    attention kernels' entries also hold ``grid_steps`` and ``live_steps``
+    (the ``attn_win_*`` ones ``heads_a_tile``, ``tiles`` and ``walked_pairs``
+    too: ``note_attention_cost``), the grouped matmuls' ``tiles`` [tm, tk, tn],
+    ``work_items`` (the static bound on row-tile visits, M / tm + G - 1) and
+    ``rhs_resident`` (``moe_gmm``:
     ``tk`` spans the contraction, so a group's matrix block is fetched once a
     group and column block; ``moe_tgmm``: ``tk`` spans lhs's width)."""
     with _lock:
@@ -142,7 +144,8 @@ def _attention_bytes(part: str, q, k, v, residuals: bool) -> float:
 
 
 def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *,
-                        residuals: bool = True, masked: bool = False, steps=None) -> None:
+                        residuals: bool = True, masked: bool = False, steps=None,
+                        **geometry) -> None:
     """Record one call of an attention kernel variant (``attn_win_*``: a
     window; ``attn_sel_*``: a key set a query row; a plain kernel whose value
     head is narrower than its key head keeps its ``flash_*`` name). FLOPs are
@@ -151,14 +154,21 @@ def note_attention_cost(part: str, variant: str | None, q, k, v, pairs: float, *
     width (QK^T; in the backward kernels also dS K or dS^T Q) and 2 Dv for
     each over the value head's (PV; dO V^T; P^T dO). Bytes as
     ``note_flash_cost``, with v, o and dO at the value head's width and, where
-    ``masked``, the int8 key sets once."""
+    ``masked``, the int8 key sets once (a window's grouped queries write the
+    logsumexp compact and dK / dV at the KV heads' count, fewer bytes than this
+    counts: the count stays the one ``benchmark/flops_swa.py`` mirrors until
+    both are recounted together). ``geometry``: what the call chose from its
+    shapes, kept as given; the ``attn_win_*`` calls give ``heads_a_tile`` (the
+    query heads whose rows a tile holds: a kv head's group when folded, else
+    1), ``tiles`` (a step's [rows, keys]) and ``walked_pairs`` (the pairs a
+    head's steps compute, against the ``pairs`` it keeps)."""
     b, hq, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
     n_d, n_dv = _ATTENTION_WIDTHS[part]
     flops = 2.0 * b * hq * pairs * (n_d * d + n_dv * dv)
     nbytes = _attention_bytes(part, q, k, v, residuals) + (b * sq * sk if masked else 0)
     name = f"flash_{part}" if variant is None else f"attn_{variant}_{part}"
-    note_kernel_cost(name, flops, nbytes, steps)
+    note_kernel_cost(name, flops, nbytes, steps, **geometry)
 
 
 def note_block_set_cost(part: str, q, k, v, tiles: tuple[int, int], slab: int, *,

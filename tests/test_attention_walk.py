@@ -15,6 +15,7 @@ import pytest
 import jaxpr_walk
 
 from ray_tpu.models import PRESETS, init_params, loss_fn
+from ray_tpu.models.gqa import window_blocks
 from ray_tpu.ops import trace_log
 from ray_tpu.ops.attention import _tile_walk, flash_attention, mha_reference
 
@@ -181,7 +182,50 @@ def test_every_attention_kernel_of_a_remat_stack_takes_its_tables_steps(preset, 
         assert all(t.shape == (grid[-1],) and t.dtype == jnp.int32 for t in tables), name
         if not name.startswith("attn_win_"):
             assert grid[-1] == 4 * 5 // 2, (name, grid)
-        else:  # 512-blocks: the diagonal's tile and the one behind it
+        elif preset == "latent-sparse-debug":
+            # one head a group, 512-blocks: the diagonal's tile and the one behind it
             assert grid[-1] == 8 + 7, (name, grid)
+        else:
+            # three query heads a kv head, folded under a window of 5: the band of a
+            # query block is one tile, and dK/dV's key block meets the query blocks
+            # that hold its own keys and the next block's first four queries
+            block_q, block_k = window_blocks(config.gqa_window.window, 3)
+            per_key_block = block_k // block_q + 1
+            want = (4096 // block_k * per_key_block - 1 if name.endswith("dkdv")
+                    else 4096 // block_q)
+            assert grid[-1] == want, (name, grid)
+            assert eqn.params["grid_mapping"].grid[1] == config.gqa_window.kv_heads
         record = trace_log.kernel_costs()[name]
         assert record["grid_steps"] == record["live_steps"] == grid[-1], (name, record)
+
+
+@pytest.mark.parametrize("hq,hkv,window,want", [
+    (8, 8, 512, {"fwd": (1, [512, 512], 0.50), "bwd_dq": (1, [512, 512], 0.50),
+                 "bwd_dkdv": (1, [512, 512], 0.50)}),
+    (72, 8, 512, {"fwd": (9, [1152, 640], 0.7875), "bwd_dq": (9, [1152, 640], 0.7875),
+                  "bwd_dkdv": (9, [1152, 256], 0.6667)}),
+    (28, 4, 4096, {"fwd": (7, [1792, 512], 0.8889), "bwd_dq": (7, [1792, 512], 0.8889),
+                   "bwd_dkdv": (7, [1792, 512], 0.8889)}),
+], ids=["one_head_a_group_512", "laguna_72to8_512", "smallthinker_28to4_4096"])
+def test_the_window_kernels_record_their_tiles_and_the_pairs_they_walk(hq, hkv, window, want):
+    """Traced (nothing runs) at 1 x 16,384 x 128 with the blocks ``models/gqa.py``
+    gives the call: ``kernel_costs()`` holds the query heads a tile takes, a
+    step's [rows, keys] and the pairs a head's steps walk; kept over walked is
+    0.50 for one head a tile at 512 keys (two 512-blocks a query block) and the
+    folded rule's at 72 : 8 (a band of 640 keys for the 512-639 a query block
+    needs; dK/dV, whose key block of 256 meets six query blocks, walks 1.5 x)."""
+    s, d = 16384, 128
+    q = jax.ShapeDtypeStruct((1, hq, s, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, hkv, s, d), jnp.bfloat16)
+    block_q, block_k = window_blocks(window, hq // hkv)
+    jax.make_jaxpr(jax.grad(lambda *x: flash_attention(
+        *x, window=window, block_q=block_q, block_k=block_k).astype(jnp.float32).sum(),
+        (0, 1, 2)))(q, kv, kv)
+    kept = window * s - window * (window - 1) / 2
+    for part, (heads, tiles, share) in want.items():
+        record = trace_log.kernel_costs()[f"attn_win_{part}"]
+        assert record["flops"] == 2.0 * hq * kept * d * {"fwd": 2, "bwd_dq": 3, "bwd_dkdv": 4}[part]
+        assert (record["heads_a_tile"], record["tiles"]) == (heads, tiles), (part, record)
+        assert record["walked_pairs"] == record["grid_steps"] * tiles[0] // heads * tiles[1]
+        assert kept / record["walked_pairs"] == pytest.approx(share, abs=6e-4), (part, record)
+        assert record["grid_steps"] == record["live_steps"]

@@ -63,15 +63,15 @@ def _flash(chip, b, hq, hkv, s, d, backward):
 
 def _grouped_window(chip, backward, b=1, hq=72, hkv=8, s=16384, d=128, window=512):
     """The window kernels as ``models/gqa.py`` calls them: grouped queries,
-    blocks that follow the window (512-blocks at 512 keys: a band of two key
-    blocks a query block)."""
-    block = window_blocks(window)
+    a kv head's group the rows of a tile, blocks that follow the window (at 512
+    keys 128 queries a head against the band's 640 keys as one tile)."""
+    block_q, block_k = window_blocks(window, hq // hkv)
     q = jax.ShapeDtypeStruct((b, hq, s, d), jnp.bfloat16, sharding=chip)
     kv = jax.ShapeDtypeStruct((b, hkv, s, d), jnp.bfloat16, sharding=chip)
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=window, block_q=block,
-                               block_k=block, interpret=False)
+        return flash_attention(q, k, v, causal=True, window=window, block_q=block_q,
+                               block_k=block_k, interpret=False)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()

@@ -16,10 +16,13 @@ import numpy as np
 import optax
 import pytest
 
+import jaxpr_walk
+
 from benchmark.reference import windowed_moe_decoder as ref
 from ray_tpu.models import PRESETS, init_params, loss_fn
 from ray_tpu.models.gqa import gqa_mixer
 from ray_tpu.models.moe import moe_block
+from ray_tpu.ops import attention, trace_log
 from ray_tpu.ops.attention import flash_attention, mha_reference
 from ray_tpu.ops.rope import yarn_frequencies
 
@@ -103,21 +106,61 @@ def test_yarn_frequencies_at_the_published_numbers_match_values_worked_by_hand()
     assert np.allclose(small / plain, [1, (1 + 1 / 8) / 2, 1 / 8, 1 / 8], rtol=1e-6)
 
 
-@pytest.mark.parametrize("hq,hkv,window", [(18, 2, 40), (9, 1, 5), (12, 2, None), (6, 1, None)],
-                         ids=["win_9to1_h18", "win_9to1_h9", "full_6to1_h12", "full_6to1_h6"])
-def test_attention_kernels_at_grouped_ratios_match_mha_reference(hq, hkv, window):
-    """``attn_win_*`` at 9 query heads a kv head and ``flash_*`` at 6, values
-    and all three gradients (dK and dV summed over a kv head's group)."""
+GROUPED = {
+    # name: (query heads, kv heads, window, sequence) at 32-row blocks
+    "win_9to1_h18": (18, 2, 40, 128),
+    "win_9to1_h9": (9, 1, 5, 128),
+    "full_6to1_h12": (12, 2, None, 128),
+    "full_6to1_h6": (6, 1, None, 128),
+    # Laguna's and SmallThinker's ratios, folded: a window narrower than the
+    # query block, equal to it and four times it (the band of the first query
+    # blocks is cut by position 0 in each)
+    "fold_72to8_narrow": (72, 8, 20, 128),
+    "fold_72to8_equal": (72, 8, 32, 128),
+    "fold_72to8_four_blocks": (72, 8, 128, 256),
+    "fold_28to4_narrow": (28, 4, 20, 128),
+    "fold_28to4_equal": (28, 4, 32, 128),
+    "fold_28to4_four_blocks": (28, 4, 128, 256),
+    "win_one_head_a_group": (8, 8, 40, 128),
+}
+# the same folded calls with no room for a query block's band in one tile: the
+# band is walked in key blocks under the online softmax (the 4,096-key band's way)
+WALKED = ("fold_72to8_narrow", "fold_28to4_equal", "fold_28to4_four_blocks")
+
+
+@pytest.mark.parametrize("case", list(GROUPED) + [f"{name}_walked" for name in WALKED])
+def test_attention_kernels_at_grouped_ratios_match_mha_reference(case, monkeypatch):
+    """``attn_win_*`` at 9 and 7 query heads a kv head and ``flash_*`` at 6,
+    values and all three gradients. Under a window and grouped queries a tile's
+    rows are a kv head's whole group (``heads_a_tile``), dK and dV leave the
+    kernel at the KV heads' count and nothing sums them afterwards; one head a
+    group under a window takes the unfolded kernels."""
+    hq, hkv, window, s = GROUPED[case.removesuffix("_walked")]
+    if case.endswith("_walked"):
+        monkeypatch.setattr(attention, "_BAND_TILE_BYTES", 0)
     key = jax.random.PRNGKey(3)
-    b, s, d = 1, 128, 32
+    b, d = 1, 32
     q = jax.random.normal(key, (b, hq, s, d))
     k, v = (jax.random.normal(jax.random.fold_in(key, i), (b, hkv, s, d)) for i in (1, 2))
     got = jax.jit(lambda *x: flash_attention(*x, block_q=32, block_k=32, window=window))
     want = jax.jit(lambda *x: mha_reference(*x, window=window))
     assert rel(got(q, k, v), want(q, k, v)) < 1e-5
-    g = jax.jit(jax.grad(lambda *x: jnp.sum(got(*x) ** 2), (0, 1, 2)))(q, k, v)
+    grad = jax.grad(lambda *x: jnp.sum(got(*x) ** 2), (0, 1, 2))
+    g = jax.jit(grad)(q, k, v)
     w = jax.jit(jax.grad(lambda *x: jnp.sum(want(*x) ** 2), (0, 1, 2)))(q, k, v)
     assert max(rel(a, b_) for a, b_ in zip(g, w)) < 1e-5
+    if window is None:
+        return
+    costs = trace_log.kernel_costs()
+    for part in ("fwd", "bwd_dq", "bwd_dkdv"):
+        assert costs[f"attn_win_{part}"]["heads_a_tile"] == hq // hkv
+    one_tile = hq > hkv and not case.endswith("_walked")
+    assert (costs["attn_win_fwd"]["grid_steps"] == s // 32) == one_tile
+    eqns = list(jaxpr_walk.equations(jax.make_jaxpr(grad)(q, k, v).jaxpr))
+    dkdv = next(e for e in eqns if e.primitive.name == "pallas_call"
+                and str(e.params["name"]) == "attn_win_bwd_dkdv")
+    assert [o.aval.shape for o in dkdv.outvars] == [(b, hkv, s, d)] * 2
+    assert "reduce_sum" not in {e.primitive.name for e in eqns[eqns.index(dkdv) + 1:]}
 
 
 FAULTS = {

@@ -135,9 +135,14 @@ def test_a_spec_with_no_rope_leaves_q_and_k_as_projected_and_a_roped_one_does_no
 
 
 def test_a_windows_blocks_follow_the_window():
-    assert gqa.window_blocks(512) == 512 and gqa.window_blocks(5) == 512
-    assert gqa.window_blocks(4096) == gqa.WIDE_WINDOW_BLOCK
+    assert gqa.window_blocks(512) == (512, 512) == gqa.window_blocks(5)
+    assert gqa.window_blocks(4096) == (gqa.WIDE_WINDOW_BLOCK,) * 2
     assert gqa.WIDE_WINDOW_BLOCK in (512, 1024, 2048) and 4096 % gqa.WIDE_WINDOW_BLOCK == 0
+    # grouped queries: a kv head's group is a tile's rows, in short query blocks
+    assert gqa.window_blocks(512, 9) == gqa.window_blocks(5, 3) == gqa.FOLDED_NARROW_BLOCKS
+    assert gqa.window_blocks(4096, 7) == gqa.FOLDED_WIDE_BLOCKS
+    for block_q, block_k in (gqa.FOLDED_NARROW_BLOCKS, gqa.FOLDED_WIDE_BLOCKS):
+        assert block_q <= 256 and 16384 % block_q == 0 == 16384 % block_k
 
 
 @pytest.mark.parametrize("hq,hkv,window,block", [(6, 2, 72, 16), (7, 1, 72, 16), (28, 4, 40, 16),
