@@ -59,7 +59,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-from ..observability.tracing import device_scope
+from ..observability.tracing import device_scope, with_passes
 from ..ops import (mha_reference, ring_attention, rms_norm, apply_rope,
                    ulysses_attention)
 from ..ops.moe_rows import take_rows
@@ -935,7 +935,20 @@ def loss_fn(
     chunk ran four (the logits twice). A call that is not differentiated
     computes no gradient: one product a chunk. The mask and the tokens get
     no cotangent.
+
+    Every op carries the pass it runs in beside its scope (``rt_pass`` =
+    ``fwd`` / ``remat`` / ``bwd``: ``tracing.with_passes``); ``aux`` comes
+    from the forward pass and is not differentiated (giving it a cotangent
+    is refused: differentiate ``_loss`` for that).
     """
+    loss, aux = with_passes(
+        lambda p, b: _loss(p, b, config, mesh=mesh, chunk_tokens=chunk_tokens),
+        has_aux=True)(params, batch)
+    return (loss, aux) if return_aux else loss
+
+
+def _loss(params, batch, config: LlamaConfig, *, mesh: Mesh | None, chunk_tokens: int):
+    """``loss_fn``'s ``(loss, aux)``, under no pass."""
     tokens = batch["tokens"]
     hidden, aux = forward_hidden(params, tokens, config, mesh=mesh, return_aux=True)
     with device_scope("lm_head_loss"):
@@ -972,7 +985,7 @@ def loss_fn(
         # No weight: the two terms' gradients touch disjoint leaves
         with device_scope("index_loss"):
             loss = loss + aux["index_loss"]
-    return (loss, {"ce": ce, **aux}) if return_aux else loss
+    return loss, {"ce": ce, **aux}
 
 
 def update_buffers(params, aux, config: LlamaConfig):

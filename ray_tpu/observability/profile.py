@@ -11,9 +11,11 @@ too. Program spans are the ``tracing.annotate`` events, which carry the
 device ops are named by their HLO text, which starts with the
 instruction's name (``%flash_fwd.18 = ...``) and holds its frontend
 attributes, among them the ``rt_scope="stack/attn"`` of the
-``tracing.device_scope`` that issued it: the step by scope is read off the
-event names alone. A device plane's lines cover the same time and its op
-line nests, so busy time is a union and an op's time its self time.
+``tracing.device_scope`` that issued it and the ``rt_pass="remat"`` of
+``tracing.with_passes``: the step by scope and by pass (forward, a
+``jax.checkpoint``'s second run, backward) is read off the event names
+alone. A device plane's lines cover the same time and its op line nests,
+so busy time is a union and an op's time its self time.
 Independent of ``benchmark/``, which keeps its own reducer.
 """
 
@@ -25,11 +27,12 @@ import re
 import time
 from collections import defaultdict
 
-from .tracing import MARK, SCOPE_ATTR
+from .tracing import MARK, PASS_ATTR, SCOPE_ATTR
 
 WINDOW = "capture_window"
 _DEVICE = re.compile(r"^/device:(TPU|GPU):\d+$")
 _SCOPE = re.compile(SCOPE_ATTR + r'="([^"]*)"')
+_PASS = re.compile(PASS_ATTR + r'="([^"]*)"')
 _NS = 1e-9
 
 
@@ -167,7 +170,7 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
     mapped = [(s["name"], s["start"] + shift, s["end"] + shift, s.get("attrs"))
               for s in spans or () if s["end"] + shift > lo and s["start"] + shift < hi]
     out = {"window": {"start_s": lo, "seconds": hi - lo, **clocks},
-           "devices": [], "spans": [], "ops": [], "kernels": [], "scopes": {},
+           "devices": [], "spans": [], "ops": [], "kernels": [], "scopes": {}, "passes": {},
            "idle_by_span": [], "idle_uncovered": [], "gaps_over_1ms": 0}
     totals = defaultdict(lambda: [0.0, 0])
     for name, s, e, _ in program + mapped:
@@ -187,6 +190,9 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
         # the step by scope: every path, and rolled up to a path's first
         # component (``stack``) and to its last (``mla_q``); "" holds no scope
         scopes = {k: defaultdict(lambda: [0.0, 0]) for k in ("path", "first", "last")}
+        # the step by pass ("" is outside ``with_passes``: the optimizer, the
+        # compiler's own), and each pass by its scopes' first component
+        passes = {k: defaultdict(lambda: [0.0, 0]) for k in ("pass", "pass_first")}
         for op, (sec, n) in _self_times(ops).items():
             inst = op.partition(" = ")[0]
             groups = [by_name[re.sub(r"[.\d]+$", "", inst)]]
@@ -194,16 +200,22 @@ def summarize(path: str, spans: list | None = None, top: int = 12) -> dict:
                 groups.append(kernels[re.sub(r"^%|[.\d]+$", "", inst)])
             scope = _SCOPE.search(op)
             path = scope.group(1) if scope else ""
-            groups += [scopes["path"][path], scopes["first"][path.partition("/")[0]],
-                       scopes["last"][path.rpartition("/")[2]]]
+            first = path.partition("/")[0]
+            which = _PASS.search(op)
+            which = which.group(1) if which else ""
+            groups += [scopes["path"][path], scopes["first"][first],
+                       scopes["last"][path.rpartition("/")[2]],
+                       passes["pass"][which], passes["pass_first"][which, first]]
             for g in groups:
                 g[0] += sec
                 g[1] += n
         out.update(ops=_top(by_name, top), kernels=_top(kernels, top))
         # every row, not the top: the rows partition the busy time
-        out["scopes"] = {k: [[*row, 100.0 * row[1] / busy if busy else 0.0]
-                             for row in _top(table, len(table))]
-                         for k, table in scopes.items()}
+        for name, tables in (("scopes", scopes), ("passes", passes)):
+            out[name] = {k: [[*(key if isinstance(key, tuple) else (key,)), sec, n,
+                              100.0 * sec / busy if busy else 0.0]
+                             for key, sec, n in _top(table, len(table))]
+                         for k, table in tables.items()}
     by_span, uncovered = defaultdict(float), defaultdict(float)
     gaps.sort(key=lambda g: g[0] - g[1])
     for s, e in gaps[:300]:  # the longest; the rest are microseconds between ops
@@ -235,6 +247,13 @@ def render(summary: dict) -> str:
                            ("by a path's last scope", "last")):
             lines += [title] + [f"  {r[0] or '(no scope)':<44} {r[1]:.5f} {r[2]:>6} {r[3]:6.2f}"
                                 for r in summary["scopes"][key]]
+    if any(r[0] for r in summary.get("passes", {}).get("pass", ())):  # a pass besides ""
+        lines += ["device time by pass (self s, n, % of busy)"] + [
+            f"  {r[0] or '(no pass)':<44} {r[1]:.5f} {r[2]:>6} {r[3]:6.2f}"
+            for r in summary["passes"]["pass"]]
+        lines += ["by pass and its scopes' first"] + [
+            f"  {r[0] or '(no pass)':<8} {r[1] or '(no scope)':<35} {r[2]:.5f} {r[3]:>6} {r[4]:6.2f}"
+            for r in summary["passes"]["pass_first"]]
     lines += ["program spans (n, total ms, mean ms)"] + [
         f"  {s['name']:<28} {s['count']:>6} {s['total_ms']:>10.3f} {s['mean_ms']:>9.4f}"
         for s in summary["spans"]]

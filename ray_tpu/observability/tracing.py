@@ -29,6 +29,8 @@ through the capture's ``capture_window`` event).
 from __future__ import annotations
 
 import contextlib
+import copy
+import functools
 import os
 import random
 import sys
@@ -221,6 +223,102 @@ def device_scope(name: str):
     path = f"{outer}/{name}" if outer else name
     with jax.named_scope(name), set_xla_metadata(**{SCOPE_ATTR: path}):
         yield path
+
+
+# The frontend attribute every op issued inside a ``with_passes`` function
+# carries beside ``rt_scope``: WHEN in the step it runs.
+PASS_ATTR = "rt_pass"
+# what ``ad_checkpoint._transpose_jaxpr`` extends the name stack with around
+# the eqns of a ``jax.checkpoint``'s second run, and around no other
+_REMAT_STACK = "rematted_computation"
+
+
+def with_passes(fn: Callable, has_aux: bool = False) -> Callable:
+    """``fn(diff, *rest)`` (a value, or ``(value, aux)`` with ``has_aux``),
+    differentiable in ``diff``, with the same value and gradient, and on
+    every op it issues the frontend attribute ``rt_pass``: ``"fwd"`` when it
+    is merely called and in a differentiation's forward pass, ``"remat"`` on
+    a ``jax.checkpoint``'s second run and ``"bwd"`` on the rest of the
+    backward pass. Printed beside ``rt_scope`` in the instruction's own text,
+    so a TPU's op line splits the step by pass (``profile.summarize``).
+
+    A context cannot tell the second run from the backward pass: jax merges
+    an eqn's own metadata OVER the one open when the eqn is evaluated, so what
+    the forward trace set wins everywhere. The name stack can: each eqn of
+    the second run carries ``rematted_computation``. So the function is a
+    ``custom_vjp`` whose forward rule is ``jax.vjp`` (the pull-back is the
+    residual) and whose backward rule traces the pull-back to a jaxpr, gives
+    every eqn its pass (into ``scan``, ``cond``, ``checkpoint``, ``pjit`` and
+    ``shard_map`` bodies, an inner eqn defaulting to the enclosing one's; not
+    into a Pallas kernel's body) and evaluates it. Text in the executable and
+    nothing at run time; lowering a step traces its backward pass to a jaxpr
+    once more. ``rest`` takes no cotangent; ``aux`` comes from the forward
+    pass as under ``value_and_grad(has_aux=True)``, and giving it a cotangent
+    is refused (a differentiable ``aux`` keeps its tangents' residuals: a
+    step without remat would be another program)."""
+    import jax
+    from jax._src import core
+    from jax.custom_derivatives import SymbolicZero
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    def forward(diff, *rest):
+        with set_xla_metadata(**{PASS_ATTR: "fwd"}):
+            return fn(diff, *rest)
+
+    def marked(jaxpr, default: str, done: dict):
+        # one new jaxpr per (jaxpr, pass): two calls of one pjit body stay one
+        key = (id(jaxpr), default)
+        if key not in done:
+            eqns = []
+            for eqn in jaxpr.eqns:
+                own = ("remat" if _REMAT_STACK in str(eqn.source_info.name_stack)
+                       else default)
+                params = eqn.params
+                if eqn.primitive.name != "pallas_call":
+                    params = {k: inside(v, own, done) for k, v in params.items()}
+                ctx = copy.copy(eqn.ctx)  # compute type, mesh: as they are
+                ctx.xla_metadata = {**(ctx.xla_metadata or {}), PASS_ATTR: own}
+                eqns.append(eqn.replace(params=params, ctx=ctx))
+            done[key] = (jaxpr, jaxpr.replace(eqns=eqns))  # the key's id stays taken
+        return done[key][1]
+
+    def inside(param, default: str, done: dict):
+        if isinstance(param, core.ClosedJaxpr):
+            return param.replace(jaxpr=marked(param.jaxpr, default, done))
+        if isinstance(param, core.Jaxpr):
+            return marked(param, default, done)
+        if isinstance(param, (tuple, list)) and any(
+                isinstance(p, (core.ClosedJaxpr, core.Jaxpr)) for p in param):
+            return type(param)(inside(p, default, done) for p in param)
+        return param
+
+    @jax.custom_vjp
+    def call(diff, rest):
+        return forward(diff, *rest)
+
+    def call_fwd(diff, rest):
+        diff, rest = jax.tree.map(lambda x: x.value, (diff, rest))  # symbolic_zeros' wrappers
+        out = jax.vjp(lambda d: forward(d, *rest), diff, has_aux=has_aux)
+        return (out[0], out[2]) if has_aux else out[0], out[1]
+
+    def call_bwd(pull, ct):
+        zero = lambda c: isinstance(c, SymbolicZero)  # noqa: E731
+        if has_aux:
+            ct, aux_ct = ct
+            if not all(map(zero, jax.tree.leaves(aux_ct, is_leaf=zero))):
+                raise TypeError(
+                    f"{getattr(fn, '__name__', fn)}'s aux was given a cotangent: under "
+                    "with_passes it comes from the forward pass and is not differentiated")
+        ct = jax.tree.map(lambda c: jax.numpy.zeros(c.shape, c.dtype) if zero(c) else c,
+                          ct, is_leaf=zero)
+        closed, shape = jax.make_jaxpr(lambda p, c: p(c), return_shape=True)(pull, ct)
+        flat = core.eval_jaxpr(marked(closed.jaxpr, "bwd", {}), closed.consts,
+                               *jax.tree.leaves((pull, ct)))
+        (grad,) = jax.tree.unflatten(jax.tree.structure(shape), flat)
+        return grad, None
+
+    call.defvjp(call_fwd, call_bwd, symbolic_zeros=True)
+    return functools.wraps(fn)(lambda diff, *rest: call(diff, rest))
 
 
 _enabled: bool | None = None  # read once per process: a span must stay cheap

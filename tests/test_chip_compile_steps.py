@@ -53,10 +53,11 @@ def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_withou
         chip, monkeypatch, preset, scopes):
     """A dense, the hybrid and two latent steps, every kernel module steered to the chip
     (this process's backend is the CPU): each Mosaic call's own text holds
-    ``rt_scope`` beside ``kernel_metadata``, as the op line prints it, the
-    chip's compiler keeps every call that was traced, and as many are traced
-    with ``device_scope`` switched off (that form is lowered for the chip and
-    not compiled a second time: the compiler dropped no call of the first)."""
+    ``rt_pass`` and ``rt_scope`` beside ``kernel_metadata``, as the op line
+    prints them, the chip's compiler keeps every call that was traced, and as
+    many are traced with ``device_scope`` and ``with_passes`` switched off
+    (that form is lowered for the chip and not compiled a second time: the
+    compiler dropped no call of the first)."""
     import contextlib
     import importlib
     import re
@@ -70,15 +71,29 @@ def test_a_step_compiles_with_every_kernel_under_its_scope_and_as_many_as_withou
     calls = [line for line in lowered.compile().as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert lowered.as_text().count("@tpu_custom_call") == len(calls)
-    paths = [re.search(r'frontend_attributes=\{kernel_metadata=\{\},rt_scope="([^"]*)"\}', line)
-             for line in calls]
-    assert calls and all(paths)
-    assert {m.group(1) for m in paths} == scopes
+    attrs = [re.search(r'frontend_attributes=\{kernel_metadata=\{\},rt_pass="(fwd|remat|bwd)",'
+                       r'rt_scope="([^"]*)"\}', line) for line in calls]
+    assert calls and all(attrs)
+    assert {m.group(2) for m in attrs} == scopes
+    # every kernel under a pass (``tracing.with_passes``): a call is ONE
+    # instruction here, under the pass of its ``pallas_call`` eqn, so the
+    # backward kernels read ``bwd`` and none of them ``fwd``
+    by_kernel = {}
+    for line, m in zip(calls, attrs):
+        kernel = re.sub(r"[.\d]+$", "", line.split(" = ")[0].strip().removeprefix("ROOT ").lstrip("%"))
+        by_kernel.setdefault(kernel, set()).add(m.group(1))
+    assert {m.group(1) for m in attrs} >= {"fwd", "bwd"}
+    assert all(passes == {"bwd"} for kernel, passes in by_kernel.items() if "_bwd" in kernel), (
+        by_kernel)
+    assert all("bwd" not in passes for kernel, passes in by_kernel.items()
+               if kernel.endswith("_fwd")), by_kernel
     for module in ("llama", "moe", "mla", "gdn"):
         monkeypatch.setattr(sys.modules[f"ray_tpu.models.{module}"], "device_scope",
                             lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(sys.modules["ray_tpu.models.llama"], "with_passes",
+                        lambda fn, has_aux=False: fn)
     jax.clear_caches()
     without = _tiny_step(chip, preset).as_text()
-    assert "rt_scope" not in without
+    assert "rt_scope" not in without and "rt_pass" not in without
     assert without.count("@tpu_custom_call") == len(calls)
     jax.clear_caches()

@@ -20,11 +20,12 @@ from ray_tpu.observability import tracing
 
 # ------------------------------------------- the scopes are text in the op
 def _stripped(text: str) -> str:
-    """Compiled HLO text without names, metadata and ``rt_scope``: what is
-    left is the program."""
+    """Compiled HLO text without names, metadata, ``rt_scope`` and ``rt_pass``
+    (``tests/test_device_passes.py``): what is left is the program."""
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
-    text = re.sub(r', frontend_attributes=\{rt_scope="[^"]*"\}', "", text)
-    text = re.sub(r'rt_scope="[^"]*",|,rt_scope="[^"]*"', "", text)  # beside another attribute
+    ours = r'rt_(scope|pass)="[^"]*"'
+    text = re.sub(rf", frontend_attributes=\{{{ours}(,{ours})?\}}", "", text)
+    text = re.sub(rf"{ours},|,{ours}", "", text)  # beside another attribute
     # the tables of files, functions and stack frames that metadata points into
     text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames)$|^\d+ .*$", "", text)
     text = re.sub(r"%[\w.\-]+", "%", text)
@@ -99,6 +100,8 @@ def _without_scopes(monkeypatch):
     for module in (ray_tpu.models.llama, ray_tpu.models.moe, ray_tpu.models.mla,
                    ray_tpu.models.gdn, ray_tpu.llm.model):
         monkeypatch.setattr(module, "device_scope", lambda name: contextlib.nullcontext())
+    # and no pass (``tracing.with_passes``): what is lowered is the plain program
+    monkeypatch.setattr(ray_tpu.models.llama, "with_passes", lambda fn, has_aux=False: fn)
     jax.clear_caches()
 
 
@@ -128,6 +131,75 @@ def _scoped_instructions(text):
         named = [part for part in re.split(r"[/()]", op_name.group(1)) if part in SCOPES] \
             if op_name else []
         yield m.group(1), m.group(2), scope and scope.group(1), named
+
+
+# ------------------------------------------------ and the pass beside it
+PASSES = ("fwd", "remat", "bwd")
+_ATTRS = re.compile(r'frontend_attributes=\{(.*)\}')
+# A Pallas kernel's body is left alone: its eqns keep what they were traced
+# under (a backward rule is traced under its CALL's metadata, ``fwd``). On a
+# chip the kernel is ONE custom call under the pass of its ``pallas_call`` eqn
+# (``tests/test_chip_compile_steps.py``); interpreted on a CPU the body's ops
+# are in the program, named ``<scope>/<kernel>/while/body/...``.
+_KERNEL_BODY = re.compile(
+    r"/(flash_|moe_t?gmm|moe_rows|gdn_|attn_win_|attn_sel_|dsa_index_|dsa_probs_)\w*/(while|cond)")
+
+
+def _attributes(text):
+    """(line, rt_pass or None, rt_scope or None, op_name or "") of every
+    instruction that carries a frontend attribute or an ``op_name``."""
+    for line in text.splitlines():
+        attrs = _ATTRS.search(line)
+        which = re.search(r'rt_pass="([^"]*)"', attrs.group(1)) if attrs else None
+        scope = re.search(r'rt_scope="([^"]*)"', attrs.group(1)) if attrs else None
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if attrs or op_name:
+            yield (line, which and which.group(1), scope and scope.group(1),
+                   op_name.group(1) if op_name else "")
+
+
+def _pass_of(op_name: str) -> str:
+    """What jax's own name stack says of an op's pass."""
+    if "rematted_computation" in op_name:
+        return "remat"
+    return "bwd" if "transpose(" in op_name else "fwd"
+
+
+def check_passes(text: str, remat: bool = True) -> None:
+    """Every op of a compiled step that a scope issued carries a pass, and the
+    pass is what jax's own ``op_name`` says of the op."""
+    rows = list(_attributes(text))
+    scoped = [r for r in rows if r[2]]
+    assert scoped and not [r[0][:200] for r in scoped if r[1] not in PASSES]
+    seen = collections.Counter(r[1] for r in rows if r[1])
+    assert seen["fwd"] and seen["bwd"]
+    assert bool(seen["remat"]) == remat, seen
+    # an instruction the compiler did not make (a product, a gather, a call)
+    # keeps its own op_name and its own attributes: the two agree
+    own = [r for r in rows if r[1] and r[3] and not _KERNEL_BODY.search(r[3]) and re.search(
+        r" (dot|convolution|custom-call|gather|scatter|sort)\(", r[0])]
+    assert len(own) > 10
+    assert not [(r[1], r[3]) for r in own if r[1] != _pass_of(r[3])], (
+        "outside an interpreted kernel's body (_KERNEL_BODY) a pass disagrees with op_name")
+    # the scan over the blocks, forward and transposed (a loop the compiler
+    # sank an op into carries that op's: the head loss's, on a CPU)
+    loops = {r[1] for r in rows if " while(" in r[0] and r[1] == _pass_of(r[3])
+             and (r[2] or "").startswith("stack")}
+    assert {"fwd", "bwd"} <= loops
+    # a fusion carries its root's: most agree with the op_name they kept
+    fusions = [r for r in rows if r[1] and r[3] and " fusion(" in r[0]
+               and not _KERNEL_BODY.search(r[3])]
+    assert sum(r[1] == _pass_of(r[3]) for r in fusions) >= 0.9 * len(fusions)
+
+
+
+
+@pytest.mark.parametrize("kind", ["hybrid", "sparse"])
+def test_every_scoped_op_carries_the_pass_its_op_name_says(step_texts, kind):
+    """``tracing.with_passes`` on the two heavy kinds' steps, compiled here
+    anyway; the light kinds, no remat and remat ``full``:
+    ``tests/test_device_passes.py``."""
+    check_passes(step_texts(kind))
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
@@ -188,7 +260,7 @@ def test_scopes_change_names_metadata_and_the_attribute_only(monkeypatch, step_t
         assert re.search(rf'rt_scope="([^"]*/)?{scope}(/[^"]*)?"', with_scopes), scope
     _without_scopes(monkeypatch)
     without = lower()
-    assert "rt_scope" not in without
+    assert "rt_scope" not in without and "rt_pass" not in without
     assert not re.search(r'op_name="[^"]*[/(](attn|mlp|decode_step)[/)]', without)
     assert _stripped(with_scopes) == _stripped(without)
     assert with_scopes.count("custom-call") == without.count("custom-call")

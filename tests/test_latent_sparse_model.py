@@ -19,7 +19,7 @@ import jaxpr_walk
 
 from benchmark.reference import latent_sparse_decoder as ref
 from ray_tpu.models import PRESETS, init_params, loss_fn, update_buffers
-from ray_tpu.models.llama import MIXERS, forward, train_flops_per_token
+from ray_tpu.models.llama import MIXERS, _loss, forward, train_flops_per_token
 from ray_tpu.models.mla import mla_mixer
 from ray_tpu.models.moe import moe_block
 
@@ -161,7 +161,10 @@ def program_step(params, rows):
     counted beside it, and the gradients of the loss's two parts apart (the
     model's terms; the indexer's), which add up to the loss's."""
     def parts(p):
-        loss, aux = loss_fn(p, {"tokens": rows}, CFG, chunk_tokens=16, return_aux=True)
+        # the loss's undecorated body: ``loss_fn``'s ``aux`` comes from the forward
+        # pass and takes no cotangent (``tests/test_device_passes.py`` holds the
+        # two to one program)
+        loss, aux = _loss(p, {"tokens": rows}, CFG, mesh=None, chunk_tokens=16)
         index = aux["index_loss"]
         return jnp.stack([loss - index, index]), (loss, aux)
 

@@ -272,8 +272,11 @@ def _embed_lines(text):
     body."""
     text = re.sub(r'("stablehlo\.scatter"[^\n]*)\n.*?\n\s*(\}\) [^\n]*)', r"\1 ... \2", text,
                   flags=re.DOTALL)
-    return [re.sub(r"%[\w#]+", "%", line).strip() for line in text.splitlines()
-            if 'rt_scope = "embed"' in line]
+    # ``rt_pass`` (PR 50) stands beside the scope and is left out, so the
+    # lines stay the text the parent of PR 47 lowered
+    return [re.sub(r'%[\w#]+|rt_pass = "\w+", ', lambda m: "%" if m.group().startswith("%") else "",
+                   line).strip()
+            for line in text.splitlines() if 'rt_scope = "embed"' in line]
 
 
 def _mesh(**axes):

@@ -439,6 +439,8 @@ def test_a_capture_whose_names_carry_no_scope_renders_no_scope_table(synthetic):
     assert [r[0] for r in s["scopes"]["path"]] == [""]
     assert s["scopes"]["path"][0][3] == pytest.approx(100.0)
     assert "by scope" not in profile.render(s)
+    # nor a pass (``tests/benchmark_suite/test_bm_passes.py`` reads one that does)
+    assert [r[:1] for r in s["passes"]["pass"]] == [[""]] and "by pass" not in profile.render(s)
 
 
 # ------------------------------------- one capture, on the worker asked for
