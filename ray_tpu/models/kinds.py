@@ -25,6 +25,13 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import flash_attention
 from ..ops.rope import yarn_frequencies
 
+# The one checkpoint name that is the stack's and not a kind's: the residual
+# stream as the mixer's output joins it (``models/llama.py::_block`` gives
+# it). A mixer kind lists it in ``save_names`` to keep the stream under remat
+# ``attn``, and the block's second run then makes neither the mixer's output
+# product nor the add (the latent kinds: models/mla.py).
+POST_ATTN = "post_attn"
+
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:

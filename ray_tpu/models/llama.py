@@ -67,7 +67,7 @@ from ..parallel.sharding import shard_constraint
 from .block_sparse import BLOCK_SPARSE, BlockSparseAttention
 from .gdn import GDN
 from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention
-from .kinds import LayerKind, Yarn, flash_per_shard, norm_over_heads
+from .kinds import POST_ATTN, LayerKind, Yarn, flash_per_shard, norm_over_heads
 from .lightning import LIGHTNING, LightningAttention
 from .mla import MLA, MLA_FULL, MLA_WINDOW, LatentAttention, LatentAttentionYarn
 from .moe import MOE, bias_step
@@ -601,7 +601,11 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
         mixed = MIXERS[mixer].apply(h, layer, config=c, positions=positions, mesh=mesh,
                                     **({"return_selection": True} if return_selection else {}))
         mixed, mixed_aux = mixed if isinstance(mixed, tuple) else (mixed, {})
-        x = x + sc(_scaled(mixed, c.residual_scale), ("batch", "seq", "embed_act"))
+        # a name and nothing else: a kind whose ``save_names`` lists it keeps
+        # the stream under remat ``attn`` and its second run makes no ``wo``
+        # product (the latent kinds, models/mla.py); unlisted, no program moves
+        x = checkpoint_name(
+            x + sc(_scaled(mixed, c.residual_scale), ("batch", "seq", "embed_act")), POST_ATTN)
 
     with device_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], eps=c.norm_eps, offset=c.norm_offset)
@@ -643,7 +647,15 @@ def _apply_remat(block, c: LlamaConfig, mixer: str = "attn", lead: bool = False)
         # them, the gates, the scan's output and z, ~0.54 GB a layer at 16k
         # tokens); the conv, the chunk-local operands and the chunk states
         # are made again.
-        # The names are the kinds' own: the block's mixer and its MLP.
+        # A latent-attention mixer (models/mla.py's SAVE_NAMES) saves the
+        # latents, not the per-head q, k, v made from them, the kernel's
+        # output and logsumexp, the gate, the key sets, and since PR 52
+        # ``kinds.POST_ATTN``, the stream as the mixer's output joins it:
+        # the second run needs the mixer's output for nothing else, and
+        # remaking it is ``wo``, 16,384 (128 heads) or 8,192 (64) FLOPs a
+        # saved byte against 1,024-2,048 for q, k, v.
+        # The names are the kinds' own, the block's mixer's and its MLP's,
+        # but for POST_ATTN, which ``_block`` gives and a mixer kind lists.
         return jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(

@@ -43,9 +43,33 @@ scores over them) and ``selected_share`` (keys attended over causal keys).
 ``SAVE_NAMES`` is what a backward pass reads and cannot cheaply remake: the
 latents and not the per-head q, k and v made from them (3,712 numbers a
 token against 65,536 in a full layer), the kernel's output and logsumexp,
-the gate, the key sets (int8, 67 MB a row of 8k) and the two statistics a query
+the gate, the key sets (int8, 67 MB a row of 8k), the two statistics a query
 that the indexer's loss hands its backward kernel (``dsa_kl_z``, ``dsa_kl_lse``,
-named inside ``index_kl``'s rule: saved, the loss's forward kernel runs once).
+named inside ``index_kl``'s rule: saved, the loss's forward kernel runs once)
+and ``kinds.POST_ATTN``, the residual stream as the mixer's output joins it
+(the stack's name: ``models/llama.py::_block`` gives it in every kind's
+block, and a kind that does not list it saves nothing by it). No gradient
+reads the mixer's output:
+the block's second run needs it only to remake that stream for the MLP's
+norm, and making it again is ``wo``, the layer's widest product. By FLOPs of
+second run a saved byte the stream is the cheapest thing in the block to keep:
+heads x v_dim = 16,384 (128 heads) or 8,192 (64) for a byte of ``[B, S,
+hidden]`` bf16, against 2 x rank = 1,024-2,048 for q, k and v, which stay
+remade. The SUM and not the mixer's output: the chip's compiler adds ``wo``'s
+float32 product to the stream inside one fusion and rounds once; a saved bf16
+output is rounded before the add, in the forward pass too, and the sparse
+cell's step then stands 1.6 times as far from its float32 reference (PERF.md,
+PR 52). Saved, the stream is rounded where it is made (``jax.checkpoint`` puts
+a ``reduce_precision`` on a residual's producer) and the MLP norm's mean square
+reads the rounded values, where a program that is not differentiated reads the
+float32 sum: the step and a forward-only program on the same batch then choose
+the next indexed layer's keys alike to 0.9960 and not 0.99991 (PERF.md, PR 52:
+what a comparison that hands a reference the second program's sets sees).
+``mla`` and ``mla_win`` save it (the sparse cell's step, 2 x 8192:
+11.72 -> 11.96 GB by the chip compiler's count). ``mla_full`` does not (it
+keeps ``LATENT_NAMES``, the mixer's own): Kimi-K2's step at 4,096 tokens
+stands at 16.27 GB of the chip's 16.91 and reads 16.80 GB with five more
+arrays of 59 MB kept, past the 16.60e9 bytes ISSUE 52 set before the count.
 """
 
 from __future__ import annotations
@@ -60,10 +84,11 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope, flash_attention, rms_norm
 from ..ops.sparse_index import index_kl, index_scores, select_top_k
-from .kinds import LayerKind, Yarn, sigmoid_gate, kept_keys, rope_keywords
+from .kinds import POST_ATTN, LayerKind, Yarn, sigmoid_gate, kept_keys, rope_keywords
 
-SAVE_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate", "dsa_mask",
-              "dsa_kl_z", "dsa_kl_lse")
+LATENT_NAMES = ("mla_cq", "mla_ckv", "mla_kr", "attn_out", "attn_lse", "attn_gate",
+                "dsa_mask", "dsa_kl_z", "dsa_kl_lse")  # what ``mla_mixer`` itself names
+SAVE_NAMES = LATENT_NAMES + (POST_ATTN,)  # and the stream, where the step has the room
 INDEX_NORM_EPS = 1e-6  # the index key's LayerNorm
 
 
@@ -269,7 +294,7 @@ def _mixing_flops(a: LatentAttention, c, seq: int) -> float:
     return attention + index
 
 
-def _kind(field: str) -> LayerKind:
+def _kind(field: str, save_names: tuple) -> LayerKind:
     spec = lambda c: getattr(c, field)  # noqa: E731
     return LayerKind(
         axes=lambda c: _axes(spec(c)),
@@ -277,13 +302,13 @@ def _kind(field: str) -> LayerKind:
         apply=lambda h, layer, **kw: mla_mixer(h, layer, spec(kw["config"]), **kw),
         matmul_params=lambda c: _matmul_params(spec(c), c),
         mixing_flops=lambda c, seq: _mixing_flops(spec(c), c, seq),
-        save_names=SAVE_NAMES)
+        save_names=save_names)
 
 
-MLA = _kind("mla")
-MLA_WINDOW = _kind("mla_window")
-MLA_FULL = _kind("mla_full")
+MLA = _kind("mla", SAVE_NAMES)
+MLA_WINDOW = _kind("mla_window", SAVE_NAMES)
+MLA_FULL = _kind("mla_full", LATENT_NAMES)  # Kimi-K2's step: 16.80 GB of 16.91 with the stream
 
 __all__ = ["LatentAttention", "LatentAttentionYarn", "MLA", "MLA_WINDOW", "MLA_FULL",
-           "SAVE_NAMES", "mla_mixer",
+           "SAVE_NAMES", "LATENT_NAMES", "mla_mixer",
            "index_inputs", "kept_keys"]

@@ -13,3 +13,11 @@ def equations(jaxpr):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
                     yield from equations(sub)
+
+
+def products(equations, lhs, rhs):
+    """The ``dot_general`` equations among ``equations`` whose two operands
+    have the shapes ``lhs`` and ``rhs``."""
+    want = (tuple(lhs), tuple(rhs))
+    return [e for e in equations if e.primitive.name == "dot_general"
+            and tuple(tuple(v.aval.shape) for v in e.invars) == want]

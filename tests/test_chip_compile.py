@@ -414,6 +414,55 @@ def test_saved_residual_names_decide_the_forward_kernel_count(chip, names, kerne
     assert program.count('custom_call_target="tpu_custom_call"') == kernels
 
 
+@pytest.mark.slow  # two whole-step compiles at the cell's size: 4-5 minutes
+def test_saving_the_stream_costs_the_sparse_step_no_more_than_its_bytes(chip, monkeypatch):
+    """``dots3-note-prev.train-8k-sparse``'s step (loss and gradient at 2 x
+    8192 under remat ``attn``, every kernel module steered to the chip): with
+    ``post_attn`` among the latent kinds' saved names the compiler's count
+    rises, and by no more than TWO of the five layers' streams ([2, 8192,
+    5120] bf16 = 167,772,160 bytes each) over the tuple without it. PR 52 read
+    +193,583,104 here (the runner's whole step with its optimizer +235,457,536:
+    the scanned layers' streams join the stacked residuals, which are not all
+    live at the peak); ISSUE 52's own bound was all five, 838,860,800. Not in
+    tier 1: two compiles of 70-85 s, and no smaller step shows it (one layer
+    at 1 x 2048 and the debug preset read -0.5 to +0.8 MB, the scheduler's
+    noise; CHANGES.md, PR 52)."""
+    import dataclasses
+    import importlib
+    import json
+    import sys
+
+    from benchmark.manifest import HERE
+    from benchmark.runners import train_sparse
+    from ray_tpu.models.llama import MIXERS
+    from ray_tpu.models.mla import LATENT_NAMES, SAVE_NAMES
+    from test_chip_compile_steps import KERNEL_MODULES, lowered_step
+
+    for name in KERNEL_MODULES:
+        importlib.import_module(name)
+        monkeypatch.setattr(sys.modules[name], "on_tpu", lambda: True)
+    with open(os.path.join(HERE, "configs", "dots3-note-prev.json")) as f:
+        conf = json.load(f)
+    cfg = train_sparse.model_config(conf["model"], conf["train"], remat_policy="attn")
+    rows, seq = conf["train"]["batch"], 8192
+
+    def total():
+        jax.clear_caches()
+        m = lowered_step(chip, cfg, rows, seq, conf["train"]["loss_chunk_tokens"]
+                         ).compile().memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+                - m.alias_size_in_bytes)
+
+    saved = total()
+    for kind in ("mla", "mla_win"):
+        assert MIXERS[kind].save_names == SAVE_NAMES
+        monkeypatch.setitem(MIXERS, kind,
+                            dataclasses.replace(MIXERS[kind], save_names=LATENT_NAMES))
+    remade = total()
+    jax.clear_caches()
+    assert 0 < saved - remade <= 2 * rows * seq * cfg.hidden * 2, (saved, remade)
+
+
 def _without_locations(lowered_text: str) -> str:
     """A lowered program's text with every Mosaic call's bytecode replaced by
     its module's assembly WITHOUT debug info: the bytecode holds source lines,

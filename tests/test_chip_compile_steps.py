@@ -19,20 +19,26 @@ KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_m
                   "ray_tpu.ops.lightning_attention")
 
 
-def _tiny_step(chip, preset):
-    """A debug preset's whole step (loss and gradient under remat ``attn``),
-    lowered for the described chip: ``.as_text()`` is what was traced,
+def lowered_step(chip, cfg, rows=2, seq=256, chunk=128):
+    """A configuration's whole step (loss and gradient), lowered for the
+    described chip: ``.as_text()`` is what was traced,
     ``.compile().as_text()`` the chip's optimized program."""
-    import dataclasses
+    from ray_tpu.models.llama import init_params, loss_fn
 
-    from ray_tpu.models.llama import PRESETS, init_params, loss_fn
-
-    cfg = dataclasses.replace(PRESETS[preset], remat_policy="attn")
     on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)  # noqa: E731
     params = jax.tree.map(on, jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=chip)}
-    return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=128))
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=chip)}
+    return jax.jit(jax.grad(lambda p, b: loss_fn(p, b, cfg, chunk_tokens=chunk))
                    ).lower(params, batch)
+
+
+def _tiny_step(chip, preset):
+    """A debug preset's whole step under remat ``attn``."""
+    import dataclasses
+
+    from ray_tpu.models.llama import PRESETS
+
+    return lowered_step(chip, dataclasses.replace(PRESETS[preset], remat_policy="attn"))
 
 
 @pytest.mark.parametrize("preset,scopes", [

@@ -19,8 +19,9 @@ import jaxpr_walk
 
 from benchmark.reference import latent_sparse_decoder as ref
 from ray_tpu.models import PRESETS, init_params, loss_fn, update_buffers
+from ray_tpu.models.kinds import POST_ATTN
 from ray_tpu.models.llama import MIXERS, _loss, forward, train_flops_per_token
-from ray_tpu.models.mla import mla_mixer
+from ray_tpu.models.mla import LATENT_NAMES, SAVE_NAMES, mla_mixer
 from ray_tpu.models.moe import moe_block
 
 CFG = dataclasses.replace(PRESETS["latent-sparse-debug"], dtype=jnp.float32,
@@ -65,17 +66,24 @@ def rel(got, want):
 
 
 
+def step_equations(params):
+    """Every equation of the differentiated, remat-ed stack (a leading full
+    layer and a scanned period of a full and three window ones) on 1 x 48
+    tokens."""
+    assert CFG.remat_policy == "attn"
+    tokens = jnp.zeros((1, 48), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16)))(params)
+    return list(jaxpr_walk.equations(jaxpr.jaxpr))
+
+
 def test_the_indexers_loss_is_one_kernel_each_way_and_none_again_under_remat(params):
     """The differentiated, remat-ed stack (a leading full layer and a scanned
     one): the forward kernel once a full layer, the backward kernel once, no
     second run of the forward under remat (its statistics are saved by
     name), and the KL's exponentials and logarithms nowhere in XLA."""
     assert {"dsa_kl_z", "dsa_kl_lse"} <= set(MIXERS["mla"].save_names)
-    assert CFG.remat_policy == "attn"
-    tokens = jnp.zeros((1, 48), jnp.int32)
-    jaxpr = jax.make_jaxpr(jax.grad(
-        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16)))(params)
-    equations = list(jaxpr_walk.equations(jaxpr.jaxpr))
+    equations = step_equations(params)
     kernels = [str(e.params["name"]) for e in equations if e.primitive.name == "pallas_call"]
     assert kernels.count("dsa_probs") == 2 and kernels.count("dsa_probs_bwd") == 2
     # what else an indexed layer runs, for scale: the scores again under remat
@@ -85,6 +93,33 @@ def test_the_indexers_loss_is_one_kernel_each_way_and_none_again_under_remat(par
     square = [e.primitive.name for e in equations
               if any(getattr(v.aval, "shape", None) == (1, 48, 48) for v in e.invars)]
     assert square and not {"exp", "log", "exp2", "log1p", "logistic"} & set(square), square
+
+
+def test_the_output_product_runs_once_a_layer_body_under_remat(params, monkeypatch):
+    """The same stack: ``wo``'s forward-shaped product ([1, H, 48, 16] x
+    [H, 16, 64]: 4 heads in a full layer, 2 in a window one) once in each of
+    the five layer bodies (the leading layer's and the period's four): the
+    stream it joins is a saved name (``post_attn``); with the name out of the
+    kinds' ``save_names`` it runs again in every body, and those five are all
+    the products there are more of."""
+    full, window = CFG.mla, CFG.mla_window
+
+    def wo(equations, a):
+        return len(jaxpr_walk.products(equations, (1, a.heads, 48, a.v_dim),
+                                       (a.heads, a.v_dim, CFG.hidden)))
+
+    def dots(equations):
+        return sum(e.primitive.name == "dot_general" for e in equations)
+
+    saved = step_equations(params)
+    assert [e.params["name"] for e in saved if e.primitive.name == "name"].count(POST_ATTN) == 5
+    assert (wo(saved, full), wo(saved, window), dots(saved)) == (2, 3, 207)
+    for kind in ("mla", "mla_win"):
+        assert MIXERS[kind].save_names == SAVE_NAMES == LATENT_NAMES + (POST_ATTN,)
+        monkeypatch.setitem(MIXERS, kind,
+                            dataclasses.replace(MIXERS[kind], save_names=LATENT_NAMES))
+    again = step_equations(params)
+    assert (wo(again, full), wo(again, window), dots(again)) == (4, 6, 212)
 
 
 @pytest.mark.parametrize("kind", ["mla", "mla_win"])
