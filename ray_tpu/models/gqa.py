@@ -28,6 +28,9 @@ heads of D features (head h reads kv head ``h // (H / KV)``):
                                               (``rope_theta`` 0: none, q and
                                                k go to the scores as projected)
     o_h = softmax over the allowed keys of (q_h . k_j D^-1/2) v_j
+                                             (``softmax_scale`` set: that
+                                              constant in D^-1/2's place;
+                                              Granite's 1/64 at heads of 64)
     y   = concat_h(sigmoid(x W_g)_h o_h) W_o          (``gate`` "headwise")
 
 Allowed keys of query t: s <= t, and with ``window`` t - s < window. Rope
@@ -70,12 +73,26 @@ class GroupedQueryAttention:
     yarn: Yarn | None = None
     window: int = 0           # 0: none. Else query t sees keys t - window + 1 .. t
     gate: str = "none"        # "none" | "headwise"
+    # what a spec with a published softmax constant states
+    # (``ScaledGroupedQueryAttention``'s field); here a fact of the class:
+    # the scores are scaled by head_dim ** -0.5
+    softmax_scale = None
 
     def __post_init__(self):
         if self.gate not in ("none", "headwise"):
             raise ValueError(f"gate is {self.gate!r}: 'none' or 'headwise'")
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} query heads over {self.kv_heads} kv heads")
+
+
+@dataclasses.dataclass(frozen=True)
+class ScaledGroupedQueryAttention(GroupedQueryAttention):
+    """The widths of a grouped-query attention layer whose softmax scale is a
+    published constant (Granite's ``attention_multiplier``: 1/64 at heads of
+    64, not 64^-1/2); a spec of its own: the benchmark's accepted tests hold
+    ``GroupedQueryAttention`` to its eight fields."""
+
+    softmax_scale: float | None = None   # None: head_dim ** -0.5
 
 
 def _axes(a: GroupedQueryAttention) -> dict:
@@ -189,14 +206,16 @@ def gqa_mixer(h, layer, a: GroupedQueryAttention, *, config, positions, mesh=Non
         k = checkpoint_name(_rope(k, positions, a), "k")
         v = checkpoint_name(v, "v")
         aux = {}
+        # a published constant in D^-1/2's place, where the spec states one
+        scale = {} if a.softmax_scale is None else {"sm_scale": a.softmax_scale}
         if a.window:
             block_q, block_k = window_blocks(a.window, a.heads // a.kv_heads)
             attn = flash_per_shard(q, k, v, mesh, causal=True, window=a.window,
-                                   block_q=block_q, block_k=block_k)
+                                   block_q=block_q, block_k=block_k, **scale)
             kept = jnp.sum(jnp.minimum(positions.astype(jnp.float32) + 1.0, a.window))
             aux["window_share"] = kept / (positions.size / s) / (s * (s + 1) / 2)
         else:
-            attn = flash_per_shard(q, k, v, mesh, causal=True)
+            attn = flash_per_shard(q, k, v, mesh, causal=True, **scale)
         if a.gate == "headwise":
             with device_scope("attn_gate"):
                 attn = sigmoid_gate(h, layer["w_attn_gate"], attn)
@@ -228,5 +247,5 @@ def _kind(field: str) -> LayerKind:
 GQA = _kind("gqa")
 GQA_WINDOW = _kind("gqa_window")
 
-__all__ = ["GroupedQueryAttention", "Yarn", "GQA", "GQA_WINDOW", "SAVE_NAMES", "gqa_mixer",
+__all__ = ["GroupedQueryAttention", "ScaledGroupedQueryAttention", "Yarn", "GQA", "GQA_WINDOW", "SAVE_NAMES", "gqa_mixer",
            "window_blocks"]

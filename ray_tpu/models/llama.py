@@ -11,7 +11,8 @@ causal key, ``models/mla.py``;
 ``gqa`` / ``gqa_win``: grouped-query attention whose widths a spec owns,
 whole or under a window, ``models/gqa.py``; ``lightning``: linear attention
 under a constant decay, ``models/lightning.py``; ``block_sparse``: grouped
-queries over key blocks the layer selects, ``models/block_sparse.py``)
+queries over key blocks the layer selects, ``models/block_sparse.py``;
+``mamba2``: a selective state-space layer, ``models/mamba2.py``)
 and an MLP (``dense``, below; ``moe``: the routed experts,
 ``models/moe.py``) are ``LayerKind``s (``models/kinds.py``) that own their
 leaves, logical axes, init, FLOPs, counters and the names a remat policy
@@ -28,7 +29,9 @@ SmallThinker a period of one un-roped full layer and three roped 4,096-key
 window layers, no leading layer, ReGLU experts whose router reads the block's
 input; MiniCPM-SALA a period of one block-selected layer and three of lightning
 attention, under fixed multipliers on the embedding, the residual branches and
-the head's input.
+the head's input; Granite-4.0-H a period of TEN, nine Mamba-2 state-space
+layers around one un-roped attention layer whose softmax scale is a constant,
+the embedding tied to the head, under the same three multipliers.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -66,9 +69,10 @@ from ..ops.moe_rows import take_rows
 from ..parallel.sharding import shard_constraint
 from .block_sparse import BLOCK_SPARSE, BlockSparseAttention
 from .gdn import GDN
-from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention
+from .gqa import GQA, GQA_WINDOW, GroupedQueryAttention, ScaledGroupedQueryAttention
 from .kinds import POST_ATTN, LayerKind, Yarn, flash_per_shard, norm_over_heads
 from .lightning import LIGHTNING, LightningAttention
+from .mamba2 import MAMBA2, Mamba2
 from .mla import MLA, MLA_FULL, MLA_WINDOW, LatentAttention, LatentAttentionYarn
 from .moe import MOE, bias_step
 
@@ -192,6 +196,12 @@ class LlamaConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # Mamba-2 state-space layers (models/mamba2.py): the widths of the mixer
+    # kind "mamba2". ``tie_embeddings``: the head contracts the embedding's own
+    # rows (``tie_word_embeddings``): no ``lm_head`` leaf, and the table's
+    # gradient is the sum of the row gather's and the head's.
+    mamba2: Mamba2 | None = None
+    tie_embeddings: bool = False
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
 
@@ -330,6 +340,19 @@ PRESETS: dict[str, LlamaConfig] = {
         lightning=LightningAttention(heads=4, head_dim=16, rope_theta=1e4, depth=6),
         layer_ids=(0, 1, 3, 4), embed_scale=12.0, residual_scale=1.4 / math.sqrt(6),
         logit_scale=0.25),
+    # Mamba-2 layers beside un-roped grouped-query attention at test size: ONE
+    # period of ten, five state-space layers, an attention layer and four more;
+    # 4 heads of 32 features (four a lane tile) over a state of 16 in chunks of
+    # 16, a conv of 4 taps; 4 query heads over 2 kv heads of 16 whose softmax
+    # scale is a constant (1/8, not 16^-1/2); the embedding tied to the head;
+    # the three multipliers all away from 1
+    "granite-hybrid-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=10, n_heads=4, n_kv_heads=2, intermediate=128,
+        head_dim=16, layer_pattern=("mamba2",) * 5 + ("gqa",) + ("mamba2",) * 4,
+        mamba2=Mamba2(heads=4, head_dim=32, state=16, chunk=16),
+        gqa=ScaledGroupedQueryAttention(heads=4, kv_heads=2, head_dim=16, rope_theta=0.0,
+                                        softmax_scale=0.125),
+        tie_embeddings=True, embed_scale=12.0, residual_scale=0.22, logit_scale=0.125),
 }
 
 
@@ -414,7 +437,7 @@ def param_axes(config: LlamaConfig):
         **_lead_layers(c, lambda i, mixer: block(i, mixer, lead=True)),
         "layers": _per_position(c, block),
         "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
+        **({} if c.tie_embeddings else {"lm_head": ("embed", "vocab")}),
     }
 
 
@@ -457,8 +480,14 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
         **_lead_layers(c, lambda i, mixer: block(i, mixer, leading=True)),
         "layers": _per_position(c, block),
         "final_norm": norm_fill((E,), c.dtype),
-        "lm_head": norm_init(keys[8], (E, c.vocab_size), E),
+        **({} if c.tie_embeddings else {"lm_head": norm_init(keys[8], (E, c.vocab_size), E)}),
     }
+
+
+def head_weights(params, config: LlamaConfig):
+    """The head's matrix [E, V]: the ``lm_head`` leaf, or under
+    ``tie_embeddings`` the embedding's own rows, transposed."""
+    return params["embed"].T if config.tie_embeddings else params["lm_head"]
 
 
 def _attention(q, k, v, config: LlamaConfig, mesh: Mesh | None):
@@ -560,7 +589,8 @@ LEAD_DENSE = LayerKind(
     apply=_dense_mlp, matmul_params=lambda c: 3.0 * c.hidden * c.lead_intermediate)
 MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW,
                                 "mla_full": MLA_FULL, "gqa": GQA, "gqa_win": GQA_WINDOW,
-                                "lightning": LIGHTNING, "block_sparse": BLOCK_SPARSE}
+                                "lightning": LIGHTNING, "block_sparse": BLOCK_SPARSE,
+                                "mamba2": MAMBA2}
 
 
 def _scaled(t, scale: float):
@@ -687,7 +717,9 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     training step does not ask). A block-selected mixer (``block_sparse``) adds
     ``attn_block_kept_share``, ``attn_block_forced_share`` and
     ``attn_block_tile_share``, each the mean over those layers, and with
-    ``return_selection`` its sets [those layers, B, KV, S, S / block]."""
+    ``return_selection`` its sets [those layers, B, KV, S, S / block]. A
+    state-space mixer (``mamba2``) adds ``ssm_decay_mean``, the mean of its
+    decays ``exp(dt A)`` over those layers, heads and positions."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -817,6 +849,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     if any("index_loss" in m for m in counted):
         aux.update(index_loss=both("index_loss"),
                    attn_selected_share=both("selected_share"))
+    if any("decay_mean" in m for m in counted):
+        aux["ssm_decay_mean"] = both("decay_mean")
     if any("block_kept_share" in m for m in counted):
         aux.update({f"attn_{name}": both(name) for name in (
             "block_kept_share", "block_forced_share", "block_tile_share")})
@@ -837,7 +871,7 @@ def forward(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = None):
     """tokens [B, S] int32 -> logits [B, S, vocab] f32. For inference/tests;
     training uses ``loss_fn`` which never materializes full logits."""
     x = forward_hidden(params, tokens, config, mesh=mesh)
-    logits = jnp.einsum("bse,ev->bsv", x, params["lm_head"])
+    logits = jnp.einsum("bse,ev->bsv", x, head_weights(params, config))
     return logits.astype(jnp.float32)
 
 
@@ -984,7 +1018,7 @@ def _loss(params, batch, config: LlamaConfig, *, mesh: Mesh | None, chunk_tokens
             n += pad
         nc = n // chunk
         ce = _head_loss(
-            mesh, flat_h.reshape(nc, chunk, e), params["lm_head"],
+            mesh, flat_h.reshape(nc, chunk, e), head_weights(params, config),
             flat_t.reshape(nc, chunk), flat_m.reshape(nc, chunk),
             jnp.maximum(flat_m.sum(), 1.0))
     loss = ce

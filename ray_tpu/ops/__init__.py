@@ -1,8 +1,9 @@
 """TPU compute ops: Pallas kernels and the JAX ops the models are built on.
 
 The hot paths (attention, the experts' grouped matmul and the sum of a held
-range's rows into their tokens, the gated delta rule's and lightning attention's
-scans over chunks) are Pallas TPU kernels; everything elementwise is left to XLA fusion. Sequence/context parallelism (ring attention) is
+range's rows into their tokens, the gated delta rule's, lightning attention's
+and the Mamba-2 state-space layers' (``ssd.py``) scans over chunks) are Pallas
+TPU kernels; everything elementwise is left to XLA fusion. Sequence/context parallelism (ring attention) is
 green-field — the reference has none (SURVEY.md §5.7).
 """
 

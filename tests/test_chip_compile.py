@@ -23,6 +23,7 @@ from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.lightning_attention import lightning_attention
+from ray_tpu.ops.ssd import ssd
 from ray_tpu.models.gqa import window_blocks
 from ray_tpu.ops.moe_rows import sum_rows
 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -92,6 +93,21 @@ def _lightning(chip, backward, b=1, h=32, t=16384, d=128):
     fn = jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)
                   ) if backward else fwd
     return jax.jit(fn).lower(x, x, x, decay)
+
+
+def _ssd(chip, backward, b=1, h=64, t=32768, p=64, n=128, chunk=256):
+    """Granite-4.0-H's state-space layers at one row of 32,768: 64 heads of 64
+    features, two a lane tile, over a state of 128 in chunks of 256; bf16 x, B
+    and C, float32 steps; backward keeps a group of 8 heads' 128 chunk states in
+    VMEM (32 MB of scratch)."""
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731
+    f32 = jnp.float32
+    args = (sd((b, h * p // 128, t, 128)), sd((b, h, t), f32), sd((h,), f32),
+            sd((b, t, n)), sd((b, t, n)), sd((h,), f32))
+    fwd = lambda *a: ssd(*a, chunk=chunk, interpret=False)  # noqa: E731
+    fn = jax.grad(lambda *a: fwd(*a).astype(f32).sum(), argnums=tuple(range(6))
+                  ) if backward else fwd
+    return jax.jit(fn).lower(*args)
 
 
 def _block_sets(chip, backward, b=1, hq=32, hkv=2, t=16384, d=128, block=64):
@@ -377,6 +393,15 @@ CASES = {
     "attn-blk-fwd-32to2-16k": lambda c: _block_sets(c, backward=False),
     "attn-blk-bwd-32to2-16k": lambda c: _block_sets(c, backward=True),
     "embed-rows-sala": lambda c: _rows(c, 18362, 16384, 4096),
+    # granite-4.0-h-micro at one row of 32,768: the scan's kernels at 64 heads
+    # of 64 (half a lane tile), the plain attention kernels at 32 : 8 heads of
+    # 64 (the first head under 128 through them on the chip) and the tied
+    # table's gradient at 25,088 rows and 32,768 ids a call
+    "ssd-fwd-64h-32k": lambda c: _ssd(c, backward=False),
+    "ssd-bwd-64h-32k": lambda c: _ssd(c, backward=True),
+    "flash-fwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=False),
+    "flash-bwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=True),
+    "embed-rows-granite": lambda c: _rows(c, 25088, 32768, 2048),
 }
 
 
