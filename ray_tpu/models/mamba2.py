@@ -26,7 +26,16 @@ float32 whatever the model's type: a step of 0.001 is under bfloat16's spacing
 at ``log 64``.
 
 Named scopes ``mamba_proj``, ``mamba_conv``, ``mamba_scan``, ``mamba_out`` split a
-layer on the trace; the conv and the gated norm are plain XLA under theirs.
+layer on the trace. Under ``mamba_conv`` the conv with its bias and SiLU, over x
+and over B | C, runs on the chip as ``ops/mamba_elementwise.py``'s kernel pair,
+every float32 intermediate in VMEM, exactly when the code can see it fits
+(``on_tpu()`` and that module's ``fits``: a last axis of one lane tile, rows in
+whole units of 64, four taps); on every other backend and at every other
+width ``causal_conv`` below is the mixer, the same mathematics at the same
+precisions. The pair adds no saved name: its residuals are its inputs and
+its outputs are ``mamba_x`` / ``mamba_bc``. The gated norm is plain XLA under
+``mamba_out`` everywhere: the chip's compiler fuses it into the products
+beside it, and a kernel pair for it slowed the step (PERF.md, PR 54).
 ``SAVE_NAMES`` is what the backward pass reads: x, B and C as the scan takes
 them, dt, the scan's output and z; the chunk states are made again in VMEM.
 The mixer counts ``decay_mean`` beside its output, the mean of ``exp(dt A)``
@@ -43,7 +52,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.tracing import device_scope
+from ..ops import mamba_elementwise
 from ..ops.ssd import heads_a_tile, ssd
+from ..ops.trace_log import note_kernel_trace
+from ..tpu import on_tpu
 from .kinds import LayerKind
 
 SAVE_NAMES = ("mamba_x", "mamba_bc", "mamba_dt", "mamba_y", "mamba_z")
@@ -126,6 +138,17 @@ def gated_norm(y, z, weight, eps):
             * weight.astype(jnp.float32).reshape(1, c, 1, w)).astype(y.dtype)
 
 
+def _conv_silu(x, taps, bias, dtype):
+    """``silu(causal_conv(x) + bias)`` rounded once to ``dtype``: on the chip,
+    at shapes ``mamba_elementwise.fits`` takes, as its kernel pair; the plain
+    functions on every other backend and at every other width."""
+    if (on_tpu() and x.dtype == dtype
+            and mamba_elementwise.fits(x.shape[3], x.shape[2], taps.shape[0])):
+        return mamba_elementwise.conv_silu(x, taps, bias)
+    note_kernel_trace("mamba_conv", "jnp")
+    return jax.nn.silu(causal_conv(x, taps, bias)).astype(dtype)
+
+
 def mamba2_mixer(h, layer, *, config, positions, mesh=None, scan=None,
                  return_selection: bool = False):
     """h [B, S, E] (normed) -> (y [B, S, E], {"decay_mean"}). ``scan`` swaps the
@@ -147,10 +170,11 @@ def mamba2_mixer(h, layer, *, config, positions, mesh=None, scan=None,
         dt = jnp.einsum("bse,eh->bhs", h, layer["w_dt"], preferred_element_type=jnp.float32)
         z = checkpoint_name(z, "mamba_z")
     with device_scope("mamba_conv"):
-        x = jax.nn.silu(causal_conv(x, lanes(layer["conv_x"]), lanes(layer["conv_x_bias"])))
-        bc = jax.nn.silu(causal_conv(bc, layer["conv_bc"], layer["conv_bc_bias"]))
-        x = checkpoint_name(x.astype(c.dtype), "mamba_x")
-        bc = checkpoint_name(bc.astype(c.dtype), "mamba_bc")
+        # x last: a kernel's recorded cost is its latest trace's
+        bc = _conv_silu(bc, layer["conv_bc"], layer["conv_bc_bias"], c.dtype)
+        x = _conv_silu(x, lanes(layer["conv_x"]), lanes(layer["conv_x_bias"]), c.dtype)
+        x = checkpoint_name(x, "mamba_x")
+        bc = checkpoint_name(bc, "mamba_bc")
         dt = checkpoint_name(jax.nn.softplus(dt + layer["dt_bias"][:, None]), "mamba_dt")
     with device_scope("mamba_scan"):
         rate = -jnp.exp(layer["a_log"].astype(jnp.float32))

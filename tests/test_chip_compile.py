@@ -19,7 +19,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops import gated_delta, gdn_elementwise, sparse_index
+from ray_tpu.ops import gated_delta, gdn_elementwise, mamba_elementwise, sparse_index
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.lightning_attention import lightning_attention
@@ -234,6 +234,19 @@ def _gdn_elementwise(chip, what, backward, b=2, t=8192, kh=16, vh=32, d=128):
     return jax.jit(lambda ct, *a: jax.vjp(fwd, *a)[1](ct)).lower(cotangent, *args)
 
 
+def _mamba_conv(chip, backward, b=1, c=32, t=32768):
+    """The Mamba-2 mixer's conv kernels at Granite-4.0-H's one row of 32,768:
+    over x ``[1, 32, 32768, 128]`` as the projection leaves it (two heads of 64
+    a lane tile) with its four taps and bias as the leaves lie; backward alone
+    (the pull-back needs no forward call: the residuals are the inputs)."""
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)  # noqa: E731
+    args = (sd((b, c, t, 128)), sd((4, c, 128)), sd((c, 128)))
+    fwd = lambda *a: mamba_elementwise.conv_silu(*a, interpret=False)  # noqa: E731
+    if not backward:
+        return jax.jit(fwd).lower(*args)
+    return jax.jit(lambda ct, *a: jax.vjp(fwd, *a)[1](ct)).lower(args[0], *args)
+
+
 def _latent(chip, kind, backward, b=2, t=8192):
     """dots3-note-prev's attention at the benchmark's 2 x 8192: ``sel`` the
     full layers' (128 heads, a 192-wide key head and a 128-wide value head,
@@ -399,6 +412,12 @@ CASES = {
     # table's gradient at 25,088 rows and 32,768 ids a call
     "ssd-fwd-64h-32k": lambda c: _ssd(c, backward=False),
     "ssd-bwd-64h-32k": lambda c: _ssd(c, backward=True),
+    # its conv over x as the projection leaves it, and over B | C (one lane
+    # tile a state of 128: two channel tiles)
+    "mamba-conv-fwd-32k": lambda c: _mamba_conv(c, backward=False),
+    "mamba-conv-bwd-32k": lambda c: _mamba_conv(c, backward=True),
+    "mamba-conv-fwd-bc-32k": lambda c: _mamba_conv(c, backward=False, c=2),
+    "mamba-conv-bwd-bc-32k": lambda c: _mamba_conv(c, backward=True, c=2),
     "flash-fwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=False),
     "flash-bwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=True),
     "embed-rows-granite": lambda c: _rows(c, 25088, 32768, 2048),
@@ -411,7 +430,7 @@ def test_kernel_compiles_for_the_chip(chip, case):
     assert "tpu_custom_call" in program
     if case.startswith("dsa-probs"):  # the loss's forward kernel; with its gradient, both
         assert program.count('custom_call_target="tpu_custom_call"') == 1 + ("bwd" in case)
-    if case.startswith(("gdn-conv", "gdn-norm")):  # one kernel each way
+    if case.startswith(("gdn-conv", "gdn-norm", "mamba-conv")):  # one kernel each way
         assert program.count('custom_call_target="tpu_custom_call"') == 1
     if case.startswith("moe-gmm") and case.endswith("-grad"):  # forward, d_lhs, d_rhs
         assert program.count('custom_call_target="tpu_custom_call"') == 3
@@ -437,6 +456,42 @@ def test_saved_residual_names_decide_the_forward_kernel_count(chip, names, kerne
 
     program = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
     assert program.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+def test_a_mamba2_layer_calls_each_conv_forward_once_and_none_under_remat(chip, monkeypatch):
+    """One ``mamba2`` layer's step (two heads of 64 a lane tile, a state of
+    128) under remat ``attn`` with the kind's ``SAVE_NAMES``, every kernel
+    module steered to the chip: the optimized text holds ``mamba_conv_fwd``
+    ONCE per conv (x, and B | C) and none under ``rt_pass="remat"`` (its
+    outputs are saved names and its residuals are its inputs, so the second run
+    has no use for the call), ``mamba_conv_bwd`` once per conv."""
+    import dataclasses
+    import importlib
+    import re
+    import sys
+
+    from ray_tpu.models.llama import MIXERS, PRESETS
+    from ray_tpu.models.mamba2 import SAVE_NAMES, Mamba2
+    from test_chip_compile_steps import KERNEL_MODULES, lowered_step
+
+    for name in KERNEL_MODULES:
+        importlib.import_module(name)
+        monkeypatch.setattr(sys.modules[name], "on_tpu", lambda: True)
+    assert MIXERS["mamba2"].save_names == SAVE_NAMES
+    cfg = dataclasses.replace(
+        PRESETS["granite-hybrid-debug"], n_layers=1, layer_pattern=("mamba2",), remat_policy="attn",
+        mamba2=Mamba2(heads=4, head_dim=64, state=128, chunk=128))
+    jax.clear_caches()
+    calls = [line for line in lowered_step(chip, cfg).compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    jax.clear_caches()
+    passes = {}
+    for line in calls:
+        kernel = re.sub(r"[.\d]+$", "", line.split(" = ")[0].strip().removeprefix("ROOT ").lstrip("%"))
+        if kernel.startswith("mamba_"):
+            assert 'rt_scope="stack/attn/mamba_conv"' in line
+            passes.setdefault(kernel, []).append(re.search(r'rt_pass="(\w+)"', line).group(1))
+    assert passes == {"mamba_conv_fwd": ["fwd", "fwd"], "mamba_conv_bwd": ["bwd", "bwd"]}, passes
 
 
 @pytest.mark.slow  # two whole-step compiles at the cell's size: 4-5 minutes
