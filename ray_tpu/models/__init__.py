@@ -18,7 +18,10 @@ kv group, no rope, an element-wise gate) and Mamba-2 state-space layers
 (``mamba2.py``: heads of 64 features over a 128-wide float32 state whose decay
 is a function of the token and of a parameter, one B and one C for all heads,
 a causal conv with a bias, the gate inside a norm over all features; two Pallas
-kernels over chunks, ``ops/ssd.py``). A spec of grouped-query attention may
+kernels over chunks, ``ops/ssd.py``) and double-gated short convolutions
+(``short_conv.py``: one product to two gates and a conv's input, a causal
+depthwise conv of 3 taps between the gates, no activation, one product out;
+plain XLA). A spec of grouped-query attention may
 state its softmax scale as a constant, and ``tie_embeddings`` makes the head
 contract the embedding's own rows (no ``lm_head`` leaf).
 MLPs: dense SwiGLU, or with
@@ -30,9 +33,9 @@ have an MLP kind of their own. A block hands its MLP kind the block's input
 before the mixer runs, for a router that reads the residual stream there and
 not the MLP's own normed input (``kinds.py``: ``early``).
 Llama-3, InternLM2, Mistral, OLMoE-1B-7B, Qwen3-Next, dots3-note-prev,
-Laguna-S-2.1, Kimi-K2, SmallThinker, MiniCPM-SALA and Granite-4.0-H (the last
-two under fixed multipliers on the embedding, the residual branches and the
-head's input) are configurations."""
+Laguna-S-2.1, Kimi-K2, SmallThinker, MiniCPM-SALA, Granite-4.0-H (these two
+under fixed multipliers on the embedding, the residual branches and the head's
+input) and LFM2 are configurations."""
 
 from .llama import (
     LlamaConfig,

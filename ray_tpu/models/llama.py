@@ -12,7 +12,8 @@ causal key, ``models/mla.py``;
 whole or under a window, ``models/gqa.py``; ``lightning``: linear attention
 under a constant decay, ``models/lightning.py``; ``block_sparse``: grouped
 queries over key blocks the layer selects, ``models/block_sparse.py``;
-``mamba2``: a selective state-space layer, ``models/mamba2.py``)
+``mamba2``: a selective state-space layer, ``models/mamba2.py``; ``sconv``:
+a double-gated short convolution, ``models/short_conv.py``)
 and an MLP (``dense``, below; ``moe``: the routed experts,
 ``models/moe.py``) are ``LayerKind``s (``models/kinds.py``) that own their
 leaves, logical axes, init, FLOPs, counters and the names a remat policy
@@ -31,7 +32,10 @@ input; MiniCPM-SALA a period of one block-selected layer and three of lightning
 attention, under fixed multipliers on the embedding, the residual branches and
 the head's input; Granite-4.0-H a period of TEN, nine Mamba-2 state-space
 layers around one un-roped attention layer whose softmax scale is a constant,
-the embedding tied to the head, under the same three multipliers.
+the embedding tied to the head, under the same three multipliers; LFM2 two
+leading dense layers under short convolutions, then a period of one roped
+attention layer with per-head q/k norms and three short convolutions, sigmoid
+routing under a selection bias with no shared expert, the embedding tied.
 
 Design choices (vs. a torch port):
 - Layers are **stacked and scanned** (`lax.scan`) over periods: the body is
@@ -75,6 +79,7 @@ from .lightning import LIGHTNING, LightningAttention
 from .mamba2 import MAMBA2, Mamba2
 from .mla import MLA, MLA_FULL, MLA_WINDOW, LatentAttention, LatentAttentionYarn
 from .moe import MOE, bias_step
+from .short_conv import SCONV
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,6 +207,9 @@ class LlamaConfig:
     # gradient is the sum of the row gather's and the head's.
     mamba2: Mamba2 | None = None
     tie_embeddings: bool = False
+    # Double-gated short convolution (models/short_conv.py), the mixer kind
+    # "sconv": the taps of its causal depthwise conv (LFM2's ``conv_L_cache``).
+    sconv_taps: int = 3
     # Pipeline parallelism: microbatches per step when the mesh has pp > 1.
     pipeline_microbatches: int = 4
 
@@ -353,6 +361,19 @@ PRESETS: dict[str, LlamaConfig] = {
         gqa=ScaledGroupedQueryAttention(heads=4, kv_heads=2, head_dim=16, rope_theta=0.0,
                                         softmax_scale=0.125),
         tie_embeddings=True, embed_scale=12.0, residual_scale=0.22, logit_scale=0.125),
+    # short convolutions beside roped attention at test size: TWO leading dense
+    # layers under short convolutions, then two periods of an attention layer
+    # (4 query heads over 2 kv heads of 16, q and k normed a head, rope over the
+    # whole head) and three short convolutions of 3 taps; a sigmoid router with
+    # its bias over 8 experts, top-3 renormalised, 2 held, no shared expert; the
+    # embedding tied to the head
+    "conv-moe-debug": LlamaConfig(
+        vocab_size=256, hidden=64, n_layers=10, n_heads=4, n_kv_heads=2, intermediate=32,
+        head_dim=16, rope_theta=1e4, head_qk_norm=True,
+        layer_pattern=("attn", "sconv", "sconv", "sconv"), lead_pattern=("sconv", "sconv"),
+        lead_intermediate=96, moe_experts=8, moe_top_k=3, moe_norm_topk=True,
+        moe_held=(0, 2), moe_score="sigmoid", moe_bias_rate=0.001, moe_aux_weight=0.0001,
+        tie_embeddings=True),
 }
 
 
@@ -590,7 +611,7 @@ LEAD_DENSE = LayerKind(
 MIXERS: dict[str, LayerKind] = {"attn": ATTN, "gdn": GDN, "mla": MLA, "mla_win": MLA_WINDOW,
                                 "mla_full": MLA_FULL, "gqa": GQA, "gqa_win": GQA_WINDOW,
                                 "lightning": LIGHTNING, "block_sparse": BLOCK_SPARSE,
-                                "mamba2": MAMBA2}
+                                "mamba2": MAMBA2, "sconv": SCONV}
 
 
 def _scaled(t, scale: float):
@@ -719,7 +740,9 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
     ``attn_block_tile_share``, each the mean over those layers, and with
     ``return_selection`` its sets [those layers, B, KV, S, S / block]. A
     state-space mixer (``mamba2``) adds ``ssm_decay_mean``, the mean of its
-    decays ``exp(dt A)`` over those layers, heads and positions."""
+    decays ``exp(dt A)`` over those layers, heads and positions. A short
+    convolution (``sconv``) adds ``sconv_past_share``, the mean over those layers
+    of the share of the conv's output that earlier positions give."""
     c = config
     b, s = tokens.shape
     positions = jnp.arange(s, dtype=jnp.int32)
@@ -851,6 +874,8 @@ def forward_hidden(params, tokens, config: LlamaConfig, *, mesh: Mesh | None = N
                    attn_selected_share=both("selected_share"))
     if any("decay_mean" in m for m in counted):
         aux["ssm_decay_mean"] = both("decay_mean")
+    if any("past_share" in m for m in counted):
+        aux["sconv_past_share"] = both("past_share")
     if any("block_kept_share" in m for m in counted):
         aux.update({f"attn_{name}": both(name) for name in (
             "block_kept_share", "block_forced_share", "block_tile_share")})
