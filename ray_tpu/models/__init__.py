@@ -21,7 +21,8 @@ a causal conv with a bias, the gate inside a norm over all features; two Pallas
 kernels over chunks, ``ops/ssd.py``) and double-gated short convolutions
 (``short_conv.py``: one product to two gates and a conv's input, a causal
 depthwise conv of 3 taps between the gates, no activation, one product out;
-plain XLA). A spec of grouped-query attention may
+the pass between the products a Pallas kernel pair on the chip,
+``ops/sconv_elementwise.py``, and plain XLA elsewhere). A spec of grouped-query attention may
 state its softmax scale as a constant, and ``tie_embeddings`` makes the head
 contract the embedding's own rows (no ``lm_head`` leaf).
 MLPs: dense SwiGLU, or with

@@ -16,8 +16,14 @@ that matrix as [E, 3, E], the same numbers, so that the product leaves its
 thirds as whole arrays [3, B, S, E] and no third is a slice at a lane offset
 of another's rows. ``conv`` is [K, E].
 
-The two gates and the conv run in float32 and round once, plain XLA under
-``sconv_mix``; the products on either side are ``sconv_proj`` and
+The two gates and the conv run in float32 and round once under ``sconv_mix``:
+on the chip, at shapes ``ops/sconv_elementwise.py::fits`` takes (features in
+whole lane tiles, rows in whole units of 64, three taps, the model's own float
+dtype), as that module's kernel pair ``sconv_fwd`` / ``sconv_bwd``, which keeps
+every float32 intermediate in VMEM and reads the thirds where the product left
+them; on every other backend and at every other width as ``gated_conv`` in
+plain XLA (``trace_log.kernel_traces()`` says which: ``sconv:pallas`` or
+``sconv:jnp``). The products on either side are ``sconv_proj`` and
 ``sconv_out``. ``SAVE_NAMES`` is what remat ``attn`` keeps of a layer: the
 stream as the mixer's output joins it (``kinds.POST_ATTN``), so that the
 block's second run makes no ``W_out`` product; B, C and X ([3, tokens, E]:
@@ -34,6 +40,9 @@ import jax
 import jax.numpy as jnp
 
 from ..observability.tracing import device_scope
+from ..ops import sconv_elementwise
+from ..ops.trace_log import note_kernel_trace
+from ..tpu import on_tpu
 from .kinds import POST_ATTN, LayerKind
 
 SAVE_NAMES = (POST_ATTN,)
@@ -70,10 +79,16 @@ def sconv_mixer(h, layer, *, config, positions, mesh=None, return_selection: boo
     ``return_selection`` is the block's question to every mixer of a stack that
     selects keys somewhere: this one has no selection to return."""
     with device_scope("sconv_proj"):
-        b, c, x = jnp.einsum("bse,egc->gbsc", h, layer["w_in"])
+        bcx = jnp.einsum("bse,egc->gbsc", h, layer["w_in"])
     with device_scope("sconv_mix"):
-        gated, past_share = gated_conv(b, c, x, layer["conv"])
-        gated = gated.astype(h.dtype)
+        taps = layer["conv"]
+        if on_tpu() and sconv_elementwise.fits(bcx.shape[3], bcx.shape[2], taps.shape[0],
+                                               bcx.dtype):
+            gated, past_share = sconv_elementwise.gated_conv3(bcx, taps)
+        else:
+            note_kernel_trace("sconv", "jnp")
+            gated, past_share = gated_conv(*bcx, taps)
+            gated = gated.astype(h.dtype)
     with device_scope("sconv_out"):
         out = jnp.einsum("bsc,ce->bse", gated, layer["w_out"])
     return out, {"past_share": past_share}

@@ -3,9 +3,9 @@
 The hot paths (attention, the experts' grouped matmul and the sum of a held
 range's rows into their tokens, the gated delta rule's, lightning attention's
 and the Mamba-2 state-space layers' (``ssd.py``) scans over chunks) are Pallas
-TPU kernels; what is elementwise is left to XLA fusion, but for the two
-mixers' passes XLA ran at a sixth of their bytes (``gdn_elementwise.py``,
-``mamba_elementwise.py``). Sequence/context parallelism (ring attention) is
+TPU kernels; what is elementwise is left to XLA fusion, but for the three
+mixers' passes XLA ran at a sixth to a quarter of their bytes
+(``gdn_elementwise.py``, ``mamba_elementwise.py``, ``sconv_elementwise.py``). Sequence/context parallelism (ring attention) is
 green-field — the reference has none (SURVEY.md §5.7).
 """
 

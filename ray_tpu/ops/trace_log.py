@@ -18,6 +18,9 @@ chunk-local pair is traced as ``gdn_wy``, its recurrence as ``gdn``; the
 DeltaNet mixer's elementwise pairs ``gdn_conv_fwd`` / ``gdn_conv_bwd`` and
 ``gdn_norm_fwd`` / ``gdn_norm_bwd``, traced as ``gdn_conv`` and ``gdn_norm``
 (``pallas`` / ``interpret``, or ``jnp`` where the mixer's plain functions ran); the
+Mamba-2 conv's ``mamba_conv_fwd`` / ``mamba_conv_bwd`` and the short conv's
+``sconv_fwd`` / ``sconv_bwd`` (0 FLOPs, each array's bytes once), traced as
+``mamba_conv`` and ``sconv`` the same way; the
 attention kernels under a window or a key set ``attn_win_*`` / ``attn_sel_*``,
 traced as ``flash_attention`` like the plain ones; the indexer's
 ``dsa_index_fwd`` / ``dsa_index_bwd_dq`` / ``dsa_index_bwd_dk``, traced as

@@ -19,7 +19,8 @@ from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops import gated_delta, gdn_elementwise, mamba_elementwise, sparse_index
+from ray_tpu.ops import (gated_delta, gdn_elementwise, mamba_elementwise, sconv_elementwise,
+                         sparse_index)
 from ray_tpu.ops.gated_delta import gated_delta_rule
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.lightning_attention import lightning_attention
@@ -247,6 +248,18 @@ def _mamba_conv(chip, backward, b=1, c=32, t=32768):
     return jax.jit(lambda ct, *a: jax.vjp(fwd, *a)[1](ct)).lower(args[0], *args)
 
 
+def _sconv(chip, backward, b=4, t=8192, e=2048):
+    """LFM2's double-gated conv at the benchmark's 4 x 8,192: the thirds
+    ``[3, 4, 8192, 2048]`` as the in-projection leaves them and the three taps as
+    the leaf lies; backward alone (the residuals are the inputs)."""
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)  # noqa: E731
+    args = (sd((3, b, t, e)), sd((3, e)))
+    fwd = lambda *a: sconv_elementwise.gated_conv3(*a, interpret=False)[0]  # noqa: E731
+    if not backward:
+        return jax.jit(fwd).lower(*args)
+    return jax.jit(lambda ct, *a: jax.vjp(fwd, *a)[1](ct)).lower(sd((b, t, e)), *args)
+
+
 def _latent(chip, kind, backward, b=2, t=8192):
     """dots3-note-prev's attention at the benchmark's 2 x 8192: ``sel`` the
     full layers' (128 heads, a 192-wide key head and a 128-wide value head,
@@ -421,6 +434,9 @@ CASES = {
     "flash-fwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=False),
     "flash-bwd-32to8-d64-32k": lambda c: _flash(c, 1, 32, 8, 32768, 64, backward=True),
     "embed-rows-granite": lambda c: _rows(c, 25088, 32768, 2048),
+    # lfm2-8b-a1b at four rows of 8,192: the short conv's mix between its products
+    "sconv-fwd-4x8k": lambda c: _sconv(c, backward=False),
+    "sconv-bwd-4x8k": lambda c: _sconv(c, backward=True),
 }
 
 
@@ -430,7 +446,7 @@ def test_kernel_compiles_for_the_chip(chip, case):
     assert "tpu_custom_call" in program
     if case.startswith("dsa-probs"):  # the loss's forward kernel; with its gradient, both
         assert program.count('custom_call_target="tpu_custom_call"') == 1 + ("bwd" in case)
-    if case.startswith(("gdn-conv", "gdn-norm", "mamba-conv")):  # one kernel each way
+    if case.startswith(("gdn-conv", "gdn-norm", "mamba-conv", "sconv")):  # one kernel each way
         assert program.count('custom_call_target="tpu_custom_call"') == 1
     if case.startswith("moe-gmm") and case.endswith("-grad"):  # forward, d_lhs, d_rhs
         assert program.count('custom_call_target="tpu_custom_call"') == 3

@@ -17,7 +17,8 @@ KERNEL_MODULES = ("ray_tpu.tpu", "ray_tpu.ops.attention", "ray_tpu.ops.grouped_m
                   "ray_tpu.ops.sparse_index", "ray_tpu.ops.gated_delta",
                   "ray_tpu.ops.gdn_elementwise", "ray_tpu.models.gdn", "ray_tpu.ops.moe_rows",
                   "ray_tpu.ops.lightning_attention", "ray_tpu.ops.ssd",
-                  "ray_tpu.ops.mamba_elementwise", "ray_tpu.models.mamba2")
+                  "ray_tpu.ops.mamba_elementwise", "ray_tpu.models.mamba2",
+                  "ray_tpu.ops.sconv_elementwise", "ray_tpu.models.short_conv")
 
 
 def lowered_step(chip, cfg, rows=2, seq=256, chunk=128):

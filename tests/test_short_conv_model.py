@@ -6,6 +6,7 @@ reference ``benchmark/reference/conv_moe_decoder.py`` on seeded float32
 weights."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +54,35 @@ def seeded():
 @pytest.fixture(scope="module")
 def conv_layer(seeded):
     return seeded[0]["lead_layers"]["layer1"]
+
+
+# one compile a program and a reference for the whole file: the tests below
+# differ in the leaves or the faults they hand these, not in the program
+@functools.lru_cache(maxsize=None)
+def program_logits(cfg):
+    return jax.jit(lambda p, t: forward(p, t, cfg))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_logits(*faults):
+    return jax.jit(lambda p, t: ref.logits(p, t, arch_of(CFG, *faults)))
+
+
+@pytest.fixture(scope="module")
+def sound(seeded):
+    """(the program's logits [1, S, V], the reference's (logits [S, V], what it
+    saw)) of the first row under the true configuration."""
+    params, tokens = seeded
+    return program_logits(CFG)(params, tokens[:1]), reference_logits()(params, tokens[0])
+
+
+@pytest.fixture(scope="module")
+def program_step(seeded):
+    """((loss, aux), gradients) of the program's loss on both rows."""
+    params, tokens = seeded
+    return jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16, return_aux=True),
+        has_aux=True))(params)
 
 
 def plain_mixer(h, w):
@@ -135,16 +165,14 @@ def test_zeros_stand_before_a_row_and_rows_do_not_see_each_other(conv_layer):
     assert not np.allclose(again[:, 20:23], got[:, 20:23])
 
 
-def test_the_stack_equals_the_reference_in_logits_loss_and_every_leafs_gradient(seeded):
+def test_the_stack_equals_the_reference_in_logits_loss_and_every_leafs_gradient(
+        seeded, sound, program_step):
     params, tokens = seeded
     arch = arch_of(CFG)
-    want, seen = jax.jit(lambda p, t: ref.logits(p, t, arch))(params, tokens[0])
-    got = jax.jit(lambda p, t: forward(p, t, CFG))(params, tokens[:1])[0]
-    assert float(jnp.max(ref.position_errors(got, want))) < TIGHT
+    got, (want, seen) = sound
+    assert float(jnp.max(ref.position_errors(got[0], want))) < TIGHT
     weight = CFG.moe_aux_weight
-    (loss, aux), grads = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=16, return_aux=True),
-        has_aux=True))(params)
+    (loss, aux), grads = program_step
     (ref_loss, ref_seen), ref_grads = jax.jit(jax.value_and_grad(
         lambda p: ref.loss(p, tokens, arch, aux_weight=weight, return_seen=True),
         has_aux=True))(params)
@@ -176,15 +204,15 @@ REFERENCE_FAULTS = ("no_c_gate", "silu_after_conv", "no_rope", "bias_on_gates")
 
 
 @pytest.mark.parametrize("fault", [*PROGRAM_FAULTS, *REFERENCE_FAULTS])
-def test_each_misreading_moves_the_logits(seeded, fault):
+def test_each_misreading_moves_the_logits(seeded, sound, fault):
     """The runner's controls at test size: a conv left out, its taps reversed,
     the thirds misread, the head norms or the bias left out, a softmax router
     in the program; a gate left out, a silu put in, rope left out or the bias
     on the gates in the reference."""
     params, tokens = seeded
-    cfg, arch, given = CFG, arch_of(CFG), params
+    got, (want, _) = sound
     if fault in REFERENCE_FAULTS:
-        arch = arch_of(CFG, fault)
+        want, _ = reference_logits(fault)(params, tokens[0])
     else:
         cfg = dataclasses.replace(CFG, **PROGRAM_FAULTS[fault])
         given = planted(params, fault)
@@ -192,8 +220,8 @@ def test_each_misreading_moves_the_logits(seeded, fault):
             given = jax.tree.map(lambda a: a, params)
             for slot in given["layers"].values():
                 slot.pop("router_bias")
-    want, _ = jax.jit(lambda p, t: ref.logits(p, t, arch))(params, tokens[0])
-    got = jax.jit(lambda p, t: forward(p, t, cfg))(given, tokens[:1])[0]
+        got = program_logits(cfg)(given, tokens[:1])
+    got = got[0]
     # a hundred times what rounding gives and more (the bias on the gates, a
     # seeded +-0.05 on scores near a half, is the weakest: 4e-3)
     assert float(jnp.median(ref.position_errors(got, want))) > 100 * TIGHT, fault
@@ -230,10 +258,10 @@ def test_the_four_shares_of_the_experts_add_up_to_the_uncut_layer(seeded):
     assert max(_err(p, want) for p in parts) > 0.5
 
 
-def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(seeded):
+def test_the_tied_tables_gradient_is_the_sum_of_its_two_uses(seeded, program_step):
     params, tokens = seeded
     batch = {"tokens": tokens}
-    tied = jax.jit(jax.grad(lambda p: loss_fn(p, batch, CFG, chunk_tokens=16)))(params)["embed"]
+    tied = program_step[1]["embed"]
     apart_cfg = dataclasses.replace(CFG, tie_embeddings=False)
     apart = jax.jit(jax.grad(lambda p: loss_fn(p, batch, apart_cfg, chunk_tokens=16)))(
         {**params, "lm_head": params["embed"].T})
