@@ -349,12 +349,6 @@ def _all_rows_counted(top_k, tokens, weights, gates, order, inv, sizes, offset,
         return out.astype(jnp.float32).sum(axis=1).astype(tokens.dtype), counts
 
 
-def _all_rows(*args, **kwargs):
-    """``_all_rows_counted``'s rows alone, for ``tests/test_moe_rows.py``, which
-    holds this path's jaxpr to an earlier commit's."""
-    return _all_rows_counted(*args, **kwargs)[0]
-
-
 def _held_rows(top_k, cap, tokens, weights, gates, order, sizes, offset, activation="silu"):
     """Only the held experts' rows: the ``cap`` sorted rows from the held
     range's first. ``take_rows`` (XLA's gather) brings their tokens in,
@@ -394,7 +388,7 @@ def _held_by_expert(top_k, cap, tokens, weights, gates, order, sizes, offset, g=
     expert's share of ``cap`` sorted rows at a time, each chunk a plain gated unit
     on its gathered tokens added into them, for as many chunks as the expert
     has rows (loops whose counts are device values). Nothing here is as long as the N*k rows, which
-    ``_all_rows`` gathers whole (0.94 GB a [N*k, E] tensor at 8,192 tokens x 8
+    ``_all_rows_counted`` gathers whole (0.94 GB a [N*k, E] tensor at 8,192 tokens x 8
     of width 7,168: as this branch of the ``cond``, which an even router never
     takes, it cost a step 4.5 GB of scratch by the chip compiler's count).
     It returns (result, ``_zeroed``'s count); with a cotangent ``g`` [N, E]
@@ -497,12 +491,6 @@ def _held_range_bwd(top_k, cap, activation, args, g):
 
 
 _held_range_counted.defvjp(_held_range_fwd, _held_range_bwd)
-
-
-def _held_range(*args, **kwargs):
-    """``_held_range_counted``'s rows alone, as ``tests/test_moe_rows.py``
-    differentiates the path."""
-    return _held_range_counted(*args, **kwargs)[0]
 
 
 def route_block(tokens, params, n_seqs: int, *, top_k: int = 2, norm_topk: bool = True,

@@ -51,53 +51,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
+from .conv_tiles import (HALO, LANE, ROW_UNIT, SUBLANES, chunks, fold, largest, mosaic_params,
+                         tapped, windows)
 from .trace_log import note_kernel_cost, note_kernel_trace
 
-LANE = 128
 # a grid step's tile of qkv: a step costs ~0.35 us whatever it does, and a row
 # of the block is one DMA burst of TILE_LANES x 2 bytes
 TILE_ROWS = 1024
 TILE_LANES = 1024
-# rows the loop inside a step handles at a time, a head at a time (a turn of
-# the loop costs what ~100 rows do: 1.88 / 1.35 / 1.17 ms a forward call at 64 /
-# 128 / 256; my chip runs, PR 38), and the unit rows come in
-CHUNK_ROWS = 256
-ROW_UNIT = 64
-# rows of the block that holds the three positions before a tile: a bfloat16
-# tile's 16 sublanes
-HALO = 16
-_SUBLANES = 8
-_VMEM_LIMIT = 96 * 1024 * 1024  # of a v5e core's 128 MiB; the default scope is 16
-TAPS = 4          # the conv's width: ``_windows`` makes a width-4 conv's views
+TAPS = 4          # the conv's width
 L2_EPS = 1e-6     # ``models/gdn.py::_l2norm``'s
 
 
 def fits(head_dim: int, rows: int, taps: int) -> bool:
     """Whether the kernels take these shapes: a head of whole lane tiles,
-    rows in whole units, a conv of the width the windows are made for."""
+    rows in whole units, a conv of ``TAPS`` taps."""
     return head_dim % LANE == 0 and rows % ROW_UNIT == 0 and taps == TAPS
-
-
-def _largest(whole: int, unit: int, most: int) -> int:
-    """The largest multiple of ``unit`` that divides ``whole`` and is at most
-    ``most`` (``unit`` itself divides it)."""
-    return max(n for n in range(unit, max(most, unit) + 1, unit) if whole % n == 0)
 
 
 def _tiles(rows: int, head_dim: int, key_width: int, value_width: int):
     """(rows, lanes) of a grid step's tile: the largest whole units / whole
     heads under the module's sizes that divide the sequence / q's and v's
     channels."""
-    t_rows = _largest(rows, ROW_UNIT, TILE_ROWS)
+    t_rows = largest(rows, ROW_UNIT, TILE_ROWS)
     t_lanes = max(n for n in range(head_dim, max(TILE_LANES, head_dim) + 1, head_dim)
                   if key_width % n == 0 and value_width % n == 0)
     return t_rows, t_lanes
-
-
-def _chunks(ref_rows: int):
-    """(rows, count) of the chunks the loop inside a step walks a tile in."""
-    c_rows = _largest(ref_rows, ROW_UNIT, CHUNK_ROWS)
-    return c_rows, ref_rows // c_rows
 
 
 def _phase_index(p, b, s, first, count, n_batch, n_tiles):
@@ -111,45 +90,24 @@ def _phase_index(p, b, s, first, count, n_batch, n_tiles):
             hold(0, n_tiles - 1, s))
 
 
-def _windows(x, before=None, after=None):
-    """x [R, D] with the 8 rows before it (or after it) -> the four [R, D]
-    views a width-4 conv reads: ``before`` gives ``x_{t-3+j}``, ``after``
-    ``x_{t+3-j}``, j = 0..3. A view is the whole stack rolled along the
-    sublanes (one rotation a vreg) and cut where tiles end: sliced at a row
-    that is no multiple of 8, every sum of two views would move one of them
-    (3.13 ms a backward call at 2 x 8192 x 8192 against 2.08; my chip runs,
-    PR 38)."""
-    r = x.shape[0]
-    if after is None:
-        e = jnp.concatenate([before, x], axis=0)
-        return [pltpu.roll(e, 3 - j, 0)[_SUBLANES:] for j in range(3)] + [x]
-    e = jnp.concatenate([x, after], axis=0)
-    return [pltpu.roll(e, r + _SUBLANES - (3 - j), 0)[:r] for j in range(3)] + [x]
-
-
 def _halo_rows(halo_ref, lanes, first_tile):
     """The 8 rows before a tile, float32: the halo block's last, zeros
     before position 0."""
-    rows = halo_ref[0, :, lanes].astype(jnp.float32)[HALO - _SUBLANES:]
+    rows = halo_ref[0, :, lanes].astype(jnp.float32)[HALO - SUBLANES:]
     return jnp.where(first_tile, 0.0, rows)
-
-
-def _tapped(views, w):
-    """sum_j views[j] w[j]: four views [R, D] against the taps [4, D]."""
-    return views[0] * w[0:1] + views[1] * w[1:2] + views[2] * w[2:3] + views[3] * w[3:4]
 
 
 def _activation(z, w):
     """The conv's output and SiLU's parts from the four views and the taps:
     (c, sigmoid(c), c sigmoid(c))."""
-    c = _tapped(z, w)
+    c = tapped(z, w)
     sig = jax.nn.sigmoid(c)
     return c, sig, c * sig
 
 
 def _fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, *, n_key, d):
     p, first_tile = pl.program_id(0), pl.program_id(2) == 0
-    heads, (c_rows, n_chunks) = x_ref.shape[2] // d, _chunks(x_ref.shape[1])
+    heads, (c_rows, n_chunks) = x_ref.shape[2] // d, chunks(x_ref.shape[1])
 
     def head(h, write, scale):
         lanes = pl.ds(pl.multiple_of(h * d, d), d)
@@ -158,11 +116,11 @@ def _fwd_kernel(x_ref, halo_ref, w_ref, q_ref, k_ref, v_ref, *, n_key, d):
         def chunk(i, before):
             rows = pl.ds(pl.multiple_of(i * c_rows, c_rows), c_rows)
             x = x_ref[0, rows, lanes].astype(jnp.float32)
-            _, _, a = _activation(_windows(x, before), w)
+            _, _, a = _activation(windows(x, TAPS, before), w)
             if scale is not None:
                 a = a * (lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS) * scale)
             write(h, rows, a)
-            return x[c_rows - _SUBLANES:]
+            return x[c_rows - SUBLANES:]
 
         lax.fori_loop(0, n_chunks, chunk, _halo_rows(halo_ref, lanes, first_tile))
 
@@ -190,7 +148,7 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref, dw_ref,
     p, b, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     # the row tiles are walked backwards: s = 0 is the sequence's end
     first_tile = s == pl.num_programs(2) - 1
-    heads, (c_rows, n_chunks) = x_ref.shape[2] // d, _chunks(x_ref.shape[1])
+    heads, (c_rows, n_chunks) = x_ref.shape[2] // d, chunks(x_ref.shape[1])
 
     @pl.when(jnp.logical_and(b == 0, s == 0))
     def _first_of_a_channel_block():
@@ -211,8 +169,8 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref, dw_ref,
             rows = pl.ds(pl.multiple_of(i * c_rows, c_rows), c_rows)
             x = x_ref[0, rows, lanes].astype(jnp.float32)
             own = x_ref[0, pl.ds(pl.multiple_of(jnp.maximum(i * c_rows - HALO, 0), HALO),
-                                 HALO), lanes].astype(jnp.float32)[HALO - _SUBLANES:]
-            z = _windows(x, jnp.where(i > 0, own, before))
+                                 HALO), lanes].astype(jnp.float32)[HALO - SUBLANES:]
+            z = windows(x, TAPS, jnp.where(i > 0, own, before))
             c, sig, a = _activation(z, w)
             da = cotangent(h, rows)
             if scale is not None:
@@ -221,11 +179,10 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref, dw_ref,
                 r = lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS)
                 da = (r * scale) * (da - a * (r * r * jnp.sum(da * a, axis=-1, keepdims=True)))
             dc = da * sig * (1.0 + c * (1.0 - sig))
-            dx_ref[0, rows, lanes] = _tapped(_windows(dc, after=after), w).astype(dx_ref.dtype)
-            fold = lambda t: t.reshape(-1, _SUBLANES, d).sum(axis=0)  # noqa: E731
-            return dc[:_SUBLANES], tuple(t + fold(dc * zj) for t, zj in zip(sums, z))
+            dx_ref[0, rows, lanes] = tapped(windows(dc, TAPS, after=after), w).astype(dx_ref.dtype)
+            return dc[:SUBLANES], tuple(t + fold(dc * zj) for t, zj in zip(sums, z))
 
-        zeros = jnp.zeros((_SUBLANES, d), jnp.float32)
+        zeros = jnp.zeros((SUBLANES, d), jnp.float32)
         after, sums = lax.fori_loop(0, n_chunks, chunk, (after_ref[h], (zeros,) * TAPS))
         after_ref[h] = after
         dw_ref[:, lanes] += jnp.concatenate(
@@ -281,13 +238,6 @@ def _specs(x, key_heads, value_heads, *, backwards):
     return grid, [tile, halo, taps], heads, (rep, d, n_key)
 
 
-def _params():
-    # every axis in order: an output keeps its block between its phases, and
-    # the taps' gradient and the rows a tile hands on are summed along the grid
-    return pltpu.CompilerParams(dimension_semantics=("arbitrary",) * 3,
-                                vmem_limit_bytes=_VMEM_LIMIT)
-
-
 def _note_costs(x, key_heads, value_heads, out_dtype):
     """One call of each: the bytes are qkv once, q, k and v at the value
     heads' count once (and the cotangents and qkv's gradient); the operations
@@ -314,7 +264,7 @@ def _forward(x, w, *, key_heads, value_heads, out_dtype, interpret):
         grid=grid, in_specs=ins, out_specs=outs,
         out_shape=[key_shape, key_shape,
                    jax.ShapeDtypeStruct((b, value_heads, s, d), out_dtype)],
-        compiler_params=_params(), interpret=interpret, name="gdn_conv_fwd",
+        compiler_params=mosaic_params(), interpret=interpret, name="gdn_conv_fwd",
     )(x, x, w)
     return q.reshape(v.shape), k.reshape(v.shape), v
 
@@ -328,8 +278,8 @@ def _backward(x, w, dq, dk, dv, *, key_heads, value_heads, interpret):
         grid=grid, in_specs=ins + heads, out_specs=ins[::2],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(w.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((ins[0].block_shape[2] // d, _SUBLANES, d), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="gdn_conv_bwd",
+        scratch_shapes=[pltpu.VMEM((ins[0].block_shape[2] // d, SUBLANES, d), jnp.float32)],
+        compiler_params=mosaic_params(), interpret=interpret, name="gdn_conv_bwd",
     )(x, x, w, by_key_head(dq), by_key_head(dk), dv)
     return dx, dw
 
@@ -384,7 +334,7 @@ def _norm_parts(o, z, w, eps):
 
 def _norm_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
     heads, d = o_ref.shape[1], o_ref.shape[3]
-    c_rows, n_chunks = _chunks(o_ref.shape[2])
+    c_rows, n_chunks = chunks(o_ref.shape[2])
     w = w_ref[...]
 
     def head(h, _):
@@ -403,7 +353,7 @@ def _norm_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, eps):
 
 def _norm_bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps):
     heads, d = o_ref.shape[1], o_ref.shape[3]
-    c_rows, n_chunks = _chunks(o_ref.shape[2])
+    c_rows, n_chunks = chunks(o_ref.shape[2])
     w = w_ref[...]
 
     @pl.when(jnp.logical_and(pl.program_id(0) == 0,
@@ -425,11 +375,11 @@ def _norm_bwd_kernel(o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, eps
             du = g * w * silu
             do = r * (du - u * jnp.mean(du * u, axis=-1, keepdims=True))
             do_ref[0, h, rows] = do.astype(do_ref.dtype)
-            return total + (gu * silu).reshape(-1, _SUBLANES, d).sum(axis=0)
+            return total + fold(gu * silu)
 
         return lax.fori_loop(0, n_chunks, chunk, total)
 
-    dw_ref[...] += lax.fori_loop(0, heads, head, jnp.zeros((_SUBLANES, d), jnp.float32))
+    dw_ref[...] += lax.fori_loop(0, heads, head, jnp.zeros((SUBLANES, d), jnp.float32))
 
 
 def _norm_specs(o):
@@ -453,7 +403,7 @@ def _norm_forward(o, z, w, *, eps, out_dtype, interpret):
         functools.partial(_norm_fwd_kernel, eps=eps),
         grid=grid, in_specs=[by_head, by_token, weight], out_specs=by_token,
         out_shape=jax.ShapeDtypeStruct(z.shape, out_dtype),
-        compiler_params=_params(), interpret=interpret, name="gdn_norm_fwd",
+        compiler_params=mosaic_params(), interpret=interpret, name="gdn_norm_fwd",
     )(o, z, w)
 
 
@@ -463,10 +413,10 @@ def _norm_backward(o, z, w, dy, *, eps, interpret):
     do, dz, dw = pl.pallas_call(
         functools.partial(_norm_bwd_kernel, eps=eps),
         grid=grid, in_specs=[by_head, by_token, weight, by_token],
-        out_specs=[by_head, by_token, pl.BlockSpec((_SUBLANES, d), lambda bi, p, si: (0, 0))],
+        out_specs=[by_head, by_token, pl.BlockSpec((SUBLANES, d), lambda bi, p, si: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype), jax.ShapeDtypeStruct(z.shape, z.dtype),
-                   jax.ShapeDtypeStruct((_SUBLANES, d), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="gdn_norm_bwd",
+                   jax.ShapeDtypeStruct((SUBLANES, d), jnp.float32)],
+        compiler_params=mosaic_params(), interpret=interpret, name="gdn_norm_bwd",
     )(o, z, w, dy)
     return do, dz, dw.sum(axis=0, keepdims=True)
 

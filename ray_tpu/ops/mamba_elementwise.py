@@ -15,7 +15,7 @@ gradient is four reductions over them. Here a grid step takes a block
 intermediate lives in VMEM and each array crosses HBM once a pass; the
 mathematics and its precisions are the plain functions' (float32 inside, one
 rounding on the way out). The shifted views, the chunk walk and the tile
-arithmetic are ``ops/gdn_elementwise.py``'s (imported, not copied); the
+arithmetic are ``ops/conv_tiles.py``'s, shared with the two other convs; the
 bodies are this layout's own: a bias, no head split, no l2 norms.
 
 ``mamba_conv_fwd``  grid (channel block, batch, row tile). The taps [4, C, 128]
@@ -61,37 +61,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .gdn_elementwise import (_SUBLANES, HALO, LANE, ROW_UNIT, TAPS, _chunks, _largest, _params,
-                              _tapped, _windows)
+from .conv_tiles import (HALO, LANE, ROW_UNIT, SUBLANES, chunks, fold, largest, mosaic_params,
+                         tapped, windows)
 from .trace_log import note_kernel_cost, note_kernel_trace
 
 # a grid step's block of the conv: channel tiles x rows x 128 lanes, a step
 # costs ~0.35 us whatever it does (PR 38) and a tile's rows are contiguous
 TILE_CHANNELS = 8
 TILE_ROWS = 1024
+TAPS = 4          # the conv's width
 
 
 def fits(width: int, rows: int, taps: int) -> bool:
     """Whether the kernels take these shapes: a last axis of one lane tile,
-    rows in whole units, a conv of the width the windows are made for."""
+    rows in whole units, a conv of ``TAPS`` taps."""
     return width == LANE and rows % ROW_UNIT == 0 and taps == TAPS
 
 
 def _tiles(channels: int, rows: int):
     """(channel tiles, rows) of a grid step's block."""
-    return _largest(channels, 1, TILE_CHANNELS), _largest(rows, ROW_UNIT, TILE_ROWS)
+    return largest(channels, 1, TILE_CHANNELS), largest(rows, ROW_UNIT, TILE_ROWS)
 
 
 def _halo_rows(halo_ref, c, first_tile):
     """The 8 rows before a tile's channel tile c, float32: the halo block's
     last, zeros before position 0."""
-    rows = halo_ref[0, c].astype(jnp.float32)[HALO - _SUBLANES:]
+    rows = halo_ref[0, c].astype(jnp.float32)[HALO - SUBLANES:]
     return jnp.where(first_tile, 0.0, rows)
-
-
-def _fold(t):
-    """[R, 128] -> [8, 128]: the sum of its vregs (no sublane reduction)."""
-    return t.reshape(-1, _SUBLANES, LANE).sum(axis=0)
 
 
 def _taps(w_ref, b_ref):
@@ -105,7 +101,7 @@ def _taps(w_ref, b_ref):
 
 def _fwd_kernel(x_ref, halo_ref, w_ref, b_ref, y_ref):
     first_tile = pl.program_id(2) == 0
-    c_rows, n_chunks = _chunks(x_ref.shape[2])
+    c_rows, n_chunks = chunks(x_ref.shape[2])
     taps = _taps(w_ref, b_ref)
     for c in range(x_ref.shape[1]):        # static; ``fori_loop`` traces ``chunk`` at once
         w, bias = taps(c)
@@ -113,9 +109,9 @@ def _fwd_kernel(x_ref, halo_ref, w_ref, b_ref, y_ref):
         def chunk(i, before):
             rows = pl.ds(pl.multiple_of(i * c_rows, c_rows), c_rows)
             x = x_ref[0, c, rows].astype(jnp.float32)
-            pre = _tapped(_windows(x, before), w) + bias
+            pre = tapped(windows(x, TAPS, before), w) + bias
             y_ref[0, c, rows] = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
-            return x[c_rows - _SUBLANES:]
+            return x[c_rows - SUBLANES:]
 
         lax.fori_loop(0, n_chunks, chunk, _halo_rows(halo_ref, c, first_tile))
 
@@ -124,7 +120,7 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, b_ref, dy_ref, dx_ref, dw_ref, db_ref, a
     b, s = pl.program_id(1), pl.program_id(2)
     # the row tiles are walked backwards: s = 0 is the sequence's end
     first_tile = s == pl.num_programs(2) - 1
-    c_rows, n_chunks = _chunks(x_ref.shape[2])
+    c_rows, n_chunks = chunks(x_ref.shape[2])
     taps = _taps(w_ref, b_ref)
 
     @pl.when(jnp.logical_and(b == 0, s == 0))
@@ -146,16 +142,16 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, b_ref, dy_ref, dx_ref, dw_ref, db_ref, a
             rows = pl.ds(pl.multiple_of(i * c_rows, c_rows), c_rows)
             x = x_ref[0, c, rows].astype(jnp.float32)
             own = x_ref[0, c, pl.ds(pl.multiple_of(jnp.maximum(i * c_rows - HALO, 0), HALO),
-                                    HALO)].astype(jnp.float32)[HALO - _SUBLANES:]
-            z = _windows(x, jnp.where(i > 0, own, before))
-            pre = _tapped(z, w) + bias
+                                    HALO)].astype(jnp.float32)[HALO - SUBLANES:]
+            z = windows(x, TAPS, jnp.where(i > 0, own, before))
+            pre = tapped(z, w) + bias
             sig = jax.nn.sigmoid(pre)
             dc = dy_ref[0, c, rows].astype(jnp.float32) * (sig * (1.0 + pre * (1.0 - sig)))
-            dx_ref[0, c, rows] = _tapped(_windows(dc, after=after), w).astype(dx_ref.dtype)
+            dx_ref[0, c, rows] = tapped(windows(dc, TAPS, after=after), w).astype(dx_ref.dtype)
             terms = [dc * zj for zj in z] + [dc]                # the taps', then the bias's
-            return dc[:_SUBLANES], tuple(t + _fold(term) for t, term in zip(sums, terms))
+            return dc[:SUBLANES], tuple(t + fold(term) for t, term in zip(sums, terms))
 
-        zeros = jnp.zeros((_SUBLANES, LANE), jnp.float32)
+        zeros = jnp.zeros((SUBLANES, LANE), jnp.float32)
         after, sums = lax.fori_loop(0, n_chunks, chunk, (after_ref[c], (zeros,) * (TAPS + 1)))
         after_ref[c] = after
         for j in range(TAPS):
@@ -192,7 +188,7 @@ def _conv_forward(x, taps, bias, *, interpret):
     return pl.pallas_call(
         _fwd_kernel, grid=grid, in_specs=[tile, halo, taps_spec, bias_spec], out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=_params(), interpret=interpret, name="mamba_conv_fwd",
+        compiler_params=mosaic_params(), interpret=interpret, name="mamba_conv_fwd",
     )(x, x, taps, bias)
 
 
@@ -204,8 +200,8 @@ def _conv_backward(x, taps, bias, dy, *, interpret):
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
                    jax.ShapeDtypeStruct(taps.shape, jnp.float32),
                    jax.ShapeDtypeStruct(bias.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((tile.block_shape[1], _SUBLANES, LANE), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="mamba_conv_bwd",
+        scratch_shapes=[pltpu.VMEM((tile.block_shape[1], SUBLANES, LANE), jnp.float32)],
+        compiler_params=mosaic_params(), interpret=interpret, name="mamba_conv_bwd",
     )(x, x, taps, bias, dy)
     return dx, dw.astype(taps.dtype), db.astype(bias.dtype)
 

@@ -19,10 +19,9 @@ lanes)`` of ``bcx`` as the product leaves it (the thirds are whole arrays: no
 slice, no copy), every float32 intermediate lives in VMEM and each array
 crosses HBM once a pass; the mathematics, its association and its precisions
 are ``short_conv.gated_conv``'s. The shifted views, the chunk walk and the tile
-arithmetic are ``ops/gdn_elementwise.py``'s (imported, not copied; of its four
-views the conv of three taps reads three, and the one it does not read is
-dead code to the chip's compiler); the bodies are this mixer's own: two gates,
-three taps, no bias, no activation, no head split.
+arithmetic are ``ops/conv_tiles.py``'s, shared with the two other convs; the
+bodies are this mixer's own: two gates, three taps, no bias, no activation,
+no head split.
 
 ``sconv_fwd``  grid (lane block, batch, row tile). The taps come as the leaf
                lies, in its own dtype, cast in VMEM (a cast ahead of the call is
@@ -56,9 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tpu import on_tpu
-from .gdn_elementwise import (_SUBLANES, HALO, LANE, ROW_UNIT, _chunks, _largest, _params,
-                              _windows)
-from .mamba_elementwise import _fold
+from .conv_tiles import (HALO, LANE, ROW_UNIT, SUBLANES, chunks, fold, largest, mosaic_params,
+                         windows)
 from .trace_log import note_kernel_cost, note_kernel_trace
 
 TAPS = 3
@@ -79,13 +77,13 @@ def fits(width: int, rows: int, taps: int, dtype) -> bool:
 
 def _tiles(rows: int, width: int):
     """(rows, lanes) of a grid step's block."""
-    return _largest(rows, ROW_UNIT, TILE_ROWS), _largest(width, LANE, TILE_LANES)
+    return largest(rows, ROW_UNIT, TILE_ROWS), largest(width, LANE, TILE_LANES)
 
 
 def _u_before(b_halo_ref, x_halo_ref, lanes, first_tile):
     """``u`` of the 8 rows before a tile, float32: the halo blocks' last,
     zeros before position 0."""
-    rows = lambda ref: ref[0, 0, :, lanes].astype(jnp.float32)[HALO - _SUBLANES:]  # noqa: E731
+    rows = lambda ref: ref[0, 0, :, lanes].astype(jnp.float32)[HALO - SUBLANES:]  # noqa: E731
     return jnp.where(first_tile, 0.0, rows(b_halo_ref) * rows(x_halo_ref))
 
 
@@ -99,7 +97,7 @@ def _conv(views, w):
 
 def _fwd_kernel(bcx_ref, b_halo_ref, x_halo_ref, w_ref, out_ref, sums_ref):
     first_tile = pl.program_id(2) == 0
-    c_rows, n_chunks = _chunks(bcx_ref.shape[2])
+    c_rows, n_chunks = chunks(bcx_ref.shape[2])
 
     @pl.when(jnp.logical_and(pl.program_id(1) == 0, first_tile))
     def _first_of_a_lane_block():
@@ -114,11 +112,11 @@ def _fwd_kernel(bcx_ref, b_halo_ref, x_halo_ref, w_ref, out_ref, sums_ref):
             rows = pl.ds(pl.multiple_of(i * c_rows, c_rows), c_rows)
             third = lambda g: bcx_ref[g, 0, rows, lanes].astype(jnp.float32)  # noqa: E731
             u = third(_B) * third(_X)
-            past, v = _conv(_windows(u, before)[1:], w)
+            past, v = _conv(windows(u, TAPS, before), w)
             out_ref[0, rows, lanes] = (third(_C) * v).astype(out_ref.dtype)
-            return u[c_rows - _SUBLANES:], past_sq + _fold(past * past), v_sq + _fold(v * v)
+            return u[c_rows - SUBLANES:], past_sq + fold(past * past), v_sq + fold(v * v)
 
-        zeros = jnp.zeros((_SUBLANES, LANE), jnp.float32)
+        zeros = jnp.zeros((SUBLANES, LANE), jnp.float32)
         _, past_sq, v_sq = lax.fori_loop(
             0, n_chunks, chunk, (_u_before(b_halo_ref, x_halo_ref, lanes, first_tile), zeros, zeros))
         sums_ref[0, :, lanes] += past_sq
@@ -131,7 +129,7 @@ def _bwd_kernel(bcx_ref, b_halo_ref, x_halo_ref, w_ref, dout_ref, dbcx_ref, dw_r
     b, s = pl.program_id(1), pl.program_id(2)
     # the row tiles are walked backwards: s = 0 is the sequence's end
     first_tile = s == pl.num_programs(2) - 1
-    c_rows, n_chunks = _chunks(bcx_ref.shape[2])
+    c_rows, n_chunks = chunks(bcx_ref.shape[2])
 
     @pl.when(jnp.logical_and(b == 0, s == 0))
     def _first_of_a_lane_block():
@@ -153,17 +151,17 @@ def _bwd_kernel(bcx_ref, b_halo_ref, x_halo_ref, w_ref, dout_ref, dbcx_ref, dw_r
             own = pl.ds(pl.multiple_of(jnp.maximum(i * c_rows - HALO, 0), HALO), HALO)
             third = lambda g, at=rows: bcx_ref[g, 0, at, lanes].astype(jnp.float32)  # noqa: E731
             gate_b, gate_c, x = third(_B), third(_C), third(_X)
-            u_own = (third(_B, own) * third(_X, own))[HALO - _SUBLANES:]
-            views = _windows(gate_b * x, jnp.where(i > 0, u_own, before))[1:]
+            u_own = (third(_B, own) * third(_X, own))[HALO - SUBLANES:]
+            views = windows(gate_b * x, TAPS, jnp.where(i > 0, u_own, before))
             _, v = _conv(views, w)
             dout = dout_ref[0, rows, lanes].astype(jnp.float32)
             dv = dout * gate_c
-            _, du = _conv(_windows(dv, after=after)[1:], w)
+            _, du = _conv(windows(dv, TAPS, after=after), w)
             for g, grad in ((_B, du * x), (_C, dout * v), (_X, du * gate_b)):
                 dbcx_ref[g, 0, rows, lanes] = grad.astype(dbcx_ref.dtype)
-            return dv[:_SUBLANES], tuple(t + _fold(dv * view) for t, view in zip(sums, views))
+            return dv[:SUBLANES], tuple(t + fold(dv * view) for t, view in zip(sums, views))
 
-        zeros = jnp.zeros((_SUBLANES, LANE), jnp.float32)
+        zeros = jnp.zeros((SUBLANES, LANE), jnp.float32)
         after, sums = lax.fori_loop(0, n_chunks, chunk, (after_ref[h], (zeros,) * TAPS))
         after_ref[h] = after
         dw_ref[:, lanes] += jnp.concatenate([t.sum(axis=0, keepdims=True) for t in sums], axis=0)
@@ -195,16 +193,16 @@ def _forward(bcx, taps, *, interpret):
     # counted: ~15 / ~30 vector operations an element over 8 / 14 bytes.
     third = bcx.size // 3 * bcx.dtype.itemsize
     leaf = taps.size * taps.dtype.itemsize
-    note_kernel_cost("sconv_fwd", 0, 4 * third + leaf + 2 * _SUBLANES * bcx.shape[3] * 4)
+    note_kernel_cost("sconv_fwd", 0, 4 * third + leaf + 2 * SUBLANES * bcx.shape[3] * 4)
     note_kernel_cost("sconv_bwd", 0, 7 * third + leaf + taps.size * 4)
     grid, ins, tile = _specs(bcx, backwards=False)
     return pl.pallas_call(
         _fwd_kernel, grid=grid, in_specs=ins,
-        out_specs=[tile, pl.BlockSpec((2, _SUBLANES, tile.block_shape[2]),
+        out_specs=[tile, pl.BlockSpec((2, SUBLANES, tile.block_shape[2]),
                                       lambda p, bi, si: (0, 0, p))],
         out_shape=[jax.ShapeDtypeStruct(bcx.shape[1:], bcx.dtype),
-                   jax.ShapeDtypeStruct((2, _SUBLANES, bcx.shape[3]), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="sconv_fwd",
+                   jax.ShapeDtypeStruct((2, SUBLANES, bcx.shape[3]), jnp.float32)],
+        compiler_params=mosaic_params(), interpret=interpret, name="sconv_fwd",
     )(bcx, bcx, bcx, taps)
 
 
@@ -214,8 +212,8 @@ def _backward(bcx, taps, dout, *, interpret):
         _bwd_kernel, grid=grid, in_specs=ins + [tile], out_specs=[ins[0], ins[3]],
         out_shape=[jax.ShapeDtypeStruct(bcx.shape, bcx.dtype),
                    jax.ShapeDtypeStruct(taps.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((tile.block_shape[2] // LANE, _SUBLANES, LANE), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="sconv_bwd",
+        scratch_shapes=[pltpu.VMEM((tile.block_shape[2] // LANE, SUBLANES, LANE), jnp.float32)],
+        compiler_params=mosaic_params(), interpret=interpret, name="sconv_bwd",
     )(bcx, bcx, bcx, taps, dout)
     return dbcx, dw.astype(taps.dtype)
 

@@ -194,7 +194,13 @@ def check_passes(text: str, remat: bool = True) -> None:
 
 
 
-@pytest.mark.parametrize("kind", ["hybrid", "sparse"])
+# the sparse kind's cases are ``tests/test_device_scopes_sparse.py``'s: its step
+# compiles twice (with the scopes and without) as the hybrid's does, and a file
+# goes to one worker
+HERE = [kind for kind in KINDS if kind != "sparse"]
+
+
+@pytest.mark.parametrize("kind", ["hybrid"])
 def test_every_scoped_op_carries_the_pass_its_op_name_says(step_texts, kind):
     """``tracing.with_passes`` on the two heavy kinds' steps, compiled here
     anyway; the light kinds, no remat and remat ``full``:
@@ -202,9 +208,13 @@ def test_every_scoped_op_carries_the_pass_its_op_name_says(step_texts, kind):
     check_passes(step_texts(kind))
 
 
-@pytest.mark.parametrize("kind", list(KINDS))
+@pytest.mark.parametrize("kind", HERE)
 def test_every_op_a_model_scope_issued_carries_its_path(step_texts, kind):
-    rows = list(_scoped_instructions(step_texts(kind)))
+    check_paths(step_texts(kind), kind)
+
+
+def check_paths(text, kind):
+    rows = list(_scoped_instructions(text))
     paths = {scope for _, _, scope, _ in rows if scope}
     assert KINDS[kind][1] <= paths, KINDS[kind][1] - paths
     assert all(set(path.split("/")) <= set(SCOPES) for path in paths), paths
@@ -249,8 +259,12 @@ def test_the_head_loss_and_the_held_range_keep_their_scope_in_the_backward_rule(
                                   ("embed", True)}
 
 
-@pytest.mark.parametrize("kind", list(KINDS) + ["serving"])
+@pytest.mark.parametrize("kind", HERE + ["serving"])
 def test_scopes_change_names_metadata_and_the_attribute_only(monkeypatch, step_texts, kind):
+    check_scopes_alone(monkeypatch, step_texts, kind)
+
+
+def check_scopes_alone(monkeypatch, step_texts, kind):
     lower = _lower_mixed if kind == "serving" else functools.partial(
         _lower_loss, KINDS[kind][0], **KINDS[kind][2])
     with_scopes = _lower_mixed() if kind == "serving" else step_texts(kind)

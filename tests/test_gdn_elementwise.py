@@ -12,6 +12,7 @@ import pytest
 
 from ray_tpu.models import gdn
 from ray_tpu.models.llama import PRESETS, init_params
+from ray_tpu.ops import conv_tiles
 from ray_tpu.ops import gdn_elementwise as ge
 from ray_tpu.ops.trace_log import kernel_costs, kernel_traces
 
@@ -34,7 +35,7 @@ def tiles(monkeypatch):
     def set_rows(name):
         tile, chunk, positions = ROWS[name]
         monkeypatch.setattr(ge, "TILE_ROWS", tile)
-        monkeypatch.setattr(ge, "CHUNK_ROWS", chunk)
+        monkeypatch.setattr(conv_tiles, "CHUNK_ROWS", chunk)
         monkeypatch.setattr(ge, "TILE_LANES", 2 * D)
         return positions
     return set_rows
@@ -107,7 +108,7 @@ def test_the_conv_reaches_across_a_boundary_both_ways(tiles, boundary):
     it (the rows a tile reads from the block before it, a chunk from the
     chunk before it), and cotangents on those three rows reach back to it."""
     c, positions = _config(2, jnp.float32), tiles("three-tiles")
-    edge = ge.TILE_ROWS if boundary == "tile" else ge.CHUNK_ROWS
+    edge = ge.TILE_ROWS if boundary == "tile" else conv_tiles.CHUNK_ROWS
     qkv, conv_w, kernels, plain = _conv_sides(c, 1, positions)
     moved = qkv.at[:, edge - 1].add(1.0)
     after = slice(edge, edge + 3)
@@ -131,7 +132,7 @@ def test_the_shapes_the_kernels_take_and_what_a_call_costs():
     assert not ge.fits(128, 100, 4)           # rows in no whole unit
     assert not ge.fits(128, 8192, 3)          # the windows are a width-4 conv's
     assert ge._tiles(8192, 128, 2048, 4096) == (ge.TILE_ROWS, ge.TILE_LANES)
-    assert ge._tiles(192, 128, 256, 512) == (192, 256) and ge._chunks(192) == (192, 1)
+    assert ge._tiles(192, 128, 256, 512) == (192, 256) and conv_tiles.chunks(192) == (192, 1)
     assert ge._tiles(2048, 128, 384, 768) == (1024, 384)
     c = _config(2, jnp.bfloat16)
     qkv, conv_w, kernels, _ = _conv_sides(c, 2, 128)

@@ -69,10 +69,11 @@ def test_logits_and_loss_match_the_reference(model, program_step):
     (loss, aux), _ = program_step
     with jax.default_matmul_precision("highest"):
         got = jax.jit(lambda p: forward(p, tokens, CFG))(params)
+    # the reference as one program a call, not an eager op at a time
+    logits = jax.jit(lambda p, row: ref.logits(p, row, **ARCH)[0])
     for i in range(tokens.shape[0]):
-        want, _ = ref.logits(params, tokens[i], **ARCH)
-        assert float(ref.position_errors(got[i], want).max()) < 1e-4
-    want = ref.loss(params, tokens, aux_weight=CFG.moe_aux_weight, **ARCH)
+        assert float(ref.position_errors(got[i], logits(params, tokens[i])).max()) < 1e-4
+    want = jax.jit(lambda p: ref.loss(p, tokens, aux_weight=CFG.moe_aux_weight, **ARCH))(params)
     assert float(loss) == pytest.approx(float(want), abs=2e-5)
     # the counters: rows over all experts, over the held ones, nothing dropped
     n_rows = tokens.size * CFG.moe_top_k

@@ -12,7 +12,7 @@ import pytest
 
 from ray_tpu.models import mamba2
 from ray_tpu.models.llama import PRESETS, init_params
-from ray_tpu.ops import gdn_elementwise as ge
+from ray_tpu.ops import conv_tiles
 from ray_tpu.ops import mamba_elementwise as me
 from ray_tpu.ops.ssd import ssd_scan
 from ray_tpu.ops.trace_log import kernel_costs, kernel_traces
@@ -32,7 +32,7 @@ def tiles(monkeypatch):
     def set_rows(name):
         tile, chunk, positions = ROWS[name]
         monkeypatch.setattr(me, "TILE_ROWS", tile)
-        monkeypatch.setattr(ge, "CHUNK_ROWS", chunk)   # ``_chunks`` is imported from there
+        monkeypatch.setattr(conv_tiles, "CHUNK_ROWS", chunk)
         return positions
     return set_rows
 
@@ -97,7 +97,7 @@ def test_the_conv_reaches_across_a_boundary_both_ways(tiles, boundary, back):
     it, a chunk from the chunk before it) and no row further on; cotangents on
     the three rows after the boundary reach back to the three before it."""
     positions = tiles("three-tiles")
-    edge = me.TILE_ROWS if boundary == "tile" else ge.CHUNK_ROWS
+    edge = me.TILE_ROWS if boundary == "tile" else conv_tiles.CHUNK_ROWS
     x, taps, bias = _conv_operands(1, 2, positions, jnp.float32)
     moved = x.at[:, :, edge - back].add(1.0)
     reached = slice(edge, edge + me.TAPS - back)

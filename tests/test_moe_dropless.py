@@ -41,13 +41,15 @@ def _model(top_k, qk_norm, norm_topk, seed=0):
 def test_logits_loss_and_every_gradient_match_the_reference(top_k, qk_norm, norm_topk):
     cfg, params, arch = _model(top_k, qk_norm, norm_topk)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    want = jnp.stack([moe_decoder.logits(params, row, **arch)[0] for row in tokens])
-    np.testing.assert_allclose(forward(params, tokens, cfg), want, atol=2e-5)
-    (loss, aux), grads = jax.value_and_grad(
+    # each whole-stack pass, the reference's too, is one program, not an eager op at a time
+    row_logits = jax.jit(lambda p, row: moe_decoder.logits(p, row, **arch)[0])
+    want = jnp.stack([row_logits(params, row) for row in tokens])
+    np.testing.assert_allclose(jax.jit(lambda p: forward(p, tokens, cfg))(params), want, atol=2e-5)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"tokens": tokens}, cfg, return_aux=True),
-        has_aux=True)(params)
-    ref_loss, ref_grads = jax.value_and_grad(lambda p: moe_decoder.loss(
-        p, tokens, aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight, **arch))(params)
+        has_aux=True))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(lambda p: moe_decoder.loss(
+        p, tokens, aux_weight=cfg.moe_aux_weight, z_weight=cfg.moe_z_weight, **arch)))(params)
     assert float(loss) == pytest.approx(float(ref_loss), abs=2e-6)
     assert aux["rows_per_expert"].shape == (cfg.n_layers, cfg.moe_experts)
     assert int(aux["rows_dropped"]) == 0
@@ -150,7 +152,7 @@ def test_two_expert_shards_equal_one():
     np.testing.assert_allclose(jax.jit(sharded)(x, params), single(x, params), atol=1e-5)
     loss = lambda f: (lambda x, p: jnp.sum(jnp.square(f(x, p))))  # noqa: E731
     got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1)))(x, params)
-    want = jax.grad(loss(single), argnums=(0, 1))(x, params)
+    want = jax.jit(jax.grad(loss(single), argnums=(0, 1)))(x, params)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, atol=1e-4)
 

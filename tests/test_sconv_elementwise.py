@@ -14,7 +14,7 @@ import jaxpr_walk
 from test_mamba_elementwise import _close
 from ray_tpu.models import PRESETS, init_params, loss_fn, short_conv
 from ray_tpu.models.short_conv import gated_conv, sconv_mixer
-from ray_tpu.ops import gdn_elementwise as ge
+from ray_tpu.ops import conv_tiles
 from ray_tpu.ops import sconv_elementwise as se
 from ray_tpu.ops.trace_log import kernel_costs, kernel_traces
 
@@ -32,7 +32,7 @@ def tiles(monkeypatch):
         tile, chunk, positions = ROWS[name]
         monkeypatch.setattr(se, "TILE_ROWS", tile)
         monkeypatch.setattr(se, "TILE_LANES", 128)
-        monkeypatch.setattr(ge, "CHUNK_ROWS", chunk)   # ``_chunks`` is imported from there
+        monkeypatch.setattr(conv_tiles, "CHUNK_ROWS", chunk)
         return positions
     return set_rows
 
@@ -51,9 +51,8 @@ def _kernels(bcx, taps):
     return se.gated_conv3(bcx, taps, interpret=True)
 
 
-# a case compiles one program on the CPU, so batch and type go together; without
-# LLVM's passes, which the interpreter's loops would keep busy for a second a case
-UNOPTIMISED = {"xla_backend_optimization_level": 0, "xla_llvm_disable_expensive_passes": True}
+# a case compiles one program on the CPU, so batch and type go together (without
+# LLVM's passes, as every program of a run: ``conftest.py``'s ``XLA_FLAGS``)
 
 
 @pytest.mark.parametrize("rows,batch,dtype", [
@@ -73,8 +72,8 @@ def test_gated_conv3_matches_gated_conv(tiles, rows, batch, dtype):
             return out, share, pull((cotangent, jnp.zeros_like(share)))
         return side(_kernels), side(_plain)
 
-    both = jax.jit(both).lower(bcx, taps, cotangent).compile(compiler_options=UNOPTIMISED)
-    (got, got_share, got_grads), (want, want_share, want_grads) = both(bcx, taps, cotangent)
+    (got, got_share, got_grads), (want, want_share, want_grads) = jax.jit(both)(
+        bcx, taps, cotangent)
     assert got.dtype == want.dtype == dtype
     _close(got, want, dtype, "output")
     assert float(got_share) == pytest.approx(float(want_share), rel=1e-5)

@@ -159,20 +159,24 @@ def test_a_row_shorter_than_top_k_blocks_is_dense_causal_attention(params):
 
 
 def test_logits_loss_and_the_selection_against_the_reference(params, tokens):
-    hidden, aux = llama.forward_hidden(params, tokens, CFG, return_aux=True,
-                                       return_selection=True)
+    # each whole-stack pass, the reference's too, is one program a shape
+    hidden, aux = jax.jit(lambda p: llama.forward_hidden(
+        p, tokens, CFG, return_aux=True, return_selection=True))(params)
     sets = jnp.moveaxis(aux["selection"], 1, 0)           # [rows, layers, KV, S, NB]
     assert sets.shape == (2, 2, 2, SEQ, SEQ // 16)
-    logits = forward(params, tokens, CFG)
+    logits = jax.jit(lambda p: forward(p, tokens, CFG))(params)
+    given = jax.jit(lambda p, row, chosen: ref.logits(p, row, ARCH, chosen))
+    sorted_ = jax.jit(lambda p, row: ref.logits(p, row, ARCH)[0])
     for row in range(2):
-        want, own = ref.logits(params, tokens[row], ARCH, sets[row])
+        want, own = given(params, tokens[row], sets[row])
         assert float(ref.position_errors(logits[row], want).max()) < 2e-5
         assert ref.sets_agreement(np.asarray(own[0]), np.asarray(sets[row, 0]))["sets"] == 1.0
         # its own selection, by a sort: the same logits
-        assert float(ref.position_errors(logits[row], ref.logits(
-            params, tokens[row], ARCH)[0]).max()) < 2e-5
-    loss, counted = loss_fn(params, {"tokens": tokens}, CFG, chunk_tokens=64, return_aux=True)
-    assert float(loss) == pytest.approx(float(ref.loss(params, tokens, ARCH, sets)), rel=2e-6)
+        assert float(ref.position_errors(logits[row], sorted_(params, tokens[row])).max()) < 2e-5
+    loss, counted = jax.jit(lambda p: loss_fn(
+        p, {"tokens": tokens}, CFG, chunk_tokens=64, return_aux=True))(params)
+    want = jax.jit(lambda p: ref.loss(p, tokens, ARCH, sets))(params)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
     assert float(counted["attn_block_tile_share"]) == 1.0
     assert 0 < float(counted["attn_block_forced_share"]) < 1
     assert update_buffers(params, counted, CFG) is params
@@ -180,11 +184,14 @@ def test_logits_loss_and_the_selection_against_the_reference(params, tokens):
 
 def test_one_adafactor_step_and_the_block_at_a_time_gradient(params, tokens):
     tokens = tokens[:1]     # one row: the by-hand pass compiles a program a block
-    _, aux = llama.forward_hidden(params, tokens, CFG, return_aux=True, return_selection=True)
+    # each whole-stack pass is one program, not an eager op at a time
+    _, aux = jax.jit(lambda p: llama.forward_hidden(
+        p, tokens, CFG, return_aux=True, return_selection=True))(params)
     sets = jnp.moveaxis(aux["selection"], 1, 0)
-    grads = jax.grad(lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=64))(params)
+    grads = jax.jit(jax.grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, CFG, chunk_tokens=64)))(params)
     ref_loss, seen, by_name = ref.loss_and_grads(params, tokens, ARCH, sets)
-    whole = jax.grad(lambda p: ref.loss(p, tokens, ARCH, sets))(params)
+    whole = jax.jit(jax.grad(lambda p: ref.loss(p, tokens, ARCH, sets)))(params)
     named = lambda tree: {jax.tree_util.keystr(k): v for k, v in  # noqa: E731
                           jax.tree_util.tree_flatten_with_path(tree)[0]}
     assert seen["own_sets"].shape == (2, 2, SEQ, SEQ // 16)    # [layers, KV, S, NB]
@@ -195,7 +202,7 @@ def test_one_adafactor_step_and_the_block_at_a_time_gradient(params, tokens):
         assert _rel(leaf, by_name[name]) < 5e-5, name
         assert _rel(named(whole)[name], by_name[name]) < 5e-5, name
     opt = optax.adafactor(0.001)
-    step = lambda p, g: optax.apply_updates(p, opt.update(g, opt.init(p), p)[0])  # noqa: E731
+    step = jax.jit(lambda p, g: optax.apply_updates(p, opt.update(g, opt.init(p), p)[0]))
     got, want = step(params, grads), step(params, jax.tree_util.tree_unflatten(
         jax.tree.structure(params), [by_name[n] for n in named(params)]))
     for name, leaf in named(got).items():

@@ -127,19 +127,28 @@ def test_workflow_rerun_same_id_returns_checkpointed(ray_cluster, tmp_path):
 
 
 def test_independent_branches_run_concurrently(ray_cluster, tmp_path):
-    """Two independent 1.2s branches run at the same time — the executor
-    schedules every ready step (reference workflow_executor.py:32), not
-    one at a time. Read from the steps' own intervals on the host's
-    monotonic clock (one clock for every process of a Linux host), not
-    from the run's duration, which a loaded host stretches past any bound."""
+    """Two independent branches are in flight at the same time: the executor
+    schedules every ready step (reference workflow_executor.py:32), not one
+    at a time. Read as an order of events, not as an overlap of two sleeps:
+    each step says that it has started and then waits, bounded, until the
+    other has. A step ends only when both ran at once, however late the
+    cluster starts the second one's worker: the two share a scheduling key,
+    so while that worker is starting (longer than any sleep, beside busy
+    processes) a step that has ended hands ITS worker to the one still
+    queued, and two sleeps run one after the other with nothing serialized."""
 
     @workflow.step
-    def slow(tag):
+    def meet(tag, other, where):
+        import os
         import time
 
-        t0 = time.monotonic()
-        time.sleep(1.2)
-        return tag, t0, time.monotonic()
+        open(os.path.join(where, tag), "w").close()
+        deadline = time.monotonic() + 45.0
+        while not os.path.exists(os.path.join(where, other)):
+            if time.monotonic() > deadline:
+                return tag, False
+            time.sleep(0.02)
+        return tag, True
 
     @workflow.step
     def join(a, b):
@@ -147,12 +156,13 @@ def test_independent_branches_run_concurrently(ray_cluster, tmp_path):
 
     import time as _time
 
-    (tag_a, start_a, end_a), (tag_b, start_b, end_b) = workflow.run(
-        join(slow("a"), slow("b")), workflow_id=f"wf-par-{_time.time_ns()}",
-        storage=str(tmp_path))
+    started = tmp_path / "started"
+    started.mkdir()
+    (tag_a, met_a), (tag_b, met_b) = workflow.run(
+        join(meet("a", "b", str(started)), meet("b", "a", str(started))),
+        workflow_id=f"wf-par-{_time.time_ns()}", storage=str(tmp_path), step_timeout_s=60.0)
     assert tag_a + tag_b == "ab"
-    overlap = min(end_a, end_b) - max(start_a, start_b)
-    assert overlap > 0.6, f"branches serialized: they overlap by {overlap:.1f}s"
+    assert met_a and met_b, "branches serialized: one ended before the other had started"
 
 
 def test_continuation_extends_workflow(ray_cluster, tmp_path):
