@@ -43,6 +43,17 @@ kv head, a tile's rows are the kv head's whole group x a short query block.
 The mixer counts beside its output, where it has a window, ``window_share``:
 the (query, key) pairs it attends over the causal pairs, from the positions
 it was given.
+
+``SAVE_NAMES``, one tuple for both kinds, is what remat ``attn`` keeps of a
+block: q, k and v as the kernel takes them, the kernel's output and
+logsumexp, the gate, and since PR 59 ``kinds.POST_ATTN``, the residual stream
+as the mixer's output joins it (the stack's name: ``models/llama.py::_block``
+gives it). No gradient reads the mixer's output, so the block's second run
+then makes neither ``wo``'s product nor the add. A saved byte of that stream
+spares ``heads x head_dim`` FLOPs of second run (6,144-9,216 in Laguna, 3,584
+in SmallThinker, 2,048 in Granite's one layer) where a saved byte of q spares
+``hidden`` (3,072 / 2,560 / 2,048): never less. The SUM and not the mixer's
+output, which would be rounded once more before the add (``models/mla.py``).
 """
 
 from __future__ import annotations
@@ -56,9 +67,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ..observability.tracing import device_scope
 from ..ops import apply_rope
 from ..parallel.sharding import shard_constraint
-from .kinds import LayerKind, Yarn, flash_per_shard, sigmoid_gate, kept_keys, rope_keywords
+from .kinds import POST_ATTN, LayerKind, Yarn, flash_per_shard, sigmoid_gate, kept_keys, rope_keywords
 
-SAVE_NAMES = ("q", "k", "v", "attn_out", "attn_lse", "attn_gate")
+SAVE_NAMES = ("q", "k", "v", "attn_out", "attn_lse", "attn_gate", POST_ATTN)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -29,7 +29,9 @@ from ..ops.rope import yarn_frequencies
 # stream as the mixer's output joins it (``models/llama.py::_block`` gives
 # it). A mixer kind lists it in ``save_names`` to keep the stream under remat
 # ``attn``, and the block's second run then makes neither the mixer's output
-# product nor the add (the latent kinds: models/mla.py).
+# product nor the add (the latent kinds ``mla`` and ``mla_win``: models/mla.py;
+# the grouped-query kinds ``gqa`` and ``gqa_win``: models/gqa.py; the short
+# conv: models/short_conv.py).
 POST_ATTN = "post_attn"
 
 

@@ -654,7 +654,8 @@ def _block(x, layer, positions, config: LlamaConfig, mesh: Mesh | None,
         mixed, mixed_aux = mixed if isinstance(mixed, tuple) else (mixed, {})
         # a name and nothing else: a kind whose ``save_names`` lists it keeps
         # the stream under remat ``attn`` and its second run makes no ``wo``
-        # product (the latent kinds, models/mla.py); unlisted, no program moves
+        # product (the latent kinds, models/mla.py; the grouped-query kinds,
+        # models/gqa.py; the short conv); unlisted, no program moves
         x = checkpoint_name(
             x + sc(_scaled(mixed, c.residual_scale), ("batch", "seq", "embed_act")), POST_ATTN)
 
@@ -705,6 +706,12 @@ def _apply_remat(block, c: LlamaConfig, mixer: str = "attn", lead: bool = False)
         # the second run needs the mixer's output for nothing else, and
         # remaking it is ``wo``, 16,384 (128 heads) or 8,192 (64) FLOPs a
         # saved byte against 1,024-2,048 for q, k, v.
+        # The grouped-query kinds by spec (models/gqa.py's SAVE_NAMES, one
+        # tuple for ``gqa`` and ``gqa_win``) save the stream too since PR 59:
+        # ``heads x head_dim`` FLOPs a saved byte (6,144-9,216 in Laguna) and
+        # never less than ``hidden``, what a byte of their saved q spares.
+        # This module's own ``attn`` kind does not list it: ``train-4k`` has
+        # no room for 24 streams (15.12 GB).
         # The names are the kinds' own, the block's mixer's and its MLP's,
         # but for POST_ATTN, which ``_block`` gives and a mixer kind lists.
         return jax.checkpoint(
